@@ -48,8 +48,8 @@ are configurable the way the reference's are:
   more than momentum rounding does).
 
 All default to the r5 behavior (fp32 everywhere): existing callers and
-the parity tests are unchanged.  The fitting sweep behind the choices is
-recorded in BASELINE.md (gpt1p3b section).
+the parity tests are unchanged.  The analytic fitting table behind the
+choices is in ``transformer/testing/flagship.py``.
 """
 
 from __future__ import annotations
@@ -62,6 +62,30 @@ import jax.numpy as jnp
 import numpy as np
 
 from apex_tpu.multi_tensor.flat import FlatSchema, flatten, make_schema, unflatten
+
+
+def _pack(tree, schema: FlatSchema, dtype):
+    """:func:`flatten` into a superblock that stays a buffer of its own
+    (and :func:`_unpack`, the same for :func:`unflatten`'s input).
+
+    The step is pack -> elementwise update -> unpack.  Left free, XLA
+    moves the update's fp32 converts back through the pack and the
+    unpack's per-leaf reshapes back through the update, so the update
+    runs on full-size fp32 copies of the superblock, once per leaf
+    width, each relaid from the flat tiling to a 2-D one.  Compiled for
+    one v5e chip (jax 0.9.0, libtpu 0.0.34, PR 22) the six-layer
+    flagship step then asks for 14.0 GB and 200 s of compilation
+    against 5.9 GB and 7 s with the superblocks pinned, and the
+    four-layer (2, 2, 1) bucketed step for 590 s against 12.  (At
+    dp=4 the single-axis step's whole-buffer collectives happened to
+    pin the buffers already; a world of one has no collectives and the
+    bucketed step's are per slice.)"""
+    return jax.lax.optimization_barrier(
+        flatten(tree, schema, dtype=dtype)[0])
+
+
+def _unpack(flat, schema: FlatSchema):
+    return unflatten(jax.lax.optimization_barrier(flat), schema)
 
 
 class ShardedOptState(NamedTuple):
@@ -178,8 +202,7 @@ class DistributedShardedOptimizer:
         rank = jax.lax.axis_index(self.axis_name)
         shard = schema.total // world
 
-        flat_g, _ = flatten(grads, schema,
-                            dtype=self.scatter_dtype or jnp.float32)
+        flat_g = _pack(grads, schema, self.scatter_dtype or jnp.float32)
         # reduce-scatter: each rank receives the summed shard it owns
         # (in scatter_dtype — the reference's fp16 flat grad buffer);
         # the update math upcasts to fp32 inside the fused chain
@@ -192,7 +215,7 @@ class DistributedShardedOptimizer:
         # gather_dtype (the compressed delta is the transport narrowing)
         flat_dtype = (jnp.float32 if self.e5m2_allgather
                       else self.gather_dtype or jnp.float32)
-        flat_p, _ = flatten(params, schema, dtype=flat_dtype)
+        flat_p = _pack(params, schema, flat_dtype)
         p_shard = jax.lax.dynamic_slice_in_dim(
             flat_p, rank * shard, shard).astype(jnp.float32)
 
@@ -210,7 +233,7 @@ class DistributedShardedOptimizer:
             new_flat_p = jax.lax.all_gather(
                 new_p_shard.astype(flat_dtype), self.axis_name,
                 axis=0, tiled=True)
-        return unflatten(new_flat_p, schema), new_state
+        return _unpack(new_flat_p, schema), new_state
 
     def step_buckets(self, partial_grads, state: ShardedOptState, params,
                      schema: FlatSchema, plan):
@@ -268,10 +291,10 @@ class DistributedShardedOptimizer:
         plan.validate()
         shard = plan.shard
 
-        flat_g, _ = flatten(partial_grads, schema,
-                            dtype=self.scatter_dtype or jnp.float32)
+        flat_g = _pack(partial_grads, schema,
+                       self.scatter_dtype or jnp.float32)
         flat_dtype = self.gather_dtype or jnp.float32
-        flat_p, _ = flatten(params, schema, dtype=flat_dtype)
+        flat_p = _pack(params, schema, flat_dtype)
         # the canonical [world, shard] view: column block [:, lo:hi]
         # flattened rank-major is bucket b's reduce-scatter payload
         g_view = flat_g.reshape(plan.world, shard)
@@ -305,7 +328,7 @@ class DistributedShardedOptimizer:
             exp_avg=jnp.concatenate(new_m),
             exp_avg_sq=jnp.concatenate(new_v))
         new_flat_p = jnp.concatenate(new_cols, axis=1).reshape(-1)
-        return unflatten(new_flat_p, schema), new_state
+        return _unpack(new_flat_p, schema), new_state
 
 
 @dataclasses.dataclass(frozen=True)

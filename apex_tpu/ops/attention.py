@@ -175,21 +175,23 @@ def _segment_block_bounds(seg_q, seg_k, block_q, block_k):
 def _skip_spec_arg(lohi, gridded, n_rows):
     """(specs, args) tail for a block-skip index operand.
 
-    ``gridded`` True: the grid's second dim walks the rows of ``lohi``
-    (fwd q-blocks / bwd k-blocks) and each cell reads its own (1, 1, 2)
-    row.  False: one grid step takes the whole (1, n_rows, 2) table
-    (the varlen whole-sequence kernels).  ``lohi`` batch dim ∈ {bh, 1}
-    broadcasting like the seg operands."""
+    Every grid step takes the whole (1, n_rows, 2) table of its
+    batch-head: Mosaic requires a block's last two dims to be tile
+    multiples or the full array extent, so a per-row (1, 1, 2) block is
+    not lowerable once ``n_rows > 1``.  ``gridded`` True: the grid's
+    second dim walks the rows (fwd q-blocks / bwd k-blocks), the index
+    map ignores it (the table is fetched once per batch-head) and the
+    kernel reads row ``pl.program_id(1)``.  False: one grid step per
+    batch-head (the varlen whole-sequence kernels).  ``lohi`` batch dim
+    ∈ {bh, 1} broadcasting like the seg operands."""
     if lohi is None:
         return [], []
     one = lohi.shape[0] == 1
     if gridded:
-        specs = [pl.BlockSpec((1, 1, 2),
-                              lambda b, i, o=one: (0 if o else b, i, 0))]
+        index_map = lambda b, i, o=one: (0 if o else b, 0, 0)
     else:
-        specs = [pl.BlockSpec((1, n_rows, 2),
-                              lambda b, o=one: (0 if o else b, 0, 0))]
-    return specs, [lohi]
+        index_map = lambda b, o=one: (0 if o else b, 0, 0)
+    return [pl.BlockSpec((1, n_rows, 2), index_map)], [lohi]
 
 
 # ---------------------------------------------------------------------------
@@ -254,8 +256,8 @@ def _make_fwd_kernel(*, scale, causal, block_q, block_k, sq, sk,
             # block-skip index: only k blocks in [lo, hi) can contain a
             # visible (seg_q == seg_k) pair for this q block — padding
             # tails and cross-segment blocks never enter the loop
-            kb_lo = skip_ref[0, 0, 0]
-            n_grp = skip_ref[0, 0, 1]
+            kb_lo = skip_ref[0, pl.program_id(1), 0]
+            n_grp = skip_ref[0, pl.program_id(1), 1]
         if causal:
             # dynamic trip count: skip k blocks strictly above this q
             # block's last row (fully masked) — halves the MXU work
@@ -318,7 +320,7 @@ def _merge_parts(parts):
     chain stays short while every tile's two MXU dots remain mutually
     independent — the scheduler can overlap VPU softmax work of one tile
     with MXU dots of another (measured: independent d=64 dots run at
-    ~95 TF on v5e vs 47 TF when chained; BASELINE.md r5 notes)."""
+    ~95 TF on v5e vs 47 TF when chained, r5)."""
     while len(parts) > 1:
         nxt = []
         for a in range(0, len(parts) - 1, 2):
@@ -914,8 +916,8 @@ def _make_fused_bwd_kernel(*, scale, causal, block_q, block_k, sq, sk,
             # hold a visible pair with this k block — a skipped tile
             # contributes 0 to dk/dv here AND to its own dq (identical
             # to the computed-and-masked result, minus the MXU work)
-            qb0 = jnp.maximum(qb0, skip_ref[0, 0, 0])
-            qb1 = skip_ref[0, 0, 1]
+            qb0 = jnp.maximum(qb0, skip_ref[0, j, 0])
+            qb1 = skip_ref[0, j, 1]
 
         def body(qb, _):
             qi = qb * block_q
@@ -1447,7 +1449,7 @@ _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # The GPT path pays ~10 ms/step (B=8, s=1024) of pure layout churn
 # around the [bh, s, d] kernels: transposes of q/k/v ([b,s,np,hn] ->
 # [b,np,s,hn]) in forward AND in the attn_res recompute, plus the
-# reshape copies of dq/dk/dv back to [b, s, h] (r5 trace, BASELINE.md).
+# reshape copies of dq/dk/dv back to [b, s, h] (r5 trace).
 # These kernels instead consume the qkv projection output DIRECTLY in
 # its Megatron-interleaved layout — [b, s, np*(q64|k64|v64)] — slicing
 # each head's q/k/v statically from the lane dimension (64-granularity
@@ -1618,9 +1620,19 @@ def _make_bwd_kernel_qkv(*, scale, causal, block, s, hn, group,
                         * o_ref[0, pl.ds(i * block, block),
                                 ob:ob + hn].astype(jnp.float32), axis=-1)
                 for i in range(n_b)]
+
+            def blocksum(parts):
+                # cast each block's fp32 tree-sum to the OUTPUT dtype
+                # here rather than at the joint store: the held
+                # per-head grads are the largest resident term of
+                # _qkv_packed_ok's VMEM estimate, and the cast happens
+                # either way (bitwise-identical result, half the bytes
+                # held for bf16)
+                return (_tree_sum(parts).astype(dqkv_ref.dtype) if parts
+                        else jnp.zeros((block, hn), dqkv_ref.dtype))
+
             dq_parts = [[] for _ in range(n_b)]
-            dk_parts = [[] for _ in range(n_b)]
-            dv_parts = [[] for _ in range(n_b)]
+            dks, dvs = [], []
             for kb in range(n_b):
                 ki = kb * block
                 k = qkv_ref[0, pl.ds(ki, block), base + hn:base + 2 * hn]
@@ -1628,6 +1640,7 @@ def _make_bwd_kernel_qkv(*, scale, causal, block, s, hn, group,
                             base + 2 * hn:base + 3 * hn]
                 seg_k = (segk_ref[0, pl.ds(ki, block), 0]
                          if has_seg else None)
+                dk_parts, dv_parts = [], []
                 for qb in range(n_b):
                     qi = qb * block
                     if causal and qi < ki:
@@ -1654,31 +1667,24 @@ def _make_bwd_kernel_qkv(*, scale, causal, block, s, hn, group,
                         dp = jnp.where(keep, dp, 0.0) * inv
                     else:
                         p_drop = p
-                    dv_parts[kb].append(jax.lax.dot_general(
+                    dv_parts.append(jax.lax.dot_general(
                         p_drop.astype(do.dtype), do,
                         (((0,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32))
                     ds = p * (dp - deltas[qb][:, None]) * scale
-                    dk_parts[kb].append(jax.lax.dot_general(
+                    dk_parts.append(jax.lax.dot_general(
                         ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32))
                     dq_parts[qb].append(jax.lax.dot_general(
                         ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32))
+                # this k-block's dk/dv are complete once its q-blocks
+                # are walked: sum them now, so the fp32 partials of one
+                # k-block are live at a time and not those of all
+                dks.append(blocksum(dk_parts))
+                dvs.append(blocksum(dv_parts))
 
-            def blocksum(parts):
-                # cast each block's fp32 tree-sum to the OUTPUT dtype
-                # here rather than at the joint store: the held
-                # per-head grads are the largest resident term of
-                # _qkv_packed_ok's VMEM estimate, and the cast happens
-                # either way (bitwise-identical result, half the bytes
-                # held for bf16)
-                return [(_tree_sum(p).astype(dqkv_ref.dtype) if p
-                         else jnp.zeros((block, hn), dqkv_ref.dtype))
-                        for p in parts]
-
-            head_grads.append((blocksum(dq_parts), blocksum(dk_parts),
-                               blocksum(dv_parts)))
+            head_grads.append(([blocksum(p) for p in dq_parts], dks, dvs))
         for i in range(n_b):
             cols = []
             for dqs, dks, dvs in head_grads:
@@ -1690,6 +1696,14 @@ def _make_bwd_kernel_qkv(*, scale, causal, block, s, hn, group,
 
 
 _QKV_VMEM_BUDGET = 12 * 1024 * 1024
+# What the backward may actually allocate.  The gate above prices the
+# pipeline buffers and the held output-dtype grads; the compiler also
+# keeps the unrolled tiles' fp32 dq partials on its stack until each
+# q-block's tree-sum, which the estimate cannot see.  At the 1.3B
+# flagship shape (s=2048, d=128, block 256) Mosaic asks for 16.3 MiB
+# against an estimate of 10.4 and its own default limit of 16 (a v5e
+# core has 128), so the limit is named here, at twice the default.
+_QKV_BWD_VMEM_LIMIT = 32 * 1024 * 1024
 
 
 def _qkv_packed_ok(b, s, num_heads, hn, block, causal, dropout_rate,
@@ -1829,6 +1843,8 @@ def _flash_qkv_bwd_pallas(qkv, dropout_seed, ctx, lse, dctx, num_heads,
         ] + seg_specs + seed_specs,
         out_specs=pl.BlockSpec((1, s, w), lambda bi, g: (bi, 0, g)),
         out_shape=jax.ShapeDtypeStruct(qkv.shape, qkv.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_QKV_BWD_VMEM_LIMIT),
         interpret=use_interpret(),
     )(qkv, dctx, ctx, lse, *seg_args, *seed_args)
     return dqkv
@@ -2148,28 +2164,34 @@ def flash_attention_varlen(
 # request's cache is a *page list*, not a slab.  The decode kernel
 # consumes that layout directly: the page table rides in as a
 # scalar-prefetch operand and DRIVES THE BLOCK INDEX MAP — grid step
-# (b, h, p) DMAs pool page ``page_table[b, p]`` into VMEM, so the
+# (b, p) DMAs pool page ``page_table[b, p]`` into VMEM, so the
 # gather that the generic XLA baseline materialises in HBM never
-# happens.  Per-request raggedness is the same trick as the varlen
-# block-skip index: the k-loop (here the page grid dimension) is
-# bounded by the request's page count — pages past ``kv_len`` are
-# predicated off with ``pl.when`` (and, because table rows pad with
-# page 0, their repeated block index elides the dead DMAs too).  The
-# online-softmax carry lives in VMEM scratch across the page steps of
-# one (b, h) cell (the TPU grid is sequential, innermost-last), exactly
-# like the fused backward's persistent dq accumulator.
+# happens.  A block is a WHOLE page, every head of it:
+# ``(1, page_size, h, d)`` ends in the pool's own ``(h, d)`` extent,
+# which is what Mosaic's block rule asks of the last two dims (a unit
+# block on the head axis is neither a multiple of 8 nor the full
+# extent and does not lower), and it is one contiguous DMA per page.
+# The heads are then a static loop inside the kernel.  Per-request
+# raggedness is the same trick as the varlen block-skip index: the
+# k-loop (here the page grid dimension) is bounded by the request's
+# page count — pages past ``kv_len`` are predicated off with
+# ``pl.when`` (and, because table rows pad with page 0, their repeated
+# block index elides the dead DMAs too).  The online-softmax carry
+# lives in VMEM scratch across the page steps of one request (the TPU
+# grid is sequential, innermost-last), exactly like the fused
+# backward's persistent dq accumulator.
 # ---------------------------------------------------------------------------
 
 
-def _make_decode_kernel(*, scale, page_size, q_len, d, quantized=False):
-    """Decode forward: grid (b, h, p_max); scalar-prefetch operands
+def _make_decode_kernel(*, scale, page_size, q_len, h, d, quantized=False):
+    """Decode forward: grid (b, p_max); scalar-prefetch operands
     (page_table [b, p_max], kv_len [b]).  Queries are the LAST ``q_len``
     positions of the request's ``kv_len``-token cache (their own k/v
     already appended), so row i's causal limit is column
     ``kv_len - q_len + i``.
 
     ``quantized`` adds two per-(page, slot, head) fp32 scale operands
-    (blocks [1, page_size, 1]) and dequantizes K/V *in-register* right
+    (blocks [1, page_size, h]) and dequantizes K/V *in-register* right
     after the page DMA — the narrow pool bytes are what crosses HBM,
     the fp32 view never exists outside VMEM (r17)."""
 
@@ -2179,59 +2201,59 @@ def _make_decode_kernel(*, scale, page_size, q_len, d, quantized=False):
         else:
             o_ref, m_ref, l_ref, acc_ref = rest
         b_idx = pl.program_id(0)
-        p = pl.program_id(2)
-        n_p = pl.num_programs(2)
+        p = pl.program_id(1)
+        n_p = pl.num_programs(1)
         kv = kl_ref[b_idx]
         pages_used = (kv + page_size - 1) // page_size
 
         @pl.when(p == 0)
         def _():
-            m_ref[...] = jnp.full((q_len, 1), _NEG_INF, jnp.float32)
-            l_ref[...] = jnp.zeros((q_len, 1), jnp.float32)
-            acc_ref[...] = jnp.zeros((q_len, d), jnp.float32)
+            m_ref[...] = jnp.full((h, q_len, 1), _NEG_INF, jnp.float32)
+            l_ref[...] = jnp.zeros((h, q_len, 1), jnp.float32)
+            acc_ref[...] = jnp.zeros((h, q_len, d), jnp.float32)
 
         @pl.when(p < pages_used)
         def _():
-            q = q_ref[0, 0]          # [q_len, d]
-            k = k_ref[0, :, 0, :]    # [page_size, d]
-            v = v_ref[0, :, 0, :]
-            if quantized:
-                # ks_ref/vs_ref blocks are [1, page_size, 1]; [0] keeps
-                # the trailing unit dim so the multiply broadcasts over
-                # the lane (d) axis without a 1-D reshape
-                q = q.astype(jnp.float32)
-                k = k.astype(jnp.float32) * ks_ref[0]
-                v = v.astype(jnp.float32) * vs_ref[0]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = p * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            # one mask does both jobs: the causal limit for the q_len
-            # tail AND the kv_len cutoff (row i's limit kv - q_len + i
-            # is < kv, so garbage past the ragged end never scores)
-            s = jnp.where(cols <= kv - q_len + rows, s, _NEG_INF)
-            m_prev = m_ref[...]
-            m_new = jnp.maximum(m_prev,
-                                jnp.max(s, axis=-1, keepdims=True))
-            pexp = _masked_exp(s, m_new)
-            # a page whose every column is masked for some row leaves
-            # that row's m at -inf: guard the rescale like _merge_parts
-            alpha = jnp.where(m_prev <= _NEG_INF / 2, 0.0,
-                              jnp.exp(m_prev - m_new))
-            l_ref[...] = alpha * l_ref[...] + jnp.sum(pexp, axis=-1,
-                                                      keepdims=True)
-            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-                pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[...] = m_new
+            for hi in range(h):
+                q = q_ref[0, hi]          # [q_len, d]
+                k = k_ref[0, :, hi, :]    # [page_size, d]
+                v = v_ref[0, :, hi, :]
+                if quantized:
+                    q = q.astype(jnp.float32)
+                    k = k.astype(jnp.float32) * ks_ref[0, :, hi][:, None]
+                    v = v.astype(jnp.float32) * vs_ref[0, :, hi][:, None]
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                cols = p * page_size + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 1)
+                # one mask does both jobs: the causal limit for the
+                # q_len tail AND the kv_len cutoff (row i's limit
+                # kv - q_len + i is < kv, so garbage past the ragged
+                # end never scores)
+                s = jnp.where(cols <= kv - q_len + rows, s, _NEG_INF)
+                m_prev = m_ref[hi]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                pexp = _masked_exp(s, m_new)
+                # a page whose every column is masked for some row
+                # leaves that row's m at -inf: guard the rescale like
+                # _merge_parts
+                alpha = jnp.where(m_prev <= _NEG_INF / 2, 0.0,
+                                  jnp.exp(m_prev - m_new))
+                l_ref[hi] = alpha * l_ref[hi] + jnp.sum(
+                    pexp, axis=-1, keepdims=True)
+                acc_ref[hi] = acc_ref[hi] * alpha + jax.lax.dot_general(
+                    pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                m_ref[hi] = m_new
 
         @pl.when(p == n_p - 1)
         def _():
             l = l_ref[...]
             l_safe = jnp.where(l == 0, 1.0, l)
-            o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+            o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
     return kernel
 
@@ -2246,39 +2268,32 @@ def _flash_decode_pallas(q, k_pages, v_pages, page_table, kv_len, scale,
     page_size = k_pages.shape[1]
     p_max = page_table.shape[1]
     quantized = k_scale is not None
-    in_specs = [
-        pl.BlockSpec((1, 1, q_len, d),
-                     lambda bi, hi, p, pt, kl: (bi, hi, 0, 0)),
-        pl.BlockSpec((1, page_size, 1, d),
-                     lambda bi, hi, p, pt, kl: (pt[bi, p], 0, hi, 0)),
-        pl.BlockSpec((1, page_size, 1, d),
-                     lambda bi, hi, p, pt, kl: (pt[bi, p], 0, hi, 0)),
-    ]
+    q_spec = pl.BlockSpec((1, h, q_len, d),
+                          lambda bi, p, pt, kl: (bi, 0, 0, 0))
+    page_spec = pl.BlockSpec((1, page_size, h, d),
+                             lambda bi, p, pt, kl: (pt[bi, p], 0, 0, 0))
+    in_specs = [q_spec, page_spec, page_spec]
     operands = [q, k_pages, v_pages]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, page_size, 1),
-                         lambda bi, hi, p, pt, kl: (pt[bi, p], 0, hi)),
-            pl.BlockSpec((1, page_size, 1),
-                         lambda bi, hi, p, pt, kl: (pt[bi, p], 0, hi)),
-        ]
+        scale_spec = pl.BlockSpec((1, page_size, h),
+                                  lambda bi, p, pt, kl: (pt[bi, p], 0, 0))
+        in_specs += [scale_spec, scale_spec]
         operands += [k_scale.astype(jnp.float32),
                      v_scale.astype(jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h, p_max),
+        grid=(b, p_max),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, q_len, d),
-                               lambda bi, hi, p, pt, kl: (bi, hi, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((q_len, 1), jnp.float32),
-            pltpu.VMEM((q_len, 1), jnp.float32),
-            pltpu.VMEM((q_len, d), jnp.float32),
+            pltpu.VMEM((h, q_len, 1), jnp.float32),
+            pltpu.VMEM((h, q_len, 1), jnp.float32),
+            pltpu.VMEM((h, q_len, d), jnp.float32),
         ],
     )
     return pl.pallas_call(
         _make_decode_kernel(scale=scale, page_size=page_size,
-                            q_len=q_len, d=d, quantized=quantized),
+                            q_len=q_len, h=h, d=d, quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, q_len, d), q.dtype),
         interpret=use_interpret(),
@@ -2338,7 +2353,7 @@ def _decode_tpu_ok(q):
     """The EXTRA constraint auto-routing applies before picking the
     kernel on a real TPU: the head dim is the block's lane extent and
     must be a whole number of 128-lane tiles for Mosaic to lower the
-    (1, page_size, 1, d) K/V blocks.  Conservative by design — the
+    (1, page_size, h, d) K/V blocks.  Conservative by design — the
     flagship geometry (d=128) passes; a forced "decode" skips this
     (interpret mode has no lane constraint, and on-TPU forcing is the
     caller's explicit opt-in, same contract as the fwd/bwd tables)."""

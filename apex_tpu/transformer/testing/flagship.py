@@ -2,8 +2,8 @@
 
 The benched flagship was pinned for five rounds to GPT-350M (h=1024,
 16 heads → d=64), a shape whose head dim half-fills the MXU contraction
-lanes and caps attention at the measured 54.9 TF dot floor (BASELINE.md
-r5).  This module stands up the shape the hardware likes — **h=2048,
+lanes and caps attention at the measured 54.9 TF dot floor (r5).  This
+module stands up the shape the hardware likes — **h=2048,
 16 heads → d=128, seq 2048** (~1.32 B params with the 51200 vocab) —
 as a first-class configuration, plus the memory-fit machinery a 1.3B
 model needs on a 16 GB chip.
@@ -22,11 +22,11 @@ data path — per-bucket reduce-scatter/all-gather over partial grads,
 docs/performance.md "Overlap-aware ZeRO".
 
 Fit plans — why a 15.75-GiB (16.9e9-byte) chip needs one (1.32 B
-params; bytes in GB, world=1):
+params; bytes in GB, world=1).  The table is ANALYTIC:
 
 =============  ======  =====  =========  ==================  ========
-plan           params  grads  m / v      optimizer-phase     fits?
-                                         peak (see note)
+plan           params  grads  m / v      optimizer-phase     under
+                                         peak (see note)     16.9 GB?
 =============  ======  =====  =========  ==================  ========
 fp32           5.3     5.3    5.3 / 5.3  26.4 GB             no
 bf16_fp32m     2.6     2.6    5.3 / 5.3  18.5 GB             no
@@ -37,8 +37,12 @@ Peak note: the ZeRO step packs grads and params into flat superblocks,
 so the optimizer-phase live set is m + v + flat grads + 2× flat params
 (old tree and grad tree freed by donation — ``donate=True`` below is
 load-bearing, not an optimization).  :func:`flagship_state_bytes`
-computes both columns; BASELINE.md (gpt1p3b section) carries the full
-table with the measured counterpart from the chip.
+computes both columns.  The compiler does not reach the analytic peak:
+on jax 0.9.0 / libtpu 0.0.34, XLA's memory report for the 24-layer
+``bf16_fit`` step on one v5e chip asks for 24.6 GiB of the chip's
+15.75, at batch 4 and at batch 1 alike (arguments 12.3, program 12.3),
+and 13 layers at full width are the most it places (PR 22; PERF.md,
+"Where the time goes").
 
 ``bf16_fit`` keeps the variance (the adaptive step size) fp32 and
 narrows params/grads/momentum to bf16; the update math itself always
@@ -51,12 +55,13 @@ fp32 FusedAdam is asserted on the emulated mesh in
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from apex_tpu.contrib.optimizers import DistributedFusedAdam
 from apex_tpu.multi_tensor.buckets import DEFAULT_BUCKET_BYTES, plan_buckets
@@ -146,7 +151,7 @@ FIT_PLANS = {
 def flagship_state_bytes(cfg: GPTConfig, plan: ZeroFitPlan,
                          n_shards: int = 1) -> dict:
     """Analytic persistent-state + grad bytes for the fitting table
-    (BASELINE.md gpt1p3b section); activations/logits excluded."""
+    of the module docstring; activations/logits excluded."""
     n = gpt_param_count(cfg)
     it = lambda d: jnp.dtype(d).itemsize
     out = {
@@ -188,6 +193,27 @@ class FlagshipSetup(NamedTuple):
     # the ISSUE 15 bucketed-overlap plan the 3-D step compiled with
     # (None on the single-axis path and the legacy serialized control)
     bucket_plan: Any = None
+
+
+def _place_state(mesh, params, opt, schema, lead_shape, opt_spec):
+    """(params, opt_state) committed to ``mesh`` with the shardings the
+    step runs under: params replicated, the zero optimizer state built
+    already sharded, each device materializing only its own slice.
+
+    Left uncommitted, both would sit whole on the first device (at
+    world=4 the stacked state alone is the size of an unsharded one)
+    and the step would copy them onto the mesh on every call, so its
+    donation could not alias."""
+    params = jax.device_put(params, NamedSharding(mesh, P()))
+
+    def zeros():
+        return jax.tree_util.tree_map(
+            lambda a: jnp.broadcast_to(a, (*lead_shape, *a.shape)),
+            opt.init(params, schema, math.prod(lead_shape)))
+
+    opt_state = jax.jit(
+        zeros, out_shardings=NamedSharding(mesh, opt_spec))()
+    return params, opt_state
 
 
 def build_flagship_train_step(
@@ -285,11 +311,9 @@ def build_flagship_train_step(
         gather_dtype=plan.gather_dtype,
         exp_avg_dtype=plan.exp_avg_dtype)
     schema = opt.make_schema(params, n_shards)
-    state0 = opt.init(params, schema, n_shards)
-    # per-rank state with an explicit leading shard axis (every rank's
-    # init is zeros, so a broadcast is exact)
-    opt_state = jax.tree_util.tree_map(
-        lambda a: jnp.broadcast_to(a[None], (n_shards, *a.shape)), state0)
+    # per-rank state with an explicit leading shard axis
+    params, opt_state = _place_state(mesh, params, opt, schema,
+                                     (n_shards,), P("data"))
 
     def inner(p, state, tokens, labels):
         state = jax.tree_util.tree_map(lambda a: a[0], state)
@@ -389,11 +413,9 @@ def _build_flagship_train_step_3d(cfg, *, plan, lr, weight_decay, devs,
         exp_avg_dtype=plan.exp_avg_dtype,
         axis_name=tuple(parallel_state.MESH_AXES))
     schema = opt.make_schema(master, world)
-    state0 = opt.init(master, schema, world)
-    opt_state = jax.tree_util.tree_map(
-        lambda a: jnp.broadcast_to(a[None, None, None],
-                                   (dp, pp, tp, *a.shape)), state0)
     spec3 = P(*parallel_state.MESH_AXES)
+    master, opt_state = _place_state(mesh, master, opt, schema,
+                                     (dp, pp, tp), spec3)
     mesh_axes = {parallel_state.DATA_AXIS: dp,
                  parallel_state.PIPELINE_AXIS: pp,
                  parallel_state.TENSOR_AXIS: tp}

@@ -450,7 +450,7 @@ class ParallelTransformer:
                 # 4h² in the recompute term accordingly).  Costs
                 # +b·s·4h·2B per layer over attn_res (64 MB at the
                 # 350M bench shape); measured LOSING to attn_res at
-                # B=8/16 (BASELINE.md r5 sweep)
+                # B=8/16 (r5 sweep)
                 policy = jax.checkpoint_policies.save_only_these_names(
                     "flash_attn_out", "flash_attn_lse", "mlp_4h")
             elif self.cfg.remat_policy == "attn_out":
@@ -461,7 +461,7 @@ class ParallelTransformer:
                 # residuals, so remat re-runs the kernel to rebuild them
                 # (only attn_res skips the kernel re-run; bench.py's
                 # hw-flops accounting sets remat_attn=True here).
-                # Measured ~7% off the step at B=8 (BASELINE.md r4 sweep)
+                # Measured ~7% off the step at B=8 (r4 sweep)
                 policy = jax.checkpoint_policies.save_only_these_names(
                     "attn_out")
             elif self.cfg.remat_policy == "full":

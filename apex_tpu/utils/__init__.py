@@ -6,3 +6,4 @@ from apex_tpu.utils.tree import (  # noqa: F401
     tree_size,
     tree_zeros_like,
 )
+from apex_tpu.utils.compile_cache import configure_compile_cache  # noqa: F401

@@ -1,0 +1,414 @@
+#!/usr/bin/env python3
+"""Start the system on the chip, once, through the entry points a user calls.
+
+    python3 chip_smoke.py
+
+One process, one pass, no options.  It needs a TPU: where
+``jax.default_backend()`` is anything else it exits 2 before doing any
+work, and it has no CPU mode.  With random weights made from a seed it
+
+* checks one forward+backward of packed-QKV attention and four
+  ``flash_decode`` calls against the XLA routes at the flagship shapes;
+* trains the full-width GPT-1.3B flagship step (``bf16_fit`` ZeRO plan)
+  for a few steps on a fixed batch: finite, falling loss;
+* serves a seeded Poisson trace on the 1.3B-geometry ``ServingEngine``,
+  then the same prompts again: every request completes, nothing
+  compiles after warm-up, the streams repeat;
+* warms the verify, chunk and int8 executables at two layers;
+* with four or more devices, repeats train on a ``(2, 2, 1)`` mesh and
+  serve at ``tp=4`` and checks that state is spread over the mesh.
+
+Width is never cut.  Depth is: the train legs run ``TRAIN_LAYERS`` of
+the model's 24 layers, because XLA needs 24.6 GiB for the 24-layer
+``bf16_fit`` step on a 15.75 GiB chip, at batch 1 as at batch 4
+(PERF.md, "Where the time goes").  Times and bytes are printed as information;
+no utilization is computed.  Any failed check is a non-zero exit.  The
+last line of standard output is one JSON object,
+``{"ok": true, "device": {...}}``.
+"""
+
+from __future__ import annotations
+
+import functools
+import gc
+import json
+import sys
+import time
+import warnings
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from apex_tpu import _native
+from apex_tpu.analysis import hot_path_guard
+from apex_tpu.ops import (flash_attention_qkv, flash_attention_qkv_route,
+                          flash_attention_route, flash_decode,
+                          flash_decode_route, routing_override)
+from apex_tpu.serving import (ServingEngine, ServingModelConfig, SpecConfig,
+                              poisson_trace)
+from apex_tpu.transformer import parallel_state
+from apex_tpu.transformer.testing import (build_flagship_train_step,
+                                          gpt1p3b_config)
+from apex_tpu.utils import configure_compile_cache
+
+# Of 24.  The compiler places 13 layers at most on one v5e chip; 12
+# leave it about 1 GiB of the 15.75 (see the module docstring).
+TRAIN_LAYERS = 12
+WARM_LAYERS = 2
+
+ROUTES_ON_TPU = {"decode": "decode", "qkv": "packed", "prefill_fwd": "varlen"}
+
+# bf16 against an fp32-accumulating XLA reference: relative L2 error.
+FWD_TOL = 2e-2
+GRAD_TOL = 3e-2
+# Chips holding equal shards of one program's state.
+BALANCE_FACTOR = 1.5
+
+
+class SmokeFailure(RuntimeError):
+    """A check of the smoke did not hold."""
+
+
+def require(ok: bool, what: str) -> None:
+    if not ok:
+        raise SmokeFailure(what)
+
+
+def rel_l2(got, want) -> float:
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+def memory_by_device() -> list:
+    """[{"in_use", "peak", "limit"}] per device; None where the
+    backend keeps no statistics."""
+    out = []
+    for dev in jax.devices():
+        stats = dev.memory_stats()
+        out.append(None if stats is None else {
+            "in_use": stats.get("bytes_in_use"),
+            "peak": stats.get("peak_bytes_in_use"),
+            "limit": stats.get("bytes_limit")})
+    return out
+
+
+def require_on_whole_mesh(tree, mesh, what: str) -> None:
+    want = set(mesh.devices.flat)
+    for leaf in jax.tree_util.tree_leaves(tree):
+        require(set(leaf.sharding.device_set) == want,
+                f"{what}: a leaf lives on {len(leaf.sharding.device_set)} "
+                f"of the mesh's {len(want)} devices")
+
+
+def require_balanced(what: str) -> None:
+    used = [m["in_use"] for m in memory_by_device() if m is not None]
+    if used:
+        require(max(used) <= BALANCE_FACTOR * min(used),
+                f"{what}: bytes in use differ by more than "
+                f"{BALANCE_FACTOR}x across chips: {used}")
+
+
+def free_device_memory() -> None:
+    gc.collect()
+    jax.clear_caches()
+
+
+# -- legs ---------------------------------------------------------------------
+
+def leg_kernels(*, batch, seq, heads, head_dim, block, pages, page_size,
+                max_batch, pages_per_request, seed=0) -> dict:
+    """The attention kernels against the XLA routes the repo keeps."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 8)
+    routes = {
+        "qkv": flash_attention_qkv_route(batch, seq, heads, head_dim,
+                                         block=block),
+    }
+    qkv = jax.random.normal(keys[0], (batch, seq, heads * 3 * head_dim),
+                            jnp.bfloat16)
+    dctx = jax.random.normal(keys[1], (batch, seq, heads * head_dim),
+                             jnp.bfloat16)
+
+    def fwd_bwd(qkv, dctx, **kw):
+        ctx, vjp = jax.vjp(lambda x: flash_attention_qkv(
+            x, heads, causal=True, block=block, **kw), qkv)
+        return ctx, vjp(dctx)[0]
+
+    ctx, dqkv = jax.jit(fwd_bwd)(qkv, dctx)
+    with routing_override(fwd="xla", bwd="xla"):
+        # a block_k differing from block is the wrapper's generic path
+        ctx_ref, dqkv_ref = jax.jit(functools.partial(
+            fwd_bwd, block_k=block // 2))(qkv, dctx)
+    errors = {"qkv_fwd": rel_l2(ctx, ctx_ref),
+              "qkv_bwd": rel_l2(dqkv, dqkv_ref)}
+
+    rng = np.random.RandomState(seed)
+    table = jnp.asarray(rng.randint(
+        1, pages, (max_batch, pages_per_request)), jnp.int32)
+    for name, q_len, quantized in (("decode", 1, False),
+                                   ("verify", 5, False),
+                                   ("chunk", 128, False),
+                                   ("decode_int8", 1, True)):
+        q = jax.random.normal(
+            keys[2], (max_batch, heads, q_len, head_dim), jnp.bfloat16)
+        pool_shape = (pages, page_size, heads, head_dim)
+        kv_len = jnp.asarray(rng.randint(
+            q_len, pages_per_request * page_size + 1, (max_batch,)),
+            jnp.int32)
+        if quantized:
+            k = jax.random.randint(keys[3], pool_shape, -127, 128, jnp.int8)
+            v = jax.random.randint(keys[4], pool_shape, -127, 128, jnp.int8)
+            scales = dict(
+                k_scale=jax.random.uniform(keys[5], pool_shape[:3]) / 64,
+                v_scale=jax.random.uniform(keys[6], pool_shape[:3]) / 64)
+        else:
+            k = jax.random.normal(keys[3], pool_shape, jnp.bfloat16)
+            v = jax.random.normal(keys[4], pool_shape, jnp.bfloat16)
+            scales = {}
+        routes.setdefault("decode", flash_decode_route(q, k))
+
+        def decode(**route):
+            # a fresh jit each time: the route is chosen while tracing
+            with routing_override(**route):
+                return jax.jit(lambda a, kw: flash_decode(*a, **kw))(
+                    (q, k, v, table, kv_len), scales)
+
+        errors[name] = rel_l2(decode(), decode(decode="xla"))
+
+    for name, err in errors.items():
+        require(np.isfinite(err), f"kernels: {name} is not finite")
+        require(err <= (GRAD_TOL if name == "qkv_bwd" else FWD_TOL),
+                f"kernels: {name} differs from the XLA route by {err:.3e}")
+    return {"routes": routes, "rel_l2": errors}
+
+
+def leg_train(cfg, *, batch_per_chip, seq, steps, mesh_shape=None,
+              devices=None) -> dict:
+    """A few steps of the flagship ZeRO train step on a fixed batch
+    (``devices=None``: every device, ZeRO over all of them)."""
+    fs = build_flagship_train_step(cfg, plan="bf16_fit", devices=devices,
+                                   mesh_shape=mesh_shape)
+    require_on_whole_mesh((fs.params, fs.opt_state), fs.mesh, "train state")
+    dp = fs.mesh.shape[parallel_state.DATA_AXIS]
+    tokens = jax.random.randint(jax.random.PRNGKey(1),
+                                (batch_per_chip * dp, seq), 0,
+                                cfg.vocab_size)
+    labels = jnp.roll(tokens, -1, axis=-1)
+    params, opt_state, step, mesh = fs.params, fs.opt_state, fs.step, fs.mesh
+
+    t0 = time.perf_counter()
+    params, opt_state, loss = step(params, opt_state, tokens, labels)
+    losses = [float(loss)]
+    first_step_s = time.perf_counter() - t0
+    step_ms = []
+    for _ in range(steps):
+        t0 = time.perf_counter()
+        params, opt_state, loss = step(params, opt_state, tokens, labels)
+        losses.append(float(loss))
+        step_ms.append(round((time.perf_counter() - t0) * 1e3, 1))
+    require(all(np.isfinite(losses)), f"train: non-finite loss {losses}")
+    require(losses[-1] < losses[0], f"train: loss did not fall {losses}")
+    require_on_whole_mesh((params, opt_state), mesh, "train state, stepped")
+    if mesh.size > 1:
+        require_balanced("train")
+    memory = memory_by_device()
+    parallel_state.destroy_model_parallel()
+    return {"layers": cfg.num_layers, "batch": int(tokens.shape[0]),
+            "seq": seq, "mesh": dict(mesh.shape), "losses": losses,
+            "first_step_s": round(first_step_s, 1), "step_ms": step_ms,
+            "memory": memory}
+
+
+def pool_geometry(*, page_size, max_batch, prompt_len, max_new, **_):
+    """(pages, pages per request) as ``bench_serving`` sizes the pool:
+    1.5x the worst footprint of ``max_batch`` requests, plus the
+    scratch page."""
+    per_request = -(-(prompt_len[1] + max_new[1]) // page_size)
+    return 1 + max_batch * per_request * 3 // 2, per_request
+
+
+def _engine(cfg, *, page_size, max_batch, prompt_len, max_new, **kw):
+    pages, per_request = pool_geometry(
+        page_size=page_size, max_batch=max_batch, prompt_len=prompt_len,
+        max_new=max_new)
+    return ServingEngine(
+        cfg, num_pages=pages, page_size=page_size, max_batch=max_batch,
+        max_pages_per_request=per_request,
+        prefill_budget=cfg.max_position, **kw)
+
+
+def _serve_twice(eng, trace):
+    """Serve ``trace`` on the engine's clock, then submit the same
+    prompts again and drain.  Returns the token streams and whether
+    the second pass repeated them."""
+    prompts = [(list(r.prompt), r.max_new_tokens) for r in trace]
+    eng.serve(trace)
+    again = [eng.submit(p, n) for p, n in prompts]
+    eng.run()
+    for r in list(trace) + again:
+        require(r.finish_reason in ("length", "eos"),
+                f"serve: request {r.rid} ended as {r.finish_reason!r}")
+        require(r.finish_reason == "eos"
+                or len(r.generated) == r.max_new_tokens,
+                f"serve: request {r.rid} made {len(r.generated)} of "
+                f"{r.max_new_tokens} tokens")
+    streams = [list(r.generated) for r in trace]
+    return streams, streams == [list(r.generated) for r in again]
+
+
+def leg_serve(cfg, *, requests, seed, rate, prompt_len, max_new, page_size,
+              max_batch, tp=1) -> dict:
+    """warmup(), a Poisson trace on the wall clock, the prompts again."""
+    eng = _engine(cfg, page_size=page_size, max_batch=max_batch,
+                  prompt_len=prompt_len, max_new=max_new, tp=tp)
+    if tp > 1:
+        require_on_whole_mesh((eng.params, eng.cache.k, eng.cache.v),
+                              eng._mesh, "serve state")
+    # what one device's kernels see: its head slice of a layer's pool
+    sds = jax.ShapeDtypeStruct
+    heads, d = cfg.num_heads // tp, cfg.head_dim
+    routes = {
+        "decode": flash_decode_route(
+            sds((max_batch, heads, 1, d), cfg.dtype),
+            sds((eng.cache.num_pages, page_size, heads, d), cfg.dtype)),
+        "prefill_fwd": flash_attention_route(
+            sds((heads, cfg.max_position, d), cfg.dtype),
+            segment_ids=True)["fwd"]}
+    warmup_s = eng.warmup()
+    trace = poisson_trace(seed, requests, rate=rate, prompt_len=prompt_len,
+                          max_new=max_new, vocab_size=cfg.vocab_size)
+    t0 = time.perf_counter()
+    with hot_path_guard("serving after warm-up", transfers=None,
+                        tripwire=False):
+        streams, repeated = _serve_twice(eng, trace)
+    serve_s = time.perf_counter() - t0
+    # one decode executable makes every token: batching cannot show
+    require(repeated, "serve: the same prompts gave different streams")
+    if tp > 1:
+        require_balanced("serve")
+    return {"layers": cfg.num_layers, "tp": tp, "routes": routes,
+            "requests": 2 * requests,
+            "tokens": 2 * sum(len(s) for s in streams),
+            "warmup_s": round(warmup_s, 1), "serve_s": round(serve_s, 1),
+            "decode_steps": eng.decode_steps, "memory": memory_by_device(),
+            "streams": streams}
+
+
+def leg_warm(cfg, *, spec_k, chunk_size, seed, rate, prompt_len, max_new,
+             page_size, max_batch) -> dict:
+    """The verify, chunk and int8 executables: compile, then a short
+    trace through them (a prompt longer than ``chunk_size`` prefills
+    through the chunk step, a boundary with a draft goes through
+    verify).  Whether the streams repeat is reported, not required: a
+    boundary runs the verify executable when any row of the batch has
+    a draft and the decode executable otherwise, and on the chip the
+    two agree to bf16 rounding, not bit for bit, so a greedy near-tie
+    can fall either way with the batch's composition."""
+    out = {}
+    for kv_quant in (None, "int8"):
+        eng = _engine(cfg, page_size=page_size, max_batch=max_batch,
+                      prompt_len=prompt_len, max_new=max_new,
+                      spec=SpecConfig(k=spec_k, chunk_size=chunk_size),
+                      kv_quant=kv_quant)
+        warmup_s = eng.warmup()
+        trace = poisson_trace(seed, 4, rate=rate, prompt_len=prompt_len,
+                              max_new=max_new, vocab_size=cfg.vocab_size)
+        with hot_path_guard("spec serving after warm-up", transfers=None,
+                            tripwire=False):
+            streams, repeated = _serve_twice(eng, trace)
+        out[kv_quant or "bf16"] = {
+            "warmup_s": round(warmup_s, 1),
+            "tokens": 2 * sum(len(s) for s in streams),
+            "streams_repeated": repeated}
+        del eng
+        free_device_memory()
+    return out
+
+
+# -- the run ------------------------------------------------------------------
+
+SERVE_TRAFFIC = dict(rate=8.0, prompt_len=(64, 256), max_new=(16, 64),
+                     page_size=64, max_batch=8)
+
+
+def _serving_config(layers: int) -> ServingModelConfig:
+    return ServingModelConfig(51200, 2048, 16, layers, max_position=1024,
+                              dtype=jnp.bfloat16)
+
+
+def _report(name: str, result: dict) -> dict:
+    shown = {k: v for k, v in result.items() if k != "streams"}
+    print(f"[{name}] {json.dumps(shown)}", flush=True)
+    free_device_memory()
+    return result
+
+
+def main() -> int:
+    if jax.default_backend() != "tpu":
+        print(f"chip_smoke: needs a TPU, found backend "
+              f"{jax.default_backend()!r}", file=sys.stderr)
+        return 2
+    # a donation that cannot alias means state is being copied per step
+    warnings.filterwarnings(
+        "error", message=".*donated buffers were not usable.*")
+    cache_dir = configure_compile_cache()
+    devices = jax.devices()
+    device = {"platform": devices[0].platform,
+              "kind": devices[0].device_kind, "count": len(devices)}
+    import jaxlib
+    from importlib.metadata import version
+    print(f"jax {jax.__version__} jaxlib {jaxlib.__version__} "
+          f"libtpu {version('libtpu')}")
+    print(f"platform={device['platform']} device_kind={device['kind']} "
+          f"devices={device['count']} cache_dir={cache_dir}")
+    print(f"_native.available()={_native.available()} "
+          f"build_error()={_native.build_error()}", flush=True)
+
+    flagship = gpt1p3b_config()
+    pages, pages_per_request = pool_geometry(**SERVE_TRAFFIC)
+    kernels = _report("kernels", leg_kernels(
+        batch=4, seq=flagship.max_position_embeddings,
+        heads=flagship.num_attention_heads, head_dim=flagship.kv_channels,
+        block=flagship.flash_block_q, pages=pages,
+        page_size=SERVE_TRAFFIC["page_size"],
+        max_batch=SERVE_TRAFFIC["max_batch"],
+        pages_per_request=pages_per_request))
+
+    print(f"train legs: {TRAIN_LAYERS} of {flagship.num_layers} layers "
+          "(depth cut; width, batch and sequence are the flagship's)")
+    _report("train", leg_train(
+        gpt1p3b_config(num_layers=TRAIN_LAYERS), batch_per_chip=4,
+        seq=flagship.max_position_embeddings, steps=5))
+
+    serve = _report("serve", leg_serve(
+        _serving_config(24), requests=10, seed=0, **SERVE_TRAFFIC))
+
+    routes = {**kernels["routes"], **serve["routes"]}
+    print(f"routes: {json.dumps(routes)}", flush=True)
+    require(routes == ROUTES_ON_TPU,
+            f"routes {routes} are not {ROUTES_ON_TPU}")
+
+    _report("warm", leg_warm(
+        _serving_config(WARM_LAYERS), spec_k=4, chunk_size=128, seed=1,
+        **SERVE_TRAFFIC))
+
+    if len(devices) >= 4:
+        _report("train_mesh", leg_train(
+            gpt1p3b_config(num_layers=TRAIN_LAYERS), batch_per_chip=4,
+            seq=flagship.max_position_embeddings, steps=5,
+            mesh_shape=(2, 2, 1), devices=devices[:4]))
+        serve_tp = _report("serve_tp4", leg_serve(
+            _serving_config(24), requests=10, seed=0, tp=4,
+            **SERVE_TRAFFIC))
+        print("tp=4 streams equal tp=1 streams: "
+              f"{serve_tp['streams'] == serve['streams']}")
+    else:
+        print(f"multi-chip legs not run: {len(devices)} device(s)")
+
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

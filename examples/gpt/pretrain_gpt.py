@@ -56,6 +56,7 @@ from apex_tpu import resilience  # noqa: E402
 from apex_tpu.transformer import parallel_state  # noqa: E402
 from apex_tpu.transformer.testing import GPTConfig, GPTModel  # noqa: E402
 from apex_tpu.transformer.testing.arguments import parse_args  # noqa: E402
+from apex_tpu.utils import configure_compile_cache  # noqa: E402
 
 
 def _extra_args(parser):
@@ -451,4 +452,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

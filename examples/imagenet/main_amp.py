@@ -36,6 +36,7 @@ import numpy as np
 from apex_tpu import amp, checkpoint as ckpt, optimizers
 from apex_tpu.models import ResNet, ResNetConfig, resnet18_config, resnet50_config
 from apex_tpu.ops import softmax_cross_entropy_loss
+from apex_tpu.utils import configure_compile_cache
 
 ARCHS = {
     "resnet18": resnet18_config,
@@ -388,4 +389,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

@@ -194,6 +194,8 @@ class TestDistributedFusedAdam:
 
 
 class TestDistributedFusedLAMB:
+    # slow since PR 22: pays for test_tpu_lowering / test_chip_smoke in tier-1
+    @pytest.mark.slow
     def test_step_moves_toward_target_with_clipping(self, mesh):
         params = _params(jax.random.PRNGKey(0))
         dopt = DistributedFusedLAMB(lr=1e-2, max_grad_norm=1.0)
@@ -213,6 +215,8 @@ class TestDistributedFusedLAMB:
             assert delta < 0.1, (k, delta)
             assert delta > 0
 
+    # slow since PR 22: pays for test_tpu_lowering / test_chip_smoke in tier-1
+    @pytest.mark.slow
     def test_replicated_output_across_ranks(self, mesh):
         params = _params(jax.random.PRNGKey(0))
         grads = _params(jax.random.PRNGKey(1))

@@ -155,7 +155,7 @@ def test_param_count_matches_tree():
 
 
 def test_fit_plan_table_matches_module_docs():
-    """The fitting table the BASELINE.md gpt1p3b section records: at the
+    """The analytic fitting table of the flagship module: at the
     full 1.3B shape only bf16_fit's optimizer-phase peak fits a
     15.75-GiB chip at world=1; bf16_fp32m fits once sharded."""
     cfg = gpt1p3b_config()

@@ -165,8 +165,8 @@ def test_sweep_cells_not_losing():
     worse than naive somewhere inside the window it claims, which the
     single-shape scalar gates cannot see.  Winners (>= SWEEP_WIN_MIN)
     are surfaced by kernel_defaults.sweep_verdict as the per-shape
-    evidence behind keeping each default (the demote-or-gate decision
-    protocol recorded in BASELINE.md)."""
+    evidence behind keeping each default (the r6 demote-or-gate
+    decision protocol)."""
     from apex_tpu.ops.kernel_defaults import (
         SWEEP_PARITY_MIN, SWEEP_SECTIONS, sweep_cells, sweep_verdict)
 
@@ -355,7 +355,7 @@ def test_spilled_sections_merge_back_from_sidecar(tmp_path, monkeypatch):
         mod.test_every_default_wins_in_latest_record()
 
 
-def test_summary_line_fits_even_on_relay_down_run():
+def test_summary_line_fits_when_extras_are_long_strings():
     """A run where every microbench fails leaves only long *_error
     strings in extras — those must spill too (review finding: strings
     alone recreated the oversized-line incident)."""

@@ -283,6 +283,8 @@ class TestBert:
                 atol=2e-5, err_msg=f"flash={flash}")
         parallel_state.destroy_model_parallel()
 
+    # slow since PR 22: pays for test_tpu_lowering / test_chip_smoke in tier-1
+    @pytest.mark.slow
     def test_bert_forward_and_loss(self):
         cfg = BertConfig(num_layers=2, hidden_size=32, num_attention_heads=4,
                          vocab_size=VOCAB, max_position_embeddings=SEQ,

@@ -15,6 +15,8 @@ def _tiny_cfg(**kw):
 
 
 class TestResNet:
+    # slow since PR 22: pays for test_tpu_lowering / test_chip_smoke in tier-1
+    @pytest.mark.slow
     def test_forward_shapes_and_state(self):
         model = ResNet(_tiny_cfg())
         params, state = model.init(jax.random.PRNGKey(0))

@@ -91,6 +91,8 @@ class TestSwitchMLP:
                                    np.asarray(dense)[kept],
                                    rtol=1e-5, atol=1e-5)
 
+    # slow since PR 22: pays for test_tpu_lowering / test_chip_smoke in tier-1
+    @pytest.mark.slow
     def test_gradients_flow(self):
         moe = SwitchMLP(_cfg())
         params = moe.init_master(jax.random.PRNGKey(0))

@@ -89,6 +89,8 @@ class TestAllReduceGrads:
 
 
 class TestSyncBatchNorm:
+    # slow since PR 22: pays for test_tpu_lowering / test_chip_smoke in tier-1
+    @pytest.mark.slow
     def test_matches_full_batch_bn(self, mesh):
         # reference tests/distributed/synced_batchnorm: SyncBN over N devices
         # must equal single-device BN over the full batch.
@@ -112,6 +114,8 @@ class TestSyncBatchNorm:
             rm.reshape(N_DEV, -1)[0],
             0.1 * full.astype(jnp.float32).mean((0, 1, 2)), rtol=1e-4, atol=1e-5)
 
+    # slow since PR 22: pays for test_tpu_lowering / test_chip_smoke in tier-1
+    @pytest.mark.slow
     def test_grad_matches_full_batch_bn(self, mesh):
         full = jax.random.normal(jax.random.PRNGKey(2), (16, 8))
         w = jnp.full((8,), 1.2)

@@ -752,7 +752,7 @@ def test_multichip_records_are_geometry_stamped(tmp_path):
     from apex_tpu.telemetry import load_multichip_record
 
     paths = sorted(glob.glob(os.path.join(REPO, "MULTICHIP_r*.json")))
-    assert len(paths) >= 9  # r01..r08 + r15
+    assert len(paths) >= 8  # r06..r08 + r15..r19
     for p in paths:
         rec = load_multichip_record(p)
         assert rec["geometry"], p

@@ -399,6 +399,8 @@ class TestHealthChaos:
 
 
 class TestRollingRestart:
+    # slow since PR 22: pays for test_tpu_lowering / test_chip_smoke in tier-1
+    @pytest.mark.slow
     def test_rolling_restart_mid_serve_is_bitwise(self, serving_params):
         prompts = _prompts(6, seed=9)
         control = _control_streams(serving_params, prompts)

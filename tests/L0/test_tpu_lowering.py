@@ -1,0 +1,181 @@
+"""Cross-lower every Pallas route auto-routing can pick on a TPU.
+
+The CPU tiers run each kernel in interpret mode, which has no block
+rules, and route every shape gate to XLA.  Pallas' TPU lowering can be
+driven from the CPU all the same: trace with ``ShapeDtypeStruct``s and
+lower for ``lowering_platforms=("tpu",)`` with ``jax.default_backend``
+patched to ``"tpu"`` (the routing tests' idiom).  That runs Mosaic's
+block-shape and layout checks at the REAL shapes without a chip — the
+check that a unit block on a second-minor axis, the bug that kept the
+serving engine from starting on hardware, cannot pass.
+
+Lowering is not compiling: what Mosaic makes of VMEM limits and of
+layout changes inside a kernel is only found by the compiler on the
+chip (``chip_smoke.py``).
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from apex_tpu.ops import (flash_attention, flash_attention_qkv,
+                          flash_attention_qkv_route, flash_attention_route,
+                          flash_decode, flash_decode_route, layer_norm,
+                          routing_override)
+
+SDS = jax.ShapeDtypeStruct
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(autouse=True)
+def _as_tpu(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _mosaic_calls(fn, *structs) -> int:
+    """Lower ``fn`` for the TPU platform and count its Mosaic kernels."""
+    lowered = jax.jit(fn).trace(*structs).lower(lowering_platforms=("tpu",))
+    return lowered.as_text().count("tpu_custom_call")
+
+
+def _grad_of(fn):
+    def run(x, *rest):
+        return jax.grad(
+            lambda x: fn(x, *rest).astype(jnp.float32).sum())(x)
+    return run
+
+
+# -- paged decode: the serving engine's decode / verify / chunk steps -------
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("q_len", [1, 5, 128])
+@pytest.mark.parametrize("heads", [16, 8, 4])
+def test_paged_decode_lowers(heads, q_len, quantized):
+    # the 1.3B-geometry pool (page 64, d=128) at tp 1, 2 and 4; q_len is
+    # plain decode, the spec.k + 1 verify window, and one prefill chunk
+    b, n_pages, page, d, p_max = (1 if q_len == 128 else 8), 61, 64, 128, 16
+    q = SDS((b, heads, q_len, d), BF16)
+    pool = SDS((n_pages, page, heads, d), jnp.int8 if quantized else BF16)
+    args = [q, pool, pool, SDS((b, p_max), jnp.int32), SDS((b,), jnp.int32)]
+    assert flash_decode_route(q, pool) == "decode"
+    if quantized:
+        scale = SDS((n_pages, page, heads), jnp.float32)
+        fn = lambda q, k, v, pt, kl, ks, vs: flash_decode(
+            q, k, v, pt, kl, k_scale=ks, v_scale=vs)
+        args += [scale, scale]
+    else:
+        fn = flash_decode
+    assert _mosaic_calls(fn, *args) == 1
+
+
+# -- generic flash attention: block-skip routes with more than one block ----
+
+def _seg_attention(q, k, v, seg, **kw):
+    return flash_attention(q, k, v, causal=True, segment_ids=seg, **kw)
+
+
+def test_segment_backward_auto_route_lowers():
+    # any call with segments takes grid_skip backward by default; the
+    # prefill shape of the serving model (s=1024, d=128) also takes the
+    # varlen forward
+    q = SDS((16, 1024, 128), BF16)
+    seg = SDS((16, 1024), jnp.int32)
+    kw = dict(block_q=256, block_k=256)
+    assert flash_attention_route(q, segment_ids=True, **kw) == {
+        "fwd": "varlen", "bwd": "grid_skip"}
+
+    def loss(q, k, v, seg):
+        return jax.grad(lambda q: _seg_attention(
+            q, k, v, seg, **kw).astype(jnp.float32).sum())(q)
+
+    assert _mosaic_calls(loss, q, q, q, seg) == 2
+
+
+def test_stream_skip_forward_auto_route_lowers():
+    # whole-sequence q/k/v no longer fit VMEM at s=8192: the streaming
+    # forward reads the skip index one grid row at a time
+    q = SDS((4, 8192, 128), BF16)
+    seg = SDS((4, 8192), jnp.int32)
+    assert flash_attention_route(q, segment_ids=True)["fwd"] == "stream_skip"
+    assert _mosaic_calls(_seg_attention, q, q, q, seg) == 1
+
+
+@pytest.mark.parametrize("fwd,bwd", [("stream_skip", "grid_skip"),
+                                     ("varlen", "grid"),
+                                     ("tiles", "tiles")])
+def test_forced_generic_routes_lower(fwd, bwd):
+    q = SDS((8, 1024, 128), BF16)
+    seg = SDS((8, 1024), jnp.int32)
+    kw = dict(block_q=128, block_k=128)
+
+    def loss(q, k, v, seg):
+        with routing_override(fwd=fwd, bwd=bwd):
+            return jax.grad(lambda q: _seg_attention(
+                q, k, v, seg, **kw).astype(jnp.float32).sum())(q)
+
+    assert _mosaic_calls(loss, q, q, q, seg) == 2
+
+
+def test_broadcast_segments_lower():
+    # a [1, s] segment row shared by every batch-head (the serving
+    # prefill's form) selects row 0 of the skip table
+    q = SDS((16, 1024, 128), BF16)
+    seg = SDS((1, 1024), jnp.int32)
+    with routing_override(fwd="stream_skip"):
+        assert _mosaic_calls(functools.partial(
+            _seg_attention, block_q=256, block_k=256), q, q, q, seg) == 1
+
+
+# -- packed-QKV: the training models' attention -----------------------------
+
+_QKV_SHAPES = {
+    # name: (batch, seq, heads, head_dim, block, causal)
+    "gpt1p3b": (4, 2048, 16, 128, 256, True),
+    "gpt1p3b_tp2": (4, 2048, 8, 128, 256, True),
+    "gpt1p3b_tp4": (4, 2048, 4, 128, 256, True),
+    "gpt350m": (8, 1024, 16, 64, 512, True),
+    "bert_large": (8, 512, 16, 64, 512, False),
+}
+
+
+@pytest.mark.parametrize("segments", [False, True], ids=["dense", "varlen"])
+@pytest.mark.parametrize("name", list(_QKV_SHAPES))
+def test_packed_qkv_lowers(name, segments):
+    b, s, heads, hn, block, causal = _QKV_SHAPES[name]
+    assert flash_attention_qkv_route(
+        b, s, heads, hn, block=block, causal=causal,
+        has_segments=segments) == ("packed_varlen" if segments else "packed")
+    qkv = SDS((b, s, heads * 3 * hn), BF16)
+
+    def attn(qkv, *seg):
+        return flash_attention_qkv(qkv, heads, causal=causal, block=block,
+                                   segment_ids=seg[0] if seg else None)
+
+    structs = (qkv,) + ((SDS((b, s), jnp.int32),) if segments else ())
+    assert _mosaic_calls(_grad_of(attn), *structs) == 2
+
+
+# -- LayerNorm and the flat Adam kernel -------------------------------------
+
+def test_layer_norm_lowers():
+    x = SDS((4 * 2048, 2048), BF16)
+    w = SDS((2048,), jnp.float32)
+
+    def loss(x, w, b):
+        return jax.grad(lambda x, w, b: layer_norm(
+            x, w, b).astype(jnp.float32).sum(), (0, 1, 2))(x, w, b)
+
+    assert _mosaic_calls(loss, x, w, w) == 2
+
+
+def test_flat_fused_adam_lowers():
+    from apex_tpu.optimizers.flat import FlatAdamState, FlatFusedAdam
+
+    n = 1 << 20
+    flat = SDS((n,), jnp.float32)
+    state = FlatAdamState(step=SDS((), jnp.int32), exp_avg=flat,
+                          exp_avg_sq=flat)
+    assert _mosaic_calls(FlatFusedAdam().step, flat, state, flat) == 1
+

@@ -56,6 +56,8 @@ def _naive_loss(x, label, f_len, y_len, blank):
 
 
 class TestTransducerLoss:
+    # slow since PR 22: pays for test_tpu_lowering / test_chip_smoke in tier-1
+    @pytest.mark.slow
     def test_matches_naive(self):
         x, label, f_len, y_len = _case()
         got = transducer_loss(x, label, f_len, y_len, BLANK)
