@@ -1,0 +1,19 @@
+"""The decode executable's share of the chip's bf16 peak while it
+runs: forward FLOPs of the rows the traced decode steps carried
+(``flops.py``) / device time of those runs / chips / peak."""
+import flops
+import trace_reduce
+
+
+def read(result, ctx):
+    runs = trace_reduce.runs_between(
+        result.trace, ctx.config["executables"]["decode"],
+        result.trace_window_ns)
+    traced = result.counters["traced"]
+    if not runs or not traced["decode_kv_lens"]:
+        return None
+    m = flops.model_shape(ctx.config["model"])
+    work = sum(flops.decode_flops(m, k) for k in traced["decode_kv_lens"])
+    seconds = sum(dur for _, _, dur in runs) / 1e9
+    return 100.0 * work / seconds / ctx.config["chips"] \
+        / ctx.peak["bf16_flops_per_s"]

@@ -1,0 +1,3 @@
+"""Plain references: straightforward float32 ``jax.numpy`` at
+``highest`` matmul precision, no kernels, no cache, no batching tricks.
+Nothing here imports the program or takes anything the program made."""
