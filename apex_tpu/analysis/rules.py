@@ -69,6 +69,7 @@ _NP_ROOTS = ("np", "numpy", "onp")
 HOT_PATH_FUNCTIONS: Dict[str, Set[str]] = {
     "apex_tpu/serving/engine.py": {
         "_decode_batch", "_prefill_request", "_step_body",
+        "_step_phases",
         # ISSUE 12: the speculative verify step, the chunked-prefill
         # step, and the draft-proposal loop run at every decode
         # boundary — same steady-state heat as _decode_batch

@@ -80,12 +80,14 @@ def _pack(tree, schema: FlatSchema, dtype):
     dp=4 the single-axis step's whole-buffer collectives happened to
     pin the buffers already; a world of one has no collectives and the
     bucketed step's are per slice.)"""
-    return jax.lax.optimization_barrier(
-        flatten(tree, schema, dtype=dtype)[0])
+    with jax.named_scope("zero_pack"):
+        return jax.lax.optimization_barrier(
+            flatten(tree, schema, dtype=dtype)[0])
 
 
 def _unpack(flat, schema: FlatSchema):
-    return unflatten(jax.lax.optimization_barrier(flat), schema)
+    with jax.named_scope("zero_unpack"):
+        return unflatten(jax.lax.optimization_barrier(flat), schema)
 
 
 class ShardedOptState(NamedTuple):
@@ -219,8 +221,9 @@ class DistributedShardedOptimizer:
         p_shard = jax.lax.dynamic_slice_in_dim(
             flat_p, rank * shard, shard).astype(jnp.float32)
 
-        new_p_shard, new_state = self._shard_update(
-            p_shard, g_shard, state, flat_g)
+        with jax.named_scope("zero_update"):
+            new_p_shard, new_state = self._shard_update(
+                p_shard, g_shard, state, flat_g)
 
         if self.e5m2_allgather:
             # 8-bit-exponent compressed transport (reference e5m2_allgather):
@@ -315,7 +318,8 @@ class DistributedShardedOptimizer:
             # _shard_update increments internally, so each bucket's
             # bias correction sees the identical step number
             sub = ShardedOptState(state.step, m_b, v_b)
-            new_p_b, sub = self._shard_update(p_b, g_b, sub, None)
+            with jax.named_scope("zero_update"):
+                new_p_b, sub = self._shard_update(p_b, g_b, sub, None)
             new_m.append(sub.exp_avg)
             new_v.append(sub.exp_avg_sq)
             gathered = jax.lax.all_gather(

@@ -798,6 +798,7 @@ def _flash_fwd_pallas(q, k, v, mask_bias, seg_q, seg_k, dropout_seed,
                 jax.ShapeDtypeStruct((bh, n_qb, 8, block_q),
                                      jnp.float32),
             ],
+            name="flash_fwd_varlen",
             interpret=use_interpret(),
         )(q, k, v, *tail_args, *skip_args, *seed_args)
         return o, lse[:, :, 0, :].reshape(bh, sq)
@@ -826,6 +827,7 @@ def _flash_fwd_pallas(q, k, v, mask_bias, seg_q, seg_k, dropout_seed,
                 jax.ShapeDtypeStruct((bh, n_qb, 8, block_q),
                                      jnp.float32),
             ],
+            name="flash_fwd_tiles",
             interpret=use_interpret(),
         )(q, k, v, *tail_args, *seed_args)
         return o, lse[:, :, 0, :].reshape(bh, sq)
@@ -856,6 +858,7 @@ def _flash_fwd_pallas(q, k, v, mask_bias, seg_q, seg_k, dropout_seed,
             jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, n_qb, 8, block_q), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=use_interpret(),
     )(q, k, v, *tail_args, *skip_args, *seed_args)
     return o, lse[:, :, 0, :].reshape(bh, sq)
@@ -1184,6 +1187,7 @@ def _flash_bwd_pallas(q, k, v, mask_bias, seg_q, seg_k, dropout_seed,
                 jax.ShapeDtypeStruct(k.shape, k.dtype),
                 jax.ShapeDtypeStruct(v.shape, v.dtype),
             ],
+            name="flash_bwd_tiles",
             interpret=use_interpret(),
         )(q, k, v, do, lse[:, None, :], o, *tail_args, *seed_args)
         return dq, dk, dv
@@ -1235,6 +1239,7 @@ def _flash_bwd_pallas(q, k, v, mask_bias, seg_q, seg_k, dropout_seed,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        name="flash_bwd",
         interpret=use_interpret(),
     )(q, k, v, do, lse3, delta, *tail_args, *skip_args, *seed_args)
     return dq, dk, dv
@@ -1806,6 +1811,7 @@ def _flash_qkv_fwd_pallas(qkv, dropout_seed, num_heads, hn, scale,
             jax.ShapeDtypeStruct((b, n_hg, group, n_b, 8, block),
                                  jnp.float32),
         ],
+        name="flash_qkv_fwd",
         interpret=use_interpret(),
     )(qkv, *seg_args, *seed_args)
     return ctx, lse
@@ -1845,6 +1851,7 @@ def _flash_qkv_bwd_pallas(qkv, dropout_seed, ctx, lse, dctx, num_heads,
         out_shape=jax.ShapeDtypeStruct(qkv.shape, qkv.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_QKV_BWD_VMEM_LIMIT),
+        name="flash_qkv_bwd",
         interpret=use_interpret(),
     )(qkv, dctx, ctx, lse, *seg_args, *seed_args)
     return dqkv
@@ -2296,6 +2303,7 @@ def _flash_decode_pallas(q, k_pages, v_pages, page_table, kv_len, scale,
                             q_len=q_len, h=h, d=d, quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, q_len, d), q.dtype),
+        name="flash_decode",
         interpret=use_interpret(),
     )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32),
       *operands)

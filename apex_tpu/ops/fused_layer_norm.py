@@ -107,6 +107,7 @@ def _pallas_ln_fwd(x2d, weight, bias, eps):
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ],
+        name="layer_norm_fwd",
         interpret=use_interpret(),
     )(*args)
     return y, mean[:, 0], invvar[:, 0]
@@ -221,6 +222,7 @@ def _pallas_ln_bwd(x2d, dy, mean, invvar, weight, has_w, has_b):
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        name="layer_norm_bwd",
         interpret=use_interpret(),
     )(*args)
     outs = list(outs) if isinstance(outs, (list, tuple)) else [outs]

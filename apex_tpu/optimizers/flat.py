@@ -186,6 +186,7 @@ class FlatFusedAdam:
             out_specs=[vspec, vspec, vspec],
             out_shape=[jax.ShapeDtypeStruct(shape2d, jnp.float32)] * 3,
             input_output_aliases={1: 0, 3: 1, 4: 2},
+            name="flat_adam_update",
             interpret=use_interpret(),
         )(
             scal,

@@ -75,6 +75,15 @@ page-pool occupancy), plus the ISSUE 10 resilience set
 the bench's stream is schema-validated by the existing ``validate``
 CLI.
 
+Step phases (ISSUE 27): bus or no bus, every step marks what the host
+does with :func:`apex_tpu.telemetry.phase` — ``engine.step`` around
+``engine.prefill`` (per request) / ``engine.grow`` /
+``engine.decode`` and their ``prefill.*`` / ``decode.*`` children
+— as ``apex:`` annotations in
+any profiler session and as records in the one in-memory ring;
+``decode_step.step_ms`` and ``phase_ms`` are those records
+(docs/telemetry.md "Step phases").
+
 **Failure semantics (ISSUE 10).** The engine degrades instead of
 falling over: per-request deadlines shed/time out work that can no
 longer meet its SLO, a bounded submit queue rejects overload loudly,
@@ -108,6 +117,7 @@ from apex_tpu.serving.scheduler import (FINISHED, RUNNING, WAITING,
                                         QueueFullError, Request)
 from apex_tpu.serving.spec import (NgramProposer, SpecConfig,
                                    commit_tokens)
+from apex_tpu.telemetry.phases import phase
 
 #: The compiled-shapes contract as code, in docs/serving.md table
 #: order: every executable :meth:`ServingEngine.warmup` may build.
@@ -764,35 +774,46 @@ class ServingEngine:
                 "reserved at admission")
         _fault_point("prefill", req.rid)
         prefill_t0 = self.clock()
-        tokens = np.zeros((1, S), np.int32)
-        tokens[0, :C] = ctx
-        seg = np.zeros((1, S), np.int32)
-        seg[0, :C] = 1
-        positions = np.zeros((1, S), np.int32)
-        positions[0, :C] = np.arange(C)
-        # np.int32 scalar, NOT jnp.asarray(C - 1): converting a python
-        # int eagerly compiles a tiny convert executable the warmup
-        # never built — a hidden ~60 ms stall on the first admission's
-        # TTFT (caught by hot_path_guard's serving-lifetime pin)
-        next_tok, k, v = self._prefill_fn(
-            self.params, jnp.asarray(tokens), jnp.asarray(seg),
-            jnp.asarray(positions), np.int32(C - 1))
-        # packed position t -> (page, in-page offset); padding -> scratch
-        pages = np.zeros((S,), np.int32)
-        offsets = np.zeros((S,), np.int32)
-        idx = np.arange(C)
-        pages[:C] = np.asarray(req.pages, np.int32)[idx // ps]
-        offsets[:C] = idx % ps
-        self.cache.write_tokens(k, v, pages, offsets)
-        req.kv_len = C
-        self._register_prefix(ctx, req.pages)
-        req.generated.append(int(next_tok))
-        if req.first_token_t is None:
-            req.first_token_t = self.clock()
-            # colocated path: the token is streamable the instant it
-            # is sampled (a shipped request's stream_t is stamped at
-            # adoption instead — r19 shipping-aware TTFT)
-            req.stream_t = req.first_token_t
+        with phase("engine.prefill", rid=req.rid, C=C, S=S):
+            with phase("prefill.build"):
+                tokens = np.zeros((1, S), np.int32)
+                tokens[0, :C] = ctx
+                seg = np.zeros((1, S), np.int32)
+                seg[0, :C] = 1
+                positions = np.zeros((1, S), np.int32)
+                positions[0, :C] = np.arange(C)
+            with phase("prefill.dispatch"):
+                # np.int32 scalar, NOT jnp.asarray(C - 1): converting a
+                # python int eagerly compiles a tiny convert executable
+                # the warmup never built — a hidden ~60 ms stall on the
+                # first admission's TTFT (caught by hot_path_guard's
+                # serving-lifetime pin)
+                next_tok, k, v = self._prefill_fn(
+                    self.params, jnp.asarray(tokens), jnp.asarray(seg),
+                    jnp.asarray(positions), np.int32(C - 1))
+            with phase("prefill.scatter"):
+                # packed position t -> (page, in-page offset); padding
+                # -> scratch
+                pages = np.zeros((S,), np.int32)
+                offsets = np.zeros((S,), np.int32)
+                idx = np.arange(C)
+                pages[:C] = np.asarray(req.pages, np.int32)[idx // ps]
+                offsets[:C] = idx % ps
+                self.cache.write_tokens(k, v, pages, offsets)
+            req.kv_len = C
+            self._register_prefix(ctx, req.pages)
+            with phase("prefill.fetch"):
+                # the wait for the device
+                first = int(next_tok)
+            req.generated.append(first)
+            if req.first_token_t is None:
+                req.first_token_t = self.clock()
+                # colocated path: the token is streamable the instant
+                # it is sampled (a shipped request's stream_t is stamped
+                # at adoption instead — r19 shipping-aware TTFT)
+                req.stream_t = req.first_token_t
+        if self.telemetry is None:
+            return
         # single-shot prefill = one prefill_chunk span covering the
         # whole context (the chunked path emits one per chunk)
         life = self._life(req)
@@ -837,37 +858,43 @@ class ServingEngine:
         """One decode step for ``rows`` (≤ max_batch), idle-padded to
         the fixed batch width."""
         _fault_point("decode", self.decode_steps)
-        # opt-in read-back validation: the pages this step is about to
-        # attend over must still match their recorded CRCs
-        self.cache.verify_pages([req.pages for req in rows])
-        b = self.max_batch
-        ps = self.cache.page_size
-        tokens = np.zeros((b,), np.int32)
-        positions = np.zeros((b,), np.int32)
-        kv_len = np.ones((b,), np.int32)
-        written: List[int] = []   # the page each row's new K/V lands in
-        for i, req in enumerate(rows):
-            tokens[i] = req.generated[-1]
-            positions[i] = req.seq_len - 1
-            kv_len[i] = req.seq_len
-            written.append(req.pages[(req.seq_len - 1) // ps])
-        self._check_private(written, "decode append")
-        page_table = self.cache.page_table(
-            [req.pages for req in rows], rows=b)
-        out = self._decode_fn(
-            self.params, *self._pool_state(),
-            jnp.asarray(tokens), jnp.asarray(positions), page_table,
-            jnp.asarray(kv_len))
-        next_tok = out[0]
-        self._bind_pools(out[1:])
-        self.cache.refresh_page_crcs(written)
-        next_tok = np.asarray(next_tok)
-        for i, req in enumerate(rows):
-            req.kv_len = req.seq_len
-            req.generated.append(int(next_tok[i]))
+        with phase("decode.build"):
+            # opt-in read-back validation: the pages this step is about
+            # to attend over must still match their recorded CRCs
+            self.cache.verify_pages([req.pages for req in rows])
+            b = self.max_batch
+            ps = self.cache.page_size
+            tokens = np.zeros((b,), np.int32)
+            positions = np.zeros((b,), np.int32)
+            kv_len = np.ones((b,), np.int32)
+            written: List[int] = []   # the page each row's new K/V lands in
+            for i, req in enumerate(rows):
+                tokens[i] = req.generated[-1]
+                positions[i] = req.seq_len - 1
+                kv_len[i] = req.seq_len
+                written.append(req.pages[(req.seq_len - 1) // ps])
+            self._check_private(written, "decode append")
+            page_table = self.cache.page_table(
+                [req.pages for req in rows], rows=b)
+        with phase("decode.dispatch"):
+            out = self._decode_fn(
+                self.params, *self._pool_state(),
+                jnp.asarray(tokens), jnp.asarray(positions), page_table,
+                jnp.asarray(kv_len))
+            next_tok = out[0]
+            self._bind_pools(out[1:])
+        with phase("decode.fetch"):
+            # the wait for the device
+            next_tok = np.asarray(next_tok)
+        with phase("decode.commit"):
+            self.cache.refresh_page_crcs(written)
+            for i, req in enumerate(rows):
+                req.kv_len = req.seq_len
+                req.generated.append(int(next_tok[i]))
 
     def _verify_batch(self, rows: List[Request],
-                      drafts: Dict[int, List[int]]) -> Tuple[int, int, int]:
+                      drafts: Dict[int, List[int]]
+                      ) -> Tuple[int, int, List[int]]:
         """One speculative decode boundary: score every row's last
         committed token + draft in ONE verify launch
         (``q_len = spec.k + 1``), commit each row's longest matching
@@ -882,64 +909,72 @@ class ServingEngine:
         plain accounting: ``kv_len`` advances only over committed
         draft rows (stale K/V past it is unreachable and overwritten
         when the sequence grows back), and surplus tail pages return
-        to the pool via ``free_tail``.  Returns
-        ``(drafted, accepted, committed)`` token counts for the
-        ``decode_step`` telemetry fields."""
+        to the pool via ``free_tail``.  Returns the ``drafted`` and
+        ``accepted`` token counts for the ``decode_step`` telemetry
+        fields and the tokens ``committed`` per row (the
+        ``engine.decode`` phase's token times)."""
         _fault_point("decode", self.decode_steps)
-        self.cache.verify_pages([req.pages for req in rows])
-        b, qw = self.max_batch, self.spec_k + 1
-        ps = self.cache.page_size
-        tokens = np.zeros((b, qw), np.int32)
-        positions = np.zeros((b, qw), np.int32)
-        wpages = np.zeros((b, qw), np.int32)
-        woffs = np.zeros((b, qw), np.int32)
-        kv_len = np.full((b,), qw, np.int32)  # idle rows: kv_len == q_len
-        row_draft: List[List[int]] = []
-        written: List[int] = []
-        for i, req in enumerate(rows):
-            d = drafts.get(req.rid, [])
-            row_draft.append(d)
-            S, j = req.seq_len, len(d)
-            pad = qw - (j + 1)
-            pos = np.arange(S - 1, S + j)
-            tokens[i, pad:] = [req.generated[-1]] + d
-            positions[i, pad:] = pos
-            pg = np.asarray(req.pages, np.int32)[pos // ps]
-            wpages[i, pad:] = pg
-            woffs[i, pad:] = pos % ps
-            kv_len[i] = S + j
-            written.extend(int(p) for p in pg)
-        self._check_private(written, "verify append")
-        page_table = self.cache.page_table(
-            [req.pages for req in rows], rows=b)
-        out = self._verify_fn(
-            self.params, *self._pool_state(),
-            jnp.asarray(tokens), jnp.asarray(positions),
-            jnp.asarray(wpages), jnp.asarray(woffs), page_table,
-            jnp.asarray(kv_len))
-        next_tok = out[0]
-        self._bind_pools(out[1:])
-        self.cache.refresh_page_crcs(written)
-        next_tok = np.asarray(next_tok)
-        drafted = accepted = committed = 0
-        for i, req in enumerate(rows):
-            d = row_draft[i]
-            S, j = req.seq_len, len(d)
-            pad = qw - (j + 1)
-            out, n_draft_kv, a = commit_tokens(
-                d, next_tok[i, pad:].tolist(), eos_id=req.eos_id,
-                remaining=req.max_new_tokens - len(req.generated))
-            req.generated.extend(out)
-            req.kv_len = S + n_draft_kv
-            # rollback: pages grown for rejected draft rows go back to
-            # the pool (the next boundary's growth re-takes what the
-            # committed tokens actually need — lowest-first, so the
-            # SAME pages come back, deterministically)
-            keep = self.cache.pages_needed(max(req.seq_len, req.kv_len))
-            self.cache.free_tail(req.pages, keep)
-            drafted += j
-            accepted += a
-            committed += len(out)
+        with phase("decode.build"):
+            self.cache.verify_pages([req.pages for req in rows])
+            b, qw = self.max_batch, self.spec_k + 1
+            ps = self.cache.page_size
+            tokens = np.zeros((b, qw), np.int32)
+            positions = np.zeros((b, qw), np.int32)
+            wpages = np.zeros((b, qw), np.int32)
+            woffs = np.zeros((b, qw), np.int32)
+            # idle rows: kv_len == q_len
+            kv_len = np.full((b,), qw, np.int32)
+            row_draft: List[List[int]] = []
+            written: List[int] = []
+            for i, req in enumerate(rows):
+                d = drafts.get(req.rid, [])
+                row_draft.append(d)
+                S, j = req.seq_len, len(d)
+                pad = qw - (j + 1)
+                pos = np.arange(S - 1, S + j)
+                tokens[i, pad:] = [req.generated[-1]] + d
+                positions[i, pad:] = pos
+                pg = np.asarray(req.pages, np.int32)[pos // ps]
+                wpages[i, pad:] = pg
+                woffs[i, pad:] = pos % ps
+                kv_len[i] = S + j
+                written.extend(int(p) for p in pg)
+            self._check_private(written, "verify append")
+            page_table = self.cache.page_table(
+                [req.pages for req in rows], rows=b)
+        with phase("decode.dispatch"):
+            out = self._verify_fn(
+                self.params, *self._pool_state(),
+                jnp.asarray(tokens), jnp.asarray(positions),
+                jnp.asarray(wpages), jnp.asarray(woffs), page_table,
+                jnp.asarray(kv_len))
+            next_tok = out[0]
+            self._bind_pools(out[1:])
+        with phase("decode.fetch"):
+            next_tok = np.asarray(next_tok)
+        committed: List[int] = []
+        with phase("decode.commit"):
+            self.cache.refresh_page_crcs(written)
+            drafted = accepted = 0
+            for i, req in enumerate(rows):
+                d = row_draft[i]
+                S, j = req.seq_len, len(d)
+                pad = qw - (j + 1)
+                out, n_draft_kv, a = commit_tokens(
+                    d, next_tok[i, pad:].tolist(), eos_id=req.eos_id,
+                    remaining=req.max_new_tokens - len(req.generated))
+                req.generated.extend(out)
+                req.kv_len = S + n_draft_kv
+                # rollback: pages grown for rejected draft rows go back
+                # to the pool (the next boundary's growth re-takes what
+                # the committed tokens actually need — lowest-first, so
+                # the SAME pages come back, deterministically)
+                keep = self.cache.pages_needed(
+                    max(req.seq_len, req.kv_len))
+                self.cache.free_tail(req.pages, keep)
+                drafted += j
+                accepted += a
+                committed.append(len(out))
         if self.proposer is not None:
             self.proposer.observe(drafted, accepted)
         return drafted, accepted, committed
@@ -955,55 +990,64 @@ class ServingEngine:
         dispatch per boundary."""
         _fault_point("prefill", req.rid)
         t0 = self.clock()
-        # opt-in CRC read-back, like every other pool-reading step:
-        # this chunk attends over the pages earlier chunks filled — a
-        # corrupted earlier page must raise HERE, before the final
-        # chunk could sample the request's first token from damaged
-        # K/V and commit it into the stream (review-found, pinned;
-        # pages past the filled prefix have no CRC record and are
-        # skipped by verify_pages)
-        self.cache.verify_pages([req.pages])
         cs = self.chunk_size
-        ps = self.cache.page_size
-        ctx = req.context
-        need = self.cache.pages_needed(start + n)
-        if len(req.pages) < need:
-            raise RuntimeError(
-                f"request {req.rid}: chunk [{start}, {start + n}) found "
-                f"{len(req.pages)} reserved pages, needs {need} — pages "
-                "must be reserved at admission")
-        pad = cs - n
-        tokens = np.zeros((1, cs), np.int32)
-        positions = np.zeros((1, cs), np.int32)
-        wpages = np.zeros((1, cs), np.int32)
-        woffs = np.zeros((1, cs), np.int32)
-        pos = np.arange(start, start + n)
-        tokens[0, pad:] = ctx[start:start + n]
-        positions[0, pad:] = pos
-        pg = np.asarray(req.pages, np.int32)[pos // ps]
-        wpages[0, pad:] = pg
-        woffs[0, pad:] = pos % ps
-        self._check_private(pg, "chunk scatter")
-        page_table = self.cache.page_table([req.pages], rows=1)
-        out = self._chunk_fn(
-            self.params, *self._pool_state(),
-            jnp.asarray(tokens), jnp.asarray(positions),
-            jnp.asarray(wpages), jnp.asarray(woffs), page_table,
-            jnp.asarray(np.full((1,), start + n, np.int32)))
-        next_tok = out[0]
-        self._bind_pools(out[1:])
-        self.cache.refresh_page_crcs(int(p) for p in pg)
-        req.kv_len = start + n
-        req.prefill_pos = start + n
-        if req.prefill_pos >= len(ctx):
-            # prefill complete: sample the first token and leave
-            # chunked mode — the request decodes from the next boundary
-            req.prefill_pos = None
-            self._register_prefix(ctx, req.pages)
-            req.generated.append(int(np.asarray(next_tok)[0]))
-            if req.first_token_t is None:
-                req.first_token_t = self.clock()
-                req.stream_t = req.first_token_t
+        with phase("engine.prefill", rid=req.rid, C=n, S=cs):
+            with phase("prefill.build"):
+                # opt-in CRC read-back, like every other pool-reading
+                # step: this chunk attends over the pages earlier
+                # chunks filled — a corrupted earlier page must raise
+                # HERE, before the final chunk could sample the
+                # request's first token from damaged K/V and commit it
+                # into the stream (review-found, pinned; pages past the
+                # filled prefix have no CRC record and are skipped by
+                # verify_pages)
+                self.cache.verify_pages([req.pages])
+                ps = self.cache.page_size
+                ctx = req.context
+                need = self.cache.pages_needed(start + n)
+                if len(req.pages) < need:
+                    raise RuntimeError(
+                        f"request {req.rid}: chunk [{start}, {start + n}) "
+                        f"found {len(req.pages)} reserved pages, needs "
+                        f"{need} — pages must be reserved at admission")
+                pad = cs - n
+                tokens = np.zeros((1, cs), np.int32)
+                positions = np.zeros((1, cs), np.int32)
+                wpages = np.zeros((1, cs), np.int32)
+                woffs = np.zeros((1, cs), np.int32)
+                pos = np.arange(start, start + n)
+                tokens[0, pad:] = ctx[start:start + n]
+                positions[0, pad:] = pos
+                pg = np.asarray(req.pages, np.int32)[pos // ps]
+                wpages[0, pad:] = pg
+                woffs[0, pad:] = pos % ps
+                self._check_private(pg, "chunk scatter")
+                page_table = self.cache.page_table([req.pages], rows=1)
+            with phase("prefill.dispatch"):
+                out = self._chunk_fn(
+                    self.params, *self._pool_state(),
+                    jnp.asarray(tokens), jnp.asarray(positions),
+                    jnp.asarray(wpages), jnp.asarray(woffs), page_table,
+                    jnp.asarray(np.full((1,), start + n, np.int32)))
+                next_tok = out[0]
+                self._bind_pools(out[1:])
+            self.cache.refresh_page_crcs(int(p) for p in pg)
+            req.kv_len = start + n
+            req.prefill_pos = start + n
+            if req.prefill_pos >= len(ctx):
+                # prefill complete: sample the first token and leave
+                # chunked mode — the request decodes from the next
+                # boundary
+                req.prefill_pos = None
+                self._register_prefix(ctx, req.pages)
+                with phase("prefill.fetch"):
+                    first = int(np.asarray(next_tok)[0])
+                req.generated.append(first)
+                if req.first_token_t is None:
+                    req.first_token_t = self.clock()
+                    req.stream_t = req.first_token_t
+        if self.telemetry is None:
+            return
         life = self._life(req)
         self._emit("span", rid=req.rid,
                    span_id=f"{req.rid}:prefill_chunk:{life}:{start}",
@@ -1030,6 +1074,8 @@ class ServingEngine:
         for req in done:
             if self.proposer is not None:
                 self.proposer.release(req.rid)
+            if self.telemetry is None:
+                continue
             n = len(req.generated)
             ev = dict(rid=req.rid, reason=req.finish_reason,
                       new_tokens=n, preemptions=req.preemptions)
@@ -1140,18 +1186,37 @@ class ServingEngine:
         return drafts
 
     def _step_body(self) -> bool:
+        """One step as phases (docs/telemetry.md, "Step phases"):
+        ``engine.step`` around one ``engine.prefill`` per admitted
+        request, ``engine.grow`` and ``engine.decode``; retirement and
+        admission are its own time and its counters."""
+        cpu0_ns = time.process_time_ns()
+        with phase("engine.step", step=self.steps) as span:
+            progress = self._step_phases(span.attrs)
+            span.attrs["cpu_ns"] = time.process_time_ns() - cpu0_ns
+        self.steps += 1
+        if isinstance(self.clock, SimClock):
+            self.clock.advance()
+        return progress
+
+    def _step_phases(self, counters: Dict[str, int]) -> bool:
         now = self.clock()
         progress = self._expire(now)
-        progress = bool(self._retire(now)) or progress
+        done = self._retire(now)
+        progress = bool(done) or progress
         if self.chunk_size is not None:
             chunk_plan, admitted = self.sched.schedule_prefill()
         else:
             chunk_plan, admitted = [], self.sched.admit()
+        counters["admitted"] = len(admitted)
         for req in admitted:
             req.admit_t = now
             ctx_tokens = req.seq_len   # == len(context), O(1)
             if req.prefill_pos is None:
                 self._prefill_request(req)
+            progress = True
+            if self.telemetry is None:
+                continue
             ev = dict(rid=req.rid, context_tokens=ctx_tokens,
                       pages=len(req.pages), preemptions=req.preemptions)
             if req.prefill_pos is not None:
@@ -1177,64 +1242,77 @@ class ServingEngine:
                        span_id=f"{req.rid}:admit:{life}",
                        parent_id=qid, kind="admit", t_start=now,
                        t_end=self.clock())
-            progress = True
         for req, start, n in chunk_plan:
             self._chunk_step(req, start, n)
             progress = True
         # a request whose budget was a single token is done at prefill
-        progress = bool(self._retire(now)) or progress
+        done_at_prefill = self._retire(now)
+        progress = bool(done_at_prefill) or progress
+        counters["retired"] = len(done) + len(done_at_prefill)
         evicted: List[Request] = []
         drafts: Dict[int, List[int]] = {}
         if self.sched.running and not self.prefill_only:
-            if self.proposer is not None:
-                drafts = self._propose_drafts()
-            # growth covers each drafted row's verify footprint too
-            # (seq_len + draft); a row preempted while growing simply
-            # drops out of this boundary, draft unused — the proposer
-            # is stateless over committed tokens, so nothing leaks
-            evicted = self.sched.ensure_decode_capacity(
-                extra={rid: len(d) for rid, d in drafts.items()}
-                or None)
+            with phase("engine.grow"):
+                if self.proposer is not None:
+                    drafts = self._propose_drafts()
+                # growth covers each drafted row's verify footprint too
+                # (seq_len + draft); a row preempted while growing
+                # simply drops out of this boundary, draft unused — the
+                # proposer is stateless over committed tokens, so
+                # nothing leaks
+                evicted = self.sched.ensure_decode_capacity(
+                    extra={rid: len(d) for rid, d in drafts.items()}
+                    or None)
+        counters["evicted"] = len(evicted)
         # a prefill_only engine never decodes: finished prefills hold
         # their first token and wait for export_request to ship them
         rows = ([] if self.prefill_only else
                 [r for r in self.sched.running if r.prefill_pos is None])
         if rows:
-            t0 = self.clock()
             spec_fields = {}
-            if any(r.rid in drafts for r in rows):
-                drafted, accepted, committed = self._verify_batch(
-                    rows, drafts)
-                new_tokens = committed
-                spec_fields = {"spec_verify": True,
-                               "spec_drafted": drafted,
-                               "spec_accepted": accepted}
-            else:
-                # every draft came back empty (or speculation is off):
-                # the plain q_len=1 decode executable is cheaper
-                self._decode_batch(rows)
-                new_tokens = len(rows)
-            if self.prefix_index is not None:
-                # pages with refcount > 1 right now — the live measure
-                # of how much pool the sharing is actually saving
-                spec_fields["pool_shared_pages"] = self.cache.pages_shared
+            with phase("engine.decode", rows=len(rows),
+                       rids=tuple(r.rid for r in rows)) as span:
+                if any(r.rid in drafts for r in rows):
+                    drafted, accepted, committed = self._verify_batch(
+                        rows, drafts)
+                    new_tokens = sum(committed)
+                    # tokens per row, where that is not one each
+                    span.attrs["committed"] = tuple(committed)
+                    spec_fields = {"spec_verify": True,
+                                   "spec_drafted": drafted,
+                                   "spec_accepted": accepted}
+                else:
+                    # every draft came back empty (or speculation is
+                    # off): the plain q_len=1 decode executable is
+                    # cheaper
+                    self._decode_batch(rows)
+                    new_tokens = len(rows)
             self.decode_steps += 1
-            # evictions ride the decode_step payload (a preempted
-            # request is also visible later: its re-admission's
-            # request_admit carries preemptions > 0)
-            self._emit("decode_step", batch=len(rows),
-                       new_tokens=new_tokens,
-                       pool_used=self.cache.pages_used,
-                       pool_pages=self.cache.num_pages - 1,
-                       evicted=[r.rid for r in evicted],
-                       step_ms=round((self.clock() - t0) * 1e3, 3),
-                       **spec_fields)
+            if self.telemetry is not None:
+                if self.prefix_index is not None:
+                    # pages with refcount > 1 right now — the live
+                    # measure of how much pool the sharing is actually
+                    # saving
+                    spec_fields["pool_shared_pages"] = \
+                        self.cache.pages_shared
+                # evictions ride the decode_step payload (a preempted
+                # request is also visible later: its re-admission's
+                # request_admit carries preemptions > 0).  step_ms and
+                # phase_ms are the engine.decode phase and its children
+                # as the ring holds them: one measurement for the
+                # operator's stream and the benchmark's readers
+                self._emit("decode_step", batch=len(rows),
+                           new_tokens=new_tokens,
+                           pool_used=self.cache.pages_used,
+                           pool_pages=self.cache.num_pages - 1,
+                           evicted=[r.rid for r in evicted],
+                           step_ms=span.record.ms,
+                           phase_ms={c.name: c.ms
+                                     for c in span.children},
+                           **spec_fields)
             progress = True
         elif evicted or chunk_plan:
             progress = True
-        self.steps += 1
-        if isinstance(self.clock, SimClock):
-            self.clock.advance()
         return progress
 
     # -- crash recovery (ISSUE 10) -----------------------------------------

@@ -220,35 +220,38 @@ class PagedDecoder:
         one ``psum``."""
         cfg = self.cfg
         hd = cfg.head_dim
-        x = params["embed"][tokens] + params["pos"][positions]
+        with jax.named_scope("embedding"):
+            x = params["embed"][tokens] + params["pos"][positions]
         ks, vs = [], []
         for layer in params["layers"]:
-            hdn = _ln(x, layer["ln1"])
-            qkv = hdn @ layer["wqkv"]
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            b, s = q.shape[:2]
-            nh = k.shape[-1] // hd  # LOCAL heads (H/tp under shard_map)
-            q4 = q.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
-            k4 = k.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
-            v4 = v.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
-            ctx = flash_attention(q4, k4, v4, causal=True,
-                                  segment_ids=seg)
-            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, -1)
-            attn = ctx @ layer["wo"]
-            if tp_axis is not None:
-                attn = jax.lax.psum(attn, tp_axis)
-            x = x + attn
-            mlp = _mlp(_ln(x, layer["ln2"]), layer)
-            if tp_axis is not None:
-                mlp = jax.lax.psum(mlp, tp_axis)
-            x = x + mlp
-            ks.append(k.reshape(b, s, nh, hd))
-            vs.append(v.reshape(b, s, nh, hd))
-        x = _ln(x, params["ln_f"])
-        if last_index is not None:
-            x = jax.lax.dynamic_slice_in_dim(
-                x, jnp.asarray(last_index, jnp.int32), 1, axis=1)
-        logits = x @ params["embed"].T
+            with jax.named_scope("layer"):
+                hdn = _ln(x, layer["ln1"])
+                qkv = hdn @ layer["wqkv"]
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+                b, s = q.shape[:2]
+                nh = k.shape[-1] // hd  # LOCAL heads (H/tp under shard_map)
+                q4 = q.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
+                k4 = k.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
+                v4 = v.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
+                ctx = flash_attention(q4, k4, v4, causal=True,
+                                      segment_ids=seg)
+                ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, -1)
+                attn = ctx @ layer["wo"]
+                if tp_axis is not None:
+                    attn = jax.lax.psum(attn, tp_axis)
+                x = x + attn
+                mlp = _mlp(_ln(x, layer["ln2"]), layer)
+                if tp_axis is not None:
+                    mlp = jax.lax.psum(mlp, tp_axis)
+                x = x + mlp
+                ks.append(k.reshape(b, s, nh, hd))
+                vs.append(v.reshape(b, s, nh, hd))
+        with jax.named_scope("head"):
+            x = _ln(x, params["ln_f"])
+            if last_index is not None:
+                x = jax.lax.dynamic_slice_in_dim(
+                    x, jnp.asarray(last_index, jnp.int32), 1, axis=1)
+            logits = x @ params["embed"].T
         return logits, jnp.stack(ks), jnp.stack(vs)
 
     # -- steady state: paged decode --------------------------------------
@@ -279,40 +282,43 @@ class PagedDecoder:
         page_size = k_pool.shape[2]
         quantized = k_scale is not None
         qmax = quant_qmax(k_pool.dtype) if quantized else None
-        x = params["embed"][tokens] + params["pos"][positions]  # [b, h]
+        with jax.named_scope("embedding"):
+            x = params["embed"][tokens] + params["pos"][positions]  # [b, h]
         page_slot = positions // page_size
         page_idx = jnp.take_along_axis(
             page_table, page_slot[:, None], axis=1)[:, 0]
         offset = positions % page_size
         for li, layer in enumerate(params["layers"]):
-            hdn = _ln(x, layer["ln1"])
-            qkv = hdn @ layer["wqkv"]
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            b = q.shape[0]
-            nh = k.shape[-1] // hd  # LOCAL heads (H/tp under shard_map)
-            k_new, v_new = k.reshape(b, nh, hd), v.reshape(b, nh, hd)
-            if quantized:
-                k_new, k_s = quantize_tokens(k_new, k_pool.dtype, qmax)
-                v_new, v_s = quantize_tokens(v_new, v_pool.dtype, qmax)
-                k_scale = k_scale.at[li, page_idx, offset].set(k_s)
-                v_scale = v_scale.at[li, page_idx, offset].set(v_s)
-            k_pool = k_pool.at[li, page_idx, offset].set(k_new)
-            v_pool = v_pool.at[li, page_idx, offset].set(v_new)
-            q4 = q.reshape(b, 1, nh, hd).transpose(0, 2, 1, 3)
-            ctx = flash_decode(
-                q4, k_pool[li], v_pool[li], page_table, kv_len,
-                k_scale=k_scale[li] if quantized else None,
-                v_scale=v_scale[li] if quantized else None)
-            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, -1)
-            attn = ctx @ layer["wo"]
-            if tp_axis is not None:
-                attn = jax.lax.psum(attn, tp_axis)
-            x = x + attn
-            mlp = _mlp(_ln(x, layer["ln2"]), layer)
-            if tp_axis is not None:
-                mlp = jax.lax.psum(mlp, tp_axis)
-            x = x + mlp
-        logits = _ln(x, params["ln_f"]) @ params["embed"].T
+            with jax.named_scope("layer"):
+                hdn = _ln(x, layer["ln1"])
+                qkv = hdn @ layer["wqkv"]
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+                b = q.shape[0]
+                nh = k.shape[-1] // hd  # LOCAL heads (H/tp under shard_map)
+                k_new, v_new = k.reshape(b, nh, hd), v.reshape(b, nh, hd)
+                if quantized:
+                    k_new, k_s = quantize_tokens(k_new, k_pool.dtype, qmax)
+                    v_new, v_s = quantize_tokens(v_new, v_pool.dtype, qmax)
+                    k_scale = k_scale.at[li, page_idx, offset].set(k_s)
+                    v_scale = v_scale.at[li, page_idx, offset].set(v_s)
+                k_pool = k_pool.at[li, page_idx, offset].set(k_new)
+                v_pool = v_pool.at[li, page_idx, offset].set(v_new)
+                q4 = q.reshape(b, 1, nh, hd).transpose(0, 2, 1, 3)
+                ctx = flash_decode(
+                    q4, k_pool[li], v_pool[li], page_table, kv_len,
+                    k_scale=k_scale[li] if quantized else None,
+                    v_scale=v_scale[li] if quantized else None)
+                ctx = ctx.transpose(0, 2, 1, 3).reshape(b, -1)
+                attn = ctx @ layer["wo"]
+                if tp_axis is not None:
+                    attn = jax.lax.psum(attn, tp_axis)
+                x = x + attn
+                mlp = _mlp(_ln(x, layer["ln2"]), layer)
+                if tp_axis is not None:
+                    mlp = jax.lax.psum(mlp, tp_axis)
+                x = x + mlp
+        with jax.named_scope("head"):
+            logits = _ln(x, params["ln_f"]) @ params["embed"].T
         if quantized:
             return logits, k_pool, v_pool, k_scale, v_scale
         return logits, k_pool, v_pool
@@ -358,41 +364,44 @@ class PagedDecoder:
         b, q = tokens.shape
         quantized = k_scale is not None
         qmax = quant_qmax(k_pool.dtype) if quantized else None
-        x = params["embed"][tokens] + params["pos"][positions]  # [b, q, h]
+        with jax.named_scope("embedding"):   # x [b, q, h]
+            x = params["embed"][tokens] + params["pos"][positions]
         for li, layer in enumerate(params["layers"]):
-            hdn = _ln(x, layer["ln1"])
-            qkv = hdn @ layer["wqkv"]
-            qh, kh, vh = jnp.split(qkv, 3, axis=-1)
-            nh = kh.shape[-1] // hd  # LOCAL heads (H/tp under shard_map)
-            k_new = kh.reshape(b, q, nh, hd)
-            v_new = vh.reshape(b, q, nh, hd)
-            if quantized:
-                k_new, k_s = quantize_tokens(k_new, k_pool.dtype, qmax)
-                v_new, v_s = quantize_tokens(v_new, v_pool.dtype, qmax)
-                k_scale = k_scale.at[li, write_pages,
-                                     write_offsets].set(k_s)
-                v_scale = v_scale.at[li, write_pages,
-                                     write_offsets].set(v_s)
-            k_pool = k_pool.at[li, write_pages, write_offsets].set(k_new)
-            v_pool = v_pool.at[li, write_pages, write_offsets].set(v_new)
-            q4 = qh.reshape(b, q, nh, hd).transpose(0, 2, 1, 3)
-            ctx = flash_decode(
-                q4, k_pool[li], v_pool[li], page_table, kv_len,
-                k_scale=k_scale[li] if quantized else None,
-                v_scale=v_scale[li] if quantized else None)
-            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, q, -1)
-            attn = ctx @ layer["wo"]
-            if tp_axis is not None:
-                attn = jax.lax.psum(attn, tp_axis)
-            x = x + attn
-            mlp = _mlp(_ln(x, layer["ln2"]), layer)
-            if tp_axis is not None:
-                mlp = jax.lax.psum(mlp, tp_axis)
-            x = x + mlp
-        x = _ln(x, params["ln_f"])
-        if last_only:
-            x = x[:, -1:, :]
-        logits = x @ params["embed"].T
+            with jax.named_scope("layer"):
+                hdn = _ln(x, layer["ln1"])
+                qkv = hdn @ layer["wqkv"]
+                qh, kh, vh = jnp.split(qkv, 3, axis=-1)
+                nh = kh.shape[-1] // hd  # LOCAL heads (H/tp under shard_map)
+                k_new = kh.reshape(b, q, nh, hd)
+                v_new = vh.reshape(b, q, nh, hd)
+                if quantized:
+                    k_new, k_s = quantize_tokens(k_new, k_pool.dtype, qmax)
+                    v_new, v_s = quantize_tokens(v_new, v_pool.dtype, qmax)
+                    k_scale = k_scale.at[li, write_pages,
+                                         write_offsets].set(k_s)
+                    v_scale = v_scale.at[li, write_pages,
+                                         write_offsets].set(v_s)
+                k_pool = k_pool.at[li, write_pages, write_offsets].set(k_new)
+                v_pool = v_pool.at[li, write_pages, write_offsets].set(v_new)
+                q4 = qh.reshape(b, q, nh, hd).transpose(0, 2, 1, 3)
+                ctx = flash_decode(
+                    q4, k_pool[li], v_pool[li], page_table, kv_len,
+                    k_scale=k_scale[li] if quantized else None,
+                    v_scale=v_scale[li] if quantized else None)
+                ctx = ctx.transpose(0, 2, 1, 3).reshape(b, q, -1)
+                attn = ctx @ layer["wo"]
+                if tp_axis is not None:
+                    attn = jax.lax.psum(attn, tp_axis)
+                x = x + attn
+                mlp = _mlp(_ln(x, layer["ln2"]), layer)
+                if tp_axis is not None:
+                    mlp = jax.lax.psum(mlp, tp_axis)
+                x = x + mlp
+        with jax.named_scope("head"):
+            x = _ln(x, params["ln_f"])
+            if last_only:
+                x = x[:, -1:, :]
+            logits = x @ params["embed"].T
         if quantized:
             return logits, k_pool, v_pool, k_scale, v_scale
         return logits, k_pool, v_pool

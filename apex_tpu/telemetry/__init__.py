@@ -14,6 +14,11 @@ capture, per-op attribution) is :mod:`apex_tpu.profiling`:
 - **flight recorder** — :class:`FlightRecorder` ring of the last N
   events, flushed to ``postmortem_*.jsonl`` on SIGTERM, watchdog
   escalation, or device loss (``bus.flush_postmortem``);
+- **phases** — :func:`phase` (ISSUE 27): host-phase spans as
+  ``apex:<name>`` ``TraceAnnotation``s on the profiler's clock, every
+  one also a :class:`PhaseRecord` in :data:`PHASE_RING`, the one
+  bounded in-memory ring (the serving engine's step phases; the
+  benchmark's per-layer readers cut their window out of it);
 - **schema** — :func:`validate_event` / :func:`validate_jsonl`, the
   CI-side contract every producer is tested against;
 - **sampler** — :class:`ProfileSampler` (ISSUE 9): periodic in-run
@@ -48,6 +53,11 @@ from apex_tpu.telemetry.bus import (  # noqa: F401
     TelemetryError,
     default_mesh_topology,
     install_recompile_listener,
+)
+from apex_tpu.telemetry.phases import (  # noqa: F401
+    PHASE_RING,
+    PhaseRecord,
+    phase,
 )
 from apex_tpu.telemetry.recorder import FlightRecorder  # noqa: F401
 from apex_tpu.telemetry.regress import (  # noqa: F401
@@ -89,6 +99,9 @@ from apex_tpu.telemetry.tracing import (  # noqa: F401
 __all__ = [
     "EVENT_TYPES",
     "FlightRecorder",
+    "PHASE_RING",
+    "PhaseRecord",
+    "phase",
     "SPAN_KINDS",
     "Span",
     "TTFT_SUM_TOLERANCE_MS",

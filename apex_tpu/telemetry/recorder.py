@@ -12,11 +12,13 @@ and :meth:`TelemetryBus.flush_postmortem` dumps them to a
 from __future__ import annotations
 
 from collections import deque
-from typing import Any, Dict, List
+from typing import Any, List
 
 
 class FlightRecorder:
-    """Bounded in-memory ring of telemetry events.
+    """Bounded in-memory ring of telemetry events (bus events as
+    dicts; the step phases of :mod:`apex_tpu.telemetry.phases` as
+    ``PhaseRecord``s in a ring of their own).
 
     ``capacity`` — events retained (default 256: at one step event per
     step plus occasional ckpt/skip events, roughly the last couple of
@@ -29,10 +31,10 @@ class FlightRecorder:
         self.capacity = int(capacity)
         self._ring: deque = deque(maxlen=self.capacity)
 
-    def record(self, event: Dict[str, Any]) -> None:
+    def record(self, event: Any) -> None:
         self._ring.append(event)
 
-    def snapshot(self) -> List[Dict[str, Any]]:
+    def snapshot(self) -> List[Any]:
         """The retained events, oldest first (a copy — safe to flush
         while the loop keeps emitting)."""
         return list(self._ring)

@@ -225,7 +225,13 @@ EVENT_FIELDS: Dict[str, Dict[str, FieldSpec]] = {
         "pool_used": req(int),
         "pool_pages": req(int),
         "evicted": opt(list),
+        # the engine.decode phase as the ring holds it (ISSUE 27), on
+        # time.perf_counter_ns whatever the engine's clock is, and its
+        # children by name (decode.build / .dispatch / .fetch /
+        # .commit, ms): the same records the benchmark's readers cut
+        # out of PHASE_RING, so phase_ms sums to no more than step_ms
         "step_ms": opt(*NUMBER),
+        "phase_ms": opt(dict),
         # speculative verify boundaries (ISSUE 12): present only when
         # the step ran the draft–verify executable.  spec_verify is a
         # REAL bool; spec_drafted/spec_accepted count draft tokens

@@ -321,7 +321,10 @@ def build_flagship_train_step(
         def lossf(p):
             return jnp.mean(model.apply(p, tokens, labels=labels))
 
-        loss, grads = jax.value_and_grad(lossf)(p)
+        # the step's phases by name in the device trace: fwd_bwd here,
+        # zero_pack / zero_update / zero_unpack inside opt.step
+        with jax.named_scope("fwd_bwd"):
+            loss, grads = jax.value_and_grad(lossf)(p)
         new_p, new_state = opt.step(grads, state, p, schema)
         loss = jax.lax.pmean(loss, opt.axis_name)
         return (new_p,
@@ -452,7 +455,8 @@ def _build_flagship_train_step_3d(cfg, *, plan, lr, weight_decay, devs,
                 return jnp.mean(model.apply(_slice_tp(mp, t_idx), tokens,
                                             labels=labels))
 
-            loss, grads = jax.value_and_grad(local_loss)(mp)
+            with jax.named_scope("fwd_bwd"):
+                loss, grads = jax.value_and_grad(local_loss)(mp)
             loss = jax.lax.pmean(loss, parallel_state.DATA_AXIS)
             new_p, new_state = opt.step_buckets(grads, state, mp, schema,
                                                 bplan)
@@ -515,7 +519,8 @@ def _build_flagship_train_step_3d(cfg, *, plan, lr, weight_decay, devs,
         check_rep=False)
 
     def train_step(mp, state, tokens, labels):
-        loss, grads = jax.value_and_grad(loss_fn)(mp, tokens, labels)
+        with jax.named_scope("fwd_bwd"):
+            loss, grads = jax.value_and_grad(loss_fn)(mp, tokens, labels)
         new_p, new_state = opt_sharded(grads, state, mp)
         return new_p, new_state, loss
 
