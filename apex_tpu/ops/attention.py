@@ -2173,12 +2173,23 @@ def flash_attention_varlen(
 # scalar-prefetch operand and DRIVES THE BLOCK INDEX MAP — grid step
 # (b, p) DMAs pool page ``page_table[b, p]`` into VMEM, so the
 # gather that the generic XLA baseline materialises in HBM never
-# happens.  A block is a WHOLE page, every head of it:
-# ``(1, page_size, h, d)`` ends in the pool's own ``(h, d)`` extent,
-# which is what Mosaic's block rule asks of the last two dims (a unit
-# block on the head axis is neither a multiple of 8 nor the full
-# extent and does not lower), and it is one contiguous DMA per page.
-# The heads are then a static loop inside the kernel.  Per-request
+# happens.  The kernel's K/V operand is the engine's WHOLE pool,
+# ``[L, n_pages, page_size, h, d]``, every layer of it: the layer to
+# read is a third scalar-prefetch operand, block index
+# ``(layer[0], page_table[b, p], 0, 0, 0)`` (as an operand and not a
+# constant of the index map it leaves the model's L calls one Mosaic
+# kernel, not L of them to compile).  A Mosaic custom call cannot
+# take a view into a larger array, so handing it ``pool[layer]`` makes
+# XLA copy that layer's pages into a fresh buffer before every call
+# (48 copies of 157 MB a decode step at the 1.3B geometry: 23 ms, more
+# device time than anything but the kernel itself; PERF.md, PR 28);
+# addressed through the index map the bytes are read where they lie.
+# A block is a WHOLE page, every head of it: ``(None, None, page_size,
+# h, d)`` (layer and page squeezed) ends in the pool's own ``(h, d)``
+# extent, which is what Mosaic's block rule asks of the last two dims
+# (a unit block on the head axis is neither a multiple of 8 nor the
+# full extent and does not lower), and it is one contiguous DMA per
+# page.  The heads are then a static loop inside the kernel.  Per-request
 # raggedness is the same trick as the varlen block-skip index: the
 # k-loop (here the page grid dimension) is bounded by the request's
 # page count — pages past ``kv_len`` are predicated off with
@@ -2192,17 +2203,20 @@ def flash_attention_varlen(
 
 def _make_decode_kernel(*, scale, page_size, q_len, h, d, quantized=False):
     """Decode forward: grid (b, p_max); scalar-prefetch operands
-    (page_table [b, p_max], kv_len [b]).  Queries are the LAST ``q_len``
+    (page_table [b, p_max], kv_len [b], layer [1], the last read by
+    the index maps alone).  Queries are the LAST ``q_len``
     positions of the request's ``kv_len``-token cache (their own k/v
     already appended), so row i's causal limit is column
     ``kv_len - q_len + i``.
 
-    ``quantized`` adds two per-(page, slot, head) fp32 scale operands
-    (blocks [1, page_size, h]) and dequantizes K/V *in-register* right
+    The K/V blocks arrive with layer and page squeezed away: one page,
+    ``[page_size, h, d]``.  ``quantized`` adds two per-(page, slot, head)
+    fp32 scale operands (blocks ``[page_size, h]``, by the same page
+    index) and dequantizes K/V *in-register* right
     after the page DMA — the narrow pool bytes are what crosses HBM,
     the fp32 view never exists outside VMEM (r17)."""
 
-    def kernel(pt_ref, kl_ref, q_ref, k_ref, v_ref, *rest):
+    def kernel(pt_ref, kl_ref, layer_ref, q_ref, k_ref, v_ref, *rest):
         if quantized:
             ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
         else:
@@ -2223,12 +2237,12 @@ def _make_decode_kernel(*, scale, page_size, q_len, h, d, quantized=False):
         def _():
             for hi in range(h):
                 q = q_ref[0, hi]          # [q_len, d]
-                k = k_ref[0, :, hi, :]    # [page_size, d]
-                v = v_ref[0, :, hi, :]
+                k = k_ref[:, hi, :]       # [page_size, d]
+                v = v_ref[:, hi, :]
                 if quantized:
                     q = q.astype(jnp.float32)
-                    k = k.astype(jnp.float32) * ks_ref[0, :, hi][:, None]
-                    v = v.astype(jnp.float32) * vs_ref[0, :, hi][:, None]
+                    k = k.astype(jnp.float32) * ks_ref[:, hi][:, None]
+                    v = v.astype(jnp.float32) * vs_ref[:, hi][:, None]
                 s = jax.lax.dot_general(
                     q, k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * scale
@@ -2266,29 +2280,39 @@ def _make_decode_kernel(*, scale, page_size, q_len, h, d, quantized=False):
 
 
 def _flash_decode_pallas(q, k_pages, v_pages, page_table, kv_len, scale,
-                         k_scale=None, v_scale=None):
-    """q [b, h, q_len, d]; k_pages/v_pages [n_pages, page_size, h, d];
-    page_table [b, p_max] int32 (rows padded with page 0); kv_len [b];
-    optional k_scale/v_scale [n_pages, page_size, h] fp32 (quantized
-    pool — dequantized in-kernel).  Returns o [b, h, q_len, d]."""
+                         layer, k_scale=None, v_scale=None):
+    """q [b, h, q_len, d]; k_pages/v_pages [L, n_pages, page_size, h, d],
+    of which the static int ``layer`` is read; page_table [b, p_max]
+    int32 (rows padded with page 0); kv_len [b]; optional
+    k_scale/v_scale [L, n_pages, page_size, h] fp32 (quantized pool —
+    dequantized in-kernel).  Returns o [b, h, q_len, d]."""
     b, h, q_len, d = q.shape
-    page_size = k_pages.shape[1]
+    page_size = k_pages.shape[2]
     p_max = page_table.shape[1]
     quantized = k_scale is not None
     q_spec = pl.BlockSpec((1, h, q_len, d),
-                          lambda bi, p, pt, kl: (bi, 0, 0, 0))
-    page_spec = pl.BlockSpec((1, page_size, h, d),
-                             lambda bi, p, pt, kl: (pt[bi, p], 0, 0, 0))
+                          lambda bi, p, pt, kl, ly: (bi, 0, 0, 0))
+    page_spec = pl.BlockSpec(
+        (None, None, page_size, h, d),
+        lambda bi, p, pt, kl, ly: (ly[0], pt[bi, p], 0, 0, 0))
     in_specs = [q_spec, page_spec, page_spec]
     operands = [q, k_pages, v_pages]
     if quantized:
-        scale_spec = pl.BlockSpec((1, page_size, h),
-                                  lambda bi, p, pt, kl: (pt[bi, p], 0, 0))
+        # The scale planes, unlike the pages, go in one layer at a time.
+        # XLA keeps an fp32 [L, n_pages, page_size, h] plane in a tiled
+        # layout of its own choosing (h = 16 is a poor lane dimension:
+        # row-major it pads eightfold) and Mosaic asks for the row-major
+        # one, so the operand is laid out anew before every call
+        # whatever is passed; of one layer that is 1/L of the work and
+        # of the temporary (PERF.md, PR 28).
+        scale_spec = pl.BlockSpec(
+            (None, page_size, h),
+            lambda bi, p, pt, kl, ly: (pt[bi, p], 0, 0))
         in_specs += [scale_spec, scale_spec]
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        operands += [k_scale[layer].astype(jnp.float32),
+                     v_scale[layer].astype(jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, p_max),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -2306,28 +2330,30 @@ def _flash_decode_pallas(q, k_pages, v_pages, page_table, kv_len, scale,
         name="flash_decode",
         interpret=use_interpret(),
     )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32),
-      *operands)
+      jnp.full((1,), layer, jnp.int32), *operands)
 
 
 def _paged_attention_xla(q, k_pages, v_pages, page_table, kv_len, scale,
-                         k_scale=None, v_scale=None):
-    """Generic baseline: gather the page list into a contiguous
-    [b, p_max*page_size, h, d] KV view in HBM, then plain masked
-    attention in fp32 — identical math to the kernel, with the
-    materialised gather the kernel exists to avoid.  The decode
-    route's ``routing_override`` escape hatch and the parity sweep's
-    reference.  With ``k_scale``/``v_scale`` [n_pages, page_size, h]
-    the pool is quantized: the gathered bytes are dequantized
-    (``value * scale``, fp32) before scoring — same contraction the
-    Pallas kernel runs in VMEM."""
+                         layer, k_scale=None, v_scale=None):
+    """Generic baseline: gather the page list out of layer ``layer`` of
+    the pool ``[L, n_pages, page_size, h, d]`` into a contiguous
+    [b, p_max*page_size, h, d] KV view in HBM (XLA fuses the layer's
+    slice into the gather), then plain masked attention in fp32 —
+    identical math to the kernel, with the materialised gather the
+    kernel exists to avoid.  The decode route's ``routing_override``
+    escape hatch and the parity sweep's reference.  With
+    ``k_scale``/``v_scale`` [L, n_pages, page_size, h] the pool is
+    quantized: the gathered bytes are dequantized (``value * scale``,
+    fp32) before scoring — same contraction the Pallas kernel runs in
+    VMEM."""
     b, h, q_len, d = q.shape
-    page_size = k_pages.shape[1]
+    page_size = k_pages.shape[2]
     p_max = page_table.shape[1]
-    kc = k_pages[page_table]         # [b, p_max, page_size, h, d]
-    vc = v_pages[page_table]
+    kc = k_pages[layer][page_table]  # [b, p_max, page_size, h, d]
+    vc = v_pages[layer][page_table]
     if k_scale is not None:
-        kc = kc.astype(jnp.float32) * k_scale[page_table][..., None]
-        vc = vc.astype(jnp.float32) * v_scale[page_table][..., None]
+        kc = kc.astype(jnp.float32) * k_scale[layer][page_table][..., None]
+        vc = vc.astype(jnp.float32) * v_scale[layer][page_table][..., None]
     kc = kc.reshape(b, p_max * page_size, h, d)
     vc = vc.reshape(b, p_max * page_size, h, d)
     s = jnp.einsum("bhqd,bkhd->bhqk", q.astype(jnp.float32),
@@ -2349,9 +2375,10 @@ def _decode_shape_ok(q, k_pages):
     a whole number of native tiles for the POOL dtype (the same Mosaic
     grain rule ``_pallas_ok`` applies to block_q/block_k: 8 rows at
     fp32, 16 at bf16, 32 at one-byte dtypes), and pool/head dims must
-    agree."""
+    agree.  ``k_pages`` is the whole pool or one layer of it: the page
+    shape is its last three dimensions either way."""
     b, h, q_len, d = q.shape
-    n_pages, page_size, hp, dp = k_pages.shape
+    page_size, hp, dp = k_pages.shape[-3:]
     grain = 32 // max(1, jnp.dtype(k_pages.dtype).itemsize)
     return (hp == h and dp == d and page_size % grain == 0
             and q_len >= 1)
@@ -2361,7 +2388,7 @@ def _decode_tpu_ok(q):
     """The EXTRA constraint auto-routing applies before picking the
     kernel on a real TPU: the head dim is the block's lane extent and
     must be a whole number of 128-lane tiles for Mosaic to lower the
-    (1, page_size, h, d) K/V blocks.  Conservative by design — the
+    (page_size, h, d) K/V blocks.  Conservative by design — the
     flagship geometry (d=128) passes; a forced "decode" skips this
     (interpret mode has no lane constraint, and on-TPU forcing is the
     caller's explicit opt-in, same contract as the fwd/bwd tables)."""
@@ -2399,6 +2426,7 @@ def flash_decode(
     page_table: jnp.ndarray, kv_len: jnp.ndarray,
     *,
     scale: Optional[float] = None,
+    layer: int = 0,
     k_scale: Optional[jnp.ndarray] = None,
     v_scale: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
@@ -2407,14 +2435,20 @@ def flash_decode(
     ``q`` [b, h, q_len, d]: the last ``q_len`` positions of each
     request (q_len is 1 for plain autoregressive decode, >1 for
     speculative/chunked decode).  ``k_pages``/``v_pages``
-    [n_pages, page_size, h, d]: the shared page pool.  ``page_table``
-    [b, p_max] int32: each request's page list in cache order, rows
-    padded with page 0 (the pool's reserved scratch page — see
-    ``apex_tpu.serving.kv_cache``).  ``kv_len`` [b]: valid tokens per
-    request, INCLUDING however many of the ``q_len`` query rows are
-    real; their k/v must already be appended to the cache.  Decode is
-    causal by construction: query row i sees columns
-    ``[0, kv_len - q_len + i]``.
+    [L, n_pages, page_size, h, d]: the shared page pool, WHOLE, every
+    layer of it, and ``layer`` (a static int) the one to read.  Pass
+    the pool itself and not ``pool[layer]``: the kernel addresses the
+    layer through its block index map and reads the pages where they
+    lie, whereas a slice handed to a Mosaic call is first copied out,
+    layer by layer (157 MB twice a layer at the 1.3B geometry).  A
+    single layer's pool ``[n_pages, page_size, h, d]`` is the same
+    thing at ``L = 1``.  ``page_table`` [b, p_max] int32: each
+    request's page list in cache order, rows padded with page 0 (the
+    pool's reserved scratch page — see ``apex_tpu.serving.kv_cache``).
+    ``kv_len`` [b]: valid tokens per request, INCLUDING however many
+    of the ``q_len`` query rows are real; their k/v must already be
+    appended to the cache.  Decode is causal by construction: query
+    row i sees columns ``[0, kv_len - q_len + i]``.
 
     ``kv_len < q_len`` is ALLOWED and part of the contract (both
     routes guard the empty-window normalizer): rows whose causal
@@ -2426,10 +2460,10 @@ def flash_decode(
     ``test_kv_len_shorter_than_window_is_exact_zeros``.
 
     Quantized pool (r17): when ``k_scale``/``v_scale``
-    [n_pages, page_size, h] fp32 are given, ``k_pages``/``v_pages``
-    hold quantized codes (int8 or fp8) and BOTH routes dequantize on
-    read — ``code * scale`` per (page, slot, head), fp32 — so the
-    narrow bytes are what crosses HBM.  Note the shape gate's grain
+    [L, n_pages, page_size, h] fp32 (the pool's shape less ``d``) are
+    given, ``k_pages``/``v_pages`` hold quantized codes (int8 or fp8)
+    and BOTH routes dequantize on read — ``code * scale`` per (page,
+    slot, head), fp32 — so the narrow bytes are what crosses HBM.  Note the shape gate's grain
     rule is dtype-aware: a one-byte pool needs ``page_size % 32 == 0``
     for the Pallas route; smaller pages fall back to the XLA route,
     which runs the identical dequant math.  Scales must come in pairs
@@ -2443,15 +2477,21 @@ def flash_decode(
         raise ValueError("k_scale and v_scale must be given together")
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    if k_pages.ndim == 4:  # one layer's pool: the whole-pool form at L = 1
+        k_pages, v_pages = k_pages[None], v_pages[None]
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+    if not 0 <= layer < k_pages.shape[0]:
+        # a block index is not bounds-checked on the chip
+        raise ValueError(f"layer {layer} is not one of the pool's "
+                         f"{k_pages.shape[0]}")
     kv_len = jnp.asarray(kv_len, jnp.int32)
     page_table = jnp.asarray(page_table, jnp.int32)
-    if flash_decode_route(q, k_pages) == "decode":
-        return _flash_decode_pallas(q, k_pages, v_pages, page_table,
-                                    kv_len, float(scale),
-                                    k_scale=k_scale, v_scale=v_scale)
-    return _paged_attention_xla(q, k_pages, v_pages, page_table,
-                                kv_len, float(scale),
-                                k_scale=k_scale, v_scale=v_scale)
+    attend = (_flash_decode_pallas
+              if flash_decode_route(q, k_pages) == "decode"
+              else _paged_attention_xla)
+    return attend(q, k_pages, v_pages, page_table, kv_len, float(scale),
+                  layer, k_scale=k_scale, v_scale=v_scale)
 
 
 # ---------------------------------------------------------------------------

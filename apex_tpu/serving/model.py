@@ -276,7 +276,15 @@ class PagedDecoder:
         5-tuple appending the updated scale planes: the append
         quantizes on write and ``flash_decode`` dequantizes on read.
         ``tp_axis``: per-shard body under ``shard_map`` (local head
-        slice of pool and scales, one ``psum`` per block)."""
+        slice of pool and scales, one ``psum`` per block).
+
+        Every layer hands ``flash_decode`` the WHOLE pool ``[L,
+        n_pages, ps, H, hd]`` and ``layer=li``, never ``k_pool[li]``:
+        the kernel reads layer ``li``'s pages where they lie, and a
+        slice would be copied out first (all of a layer's pages, twice,
+        before each of the L calls).  The append before it stays in
+        place: the call reads the buffer the scatter wrote.
+        :meth:`extend` does the same."""
         cfg = self.cfg
         hd = cfg.head_dim
         page_size = k_pool.shape[2]
@@ -305,9 +313,8 @@ class PagedDecoder:
                 v_pool = v_pool.at[li, page_idx, offset].set(v_new)
                 q4 = q.reshape(b, 1, nh, hd).transpose(0, 2, 1, 3)
                 ctx = flash_decode(
-                    q4, k_pool[li], v_pool[li], page_table, kv_len,
-                    k_scale=k_scale[li] if quantized else None,
-                    v_scale=v_scale[li] if quantized else None)
+                    q4, k_pool, v_pool, page_table, kv_len, layer=li,
+                    k_scale=k_scale, v_scale=v_scale)
                 ctx = ctx.transpose(0, 2, 1, 3).reshape(b, -1)
                 attn = ctx @ layer["wo"]
                 if tp_axis is not None:
@@ -385,9 +392,8 @@ class PagedDecoder:
                 v_pool = v_pool.at[li, write_pages, write_offsets].set(v_new)
                 q4 = qh.reshape(b, q, nh, hd).transpose(0, 2, 1, 3)
                 ctx = flash_decode(
-                    q4, k_pool[li], v_pool[li], page_table, kv_len,
-                    k_scale=k_scale[li] if quantized else None,
-                    v_scale=v_scale[li] if quantized else None)
+                    q4, k_pool, v_pool, page_table, kv_len, layer=li,
+                    k_scale=k_scale, v_scale=v_scale)
                 ctx = ctx.transpose(0, 2, 1, 3).reshape(b, q, -1)
                 attn = ctx @ layer["wo"]
                 if tp_axis is not None:

@@ -210,6 +210,132 @@ class TestFlashDecodeParity:
 
 
 # ---------------------------------------------------------------------------
+# The whole pool as the operand (ISSUE 28): layer ``li`` of
+# ``[L, n_pages, page_size, h, d]`` through the index map, against the
+# same call on the slice ``pool[li]`` — and the model's jaxpr, which
+# must hand the kernel the pool itself and never build the slice
+# ---------------------------------------------------------------------------
+
+
+def _whole_pool_state(rng, q_len, quantized):
+    """Three rows over a random three-layer pool (every layer other
+    bytes, so a read of the wrong layer cannot agree): one whose whole
+    sequence is shorter than the query window, one idle row (an
+    all-scratch page row, as the engine pads them) and one crossing
+    two page boundaries."""
+    page_size, p_max, h, d = 32, 3, 2, 8
+    shape = (3, 7, page_size, h, d)     # [L, n_pages, page_size, h, d]
+    if quantized:
+        k, v = (jnp.asarray(rng.randint(-127, 128, shape), jnp.int8)
+                for _ in range(2))
+        scales = {name: jnp.asarray(rng.uniform(0.01, 0.1, shape[:-1]),
+                                    jnp.float32)
+                  for name in ("k_scale", "v_scale")}
+    else:
+        k, v = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+                for _ in range(2))
+        scales = {}
+    table = np.zeros((3, p_max), np.int32)
+    table[0, :1] = [4]
+    table[2] = [5, 2, 6]
+    kv_len = np.asarray([q_len - 1, q_len, 2 * page_size + 3], np.int32)
+    q = jnp.asarray(rng.randn(3, h, q_len, d), jnp.bfloat16)
+    return q, k, v, jnp.asarray(table), jnp.asarray(kv_len), scales
+
+
+class TestFlashDecodeWholePool:
+    @pytest.mark.parametrize("layer", [0, 1, 2],
+                             ids=["first", "middle", "last"])
+    @pytest.mark.parametrize("q_len", [1, 4])
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["bf16", "int8"])
+    @pytest.mark.parametrize("route", ["decode", "xla"])
+    def test_layer_of_whole_pool_equals_its_slice(self, route, quantized,
+                                                  q_len, layer):
+        rng = np.random.RandomState(17 * q_len + quantized)
+        q, k, v, table, kv_len, scales = _whole_pool_state(
+            rng, q_len, quantized)
+        with routing_override(decode=route):
+            assert flash_decode_route(q, k) == route
+            whole = flash_decode(q, k, v, table, kv_len, layer=layer,
+                                 **scales)
+            sliced = flash_decode(
+                q, k[layer], v[layer], table, kv_len,
+                **{name: s[layer] for name, s in scales.items()})
+        whole = np.asarray(whole, np.float32)
+        assert np.array_equal(whole, np.asarray(sliced, np.float32))
+        assert np.any(whole[2] != 0) and np.all(np.isfinite(whole))
+        # row 0's window reaches one column short of its last query row
+        assert np.all(whole[0, :, :1] == 0)
+
+    def test_layer_outside_the_pool_raises(self):
+        q, k, v, table, kv_len, _ = _whole_pool_state(
+            np.random.RandomState(0), 1, False)
+        for layer in (-1, 3):
+            with pytest.raises(ValueError, match="layer"):
+                flash_decode(q, k, v, table, kv_len, layer=layer)
+        with pytest.raises(ValueError, match="layer"):
+            flash_decode(q, k[0], v[0], table, kv_len, layer=1)
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it, but
+    not a kernel's own body (its blocks are pages, not pools)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
+@pytest.mark.parametrize("method", ["decode", "extend"])
+def test_model_hands_the_kernel_the_pool_itself(method, quantized):
+    # what would have caught the copy of ISSUE 28: a slice of the pool
+    # as a kernel operand is an array XLA has to materialise
+    from apex_tpu.serving.model import PagedDecoder
+
+    cfg = ServingModelConfig(vocab_size=64, hidden_size=32, num_heads=4,
+                             num_layers=3, max_position=96)
+    n_pages, page_size, b, p_max, window = 5, 32, 2, 2, 4
+    sds = jax.ShapeDtypeStruct
+    pool = sds((cfg.num_layers, n_pages, page_size, cfg.num_heads,
+                cfg.head_dim), jnp.int8 if quantized else cfg.dtype)
+    rows = (b,) if method == "decode" else (b, window)
+    args = [sds(rows, jnp.int32)] * (2 if method == "decode" else 4)
+    args += [sds((b, p_max), jnp.int32), sds((b,), jnp.int32)]
+    kw = {}
+    if quantized:
+        kw = dict.fromkeys(("k_scale", "v_scale"),
+                           sds(pool.shape[:-1], jnp.float32))
+    params = jax.eval_shape(lambda: init_params(cfg, 0))
+    step = getattr(PagedDecoder(cfg), method)
+    with routing_override(decode="decode"):
+        closed = jax.make_jaxpr(
+            lambda params, k_pool, v_pool, args, kw: step(
+                params, k_pool, v_pool, *args, **kw))(
+            params, pool, pool, args, kw)
+    n_leaves = len(jax.tree_util.tree_leaves(params))
+    pools = set(closed.jaxpr.invars[n_leaves:n_leaves + 2])
+    calls = 0
+    for eqn in _eqns(closed.jaxpr):
+        for out in eqn.outvars:
+            assert out.aval.shape != pool.shape[1:], (
+                f"{eqn.primitive.name} builds one layer of the pool")
+        if eqn.primitive.name == "scatter" and eqn.invars[0] in pools:
+            pools.add(eqn.outvars[0])       # the in-place append
+        if eqn.primitive.name == "pallas_call":
+            assert eqn.params["name"] == "flash_decode"
+            calls += 1
+            # page_table, kv_len, layer, q, then K and V
+            k_op, v_op = eqn.invars[4:6]
+            assert k_op in pools and v_op in pools and k_op is not v_op
+            assert k_op.aval.shape == v_op.aval.shape == pool.shape
+    assert calls == cfg.num_layers
+
+
+# ---------------------------------------------------------------------------
 # Page pool accounting
 # ---------------------------------------------------------------------------
 

@@ -34,10 +34,15 @@ def _as_tpu(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
+def _tpu_text(fn, *structs) -> str:
+    """``fn`` lowered for the TPU platform, as text."""
+    lowered = jax.jit(fn).trace(*structs).lower(lowering_platforms=("tpu",))
+    return lowered.as_text()
+
+
 def _mosaic_calls(fn, *structs) -> int:
     """Lower ``fn`` for the TPU platform and count its Mosaic kernels."""
-    lowered = jax.jit(fn).trace(*structs).lower(lowering_platforms=("tpu",))
-    return lowered.as_text().count("tpu_custom_call")
+    return _tpu_text(fn, *structs).count("tpu_custom_call")
 
 
 def _grad_of(fn):
@@ -68,6 +73,37 @@ def test_paged_decode_lowers(heads, q_len, quantized):
     else:
         fn = flash_decode
     assert _mosaic_calls(fn, *args) == 1
+
+
+@pytest.mark.parametrize("layer", [0, 23])
+@pytest.mark.parametrize("pool_dtype", [BF16, jnp.int8, jnp.float8_e4m3fn],
+                         ids=["bf16", "int8", "fp8"])
+def test_paged_decode_lowers_on_the_whole_pool(pool_dtype, layer):
+    # the serving configuration's decode step as the engine runs it:
+    # the whole 24-layer pool is the operand and the layer rides in the
+    # index map (benchmark/configs/gpt1p3b-serve.json: 600 pages of 64
+    # tokens, 32 rows of at most 28 pages)
+    b, heads, d, p_max = 32, 16, 128, 28
+    q = SDS((b, heads, 1, d), BF16)
+    pool = SDS((24, 600, 64, heads, d), pool_dtype)
+    args = [q, pool, pool, SDS((b, p_max), jnp.int32), SDS((b,), jnp.int32)]
+    assert flash_decode_route(q, pool) == "decode"
+    names = []
+    if pool_dtype != BF16:
+        names = ["k_scale", "v_scale"]
+        args += [SDS(pool.shape[:-1], jnp.float32)] * 2
+
+    def fn(q, k, v, pt, kl, *scales):
+        return flash_decode(q, k, v, pt, kl, layer=layer,
+                            **dict(zip(names, scales)))
+
+    calls = [line for line in _tpu_text(fn, *args).splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1
+    # the kernel's K and V operands are the pool, not a slice of it
+    call = calls[0]
+    whole = "x".join(map(str, pool.shape))
+    assert call.count(f"tensor<{whole}x") == 2, call[-600:]
 
 
 # -- generic flash attention: block-skip routes with more than one block ----
