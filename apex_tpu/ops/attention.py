@@ -200,11 +200,12 @@ def _skip_spec_arg(lohi, gridded, n_rows):
 
 
 def _assemble_scores(q, k, qi, ki, *, scale, causal, sq, sk,
-                     mask=None, seg_q=None, seg_k=None):
+                     mask=None, seg_q=None, seg_k=None, window=None):
     """The score block all four kernels share: q·kᵀ·scale, then additive
     mask, segment mask, and causal mask.  ``qi``/``ki`` are the absolute
     row/col offsets of this (q block, k block) tile; mask/seg operands are
-    already sliced to the tile."""
+    already sliced to the tile.  ``window`` (causal only): a row also
+    loses the columns more than ``window - 1`` behind it."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
@@ -216,11 +217,20 @@ def _assemble_scores(q, k, qi, ki, *, scale, causal, sq, sk,
         rows = qi + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         cols = ki + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(rows + (sk - sq) >= cols, s, _NEG_INF)
+        if window is not None:
+            s = jnp.where(cols > rows + (sk - sq) - window, s, _NEG_INF)
     return s
 
 
+def _window_first_block(qi, sq, sk, window, block_k):
+    """The first k block a q block starting at row ``qi`` can see under
+    a sliding window: its first row's oldest visible column."""
+    return jnp.maximum(0, qi + (sk - sq) - window + 1) // block_k
+
+
 def _make_fwd_kernel(*, scale, causal, block_q, block_k, sq, sk,
-                     has_mask, has_seg, dropout_rate, has_skip=False):
+                     has_mask, has_seg, dropout_rate, has_skip=False,
+                     window=None):
     """Online-softmax forward (grid over q blocks) — the streaming form
     for shapes whose whole-sequence working set exceeds VMEM (the
     static-tiles kernel covers the rest).  A grouped-unroll variant
@@ -263,6 +273,9 @@ def _make_fwd_kernel(*, scale, causal, block_q, block_k, sq, sk,
             # block's last row (fully masked) — halves the MXU work
             last_row = qi + block_q - 1 + (sk - sq)
             n_grp = jnp.minimum(n_grp, last_row // block_k + 1)
+        if window is not None:
+            kb_lo = jnp.maximum(kb_lo, _window_first_block(
+                qi, sq, sk, window, block_k))
 
         seg_q = segq_ref[0, :, 0] if has_seg else None  # [block_q]
 
@@ -277,7 +290,7 @@ def _make_fwd_kernel(*, scale, causal, block_q, block_k, sq, sk,
                       if has_mask else None),
                 seg_q=seg_q,
                 seg_k=(segk_ref[0, pl.ds(ki, block_k), 0]
-                       if has_seg else None))
+                       if has_seg else None), window=window)
             return s, v
 
         def dropped(p, kb):
@@ -450,7 +463,7 @@ def _tiles_ok(q, k, mask_bias, block_q, block_k):
 
 
 def _make_fwd_kernel_varlen(*, scale, causal, block_q, block_k, sq, sk,
-                            has_mask, dropout_rate):
+                            has_mask, dropout_rate, window=None):
     """Varlen fast forward (r7): the tiles kernel's whole-sequence
     residency (ONE grid step per batch-head, python-static q-blocks) but
     with each q-block's k-loop bounded by the block-skip index — a
@@ -486,6 +499,9 @@ def _make_fwd_kernel_varlen(*, scale, causal, block_q, block_k, sq, sk,
             if causal:
                 last_row = qi + block_q - 1 + (sk - sq)
                 kb_hi = jnp.minimum(kb_hi, last_row // block_k + 1)
+            if window is not None:
+                kb_lo = jnp.maximum(kb_lo, _window_first_block(
+                    qi, sq, sk, window, block_k))
 
             def body(kb, carry, qi=qi, q=q, seg_q=seg_q):
                 m, l, acc = carry
@@ -499,7 +515,8 @@ def _make_fwd_kernel_varlen(*, scale, causal, block_q, block_k, sq, sk,
                                    pl.ds(ki, block_k)]
                           if has_mask else None),
                     seg_q=seg_q,
-                    seg_k=segk_ref[0, pl.ds(ki, block_k), 0])
+                    seg_k=segk_ref[0, pl.ds(ki, block_k), 0],
+                    window=window)
                 m_new = jnp.maximum(m, jnp.max(s, axis=-1))
                 p = _masked_exp(s, m_new[:, None])
                 alpha = jnp.exp(m - m_new)
@@ -739,13 +756,22 @@ def flash_attention_route(q, k=None, *, mask_bias=None, segment_ids=None,
 
 def _flash_fwd_pallas(q, k, v, mask_bias, seg_q, seg_k, dropout_seed,
                       scale, causal, block_q, block_k, dropout_rate,
-                      route=None):
+                      route=None, group=1, window=None):
     """q [bh, sq, d], k/v [bh, sk, d] → (o [bh, sq, d], lse [bh, sq]).
 
     mask_bias: [mbh, sq, sk] additive (mbh ∈ {bh, 1}) or None.
     seg_q/seg_k: [sbh, sq]/[sbh, sk] int segment ids (sbh ∈ {bh, 1}) or
     None — scores across segments are masked (varlen packing).
     ``route`` picks the kernel (None = auto, see ``_fwd_pallas_route``).
+
+    ``group`` > 1 (grouped-query heads, forward only): k/v are
+    ``[bh // group, sk, d]`` and batch-head ``n`` of q reads row
+    ``n // group`` of them — through the block index alone, so that
+    consecutive grid steps of one group find their K/V block already
+    in VMEM.  ``window``: the causal sliding window (see
+    ``_assemble_scores``); the k loops start at the window's first
+    block.  The static-tiles kernel knows neither and hands such a
+    call to the streaming one.
     """
     bh, sq, d = q.shape
     sk = k.shape[1]
@@ -758,6 +784,15 @@ def _flash_fwd_pallas(q, k, v, mask_bias, seg_q, seg_k, dropout_seed,
         # a skip route needs segments to build the index from — a
         # forced override on an unsegmented call downgrades
         route = "stream"
+    if route == "tiles" and (group > 1 or window is not None):
+        route = "stream"
+    kw_window = {} if window is None else {"window": int(window)}
+    if group > 1:
+        kv1 = lambda b: (b // group, 0, 0)
+        kv2 = lambda b, i: (b // group, 0, 0)
+    else:
+        kv1 = lambda b: (b, 0, 0)
+        kv2 = lambda b, i: (b, 0, 0)
     seed_specs, seed_args = _seed_spec_arg(dropout_rate, dropout_seed)
     n_qb = sq // block_q
     kwargs = dict(
@@ -775,8 +810,8 @@ def _flash_fwd_pallas(q, k, v, mask_bias, seg_q, seg_k, dropout_seed,
         # varlen fast path: whole-sequence residency + block-skip index
         in_specs = [
             pl.BlockSpec((1, sq, d), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, sk, d), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, sk, d), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, sk, d), kv1),
+            pl.BlockSpec((1, sk, d), kv1),
         ]
         tail_specs, tail_args = _mask_seg_specs(
             mask_bias, seg_q, seg_k, sq, sk, gridded_q=None)
@@ -785,7 +820,7 @@ def _flash_fwd_pallas(q, k, v, mask_bias, seg_q, seg_k, dropout_seed,
         kw = dict(kwargs)
         del kw["has_seg"]
         o, lse = pl.pallas_call(
-            _make_fwd_kernel_varlen(**kw),
+            _make_fwd_kernel_varlen(**kw, **kw_window),
             grid=(bh,),
             in_specs=in_specs + tail_specs + skip_specs + seed_specs,
             out_specs=[
@@ -837,8 +872,8 @@ def _flash_fwd_pallas(q, k, v, mask_bias, seg_q, seg_k, dropout_seed,
     # [lo, hi) instead of [0, n_kb)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-        pl.BlockSpec((1, sk, d), lambda b, i: (b, 0, 0)),
-        pl.BlockSpec((1, sk, d), lambda b, i: (b, 0, 0)),
+        pl.BlockSpec((1, sk, d), kv2),
+        pl.BlockSpec((1, sk, d), kv2),
     ]
     tail_specs, tail_args = _mask_seg_specs(
         mask_bias, seg_q, seg_k, block_q, sk, gridded_q=True)
@@ -847,7 +882,8 @@ def _flash_fwd_pallas(q, k, v, mask_bias, seg_q, seg_k, dropout_seed,
         skip_specs, skip_args = _skip_spec_arg(skip_q, gridded=True,
                                                n_rows=n_qb)
     o, lse = pl.pallas_call(
-        _make_fwd_kernel(**kwargs, has_skip=route == "stream_skip"),
+        _make_fwd_kernel(**kwargs, has_skip=route == "stream_skip",
+                         **kw_window),
         grid=(bh, n_qb),
         in_specs=in_specs + tail_specs + skip_specs + seed_specs,
         out_specs=[
@@ -1250,7 +1286,7 @@ def _flash_bwd_pallas(q, k, v, mask_bias, seg_q, seg_k, dropout_seed,
 # ---------------------------------------------------------------------------
 
 
-def _apply_masks(s, mask_bias, seg_q, seg_k, causal):
+def _apply_masks(s, mask_bias, seg_q, seg_k, causal, window=None):
     if mask_bias is not None:
         s = s + mask_bias
     if seg_q is not None:
@@ -1259,17 +1295,23 @@ def _apply_masks(s, mask_bias, seg_q, seg_k, causal):
     if causal:
         sq, sk = s.shape[-2], s.shape[-1]
         tri = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+        if window is not None:
+            tri &= ~jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq - window)
         s = jnp.where(tri, s, _NEG_INF)
     return s
 
 
 def _blockwise_fwd_xla(q, k, v, scale, causal, mask_bias, seg_q, seg_k,
-                       dropout_seed=None, dropout_rate=0.0):
+                       dropout_seed=None, dropout_rate=0.0, group=1,
+                       window=None):
     """Plain-XLA forward with identical math (used off-TPU and for shapes
-    below the TPU tiling grain — where the S×S score matrix is small)."""
+    below the TPU tiling grain — where the S×S score matrix is small).
+    ``group`` > 1: every row of k/v serves ``group`` rows of q."""
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0)
     s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
-    s = _apply_masks(s, mask_bias, seg_q, seg_k, causal)
+    s = _apply_masks(s, mask_bias, seg_q, seg_k, causal, window)
     m = jnp.max(s, axis=-1)
     p = _masked_exp(s, m[..., None])
     l = jnp.sum(p, axis=-1)
@@ -1405,6 +1447,25 @@ def _flash_fwd_dispatch(q, k, v, mask_bias, seg_q, seg_k, dropout_seed,
                                  block_k, dropout_rate, route=route)
     return _blockwise_fwd_xla(q, k, v, scale, causal, mask_bias,
                               seg_q, seg_k, dropout_seed, dropout_rate)
+
+
+def _flash_fwd_grouped(q, k, v, seg_q, seg_k, scale, block_q, block_k,
+                       group, window):
+    """The forward of a call with grouped-query heads or a sliding
+    window: causal, no additive mask, no dropout, and no VJP (the
+    serving path never differentiates; training keeps the kernels and
+    the custom VJP above exactly as they were).  Same routes as
+    :func:`_flash_fwd_dispatch`, decided on q's shape."""
+    bq, bk = min(block_q, q.shape[1]), min(block_k, k.shape[1])
+    route = _fwd_route(q, k, None, seg_q is not None, bq, bk)
+    if route != "xla":
+        o, _ = _flash_fwd_pallas(q, k, v, None, seg_q, seg_k, None, scale,
+                                 True, block_q, block_k, 0.0, route=route,
+                                 group=group, window=window)
+        return o
+    o, _ = _blockwise_fwd_xla(q, k, v, scale, True, None, seg_q, seg_k,
+                              group=group, window=window)
+    return o
 
 
 def _flash_fwd_rule(q, k, v, mask_bias, seg_q, seg_k, dropout_seed,
@@ -2045,8 +2106,17 @@ def flash_attention(
     mask_is_constant: bool = True,
     dropout_rate: float = 0.0,
     dropout_seed: Optional[Union[int, jnp.ndarray]] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Fused attention over [b, h, s, d] (or [bh, s, d]) tensors.
+
+    Grouped-query heads: ``k``/``v`` may carry fewer heads than ``q``
+    (``h_q = g * h_kv``; query head ``n`` reads K/V head ``n // g``).
+    ``window`` (with ``causal``): a position sees itself and the
+    ``window - 1`` before it.  Either makes the call forward-only (no
+    VJP), causal, without additive mask or dropout: the serving
+    prefill.  With one K/V head per query head and no window the call
+    is the one it always was.
 
     ``dropout_rate`` > 0 applies attention-probability dropout INSIDE the
     kernels (the reference FMHA's Philox in-kernel dropout,
@@ -2085,8 +2155,8 @@ def flash_attention(
     if q.ndim == 4:
         b, h, sq, d = q.shape
         q = q.reshape(b * h, sq, d)
-        k = k.reshape(b * h, k.shape[2], d)
-        v = v.reshape(b * h, v.shape[2], d)
+        k = k.reshape(b * k.shape[1], k.shape[2], d)
+        v = v.reshape(b * v.shape[1], v.shape[2], d)
         if mask_bias is not None and mask_bias.ndim == 4:
             mask_bias = jnp.broadcast_to(
                 mask_bias, (b, h, sq, k.shape[1])).reshape(
@@ -2105,7 +2175,18 @@ def flash_attention(
         raise ValueError("dropout_rate > 0 requires dropout_seed")
     seed = jnp.asarray(dropout_seed if dropout_seed is not None else 0,
                        jnp.int32)
-    if mask_bias is not None and not mask_is_constant:
+    group = q.shape[0] // k.shape[0]
+    if group != 1 or window is not None:
+        if (not causal or mask_bias is not None or rate > 0
+                or group * k.shape[0] != q.shape[0]
+                or (window is not None and window < 1)):
+            raise ValueError(
+                "grouped-query heads and a sliding window are for causal "
+                "calls without mask_bias or dropout, q heads a multiple "
+                "of k/v heads, window >= 1")
+        o = _flash_fwd_grouped(q, k, v, seg_q, seg_k, float(scale),
+                               int(block_q), int(block_k), group, window)
+    elif mask_bias is not None and not mask_is_constant:
         # differentiable-bias path: same math, no custom_vjp, so AD
         # derives d(mask_bias) — the kernels only handle constant masks
         o, _ = _blockwise_fwd_xla(q, k, v, float(scale), bool(causal),
@@ -2131,6 +2212,7 @@ def flash_attention_varlen(
     scale: Optional[float] = None,
     block_q: int = 512,
     block_k: int = 1024,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Packed variable-length attention — the reference FMHA's BERT-style
     interface (fmha.py:33-75): sequences concatenated along one token
@@ -2142,6 +2224,8 @@ def flash_attention_varlen(
     construction).  Instead of the reference's CUDA varlen layout, the
     TPU mapping is *segment-id masking inside the flash kernel* — one
     fixed-shape kernel launch, no per-sequence dispatch, MXU-friendly.
+    ``k``/``v`` may carry fewer heads than ``q`` and ``window`` bounds
+    how far back a token sees, as in :func:`flash_attention`.
     """
     if cu_seqlens_k is None:
         cu_seqlens_k = cu_seqlens_q
@@ -2158,7 +2242,7 @@ def flash_attention_varlen(
     vh = jnp.moveaxis(v, 1, 0)
     o = flash_attention(qh, kh, vh, causal=causal,
                         segment_ids=(seg_q, seg_k), scale=scale,
-                        block_q=block_q, block_k=block_k)
+                        block_q=block_q, block_k=block_k, window=window)
     return jnp.moveaxis(o, 0, 1)
 
 
@@ -2201,7 +2285,21 @@ def flash_attention_varlen(
 # ---------------------------------------------------------------------------
 
 
-def _make_decode_kernel(*, scale, page_size, q_len, h, d, quantized=False):
+def _decode_q_tile(q_len, group):
+    """Query positions a grid step of the decode kernel takes.  All of
+    them while a K/V head's ``group * q_len`` rows are at most 512 (plain
+    decode, a verify window, the multi-head chunks of old): one step a
+    page, as ever.  Beyond that (a grouped-query chunk: 6 x 2,048 rows
+    a K/V head, whose blocks and accumulators would not fit in VMEM)
+    the largest halving of ``q_len`` that fits, kept a multiple of 8."""
+    tq = q_len
+    while group * tq > 512 and tq % 16 == 0:
+        tq //= 2
+    return tq
+
+
+def _make_decode_kernel(*, scale, page_size, q_len, h, d, quantized=False,
+                        group=1, tq=None, window=None, has_start=False):
     """Decode forward: grid (b, p_max); scalar-prefetch operands
     (page_table [b, p_max], kv_len [b], layer [1], the last read by
     the index maps alone).  Queries are the LAST ``q_len``
@@ -2214,29 +2312,60 @@ def _make_decode_kernel(*, scale, page_size, q_len, h, d, quantized=False):
     fp32 scale operands (blocks ``[page_size, h]``, by the same page
     index) and dequantizes K/V *in-register* right
     after the page DMA — the narrow pool bytes are what crosses HBM,
-    the fp32 view never exists outside VMEM (r17)."""
+    the fp32 view never exists outside VMEM (r17).
 
-    def kernel(pt_ref, kl_ref, layer_ref, q_ref, k_ref, v_ref, *rest):
+    Grouped-query heads (``group`` > 1): ``h`` counts K/V heads and the
+    q block's rows are ``(position, head of the group)``, position
+    major, so row ``r`` is query position ``r // group``: the group's
+    heads share one dot against the page.  ``tq`` < ``q_len`` adds a
+    grid dimension over tiles of ``tq`` query positions, (b, tiles,
+    p_max).  ``window``: row i also loses the columns at or before
+    ``kv_len - q_len + i - window``.  ``has_start``: a fourth prefetch
+    operand ``start [b]``, the absolute position of the table's first
+    column (a window pool's table holds only the pages still in the
+    window).  A grid step whose page lies wholly outside what its rows
+    can see does nothing.  With ``group`` 1, one tile, no window and no
+    start this is the kernel it always was."""
+    tq = q_len if tq is None else tq
+    tiled = tq != q_len
+    rows_n = group * tq
+
+    def kernel(pt_ref, kl_ref, layer_ref, *rest):
+        if has_start:
+            st_ref, *rest = rest
+        q_ref, k_ref, v_ref, *rest = rest
         if quantized:
             ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
         else:
             o_ref, m_ref, l_ref, acc_ref = rest
         b_idx = pl.program_id(0)
-        p = pl.program_id(1)
-        n_p = pl.num_programs(1)
+        p = pl.program_id(2 if tiled else 1)
+        n_p = pl.num_programs(2 if tiled else 1)
         kv = kl_ref[b_idx]
-        pages_used = (kv + page_size - 1) // page_size
+        # the table's column c is absolute position col0 + c
+        col0 = st_ref[b_idx] if has_start else 0
+        pages_used = (kv - col0 + page_size - 1) // page_size
+        # the first query position of this step's rows
+        row0 = kv - q_len + (pl.program_id(1) * tq if tiled else 0)
 
         @pl.when(p == 0)
         def _():
-            m_ref[...] = jnp.full((h, q_len, 1), _NEG_INF, jnp.float32)
-            l_ref[...] = jnp.zeros((h, q_len, 1), jnp.float32)
-            acc_ref[...] = jnp.zeros((h, q_len, d), jnp.float32)
+            m_ref[...] = jnp.full((h, rows_n, 1), _NEG_INF, jnp.float32)
+            l_ref[...] = jnp.zeros((h, rows_n, 1), jnp.float32)
+            acc_ref[...] = jnp.zeros((h, rows_n, d), jnp.float32)
 
-        @pl.when(p < pages_used)
+        live = p < pages_used
+        if tiled:
+            # pages past the tile's last row score nothing
+            live &= col0 + p * page_size <= row0 + tq - 1
+        if window is not None:
+            # pages wholly before the first row's window score nothing
+            live &= col0 + (p + 1) * page_size - 1 > row0 - window
+
+        @pl.when(live)
         def _():
             for hi in range(h):
-                q = q_ref[0, hi]          # [q_len, d]
+                q = q_ref[0, hi]          # [rows_n, d]
                 k = k_ref[:, hi, :]       # [page_size, d]
                 v = v_ref[:, hi, :]
                 if quantized:
@@ -2247,13 +2376,20 @@ def _make_decode_kernel(*, scale, page_size, q_len, h, d, quantized=False):
                     q, k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * scale
                 rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                if group > 1:
+                    rows = rows // group
                 cols = p * page_size + jax.lax.broadcasted_iota(
                     jnp.int32, s.shape, 1)
+                if has_start:
+                    cols = cols + col0
                 # one mask does both jobs: the causal limit for the
                 # q_len tail AND the kv_len cutoff (row i's limit
                 # kv - q_len + i is < kv, so garbage past the ragged
                 # end never scores)
-                s = jnp.where(cols <= kv - q_len + rows, s, _NEG_INF)
+                limit = row0 + rows
+                s = jnp.where(cols <= limit, s, _NEG_INF)
+                if window is not None:
+                    s = jnp.where(cols > limit - window, s, _NEG_INF)
                 m_prev = m_ref[hi]
                 m_new = jnp.maximum(m_prev,
                                     jnp.max(s, axis=-1, keepdims=True))
@@ -2279,22 +2415,56 @@ def _make_decode_kernel(*, scale, page_size, q_len, h, d, quantized=False):
     return kernel
 
 
+def _group_rows(q, group):
+    """q [b, h_kv * group, q_len, d] -> [b, h_kv, q_len * group, d]:
+    the rows of one K/V head, position major."""
+    b, hq, q_len, d = q.shape
+    q = q.reshape(b, hq // group, group, q_len, d)
+    return q.transpose(0, 1, 3, 2, 4).reshape(b, hq // group,
+                                              q_len * group, d)
+
+
+def _ungroup_rows(o, group):
+    b, h, rows, d = o.shape
+    o = o.reshape(b, h, rows // group, group, d)
+    return o.transpose(0, 1, 3, 2, 4).reshape(b, h * group,
+                                              rows // group, d)
+
+
 def _flash_decode_pallas(q, k_pages, v_pages, page_table, kv_len, scale,
-                         layer, k_scale=None, v_scale=None):
-    """q [b, h, q_len, d]; k_pages/v_pages [L, n_pages, page_size, h, d],
-    of which the static int ``layer`` is read; page_table [b, p_max]
+                         layer, k_scale=None, v_scale=None, window=None,
+                         kv_start=None):
+    """q [b, h_q, q_len, d]; k_pages/v_pages [L, n_pages, page_size, h,
+    d], of which the static int ``layer`` is read; page_table [b, p_max]
     int32 (rows padded with page 0); kv_len [b]; optional
     k_scale/v_scale [L, n_pages, page_size, h] fp32 (quantized pool —
-    dequantized in-kernel).  Returns o [b, h, q_len, d]."""
-    b, h, q_len, d = q.shape
+    dequantized in-kernel); ``window``/``kv_start`` as in
+    :func:`flash_decode`.  Returns o [b, h_q, q_len, d]."""
+    b, hq, q_len, d = q.shape
+    h = k_pages.shape[3]
+    group = hq // h
     page_size = k_pages.shape[2]
     p_max = page_table.shape[1]
     quantized = k_scale is not None
-    q_spec = pl.BlockSpec((1, h, q_len, d),
-                          lambda bi, p, pt, kl, ly: (bi, 0, 0, 0))
-    page_spec = pl.BlockSpec(
-        (None, None, page_size, h, d),
-        lambda bi, p, pt, kl, ly: (ly[0], pt[bi, p], 0, 0, 0))
+    has_start = kv_start is not None
+    tq = _decode_q_tile(q_len, group)
+    tiled = tq != q_len
+    if group > 1:
+        q = _group_rows(q, group)
+    # index maps take the grid indices, then the prefetch operands
+    if tiled:
+        q_map = lambda bi, t, p, pt, *_: (bi, 0, t, 0)
+        page_map = lambda bi, t, p, pt, kl, ly, *_: (
+            ly[0], pt[bi, p], 0, 0, 0)
+        scale_map = lambda bi, t, p, pt, *_: (pt[bi, p], 0, 0)
+        grid = (b, q_len // tq, p_max)
+    else:
+        q_map = lambda bi, p, pt, *_: (bi, 0, 0, 0)
+        page_map = lambda bi, p, pt, kl, ly, *_: (ly[0], pt[bi, p], 0, 0, 0)
+        scale_map = lambda bi, p, pt, *_: (pt[bi, p], 0, 0)
+        grid = (b, p_max)
+    q_spec = pl.BlockSpec((1, h, group * tq, d), q_map)
+    page_spec = pl.BlockSpec((None, None, page_size, h, d), page_map)
     in_specs = [q_spec, page_spec, page_spec]
     operands = [q, k_pages, v_pages]
     if quantized:
@@ -2305,36 +2475,42 @@ def _flash_decode_pallas(q, k_pages, v_pages, page_table, kv_len, scale,
         # one, so the operand is laid out anew before every call
         # whatever is passed; of one layer that is 1/L of the work and
         # of the temporary (PERF.md, PR 28).
-        scale_spec = pl.BlockSpec(
-            (None, page_size, h),
-            lambda bi, p, pt, kl, ly: (pt[bi, p], 0, 0))
+        scale_spec = pl.BlockSpec((None, page_size, h), scale_map)
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale[layer].astype(jnp.float32),
                      v_scale[layer].astype(jnp.float32)]
+    prefetch = [page_table.astype(jnp.int32), kv_len.astype(jnp.int32),
+                jnp.full((1,), layer, jnp.int32)]
+    if has_start:
+        prefetch.append(kv_start.astype(jnp.int32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b, p_max),
+        num_scalar_prefetch=len(prefetch),
+        grid=grid,
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((h, q_len, 1), jnp.float32),
-            pltpu.VMEM((h, q_len, 1), jnp.float32),
-            pltpu.VMEM((h, q_len, d), jnp.float32),
+            pltpu.VMEM((h, group * tq, 1), jnp.float32),
+            pltpu.VMEM((h, group * tq, 1), jnp.float32),
+            pltpu.VMEM((h, group * tq, d), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    o = pl.pallas_call(
         _make_decode_kernel(scale=scale, page_size=page_size,
-                            q_len=q_len, h=h, d=d, quantized=quantized),
+                            q_len=q_len, h=h, d=d, quantized=quantized,
+                            group=group, tq=tq, window=window,
+                            has_start=has_start),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, q_len, d), q.dtype),
-        name="flash_decode",
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        name="flash_decode_window" if window is not None
+        else "flash_decode",
         interpret=use_interpret(),
-    )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32),
-      jnp.full((1,), layer, jnp.int32), *operands)
+    )(*prefetch, *operands)
+    return _ungroup_rows(o, group) if group > 1 else o
 
 
 def _paged_attention_xla(q, k_pages, v_pages, page_table, kv_len, scale,
-                         layer, k_scale=None, v_scale=None):
+                         layer, k_scale=None, v_scale=None, window=None,
+                         kv_start=None):
     """Generic baseline: gather the page list out of layer ``layer`` of
     the pool ``[L, n_pages, page_size, h, d]`` into a contiguous
     [b, p_max*page_size, h, d] KV view in HBM (XLA fuses the layer's
@@ -2345,8 +2521,10 @@ def _paged_attention_xla(q, k_pages, v_pages, page_table, kv_len, scale,
     ``k_scale``/``v_scale`` [L, n_pages, page_size, h] the pool is
     quantized: the gathered bytes are dequantized (``value * scale``,
     fp32) before scoring — same contraction the Pallas kernel runs in
-    VMEM."""
-    b, h, q_len, d = q.shape
+    VMEM.  Grouped-query heads repeat the gathered K/V heads;
+    ``window``/``kv_start`` as in :func:`flash_decode`."""
+    b, hq, q_len, d = q.shape
+    h = k_pages.shape[3]
     page_size = k_pages.shape[2]
     p_max = page_table.shape[1]
     kc = k_pages[layer][page_table]  # [b, p_max, page_size, h, d]
@@ -2356,12 +2534,19 @@ def _paged_attention_xla(q, k_pages, v_pages, page_table, kv_len, scale,
         vc = vc.astype(jnp.float32) * v_scale[layer][page_table][..., None]
     kc = kc.reshape(b, p_max * page_size, h, d)
     vc = vc.reshape(b, p_max * page_size, h, d)
+    if hq != h:
+        kc = jnp.repeat(kc, hq // h, axis=2)
+        vc = jnp.repeat(vc, hq // h, axis=2)
     s = jnp.einsum("bhqd,bkhd->bhqk", q.astype(jnp.float32),
                    kc.astype(jnp.float32)) * scale
     rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
     cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 3)
+    if kv_start is not None:
+        cols = cols + kv_start.astype(jnp.int32)[:, None, None, None]
     limit = (kv_len.astype(jnp.int32) - q_len)[:, None, None, None] + rows
     s = jnp.where(cols <= limit, s, _NEG_INF)
+    if window is not None:
+        s = jnp.where(cols > limit - window, s, _NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = _masked_exp(s, m)
     l = jnp.sum(p, axis=-1, keepdims=True)
@@ -2380,7 +2565,7 @@ def _decode_shape_ok(q, k_pages):
     b, h, q_len, d = q.shape
     page_size, hp, dp = k_pages.shape[-3:]
     grain = 32 // max(1, jnp.dtype(k_pages.dtype).itemsize)
-    return (hp == h and dp == d and page_size % grain == 0
+    return (h % hp == 0 and dp == d and page_size % grain == 0
             and q_len >= 1)
 
 
@@ -2429,6 +2614,8 @@ def flash_decode(
     layer: int = 0,
     k_scale: Optional[jnp.ndarray] = None,
     v_scale: Optional[jnp.ndarray] = None,
+    window: Optional[int] = None,
+    kv_start: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Decode-mode attention against a paged KV cache.
 
@@ -2469,12 +2656,24 @@ def flash_decode(
     which runs the identical dequant math.  Scales must come in pairs
     (both or neither).
 
+    Grouped-query heads: the pool may hold fewer heads than ``q`` has
+    (``h_q = g * h``); query head ``n`` reads pool head ``n // g``.
+    ``window``: query row i sees only the ``window`` columns ending at
+    its own, ``(kv_len - q_len + i - window, kv_len - q_len + i]``.
+    ``kv_start`` [b] int32: the absolute position of the first column
+    of each row of ``page_table`` (a multiple of the page size; 0 when
+    not given) — a pool that gives pages back as a window slides hands
+    over a table of the pages still held, and ``kv_len`` stays the
+    request's whole length.  Pages no query row can see are skipped.
+
     Inference-only (no VJP — the serving path never differentiates);
     routing per :func:`flash_decode_route`, forceable via
     ``routing_override(decode=...)``.
     """
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be given together")
+    if window is not None and window < 1:
+        raise ValueError(f"window {window} must be >= 1")
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if k_pages.ndim == 4:  # one layer's pool: the whole-pool form at L = 1
@@ -2491,7 +2690,8 @@ def flash_decode(
               if flash_decode_route(q, k_pages) == "decode"
               else _paged_attention_xla)
     return attend(q, k_pages, v_pages, page_table, kv_len, float(scale),
-                  layer, k_scale=k_scale, v_scale=v_scale)
+                  layer, k_scale=k_scale, v_scale=v_scale, window=window,
+                  kv_start=kv_start)
 
 
 # ---------------------------------------------------------------------------

@@ -26,6 +26,11 @@ Three composable layers, bottom-up:
   (int8/fp8 pool codes + fp32 scales, quantize-on-write /
   dequantize-in-kernel), ``prefix_sharing`` (:class:`PrefixIndex` —
   refcounted copy-on-write pages; repeated prompts pay prefill once).
+* ISSUE 29: a second architecture behind the same engine —
+  :class:`AfmoeConfig` / ``AfmoeBlock`` (sliding-window and full
+  grouped-query layers, a dropless top-k expert layer that holds a
+  share of the experts: :mod:`apex_tpu.serving.experts`) over two page
+  lifetimes (:class:`WindowPool` beside the :class:`PagedKVCache`).
 
 See docs/serving.md for the page-table layout, the admission policy,
 decode routing, speculative decoding, prefix sharing, the quantized
@@ -43,9 +48,12 @@ from apex_tpu.serving.kv_cache import (  # noqa: F401
     PagePoolCorruption,
     PagePoolExhausted,
     PrefixIndex,
+    WindowPages,
+    WindowPool,
     quantize_tokens,
 )
 from apex_tpu.serving.model import (  # noqa: F401
+    AfmoeConfig,
     PagedDecoder,
     ServingModelConfig,
     init_params,
@@ -77,7 +85,10 @@ __all__ = [
     "PagePoolCorruption",
     "PagePoolExhausted",
     "PrefixIndex",
+    "WindowPages",
+    "WindowPool",
     "quantize_tokens",
+    "AfmoeConfig",
     "PagedDecoder",
     "ServingModelConfig",
     "init_params",

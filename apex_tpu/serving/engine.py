@@ -75,6 +75,16 @@ page-pool occupancy), plus the ISSUE 10 resilience set
 the bench's stream is schema-validated by the existing ``validate``
 CLI.
 
+**Architectures and page lifetimes (ISSUE 29).**  The model half is a
+:class:`~apex_tpu.serving.model.PagedDecoder` over whatever block the
+configuration names; the pool loop carries and the page tables are
+taken as the cache makes them (``_pool_state`` / ``_tables``), so a
+model whose sliding-window layers live in a second pool
+(``cache.window_pool``) runs through the same five executables, the
+same scheduler and the same phases, with ``engine.release`` after
+every decode step and chunk for the pages that slid out
+(docs/serving.md "The block seam", "Two page lifetimes").
+
 Step phases (ISSUE 27): bus or no bus, every step marks what the host
 does with :func:`apex_tpu.telemetry.phase` — ``engine.step`` around
 ``engine.prefill`` (per request) / ``engine.grow`` /
@@ -109,9 +119,9 @@ import numpy as np
 
 from apex_tpu.serving.kv_cache import (PagedKVCache, PagePoolCorruption,
                                        PagePoolExhausted, PrefixIndex,
-                                       verify_page_payload)
+                                       WindowPool, verify_page_payload)
 from apex_tpu.serving.model import (PagedDecoder, ServingModelConfig,
-                                    init_params, shard_params_tp)
+                                    WindowKV, init_params, shard_params_tp)
 from apex_tpu.serving.scheduler import (FINISHED, RUNNING, WAITING,
                                         ContinuousBatchingScheduler,
                                         QueueFullError, Request)
@@ -220,9 +230,20 @@ def poisson_trace(seed: int, n_requests: int, *, rate: float,
 class ServingEngine:
     """Continuous-batching inference over a paged KV cache.
 
-    ``num_pages``/``page_size`` size the shared pool;
+    ``cfg`` names the architecture (:class:`~apex_tpu.serving.model.
+    ServingModelConfig`, a GPT; :class:`~apex_tpu.serving.model.
+    AfmoeConfig`): the engine asks it for its block, its layers'
+    windows and its K/V heads, never which it is, and refuses at
+    construction the options its block does not carry
+    (docs/serving.md "The block seam").
+    ``num_pages``/``page_size`` size the shared pool, and
+    ``window_pages`` the second pool of a model whose sliding-window
+    layers keep only a window of tokens (docs/serving.md "Two page
+    lifetimes"; as many as ``num_pages`` where not given);
     ``prefill_budget`` fixes the packed prefill row width (defaults to
-    ``cfg.max_position``) and bounds prompt+generation per request;
+    ``cfg.max_position``) and bounds prompt+generation per request
+    unless prefill is chunked (a model without a position table chunks
+    by default and is bounded by :attr:`max_context`);
     ``max_batch`` fixes the decode batch width.  ``telemetry`` is an
     optional :class:`~apex_tpu.telemetry.TelemetryBus`; ``clock`` an
     optional ``() -> float`` (tests pass :class:`SimClock` for
@@ -267,9 +288,26 @@ class ServingEngine:
                  prefix_sharing: bool = False,
                  prefix_entries: int = 8,
                  prefill_only: bool = False,
-                 kv_import: bool = False):
+                 kv_import: bool = False,
+                 window_pages: Optional[int] = None):
         self.cfg = cfg
+        self.decoder = PagedDecoder(cfg)
+        # what this architecture's block cannot serve yet is refused
+        # here, loudly: none of it may silently serve wrong tokens
+        asked = {"tp": tp > 1, "kv_quant": kv_quant is not None,
+                 "prefix_sharing": prefix_sharing,
+                 "speculation": spec is not None and spec.k > 0,
+                 "prefill_only": prefill_only, "kv_import": kv_import}
+        for option in self.decoder.block.refuses:
+            if asked[option]:
+                raise ValueError(
+                    f"{cfg.name}: the engine option {option!r} is not "
+                    "supported for this model (docs/serving.md, "
+                    "\"What afmoe refuses\")")
         self.params = params if params is not None else init_params(cfg, seed)
+        if prefill_budget is None and cfg.max_position is None:
+            raise ValueError(f"{cfg.name}: no position table to take the "
+                             "prefill row's width from; give prefill_budget")
         self.prefill_budget = (cfg.max_position if prefill_budget is None
                                else prefill_budget)
         # draft–verify subsystem (ISSUE 12, docs/serving.md
@@ -280,6 +318,11 @@ class ServingEngine:
         self.spec = spec
         self.spec_k = spec.k if spec is not None else 0
         self.chunk_size = spec.chunk_size if spec is not None else None
+        if self.chunk_size is None and cfg.max_position is None:
+            # no position table bounds a request to the prefill row, so
+            # a longer prompt goes through chunked prefill by default,
+            # in chunks of the row's width
+            self.chunk_size = self.prefill_budget
         self.proposer = None
         if self.spec_k > 0:
             self.proposer = (spec.proposer if spec.proposer is not None
@@ -314,28 +357,24 @@ class ServingEngine:
             # chunking never turns a valid construction into a
             # constructor error (an oversized request still fails
             # submit() with the pages_needed check, loudly)
-            cap_tokens = (cfg.max_position if self.chunk_size is not None
-                          else self.prefill_budget)
+            cap_tokens = (self.prefill_budget if self.chunk_size is None
+                          else cfg.max_position
+                          or (num_pages - 1) * page_size)
             max_pages_per_request = min(-(-cap_tokens // page_size),
                                         max(1, num_pages - 1))
-        self.cache = PagedKVCache(
-            num_layers=cfg.num_layers, num_pages=num_pages,
-            page_size=page_size, num_heads=cfg.num_heads,
-            head_dim=cfg.head_dim,
-            max_pages_per_request=max_pages_per_request,
-            dtype=cfg.dtype, crc_pages=validate_pages,
-            quantize=kv_quant)
+        self._window_pages = window_pages
+        self.cache = self._new_cache(num_pages, page_size,
+                                     max_pages_per_request, validate_pages)
         self.prefix_index = (
             PrefixIndex(self.cache, max_entries=self.prefix_entries)
             if prefix_sharing else None)
         self.sched = ContinuousBatchingScheduler(
             self.cache, max_batch=max_batch,
             prefill_budget=self.prefill_budget,
-            max_position=cfg.max_position,
+            max_position=self.max_context,
             max_queue=max_queue, preempt_cap=preempt_cap,
             chunk_size=self.chunk_size,
             prefix_index=self.prefix_index)
-        self.decoder = PagedDecoder(cfg)
         self.max_batch = max_batch
         self.telemetry = telemetry
         self.clock = clock if clock is not None else time.monotonic
@@ -357,6 +396,9 @@ class ServingEngine:
         self.prefill_only = bool(prefill_only)
         self.kv_import = bool(kv_import)
         self.recoveries = 0
+        self._released_full = 0
+        #: per request mid-prefill, its chunks' counters still on the device
+        self._chunk_stats: Dict[int, list] = {}
         self.rejected: List[Request] = []
         self._next_rid = 0
         self.steps = 0
@@ -365,83 +407,62 @@ class ServingEngine:
         ax = self._tp_axis
         quant = self.kv_quant is not None
 
+        # the pool loop carries, in executable order: (k, v), then the
+        # quantized pool's scale planes (r17), then the window pool's
+        # k and v (ISSUE 29); a window pool's two tables follow the
+        # other operands.  The step bodies take them as they come and
+        # hand the decoder what the cache is made of.
+        n_pool = len(self._pool_state())
+        n_stat = 1 if decoder.stat_names else 0
+
+        def carries(args):
+            """(pools, the other operands, decoder keywords)."""
+            pools, rest = args[:n_pool], args[n_pool:]
+            kw = {}
+            if quant:
+                kw.update(k_scale=pools[2], v_scale=pools[3])
+            if self.cache.window_pool is not None:
+                kw["window"] = WindowKV(pools[-2], pools[-1],
+                                        rest[-2], rest[-1])
+                rest = rest[:-2]
+            return pools, rest, kw
+
         def _prefill(params, tokens, seg, positions, last_index):
             # logits for the last context position only: admission
             # needs one next-token distribution, not S of them
-            logits, k, v = decoder.prefill(params, tokens, seg,
-                                           positions, last_index,
-                                           tp_axis=ax)
-            return jnp.argmax(logits[0, 0], axis=-1), k[:, 0], v[:, 0]
+            logits, *kv = decoder.prefill(params, tokens, seg,
+                                          positions, last_index,
+                                          tp_axis=ax)
+            n_kv = len(kv) - n_stat      # K/V stacks, then the counters
+            return (jnp.argmax(logits[0, 0], axis=-1),
+                    *(a[:, 0] for a in kv[:n_kv]), *kv[n_kv:])
 
-        if quant:
-            # quantized pool (r17): the scale planes ride as loop
-            # carries next to the pools — same donation class, rebound
-            # by the engine together with cache.k/v
-            def _decode(params, k_pool, v_pool, k_scale, v_scale,
-                        tokens, positions, page_table, kv_len):
-                (logits, k_pool, v_pool, k_scale,
-                 v_scale) = decoder.decode(
-                    params, k_pool, v_pool, tokens, positions,
-                    page_table, kv_len, k_scale=k_scale,
-                    v_scale=v_scale, tp_axis=ax)
-                return (jnp.argmax(logits, axis=-1), k_pool, v_pool,
-                        k_scale, v_scale)
+        def _decode(params, *args):
+            pools, rest, kw = carries(args)
+            logits, *out = decoder.decode(
+                params, pools[0], pools[1], *rest, tp_axis=ax, **kw)
+            return (jnp.argmax(logits, axis=-1), *out)
 
-            def _verify(params, k_pool, v_pool, k_scale, v_scale,
-                        tokens, positions, write_pages, write_offsets,
-                        page_table, kv_len):
-                (logits, k_pool, v_pool, k_scale,
-                 v_scale) = decoder.extend(
-                    params, k_pool, v_pool, tokens, positions,
-                    write_pages, write_offsets, page_table, kv_len,
-                    k_scale=k_scale, v_scale=v_scale, tp_axis=ax)
-                return (jnp.argmax(logits, axis=-1), k_pool, v_pool,
-                        k_scale, v_scale)
+        def _verify(params, *args):
+            # all k+1 positions scored in ONE flash_decode launch;
+            # only the argmax ids leave the device
+            pools, rest, kw = carries(args)
+            logits, *out = decoder.extend(
+                params, pools[0], pools[1], *rest, tp_axis=ax, **kw)
+            return (jnp.argmax(logits, axis=-1), *out)
 
-            def _chunk(params, k_pool, v_pool, k_scale, v_scale,
-                       tokens, positions, write_pages, write_offsets,
-                       page_table, kv_len):
-                (logits, k_pool, v_pool, k_scale,
-                 v_scale) = decoder.extend(
-                    params, k_pool, v_pool, tokens, positions,
-                    write_pages, write_offsets, page_table, kv_len,
-                    last_only=True, k_scale=k_scale, v_scale=v_scale,
-                    tp_axis=ax)
-                return (jnp.argmax(logits[:, 0], axis=-1), k_pool,
-                        v_pool, k_scale, v_scale)
+        def _chunk(params, *args):
+            # one chunk of a long context; front-padding pins the
+            # chunk's last valid token to the final row, so
+            # last_only projects exactly one position through the
+            # LM head
+            pools, rest, kw = carries(args)
+            logits, *out = decoder.extend(
+                params, pools[0], pools[1], *rest, last_only=True,
+                tp_axis=ax, **kw)
+            return (jnp.argmax(logits[:, 0], axis=-1), *out)
 
-            pool_donate = (1, 2, 3, 4)
-        else:
-            def _decode(params, k_pool, v_pool, tokens, positions,
-                        page_table, kv_len):
-                logits, k_pool, v_pool = decoder.decode(
-                    params, k_pool, v_pool, tokens, positions,
-                    page_table, kv_len, tp_axis=ax)
-                return jnp.argmax(logits, axis=-1), k_pool, v_pool
-
-            def _verify(params, k_pool, v_pool, tokens, positions,
-                        write_pages, write_offsets, page_table, kv_len):
-                # all k+1 positions scored in ONE flash_decode launch;
-                # only the argmax ids leave the device
-                logits, k_pool, v_pool = decoder.extend(
-                    params, k_pool, v_pool, tokens, positions,
-                    write_pages, write_offsets, page_table, kv_len,
-                    tp_axis=ax)
-                return jnp.argmax(logits, axis=-1), k_pool, v_pool
-
-            def _chunk(params, k_pool, v_pool, tokens, positions,
-                       write_pages, write_offsets, page_table, kv_len):
-                # one chunk of a long context; front-padding pins the
-                # chunk's last valid token to the final row, so
-                # last_only projects exactly one position through the
-                # LM head
-                logits, k_pool, v_pool = decoder.extend(
-                    params, k_pool, v_pool, tokens, positions,
-                    write_pages, write_offsets, page_table, kv_len,
-                    last_only=True, tp_axis=ax)
-                return jnp.argmax(logits[:, 0], axis=-1), k_pool, v_pool
-
-            pool_donate = (1, 2)
+        pool_donate = tuple(range(1, 1 + n_pool))
 
         if self._mesh is not None:
             # place params and pools with their tensor-axis shardings
@@ -551,22 +572,91 @@ class ServingEngine:
 
     # -- quantized-pool plumbing (r17) -------------------------------------
 
+    def _new_cache(self, num_pages: int, page_size: int,
+                   max_pages_per_request: int,
+                   crc_pages: bool) -> PagedKVCache:
+        """The pool of the layers that keep every token and, where the
+        model has layers with a window, the :class:`WindowPool` of
+        those beside it (``cache.window_pool``; ``window_pages`` of
+        them, as many as the full pool where not given).  ``__init__``
+        and :meth:`recover` build the same."""
+        cfg, dec = self.cfg, self.decoder
+        if not dec.full_layers:
+            raise ValueError(f"{cfg.name}: a model needs at least one "
+                             "layer that keeps every token")
+        geometry = dict(page_size=page_size, num_heads=cfg.kv_heads,
+                        head_dim=cfg.head_dim, dtype=cfg.dtype,
+                        crc_pages=crc_pages)
+        cache = PagedKVCache(
+            num_layers=dec.full_layers, num_pages=num_pages,
+            max_pages_per_request=max_pages_per_request,
+            quantize=self.kv_quant, **geometry)
+        if dec.window_layers:
+            window = max(w for w in dec.windows if w is not None)
+            n = self._window_pages or num_pages
+            cache.window_pool = WindowPool(
+                window=window, num_layers=dec.window_layers, num_pages=n,
+                max_pages_per_request=min(n - 1, WindowPool.pages_per_request(
+                    window, self.chunk_size or self.prefill_budget,
+                    page_size)),
+                **geometry)
+        return cache
+
+    @property
+    def max_context(self) -> int:
+        """The most tokens (prompt + generated) a request may reach:
+        the position table's length where the model has one, else what
+        its page table can address."""
+        return (self.cfg.max_position
+                or self.cache.max_pages_per_request * self.cache.page_size)
+
+    # -- the pool loop carries ---------------------------------------------
+
     def _pool_state(self) -> Tuple:
         """The pool loop-carry operands in executable order —
-        ``(k, v)`` or, quantized, ``(k, v, k_scale, v_scale)``."""
+        ``(k, v)``, then, quantized, ``(k_scale, v_scale)``, then the
+        window pool's ``(k, v)`` where there is one."""
+        pools = (self.cache.k, self.cache.v)
         if self.kv_quant is not None:
-            return (self.cache.k, self.cache.v,
-                    self.cache.k_scale, self.cache.v_scale)
-        return (self.cache.k, self.cache.v)
+            pools += (self.cache.k_scale, self.cache.v_scale)
+        wpool = self.cache.window_pool
+        if wpool is not None:
+            pools += (wpool.k, wpool.v)
+        return pools
 
-    def _bind_pools(self, pools: Tuple) -> None:
+    def _bind_pools(self, out: Tuple) -> Tuple:
         """Rebind the cache to a step's returned pool carries (the
-        donated-buffer hand-back)."""
+        donated-buffer hand-back); returns what follows them, the
+        block's counters."""
+        n = len(self._pool_state())
+        pools, rest = out[:n], out[n:]
+        wpool = self.cache.window_pool
+        if wpool is not None:
+            wpool.k, wpool.v = pools[-2:]
         if self.kv_quant is not None:
             (self.cache.k, self.cache.v,
-             self.cache.k_scale, self.cache.v_scale) = pools
+             self.cache.k_scale, self.cache.v_scale) = pools[:4]
         else:
-            self.cache.k, self.cache.v = pools
+            self.cache.k, self.cache.v = pools[:2]
+        return rest
+
+    def _tables(self, reqs: Sequence[Request], rows: int) -> Tuple:
+        """The page tables of ``reqs`` as the executables take them:
+        the full pool's, and after the other operands the window
+        pool's compact table and its first positions."""
+        table = self.cache.page_table([r.pages for r in reqs], rows=rows)
+        wpool = self.cache.window_pool
+        if wpool is None:
+            return table, ()
+        return table, wpool.tables([r.window for r in reqs], rows=rows)
+
+    def _stats(self, stats: Tuple) -> Dict[str, int]:
+        """A launch's counters by name (one small fetch; the caller is
+        at a point where it waits for the device anyway)."""
+        if not stats:
+            return {}
+        return dict(zip(self.decoder.stat_names,
+                        np.asarray(stats[0]).tolist()))
 
     # -- compiled-artifact exposure (ISSUE 13) -----------------------------
 
@@ -581,28 +671,31 @@ class ServingEngine:
         i32 = jnp.int32
         params = jax.tree_util.tree_map(
             lambda a: sds(jnp.shape(a), a.dtype), self.params)
-        pool = sds(self.cache.k.shape, self.cache.k.dtype)
-        pools = (pool, pool)
-        if self.kv_quant is not None:
-            scale = sds(self.cache.k_scale.shape, jnp.float32)
-            pools = (pool, pool, scale, scale)
+        pools = tuple(sds(a.shape, a.dtype) for a in self._pool_state())
         S, b = self.prefill_budget, self.max_batch
         p_max = self.cache.max_pages_per_request
+        wpool = self.cache.window_pool
+
+        def tables(rows):
+            """Page table, kv_len, then the window pool's two tables."""
+            t = (sds((rows, p_max), i32), sds((rows,), i32))
+            if wpool is not None:
+                t += (sds((rows, wpool.max_pages_per_request), i32),
+                      sds((rows,), i32))
+            return t
+
         row = sds((1, S), i32)
         out = {
             "prefill": (params, row, row, row, sds((), i32)),
             "decode": ((params,) + pools
-                       + (sds((b,), i32), sds((b,), i32),
-                          sds((b, p_max), i32), sds((b,), i32))),
+                       + (sds((b,), i32), sds((b,), i32)) + tables(b)),
         }
         if self._verify_fn is not None:
             q = sds((b, self.spec_k + 1), i32)
-            out["verify"] = ((params,) + pools + (q, q, q, q,
-                             sds((b, p_max), i32), sds((b,), i32)))
+            out["verify"] = (params,) + pools + (q, q, q, q) + tables(b)
         if self._chunk_fn is not None:
             c = sds((1, self.chunk_size), i32)
-            out["chunk"] = ((params,) + pools + (c, c, c, c,
-                            sds((1, p_max), i32), sds((1,), i32)))
+            out["chunk"] = (params,) + pools + (c, c, c, c) + tables(1)
         return out
 
     def analysis_executables(self, *, donate: bool = True) -> Dict[str, Any]:
@@ -705,27 +798,37 @@ class ServingEngine:
         warmup pin runs a speculative + chunked trace too."""
         t0 = time.perf_counter()
         z = jnp.zeros((1, self.prefill_budget), jnp.int32)
-        _, wk0, wv0 = self._prefill_fn(
-            self.params, z, z, z, np.int32(0))
+        _, *kv0 = self._prefill_fn(self.params, z, z, z, np.int32(0))
         # warm the admission scatter with its real shapes: the warmup
         # prefill's K/V row scattered into the scratch page
         S = self.prefill_budget
-        self.cache.write_tokens(wk0, wv0, np.zeros((S,), np.int32),
-                                np.zeros((S,), np.int32))
+        zs = np.zeros((S,), np.int32)
+        self.cache.write_tokens(kv0[0], kv0[1], zs, zs)
+        wpool = self.cache.window_pool
+        if wpool is not None:
+            wpool.write_tokens(kv0[2], kv0[3], zs, zs)
         b = self.max_batch
         p_max = self.cache.max_pages_per_request
+
+        def tables(rows):
+            if wpool is None:
+                return ()
+            return (jnp.zeros((rows, wpool.max_pages_per_request),
+                              jnp.int32), jnp.zeros((rows,), jnp.int32))
+
         out = self._decode_fn(
             self.params, *self._pool_state(),
             jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
-            jnp.zeros((b, p_max), jnp.int32), jnp.ones((b,), jnp.int32))
-        self._bind_pools(out[1:])
+            jnp.zeros((b, p_max), jnp.int32), jnp.ones((b,), jnp.int32),
+            *tables(b))
+        self._stats(self._bind_pools(out[1:]))
         if self._verify_fn is not None:
             qw = self.spec_k + 1
             zq = jnp.zeros((b, qw), jnp.int32)
             out = self._verify_fn(
                 self.params, *self._pool_state(), zq, zq, zq, zq,
                 jnp.zeros((b, p_max), jnp.int32),
-                jnp.full((b,), qw, jnp.int32))
+                jnp.full((b,), qw, jnp.int32), *tables(b))
             self._bind_pools(out[1:])
         if self._chunk_fn is not None:
             cs = self.chunk_size
@@ -733,7 +836,7 @@ class ServingEngine:
             out = self._chunk_fn(
                 self.params, *self._pool_state(), zc, zc, zc, zc,
                 jnp.zeros((1, p_max), jnp.int32),
-                jnp.full((1,), cs, jnp.int32))
+                jnp.full((1,), cs, jnp.int32), *tables(1))
             self._bind_pools(out[1:])
         if self.prefix_index is not None:
             # r17: the prefix-sharing engine runs one more executable —
@@ -774,7 +877,8 @@ class ServingEngine:
                 "reserved at admission")
         _fault_point("prefill", req.rid)
         prefill_t0 = self.clock()
-        with phase("engine.prefill", rid=req.rid, C=C, S=S):
+        wpool = self.cache.window_pool
+        with phase("engine.prefill", rid=req.rid, C=C, S=S) as span:
             with phase("prefill.build"):
                 tokens = np.zeros((1, S), np.int32)
                 tokens[0, :C] = ctx
@@ -788,7 +892,7 @@ class ServingEngine:
                 # the warmup never built — a hidden ~60 ms stall on the
                 # first admission's TTFT (caught by hot_path_guard's
                 # serving-lifetime pin)
-                next_tok, k, v = self._prefill_fn(
+                next_tok, *kv = self._prefill_fn(
                     self.params, jnp.asarray(tokens), jnp.asarray(seg),
                     jnp.asarray(positions), np.int32(C - 1))
             with phase("prefill.scatter"):
@@ -799,12 +903,21 @@ class ServingEngine:
                 idx = np.arange(C)
                 pages[:C] = np.asarray(req.pages, np.int32)[idx // ps]
                 offsets[:C] = idx % ps
-                self.cache.write_tokens(k, v, pages, offsets)
+                self.cache.write_tokens(kv[0], kv[1], pages, offsets)
+                if wpool is not None:
+                    # the window layers' K/V, into the pages of the
+                    # window's tail; what lies before it is never read
+                    wpages = np.zeros((S,), np.int32)
+                    wpages[:C], _ = wpool.write_targets(req.window, idx)
+                    wpool.write_tokens(kv[2], kv[3], wpages, offsets)
             req.kv_len = C
             self._register_prefix(ctx, req.pages)
             with phase("prefill.fetch"):
                 # the wait for the device
                 first = int(next_tok)
+                # the block's counters follow the K/V of the pool(s)
+                span.attrs.update(self._stats(
+                    kv[2 if wpool is None else 4:]))
             req.generated.append(first)
             if req.first_token_t is None:
                 req.first_token_t = self.clock()
@@ -854,9 +967,10 @@ class ServingEngine:
                     f"{what} would write shared page {int(p)} "
                     "(refcount > 1) — copy-on-write missing")
 
-    def _decode_batch(self, rows: List[Request]) -> None:
+    def _decode_batch(self, rows: List[Request]) -> Dict[str, int]:
         """One decode step for ``rows`` (≤ max_batch), idle-padded to
-        the fixed batch width."""
+        the fixed batch width.  Returns the block's counters of the
+        launch, by name (none for a GPT)."""
         _fault_point("decode", self.decode_steps)
         with phase("decode.build"):
             # opt-in read-back validation: the pages this step is about
@@ -874,23 +988,24 @@ class ServingEngine:
                 kv_len[i] = req.seq_len
                 written.append(req.pages[(req.seq_len - 1) // ps])
             self._check_private(written, "decode append")
-            page_table = self.cache.page_table(
-                [req.pages for req in rows], rows=b)
+            page_table, wtables = self._tables(rows, b)
         with phase("decode.dispatch"):
             out = self._decode_fn(
                 self.params, *self._pool_state(),
                 jnp.asarray(tokens), jnp.asarray(positions), page_table,
-                jnp.asarray(kv_len))
+                jnp.asarray(kv_len), *wtables)
             next_tok = out[0]
-            self._bind_pools(out[1:])
+            stats = self._bind_pools(out[1:])
         with phase("decode.fetch"):
             # the wait for the device
             next_tok = np.asarray(next_tok)
+            stats = self._stats(stats)
         with phase("decode.commit"):
             self.cache.refresh_page_crcs(written)
             for i, req in enumerate(rows):
                 req.kv_len = req.seq_len
                 req.generated.append(int(next_tok[i]))
+        return stats
 
     def _verify_batch(self, rows: List[Request],
                       drafts: Dict[int, List[int]]
@@ -940,14 +1055,13 @@ class ServingEngine:
                 kv_len[i] = S + j
                 written.extend(int(p) for p in pg)
             self._check_private(written, "verify append")
-            page_table = self.cache.page_table(
-                [req.pages for req in rows], rows=b)
+            page_table, wtables = self._tables(rows, b)
         with phase("decode.dispatch"):
             out = self._verify_fn(
                 self.params, *self._pool_state(),
                 jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.asarray(wpages), jnp.asarray(woffs), page_table,
-                jnp.asarray(kv_len))
+                jnp.asarray(kv_len), *wtables)
             next_tok = out[0]
             self._bind_pools(out[1:])
         with phase("decode.fetch"):
@@ -991,7 +1105,7 @@ class ServingEngine:
         _fault_point("prefill", req.rid)
         t0 = self.clock()
         cs = self.chunk_size
-        with phase("engine.prefill", rid=req.rid, C=n, S=cs):
+        with phase("engine.prefill", rid=req.rid, C=n, S=cs) as span:
             with phase("prefill.build"):
                 # opt-in CRC read-back, like every other pool-reading
                 # step: this chunk attends over the pages earlier
@@ -1022,18 +1136,25 @@ class ServingEngine:
                 wpages[0, pad:] = pg
                 woffs[0, pad:] = pos % ps
                 self._check_private(pg, "chunk scatter")
-                page_table = self.cache.page_table([req.pages], rows=1)
+                page_table, wtables = self._tables([req], 1)
             with phase("prefill.dispatch"):
                 out = self._chunk_fn(
                     self.params, *self._pool_state(),
                     jnp.asarray(tokens), jnp.asarray(positions),
                     jnp.asarray(wpages), jnp.asarray(woffs), page_table,
-                    jnp.asarray(np.full((1,), start + n, np.int32)))
+                    jnp.asarray(np.full((1,), start + n, np.int32)),
+                    *wtables)
                 next_tok = out[0]
-                self._bind_pools(out[1:])
+                # the counters of a chunk wait, like its token, for the
+                # request's last chunk: nothing is fetched before it
+                pending = self._chunk_stats.setdefault(req.rid, [])
+                if start == 0:
+                    pending.clear()
+                pending.extend(self._bind_pools(out[1:]))
             self.cache.refresh_page_crcs(int(p) for p in pg)
             req.kv_len = start + n
             req.prefill_pos = start + n
+            self._release_windows([req])
             if req.prefill_pos >= len(ctx):
                 # prefill complete: sample the first token and leave
                 # chunked mode — the request decodes from the next
@@ -1042,6 +1163,12 @@ class ServingEngine:
                 self._register_prefix(ctx, req.pages)
                 with phase("prefill.fetch"):
                     first = int(np.asarray(next_tok)[0])
+                    per_chunk = [self._stats((a,)) for a in
+                                 self._chunk_stats.pop(req.rid)]
+                for name in self.decoder.stat_names:
+                    # a count adds up over the chunks, a maximum does not
+                    fold = max if name.endswith("_max") else sum
+                    span.attrs[name] = fold(c[name] for c in per_chunk)
                 req.generated.append(first)
                 if req.first_token_t is None:
                     req.first_token_t = self.clock()
@@ -1070,6 +1197,8 @@ class ServingEngine:
         return f"{req.preemptions}:{req.admit_t:.6f}"
 
     def _retire(self, now: float) -> List[Request]:
+        self._released_full += sum(
+            len(r.pages) for r in self.sched.running if r.done)
         done = self.sched.retire_finished(now)
         for req in done:
             if self.proposer is not None:
@@ -1199,6 +1328,35 @@ class ServingEngine:
             self.clock.advance()
         return progress
 
+    def _release_windows(self, reqs: Sequence[Request]) -> None:
+        """After a decode step or a chunk: the window pool takes back
+        the pages that slid out of ``reqs``' windows (``engine.release``
+        with ``released_window``).  The full pool gives nothing back
+        before a request retires: ``engine.step`` counts those pages as
+        ``released_full``."""
+        if self.cache.window_pool is None:
+            return
+        with phase("engine.release") as span:
+            span.attrs["released_window"] = self.sched.slide_windows(reqs)
+
+    def _held_pages(self, counters: Dict[str, int]) -> None:
+        """Once a step, where pages have two lifetimes: the pages the
+        running requests hold in each pool (``held_full``,
+        ``held_window``), a layer's worth each, and what ONE lifetime
+        for every layer would hold for the same requests
+        (``held_uniform``: every layer would keep what the full layers
+        keep); and ``released_full``, the pages of the full pool that
+        retirements gave back since the last step's count."""
+        if self.cache.window_pool is None:
+            return
+        running = self.sched.running
+        counters["released_full"] = self._released_full
+        self._released_full = 0
+        counters["held_full"] = sum(len(r.pages) for r in running)
+        counters["held_window"] = sum(
+            len(r.window.pages) for r in running if r.window is not None)
+        counters["held_uniform"] = counters["held_full"]
+
     def _step_phases(self, counters: Dict[str, int]) -> bool:
         now = self.clock()
         progress = self._expire(now)
@@ -1249,6 +1407,7 @@ class ServingEngine:
         done_at_prefill = self._retire(now)
         progress = bool(done_at_prefill) or progress
         counters["retired"] = len(done) + len(done_at_prefill)
+        self._held_pages(counters)
         evicted: List[Request] = []
         drafts: Dict[int, List[int]] = {}
         if self.sched.running and not self.prefill_only:
@@ -1285,8 +1444,9 @@ class ServingEngine:
                     # every draft came back empty (or speculation is
                     # off): the plain q_len=1 decode executable is
                     # cheaper
-                    self._decode_batch(rows)
+                    span.attrs.update(self._decode_batch(rows))
                     new_tokens = len(rows)
+            self._release_windows(rows)
             self.decode_steps += 1
             if self.telemetry is not None:
                 if self.prefix_index is not None:
@@ -1472,6 +1632,14 @@ class ServingEngine:
 
     # -- disaggregated prefill/decode (r18) --------------------------------
 
+    def _no_window_pages(self, what: str) -> None:
+        """Shipping pages is for one page lifetime: a window pool's
+        compact page lists have no wire format yet."""
+        if self.cache.window_pool is not None:
+            raise ValueError(
+                f"{self.cfg.name}: {what} cannot ship the pages of a "
+                "window pool (docs/serving.md, \"What afmoe refuses\")")
+
     def export_request(self, rid: int):
         """Detach a freshly prefilled request for shipping (the
         prefill-replica side of r18 disaggregation): serialize its KV
@@ -1487,6 +1655,7 @@ class ServingEngine:
         in ``sched.finished`` — it retires for real on the decode
         replica); the caller's handle on the DECODE replica is the
         live one after adoption."""
+        self._no_window_pages("export_request")
         req = next((r for r in self.sched.running if r.rid == rid), None)
         if req is None:
             raise ValueError(f"export_request: rid {rid} is not running")
@@ -1548,6 +1717,7 @@ class ServingEngine:
         (no decode batch slot, no pool pages) raise
         :class:`AdmissionRefused` — retryable, leaving the engine
         untouched."""
+        self._no_window_pages("adopt_prefilled")
         kv_len = int(kv_len)
         req = Request(
             rid=int(record["rid"]), prompt=list(record["prompt"]),
@@ -1641,17 +1811,13 @@ class ServingEngine:
         running = list(self.sched.running)
         waiting = list(self.sched.waiting)
         old = self.cache
-        self.cache = PagedKVCache(
-            num_layers=self.cfg.num_layers, num_pages=old.num_pages,
-            page_size=old.page_size, num_heads=self.cfg.num_heads,
-            head_dim=self.cfg.head_dim,
-            max_pages_per_request=old.max_pages_per_request,
-            dtype=self.cfg.dtype, crc_pages=old.crc_pages,
-            # the rebuilt pool keeps its quantization mode: re-prefill
-            # re-quantizes deterministically (per-(token, head) scales
-            # are order-independent), so recovery stays output-
-            # invisible at the documented quantized parity bar
-            quantize=self.kv_quant)
+        # the rebuilt pool keeps its quantization mode: re-prefill
+        # re-quantizes deterministically (per-(token, head) scales
+        # are order-independent), so recovery stays output-
+        # invisible at the documented quantized parity bar
+        self.cache = self._new_cache(old.num_pages, old.page_size,
+                                     old.max_pages_per_request,
+                                     old.crc_pages)
         if self._mesh is not None:
             self._shard_pools()
         if self.prefix_index is not None:
@@ -1663,7 +1829,7 @@ class ServingEngine:
         sched = ContinuousBatchingScheduler(
             self.cache, max_batch=self.max_batch,
             prefill_budget=self.prefill_budget,
-            max_position=self.cfg.max_position,
+            max_position=self.max_context,
             max_queue=self.sched.max_queue,
             preempt_cap=self.sched.preempt_cap,
             # the rebuilt scheduler must keep chunking (ISSUE 12): a
@@ -1677,6 +1843,7 @@ class ServingEngine:
         self.sched = sched
         for req in running:
             req.pages = []
+            req.window = None
             req.kv_len = 0
             # a mid-chunk request restarts its chunked prefill after
             # the rebuild — chunk progress is as rebuildable as KV

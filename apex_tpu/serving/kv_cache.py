@@ -30,6 +30,15 @@ of fixed-size pages:
   quantize-on-write in the scatter, dequantize-on-read in
   ``flash_decode``).
 
+* A model whose layers keep their tokens for two different LIFETIMES
+  (ISSUE 29: full-attention layers keep every token, sliding-window
+  layers only the last ``window``) gets two pools: the
+  :class:`PagedKVCache` of its full layers, and beside it, as
+  ``cache.window_pool``, a :class:`WindowPool` of its window layers
+  with its own free list, refcounts and page tables.  A request holds
+  a page list in each; the window pool gives pages back as the window
+  slides (docs/serving.md, "Two page lifetimes").
+
 The device arrays are functionally updated (``.at[].set``); the cache
 object re-binds them, so callers treat ``cache.k``/``cache.v`` (and,
 quantized, ``cache.k_scale``/``cache.v_scale``) as the current pool
@@ -40,6 +49,7 @@ from __future__ import annotations
 
 import base64
 import bisect
+import dataclasses
 import functools
 import zlib
 from collections import OrderedDict
@@ -227,6 +237,9 @@ class PagedKVCache:
         # default (docs/serving.md "Failure semantics").
         self.crc_pages = bool(crc_pages)
         self._crc: Dict[int, Tuple[int, int]] = {}
+        #: the pool of the layers that keep only a window of tokens,
+        #: where the model has such layers (the engine sets it)
+        self.window_pool: Optional["WindowPool"] = None
 
     # -- accounting ------------------------------------------------------
 
@@ -627,6 +640,101 @@ class PagedKVCache:
         for pages in page_lists:
             pages[:] = [mapping[p] for p in pages]
         return mapping
+
+
+@dataclasses.dataclass
+class WindowPages:
+    """What one request holds in a :class:`WindowPool`: the pages of
+    the logical slots ``base, base + 1, ...`` (slot ``s`` is positions
+    ``[s * page_size, (s + 1) * page_size)``).  Slots below ``base``
+    have slid out of the window and gone back to the pool."""
+
+    base: int = 0
+    pages: List[int] = dataclasses.field(default_factory=list)
+
+
+class WindowPool(PagedKVCache):
+    """The pool of a model's sliding-window layers: a page is held only
+    while some query still to come can see a token in it.
+
+    A query at position ``p`` sees keys ``(p - window, p]``, so once the
+    next query of a request is at ``p`` every slot that ends at or
+    before ``p - window`` is dead: :meth:`slide` gives those pages back
+    (after every decode step and prefill chunk), :meth:`grow` takes the
+    pages of the positions about to be written.  Allocation, refcounts,
+    the scatter and :meth:`defrag` are the parent's, over this pool's
+    own free list; a request's table (:meth:`tables`) is COMPACT, the
+    pages it still holds and the position of the first of them, so its
+    width is what a request can hold at once
+    (:meth:`pages_per_request`), not its whole length."""
+
+    def __init__(self, *, window: int, **kw):
+        super().__init__(**kw)
+        if window < 1:
+            raise ValueError(f"window {window} must be >= 1")
+        self.window = int(window)
+
+    @staticmethod
+    def pages_per_request(window: int, launch: int, page_size: int) -> int:
+        """The most pages a request holds at once when its widest launch
+        writes ``launch`` tokens (a prefill chunk; 1 for decode): the
+        window before the launch's first query, the launch, and one
+        more where the two do not end on a page boundary."""
+        return -(-(window - 1) // page_size) + -(-launch // page_size) + 1
+
+    def first_slot(self, next_query: int) -> int:
+        """The oldest slot a query at ``next_query`` (or later) sees."""
+        return max(0, next_query - self.window + 1) // self.page_size
+
+    def slide(self, held: WindowPages, next_query: int) -> int:
+        """Give back the pages no query at or after ``next_query`` can
+        see; returns how many went."""
+        n = min(self.first_slot(next_query) - held.base, len(held.pages))
+        if n <= 0:
+            return 0
+        self.free(held.pages[:n])
+        del held.pages[:n]
+        held.base += n
+        return n
+
+    def grow(self, held: WindowPages, first_query: int, end: int,
+             owner: int) -> None:
+        """Make ``held`` cover every slot a launch needs that writes
+        positions up to ``end`` (exclusive) and whose first query is at
+        ``first_query``.  A request that holds nothing yet starts at
+        the window's first slot: what lies before it is never read, so
+        a whole-row prefill of a long context keeps only its tail.
+        Raises :class:`PagePoolExhausted` with nothing taken."""
+        if not held.pages:
+            held.base = max(held.base, self.first_slot(first_query))
+        need = self.pages_needed(end) - held.base - len(held.pages)
+        if need > 0:
+            held.pages.extend(self.allocate(need, owner))
+
+    def release(self, held: WindowPages) -> None:
+        self.free(held.pages)
+        held.pages.clear()
+        held.base = 0
+
+    def tables(self, helds: Sequence[WindowPages],
+               rows: Optional[int] = None):
+        """(pages ``[rows, max_pages_per_request]``, start ``[rows]``):
+        each request's held pages, and the absolute position of the
+        first of them (``flash_decode``'s ``kv_start``)."""
+        rows = len(helds) if rows is None else rows
+        start = np.zeros((rows,), np.int32)
+        for i, held in enumerate(helds):
+            start[i] = held.base * self.page_size
+        return (self.page_table([held.pages for held in helds], rows),
+                jnp.asarray(start))
+
+    def write_targets(self, held: WindowPages, positions: np.ndarray):
+        """Host-side (pages, offsets) of ``positions`` for the scatter;
+        a position before the held slots goes to the scratch page."""
+        slot = positions // self.page_size - held.base
+        pages = np.asarray(held.pages + [0], np.int32)[
+            np.where(slot >= 0, slot, len(held.pages))]
+        return pages, (positions % self.page_size).astype(np.int32)
 
 
 class PrefixIndex:

@@ -1,5 +1,20 @@
-"""GPT-style decoder with a paged KV cache — the serving engine's
-model half.
+"""Decoders over a paged KV cache — the serving engine's model half.
+
+**The block seam (ISSUE 29).**  :class:`PagedDecoder` is one driver
+for every architecture: its three entry points below own the cache
+(the packed row, the appends, the page tables, which pool a layer
+lives in) and call the architecture's BLOCK for everything between the
+embedding and the logits.  A block is written once — ``embed``, one
+``layer`` and the head — and is handed an ``attend(q, k, v)`` that is
+the varlen prefill kernel in one entry point and append-then-
+``flash_decode`` in the other two.  :class:`GPTBlock` (learned
+positions, LayerNorm, GELU MLP, multi-head attention, tied head) is
+one definition; :class:`AfmoeBlock` (RoPE on sliding-window layers and
+none on full ones, RMSNorm sandwiches, QK-norm, a sigmoid output gate,
+grouped-query heads, SwiGLU, a dropless top-k expert layer that holds
+a share of the experts, muP-scaled embeddings, an untied head) the
+second.  The engine asks a configuration for its block and never
+asks which it got.
 
 Two entry points mirror the two phases of continuous batching:
 
@@ -75,13 +90,14 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from apex_tpu.ops import flash_attention, flash_decode
+from apex_tpu.serving.experts import expert_layer, swiglu
 from apex_tpu.serving.kv_cache import quantize_tokens
 
 
@@ -127,8 +143,10 @@ def shard_params_tp(params, tp: int):
 
 @dataclasses.dataclass(frozen=True)
 class ServingModelConfig:
-    """Decoder geometry.  ``max_position`` bounds the learned position
-    table — admission must reject requests that could outgrow it."""
+    """GPT decoder geometry.  ``max_position`` bounds the learned
+    position table and nothing else — admission must reject requests
+    that could outgrow it.  (A model without such a table is bounded by
+    its pages: ``ServingEngine.max_context``.)"""
 
     vocab_size: int = 256
     hidden_size: int = 64
@@ -138,16 +156,33 @@ class ServingModelConfig:
     mlp_ratio: int = 4
     dtype: object = jnp.float32
 
+    name = "gpt"
+
     @property
     def head_dim(self) -> int:
         if self.hidden_size % self.num_heads:
             raise ValueError("hidden_size must divide by num_heads")
         return self.hidden_size // self.num_heads
 
+    @property
+    def kv_heads(self) -> int:
+        """Heads a token's K and V have in the pool."""
+        return self.num_heads
 
-def init_params(cfg: ServingModelConfig, seed: int = 0):
-    """Deterministic parameter pytree (scaled-normal init, tied LM
-    head = embedding transpose)."""
+    @property
+    def layer_windows(self) -> Tuple[Optional[int], ...]:
+        """Per layer, how many tokens back it sees (None: all)."""
+        return (None,) * self.num_layers
+
+    def block(self) -> "GPTBlock":
+        return GPTBlock(self)
+
+
+def init_params(cfg, seed: int = 0):
+    """Deterministic parameter pytree (scaled-normal init; for a GPT,
+    tied LM head = embedding transpose)."""
+    if not isinstance(cfg, ServingModelConfig):
+        return cfg.init_params(seed)
     keys = jax.random.split(jax.random.PRNGKey(seed),
                             2 + 4 * cfg.num_layers)
     h, r = cfg.hidden_size, cfg.mlp_ratio
@@ -186,24 +221,282 @@ def _mlp(x, layer):
     return jax.nn.gelu(x @ layer["w1"]) @ layer["w2"]
 
 
-class PagedDecoder:
-    """The decoder model over the cache layouts the engine owns (the
-    engine holds params/pool; this class is pure functions of them)."""
+class GPTBlock:
+    """The pre-LN GPT block: learned positions, LayerNorm, fused QKV,
+    multi-head attention, GELU MLP, head tied to the embedding.  Under
+    ``tp_axis`` it is the per-shard body of ``shard_map``: the local
+    ``wqkv`` block carries this shard's heads and each half of the
+    block contributes its residual through one ``psum``."""
+
+    #: engine options this architecture cannot serve (none)
+    refuses: Tuple[str, ...] = ()
+    #: per-launch counters its executables return after the pools
+    stat_names: Tuple[str, ...] = ()
 
     def __init__(self, cfg: ServingModelConfig):
         self.cfg = cfg
+
+    def embed(self, params, tokens, positions):
+        return params["embed"][tokens] + params["pos"][positions]
+
+    def layer(self, layer, li, x, positions, attend, *, tp_axis=None,
+              valid=None, stats=None):
+        hd = self.cfg.head_dim
+        hdn = _ln(x, layer["ln1"])
+        qkv = hdn @ layer["wqkv"]
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        lead = q.shape[:-1]
+        nh = k.shape[-1] // hd  # LOCAL heads (H/tp under shard_map)
+        ctx = attend(q.reshape(*lead, nh, hd), k.reshape(*lead, nh, hd),
+                     v.reshape(*lead, nh, hd))
+        attn = ctx @ layer["wo"]
+        if tp_axis is not None:
+            attn = jax.lax.psum(attn, tp_axis)
+        x = x + attn
+        mlp = _mlp(_ln(x, layer["ln2"]), layer)
+        if tp_axis is not None:
+            mlp = jax.lax.psum(mlp, tp_axis)
+        return x + mlp
+
+    def final_norm(self, params, x):
+        return _ln(x, params["ln_f"])
+
+    def logits(self, params, x):
+        return x @ params["embed"].T
+
+
+# -- afmoe (Trinity): the second block ---------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class AfmoeConfig:
+    """Geometry of an ``afmoe`` decoder (arcee-ai Trinity) as one chip
+    of an expert-parallel deployment holds it: ``num_experts`` is the
+    router's width, ``experts_held`` the ``[lo, hi)`` of them whose
+    weights are here, ``vocab_size`` this chip's slice.  There is no
+    position table (RoPE on sliding layers, nothing on full ones): a
+    request is bounded by its pages."""
+
+    vocab_size: int
+    hidden_size: int
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    layer_types: Tuple[str, ...]       # "sliding_attention" | "full_attention"
+    num_dense_layers: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    num_experts: int
+    experts_held: Tuple[int, int]
+    top_k: int
+    route_scale: float
+    sliding_window: int
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-5
+    dtype: object = jnp.float32
+
+    name = "afmoe"
+    #: no learned position table
+    max_position = None
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads
+
+    @property
+    def layer_windows(self) -> Tuple[Optional[int], ...]:
+        return tuple(self.sliding_window if t == "sliding_attention"
+                     else None for t in self.layer_types)
+
+    def block(self) -> "AfmoeBlock":
+        return AfmoeBlock(self)
+
+    def init_params(self, seed: int = 0):
+        """Seeded parameters in the block's layout: matrices normal
+        with std 1/sqrt(fan_in), gains 1 + 0.02 normal, the router's
+        selection bias 0.02 normal (so that tests exercise it)."""
+        d, dt = self.hidden_size, self.dtype
+        hq, hk, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        n_held = self.experts_held[1] - self.experts_held[0]
+        keys = iter(jax.random.split(jax.random.PRNGKey(seed), 4096))
+
+        def mat(*shape):
+            return (jax.random.normal(next(keys), shape, jnp.float32)
+                    / math.sqrt(shape[-2])).astype(dt)
+
+        def gain(n):
+            return (1.0 + 0.02 * jax.random.normal(
+                next(keys), (n,), jnp.float32)).astype(dt)
+
+        def mlp(*lead, f):
+            return {"wg": mat(*lead, d, f), "wu": mat(*lead, d, f),
+                    "wd": mat(*lead, f, d)}
+
+        layers = []
+        for i in range(self.num_layers):
+            layer = {"g1": gain(d), "g2": gain(d), "g3": gain(d),
+                     "g4": gain(d), "gq": gain(hd), "gk": gain(hd),
+                     "wq": mat(d, hq * hd), "wk": mat(d, hk * hd),
+                     "wv": mat(d, hk * hd), "wgate": mat(d, hq * hd),
+                     "wo": mat(hq * hd, d)}
+            if i < self.num_dense_layers:
+                layer["mlp"] = mlp(f=self.intermediate_size)
+            else:
+                f = self.moe_intermediate_size
+                layer["moe"] = {
+                    "router": mat(d, self.num_experts),
+                    "expert_bias": (0.02 * jax.random.normal(
+                        next(keys), (self.num_experts,),
+                        jnp.float32)).astype(dt),
+                    "experts": mlp(n_held, f=f), "shared": mlp(f=f)}
+            layers.append(layer)
+        embed = (jax.random.normal(next(keys), (self.vocab_size, d),
+                                   jnp.float32) / math.sqrt(d)).astype(dt)
+        return {"embed": embed, "head": mat(d, self.vocab_size),
+                "norm_f": gain(d), "layers": layers}
+
+
+def _rms(x, g, eps):
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * g.astype(jnp.float32)).astype(x.dtype)
+
+
+def _rope(x, positions, theta):
+    """Rotate-half RoPE over all of the head dimension, absolute
+    0-based ``positions`` (the lead dimensions of ``x [..., h, d]``)."""
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[..., None, None] * freq
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+class AfmoeBlock:
+    """The ``afmoe`` block (docs/serving.md, "The block seam"):
+
+    ``h = x + RMS(Attn(RMS(x)))``, ``x' = h + RMS(MLP(RMS(h)))`` — four
+    norms a layer.  Attention: grouped-query heads with RMS norm on
+    each head of q and k, RoPE on SLIDING layers only (full layers
+    carry no positional signal), a sigmoid gate from the normed input
+    on the context before ``wo``, no biases.  The MLP is SwiGLU in the
+    first ``num_dense_layers`` layers and the dropless expert layer of
+    :mod:`apex_tpu.serving.experts` after them.  Embeddings are scaled
+    by ``sqrt(d)`` (muP); the head is its own matrix."""
+
+    refuses = ("tp", "kv_quant", "prefix_sharing", "speculation",
+               "prefill_only", "kv_import")
+    stat_names = ("moe_pairs_held", "moe_load_max")
+
+    def __init__(self, cfg: AfmoeConfig):
+        self.cfg = cfg
+        self.windows = cfg.layer_windows
+
+    def embed(self, params, tokens, positions):
+        x = params["embed"][tokens]
+        return x * jnp.asarray(math.sqrt(self.cfg.hidden_size), x.dtype)
+
+    def layer(self, layer, li, x, positions, attend, *, tp_axis=None,
+              valid=None, stats=None):
+        cfg = self.cfg
+        eps, hd = cfg.rms_norm_eps, cfg.head_dim
+        lead = x.shape[:-1]
+        u = _rms(x, layer["g1"], eps)
+        q = (u @ layer["wq"]).reshape(*lead, cfg.num_heads, hd)
+        k = (u @ layer["wk"]).reshape(*lead, cfg.num_kv_heads, hd)
+        v = (u @ layer["wv"]).reshape(*lead, cfg.num_kv_heads, hd)
+        gate = u @ layer["wgate"]
+        q, k = _rms(q, layer["gq"], eps), _rms(k, layer["gk"], eps)
+        sliding = self.windows[li] is not None
+        if sliding:
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
+        with jax.named_scope("attn_window" if sliding else "attn_full"):
+            ctx = attend(q, k, v)
+        a = (ctx.astype(jnp.float32)
+             * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(x.dtype)
+        h = x + _rms(a @ layer["wo"], layer["g2"], eps)
+        u = _rms(h, layer["g3"], eps)
+        if "mlp" in layer:
+            m = swiglu(u, layer["mlp"])
+        else:
+            m, load = expert_layer(
+                u, layer["moe"], held=cfg.experts_held, top_k=cfg.top_k,
+                route_scale=cfg.route_scale, valid=valid)
+            if stats is not None:
+                stats.append(load)
+        return h + _rms(m, layer["g4"], eps)
+
+    def final_norm(self, params, x):
+        return _rms(x, params["norm_f"], self.cfg.rms_norm_eps)
+
+    def logits(self, params, x):
+        return x @ params["head"]
+
+
+class WindowKV(NamedTuple):
+    """The window-lifetime half of a two-lifetime cache as a paged step
+    sees it: the pool of the sliding layers (``k``/``v`` ``[L_w,
+    n_pages, page, H, D]``), each row's COMPACT table of the pages it
+    still holds (``pages [b, wp_max]``) and the absolute position of
+    the table's first column (``start [b]``, ``flash_decode``'s
+    ``kv_start``)."""
+
+    k: jnp.ndarray
+    v: jnp.ndarray
+    pages: jnp.ndarray
+    start: jnp.ndarray
+
+
+class PagedDecoder:
+    """The decoder over the cache layouts the engine owns (the engine
+    holds params/pools; this class is pure functions of them), for
+    whatever block its configuration names.
+
+    Layers with a window (``cfg.layer_windows``) live in a pool of
+    their own, in layer order, and so do the others: ``pool_index[li]``
+    is layer ``li``'s index in its pool."""
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+        self.block = cfg.block()
+        self.windows = tuple(cfg.layer_windows)
+        counts = {False: 0, True: 0}
+        index = []
+        for w in self.windows:
+            index.append(counts[w is not None])
+            counts[w is not None] += 1
+        self.pool_index = tuple(index)
+        self.full_layers, self.window_layers = counts[False], counts[True]
+        self.stat_names = self.block.stat_names
+
+    def _stats(self, stats):
+        """The block's per-launch counters as one int32 vector, in
+        ``stat_names`` order (afmoe: the (token, expert) pairs its held
+        experts took, summed over layers; the most any one took)."""
+        load = jnp.stack(stats)
+        return jnp.stack([jnp.sum(load), jnp.max(load)]).astype(jnp.int32)
 
     # -- admission: packed varlen prefill --------------------------------
 
     def prefill(self, params, tokens: jnp.ndarray, seg: jnp.ndarray,
                 positions: jnp.ndarray,
                 last_index: Optional[jnp.ndarray] = None,
-                *, tp_axis: Optional[str] = None,
-                ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+                *, tp_axis: Optional[str] = None):
         """tokens/seg/positions ``[1, S]`` (one packed row; seg 0 =
         padding, real segments 1..n; positions restart per segment).
         Returns (logits, k, v ``[L, 1, S, H, D]``) — K/V for every
-        packed position, for the engine to scatter into pages.
+        packed position, for the engine to scatter into pages.  A
+        model with window layers returns two more, ``(logits, k, v,
+        wk, wv)``: the full layers' K/V and then the window layers',
+        each for its own pool; a block with counters appends them last.
 
         ``last_index`` (traced int scalar, so the compiled shape never
         changes): compute logits ``[1, 1, vocab]`` for that single
@@ -218,41 +511,131 @@ class PagedDecoder:
         the local wqkv block carries this shard's heads (the returned
         k/v are the LOCAL head slice) and each block's residual is
         one ``psum``."""
-        cfg = self.cfg
-        hd = cfg.head_dim
+        block = self.block
         with jax.named_scope("embedding"):
-            x = params["embed"][tokens] + params["pos"][positions]
-        ks, vs = [], []
-        for layer in params["layers"]:
-            with jax.named_scope("layer"):
-                hdn = _ln(x, layer["ln1"])
-                qkv = hdn @ layer["wqkv"]
-                q, k, v = jnp.split(qkv, 3, axis=-1)
+            x = block.embed(params, tokens, positions)
+        kept = {False: ([], []), True: ([], [])}
+        stats = []
+
+        def attend_in(li):
+            window = self.windows[li]
+            ks, vs = kept[window is not None]
+
+            def attend(q, k, v):
                 b, s = q.shape[:2]
-                nh = k.shape[-1] // hd  # LOCAL heads (H/tp under shard_map)
-                q4 = q.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
-                k4 = k.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
-                v4 = v.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
-                ctx = flash_attention(q4, k4, v4, causal=True,
-                                      segment_ids=seg)
-                ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, -1)
-                attn = ctx @ layer["wo"]
-                if tp_axis is not None:
-                    attn = jax.lax.psum(attn, tp_axis)
-                x = x + attn
-                mlp = _mlp(_ln(x, layer["ln2"]), layer)
-                if tp_axis is not None:
-                    mlp = jax.lax.psum(mlp, tp_axis)
-                x = x + mlp
-                ks.append(k.reshape(b, s, nh, hd))
-                vs.append(v.reshape(b, s, nh, hd))
+                ctx = flash_attention(
+                    q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                    v.transpose(0, 2, 1, 3), causal=True,
+                    segment_ids=seg, window=window)
+                ks.append(k)
+                vs.append(v)
+                return ctx.transpose(0, 2, 1, 3).reshape(b, s, -1)
+
+            return attend
+
+        for li, layer in enumerate(params["layers"]):
+            with jax.named_scope("layer"):
+                x = block.layer(layer, li, x, positions, attend_in(li),
+                                tp_axis=tp_axis, valid=seg != 0,
+                                stats=stats)
         with jax.named_scope("head"):
-            x = _ln(x, params["ln_f"])
+            x = block.final_norm(params, x)
             if last_index is not None:
                 x = jax.lax.dynamic_slice_in_dim(
                     x, jnp.asarray(last_index, jnp.int32), 1, axis=1)
-            logits = x @ params["embed"].T
-        return logits, jnp.stack(ks), jnp.stack(vs)
+            logits = block.logits(params, x)
+        out = (logits, jnp.stack(kept[False][0]), jnp.stack(kept[False][1]))
+        if self.window_layers:
+            out += (jnp.stack(kept[True][0]), jnp.stack(kept[True][1]))
+        if stats:
+            out += (self._stats(stats),)
+        return out
+
+    # -- the paged step: append, then attend over the pages --------------
+
+    def _paged(self, params, k_pool, v_pool, tokens, positions,
+               write_pages, write_offsets, page_table, kv_len, *,
+               k_scale, v_scale, tp_axis, window: Optional[WindowKV]):
+        """What :meth:`decode` (lead shape ``[b]``) and :meth:`extend`
+        (``[b, q]``) share: every layer appends its tokens' K/V at
+        ``(write_pages, write_offsets)`` of its pool and attends over
+        the row's pages.  Returns (final-norm hidden states, pools in
+        executable order, counters).
+
+        Every layer hands ``flash_decode`` the WHOLE pool ``[L,
+        n_pages, ps, H, hd]`` and ``layer=``, never ``k_pool[li]``:
+        the kernel reads that layer's pages where they lie, and a
+        slice would be copied out first (all of a layer's pages, twice,
+        before each of the L calls).  The append before it stays in
+        place: the call reads the buffer the scatter wrote.
+
+        A window layer appends into ``window``'s pool instead.  Its
+        targets are worked out here from the row's compact table (slot
+        ``position // page - start // page``); a row that writes the
+        full pool's scratch page (padding, an idle row) writes the
+        window pool's too."""
+        block = self.block
+        lead = tokens.shape
+        b = lead[0]
+        quantized = k_scale is not None
+        qmax = quant_qmax(k_pool.dtype) if quantized else None
+        real = write_pages != 0
+        pools = {False: [k_pool, v_pool, k_scale, v_scale]}
+        targets = {False: (write_pages, write_offsets)}
+        tables = {False: dict(page_table=page_table)}
+        if window is not None:
+            ps = window.k.shape[2]
+            slot = (positions.reshape(b, -1) // ps
+                    - (window.start // ps)[:, None])
+            held = jnp.take_along_axis(
+                window.pages, jnp.clip(slot, 0, window.pages.shape[1] - 1),
+                axis=1).reshape(lead)
+            pools[True] = [window.k, window.v, None, None]
+            targets[True] = (jnp.where(real & (slot.reshape(lead) >= 0),
+                                       held, 0), write_offsets)
+            tables[True] = dict(page_table=window.pages,
+                                kv_start=window.start)
+        stats = []
+
+        def attend_in(li):
+            w = self.windows[li]
+            pool = pools[w is not None]
+            pages, offsets = targets[w is not None]
+            pi = self.pool_index[li]
+
+            def attend(q, k, v):
+                nh, hd = q.shape[-2:]
+                k_new, v_new = k, v
+                if quantized:
+                    k_new, k_s = quantize_tokens(k_new, pool[0].dtype, qmax)
+                    v_new, v_s = quantize_tokens(v_new, pool[1].dtype, qmax)
+                    pool[2] = pool[2].at[pi, pages, offsets].set(k_s)
+                    pool[3] = pool[3].at[pi, pages, offsets].set(v_s)
+                pool[0] = pool[0].at[pi, pages, offsets].set(k_new)
+                pool[1] = pool[1].at[pi, pages, offsets].set(v_new)
+                q4 = q.reshape(b, -1, nh, hd).transpose(0, 2, 1, 3)
+                kw = dict(tables[w is not None])
+                if w is not None:
+                    kw["window"] = w
+                ctx = flash_decode(
+                    q4, pool[0], pool[1], kw.pop("page_table"), kv_len,
+                    layer=pi, k_scale=pool[2], v_scale=pool[3], **kw)
+                return ctx.transpose(0, 2, 1, 3).reshape(*lead, -1)
+
+            return attend
+
+        with jax.named_scope("embedding"):
+            x = block.embed(params, tokens, positions)
+        for li, layer in enumerate(params["layers"]):
+            with jax.named_scope("layer"):
+                x = block.layer(layer, li, x, positions, attend_in(li),
+                                tp_axis=tp_axis, valid=real, stats=stats)
+        out = tuple(pools[False][:4 if quantized else 2])
+        if window is not None:
+            out += tuple(pools[True][:2])
+        if stats:
+            out += (self._stats(stats),)
+        return x, out
 
     # -- steady state: paged decode --------------------------------------
 
@@ -261,7 +644,8 @@ class PagedDecoder:
                kv_len: jnp.ndarray, *,
                k_scale: Optional[jnp.ndarray] = None,
                v_scale: Optional[jnp.ndarray] = None,
-               tp_axis: Optional[str] = None):
+               tp_axis: Optional[str] = None,
+               window: Optional[WindowKV] = None):
         """One decode step for a fixed-width batch.
 
         ``tokens``/``positions`` ``[b]``: each row's newest token and
@@ -275,60 +659,26 @@ class PagedDecoder:
         quantized pool's [L, n_pages, ps, H] fp32 scale planes), a
         5-tuple appending the updated scale planes: the append
         quantizes on write and ``flash_decode`` dequantizes on read.
+        With ``window`` (:class:`WindowKV`) the window pool's k and v
+        follow the full pool's, and a block's counters come last.
         ``tp_axis``: per-shard body under ``shard_map`` (local head
         slice of pool and scales, one ``psum`` per block).
 
-        Every layer hands ``flash_decode`` the WHOLE pool ``[L,
-        n_pages, ps, H, hd]`` and ``layer=li``, never ``k_pool[li]``:
-        the kernel reads layer ``li``'s pages where they lie, and a
-        slice would be copied out first (all of a layer's pages, twice,
-        before each of the L calls).  The append before it stays in
-        place: the call reads the buffer the scatter wrote.
-        :meth:`extend` does the same."""
-        cfg = self.cfg
-        hd = cfg.head_dim
+        The append and the attention over the pages are
+        :meth:`_paged`'s; :meth:`extend` does the same."""
         page_size = k_pool.shape[2]
-        quantized = k_scale is not None
-        qmax = quant_qmax(k_pool.dtype) if quantized else None
-        with jax.named_scope("embedding"):
-            x = params["embed"][tokens] + params["pos"][positions]  # [b, h]
         page_slot = positions // page_size
         page_idx = jnp.take_along_axis(
             page_table, page_slot[:, None], axis=1)[:, 0]
         offset = positions % page_size
-        for li, layer in enumerate(params["layers"]):
-            with jax.named_scope("layer"):
-                hdn = _ln(x, layer["ln1"])
-                qkv = hdn @ layer["wqkv"]
-                q, k, v = jnp.split(qkv, 3, axis=-1)
-                b = q.shape[0]
-                nh = k.shape[-1] // hd  # LOCAL heads (H/tp under shard_map)
-                k_new, v_new = k.reshape(b, nh, hd), v.reshape(b, nh, hd)
-                if quantized:
-                    k_new, k_s = quantize_tokens(k_new, k_pool.dtype, qmax)
-                    v_new, v_s = quantize_tokens(v_new, v_pool.dtype, qmax)
-                    k_scale = k_scale.at[li, page_idx, offset].set(k_s)
-                    v_scale = v_scale.at[li, page_idx, offset].set(v_s)
-                k_pool = k_pool.at[li, page_idx, offset].set(k_new)
-                v_pool = v_pool.at[li, page_idx, offset].set(v_new)
-                q4 = q.reshape(b, 1, nh, hd).transpose(0, 2, 1, 3)
-                ctx = flash_decode(
-                    q4, k_pool, v_pool, page_table, kv_len, layer=li,
-                    k_scale=k_scale, v_scale=v_scale)
-                ctx = ctx.transpose(0, 2, 1, 3).reshape(b, -1)
-                attn = ctx @ layer["wo"]
-                if tp_axis is not None:
-                    attn = jax.lax.psum(attn, tp_axis)
-                x = x + attn
-                mlp = _mlp(_ln(x, layer["ln2"]), layer)
-                if tp_axis is not None:
-                    mlp = jax.lax.psum(mlp, tp_axis)
-                x = x + mlp
+        x, out = self._paged(
+            params, k_pool, v_pool, tokens, positions, page_idx, offset,
+            page_table, kv_len, k_scale=k_scale, v_scale=v_scale,
+            tp_axis=tp_axis, window=window)
         with jax.named_scope("head"):
-            logits = _ln(x, params["ln_f"]) @ params["embed"].T
-        if quantized:
-            return logits, k_pool, v_pool, k_scale, v_scale
-        return logits, k_pool, v_pool
+            logits = self.block.logits(
+                params, self.block.final_norm(params, x))
+        return (logits,) + out
 
     # -- draft–verify / chunked prefill: multi-token extension -----------
 
@@ -338,7 +688,8 @@ class PagedDecoder:
                kv_len: jnp.ndarray, *, last_only: bool = False,
                k_scale: Optional[jnp.ndarray] = None,
                v_scale: Optional[jnp.ndarray] = None,
-               tp_axis: Optional[str] = None):
+               tp_axis: Optional[str] = None,
+               window: Optional[WindowKV] = None):
         """Append ``q`` tokens per row to the paged cache and score
         them in one :func:`~apex_tpu.ops.flash_decode` launch.
 
@@ -360,54 +711,20 @@ class PagedDecoder:
         ``last_only`` (static): project only the final row through the
         LM head — the chunked-prefill shape, where one next-token
         distribution is wanted and front-padding pins the chunk's last
-        valid token to row ``q - 1``.  ``k_scale``/``v_scale`` and
-        ``tp_axis``: as in :meth:`decode` (quantize-on-write appends /
-        per-shard ``shard_map`` body).  Returns (logits
-        ``[b, q, vocab]`` or ``[b, 1, vocab]``, k_pool', v_pool'[,
-        k_scale', v_scale']).
+        valid token to row ``q - 1``.  ``k_scale``/``v_scale``,
+        ``window`` and ``tp_axis``: as in :meth:`decode`
+        (quantize-on-write appends / the window pool / per-shard
+        ``shard_map`` body).  Returns (logits ``[b, q, vocab]`` or
+        ``[b, 1, vocab]``, k_pool', v_pool'[, k_scale', v_scale']
+        [, wk', wv'][, counters]).
         """
-        cfg = self.cfg
-        hd = cfg.head_dim
-        b, q = tokens.shape
-        quantized = k_scale is not None
-        qmax = quant_qmax(k_pool.dtype) if quantized else None
-        with jax.named_scope("embedding"):   # x [b, q, h]
-            x = params["embed"][tokens] + params["pos"][positions]
-        for li, layer in enumerate(params["layers"]):
-            with jax.named_scope("layer"):
-                hdn = _ln(x, layer["ln1"])
-                qkv = hdn @ layer["wqkv"]
-                qh, kh, vh = jnp.split(qkv, 3, axis=-1)
-                nh = kh.shape[-1] // hd  # LOCAL heads (H/tp under shard_map)
-                k_new = kh.reshape(b, q, nh, hd)
-                v_new = vh.reshape(b, q, nh, hd)
-                if quantized:
-                    k_new, k_s = quantize_tokens(k_new, k_pool.dtype, qmax)
-                    v_new, v_s = quantize_tokens(v_new, v_pool.dtype, qmax)
-                    k_scale = k_scale.at[li, write_pages,
-                                         write_offsets].set(k_s)
-                    v_scale = v_scale.at[li, write_pages,
-                                         write_offsets].set(v_s)
-                k_pool = k_pool.at[li, write_pages, write_offsets].set(k_new)
-                v_pool = v_pool.at[li, write_pages, write_offsets].set(v_new)
-                q4 = qh.reshape(b, q, nh, hd).transpose(0, 2, 1, 3)
-                ctx = flash_decode(
-                    q4, k_pool, v_pool, page_table, kv_len, layer=li,
-                    k_scale=k_scale, v_scale=v_scale)
-                ctx = ctx.transpose(0, 2, 1, 3).reshape(b, q, -1)
-                attn = ctx @ layer["wo"]
-                if tp_axis is not None:
-                    attn = jax.lax.psum(attn, tp_axis)
-                x = x + attn
-                mlp = _mlp(_ln(x, layer["ln2"]), layer)
-                if tp_axis is not None:
-                    mlp = jax.lax.psum(mlp, tp_axis)
-                x = x + mlp
+        x, out = self._paged(
+            params, k_pool, v_pool, tokens, positions, write_pages,
+            write_offsets, page_table, kv_len, k_scale=k_scale,
+            v_scale=v_scale, tp_axis=tp_axis, window=window)
         with jax.named_scope("head"):
-            x = _ln(x, params["ln_f"])
+            x = self.block.final_norm(params, x)
             if last_only:
                 x = x[:, -1:, :]
-            logits = x @ params["embed"].T
-        if quantized:
-            return logits, k_pool, v_pool, k_scale, v_scale
-        return logits, k_pool, v_pool
+            logits = self.block.logits(params, x)
+        return (logits,) + out

@@ -40,6 +40,16 @@ so a seeded arrival trace replays bit-identically):
   docs/serving.md "Preemption").
 * **retirement**: EOS or ``max_new_tokens`` reached → pages freed (and
   immediately reusable), terminal state recorded.
+* **two page lifetimes** (ISSUE 29): where the cache has a
+  ``window_pool`` a request holds pages of both kinds and every
+  decision above counts both.  The full pool is reserved for the whole
+  context at admission, as ever; the window pool only for the launch
+  at hand (a whole-row prefill's tail, one chunk, one decode token),
+  because what slid out of the window is given back after every
+  launch (:meth:`ContinuousBatchingScheduler.slide_windows`) and a
+  long prompt never holds more than a window and a chunk there.  A
+  chunk or a decode step that finds the window pool dry preempts from
+  the back, like growth in the full pool.
 
 Resilience policy (ISSUE 10 — docs/serving.md "Failure semantics"):
 
@@ -74,6 +84,7 @@ from apex_tpu.serving.kv_cache import (
     PagedKVCache,
     PagePoolExhausted,
     PrefixIndex,
+    WindowPages,
 )
 
 WAITING = "waiting"
@@ -104,6 +115,10 @@ class Request:
     state: str = WAITING
     generated: List[int] = dataclasses.field(default_factory=list)
     pages: List[int] = dataclasses.field(default_factory=list)
+    # what the request holds in the window pool, where the model has
+    # layers that keep only a window of tokens (ISSUE 29); None until
+    # such a pool admits it
+    window: Optional[WindowPages] = None
     kv_len: int = 0               # tokens whose K/V sit in the pool
     # chunked-prefill cursor (ISSUE 12): tokens of the admission
     # context already computed into pages; None = not mid-chunk (the
@@ -218,6 +233,8 @@ class ContinuousBatchingScheduler:
         # BEFORE preempting a running request — dropping warm-cache
         # opportunism is always cheaper than killing live work
         self.prefix_index = prefix_index
+        #: the pool of the window layers' pages, where there is one
+        self.wpool = cache.window_pool
         self.waiting: Deque[Request] = deque()
         self.running: List[Request] = []   # admission order
         self.finished: List[Request] = []
@@ -315,16 +332,21 @@ class ContinuousBatchingScheduler:
         budget = self.prefill_budget
         chunks: List[tuple] = []
         if self.chunk_size is not None:
-            for req in self.running:
-                if req.prefill_pos is None:
+            for req in list(self.running):
+                if req.prefill_pos is None or req.state != RUNNING:
                     continue
                 # seq_len == len(context) during prefill, without
                 # materializing the prompt+generated list per boundary
                 n = min(self.chunk_size, req.seq_len - req.prefill_pos)
                 if n > budget:
                     break
+                self._grow_window(req, req.prefill_pos, req.prefill_pos + n)
+                if req.state != RUNNING:
+                    continue   # preempted for its own chunk's pages
                 chunks.append((req, req.prefill_pos, n))
                 budget -= n
+            # a chunk planned before a later one's growth evicted it
+            chunks = [c for c in chunks if c[0].state == RUNNING]
         admitted: List[Request] = []
         while self.waiting and \
                 len(self.running) + len(admitted) < self.max_batch:
@@ -364,6 +386,20 @@ class ContinuousBatchingScheduler:
                     raise
                 break
             pages = list(shared) + fresh
+            if self.wpool is not None:
+                # the window pool covers the first launch only: the
+                # tail of a whole-row prefill, or the first chunk
+                held = WindowPages()
+                try:
+                    self.wpool.grow(
+                        held, m if chunked else ctx,
+                        min(ctx, m + need) if chunked else ctx, req.rid)
+                except PagePoolExhausted:
+                    self.cache.free(pages)
+                    if not self.running and not admitted:
+                        raise
+                    break
+                req.window = held
             if m % self.cache.page_size:
                 # the hit ends MID-page: the suffix's first chunk will
                 # write position m into the last shared page, so it is
@@ -448,8 +484,7 @@ class ContinuousBatchingScheduler:
         if victim is None:
             victim = self.running[-1]
         self.running.remove(victim)
-        self.cache.free(victim.pages)
-        victim.pages = []
+        self._release(victim)
         victim.kv_len = 0
         # a mid-chunk victim restarts its chunked prefill on
         # re-admission — chunk progress is rebuildable, like KV
@@ -460,6 +495,39 @@ class ContinuousBatchingScheduler:
         victim.preemptions += 1
         self.waiting.appendleft(victim)
         return victim
+
+    def _release(self, req: Request) -> None:
+        """Give back every page ``req`` holds, of both lifetimes."""
+        self.cache.free(req.pages)
+        req.pages = []
+        if req.window is not None:
+            self.wpool.release(req.window)
+            req.window = None
+
+    def _grow_window(self, req: Request, first_query: int, end: int
+                     ) -> List[Request]:
+        """Take the window-pool pages ``req``'s next launch writes
+        (positions up to ``end``, first query at ``first_query``),
+        preempting from the back while the pool is dry.  Returns the
+        requests preempted for them; ``req`` itself may be the last
+        (it is then no longer RUNNING).  Nothing to do where there is
+        no window pool."""
+        evicted: List[Request] = []
+        while self.wpool is not None and req.state == RUNNING:
+            try:
+                self.wpool.grow(req.window, first_query, end, req.rid)
+                break
+            except PagePoolExhausted:
+                evicted.append(self.preempt_one())
+        return evicted
+
+    def slide_windows(self, reqs) -> int:
+        """After a launch: give back the window-pool pages that no
+        query still to come of ``reqs`` can see; returns how many."""
+        if self.wpool is None:
+            return 0
+        return sum(self.wpool.slide(r.window, r.kv_len) for r in reqs
+                   if r.window is not None)
 
     def ensure_decode_capacity(self, extra: Optional[Dict[int, int]]
                                = None) -> List[Request]:
@@ -484,6 +552,9 @@ class ContinuousBatchingScheduler:
                                       if extra else 0)
                 need_pages = self.cache.pages_needed(want)
                 if len(req.pages) >= need_pages:
+                    if req.prefill_pos is None:
+                        evicted.extend(self._grow_window(
+                            req, req.seq_len - 1, want))
                     break
                 try:
                     req.pages.extend(
@@ -544,8 +615,7 @@ class ContinuousBatchingScheduler:
                 continue
             if dt is not None and now >= dt:
                 self.running.remove(req)
-                self.cache.free(req.pages)
-                req.pages = []
+                self._release(req)
                 req.kv_len = 0
                 req.prefill_pos = None
                 req.state = FINISHED
@@ -563,8 +633,7 @@ class ContinuousBatchingScheduler:
         done = [r for r in self.running if r.done]
         for req in done:
             self.running.remove(req)
-            self.cache.free(req.pages)
-            req.pages = []
+            self._release(req)
             req.state = FINISHED
             req.finish_t = now
             req.finish_reason = (

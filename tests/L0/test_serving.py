@@ -61,7 +61,9 @@ class TestDecodeRouting:
             assert flash_decode_route(q, kp) == "xla"
 
     def test_head_mismatch_routes_generic(self):
-        q = jax.ShapeDtypeStruct((2, 8, 1, 16), jnp.float32)
+        # 6 query heads over 4 pool heads is no grouping (8 over 4 is:
+        # tests/L0/test_attention_gqa_window.py)
+        q = jax.ShapeDtypeStruct((2, 6, 1, 16), jnp.float32)
         kp = jax.ShapeDtypeStruct((8, 64, 4, 16), jnp.float32)
         with routing_override(decode="decode"):
             assert flash_decode_route(q, kp) == "xla"
