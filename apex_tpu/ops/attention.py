@@ -2254,34 +2254,35 @@ def flash_attention_varlen(
 # of a shared preallocated pool (apex_tpu.serving.kv_cache), so a
 # request's cache is a *page list*, not a slab.  The decode kernel
 # consumes that layout directly: the page table rides in as a
-# scalar-prefetch operand and DRIVES THE BLOCK INDEX MAP — grid step
-# (b, p) DMAs pool page ``page_table[b, p]`` into VMEM, so the
-# gather that the generic XLA baseline materialises in HBM never
-# happens.  The kernel's K/V operand is the engine's WHOLE pool,
-# ``[L, n_pages, page_size, h, d]``, every layer of it: the layer to
-# read is a third scalar-prefetch operand, block index
-# ``(layer[0], page_table[b, p], 0, 0, 0)`` (as an operand and not a
-# constant of the index map it leaves the model's L calls one Mosaic
-# kernel, not L of them to compile).  A Mosaic custom call cannot
-# take a view into a larger array, so handing it ``pool[layer]`` makes
-# XLA copy that layer's pages into a fresh buffer before every call
-# (48 copies of 157 MB a decode step at the 1.3B geometry: 23 ms, more
-# device time than anything but the kernel itself; PERF.md, PR 28);
-# addressed through the index map the bytes are read where they lie.
-# A block is a WHOLE page, every head of it: ``(None, None, page_size,
-# h, d)`` (layer and page squeezed) ends in the pool's own ``(h, d)``
-# extent, which is what Mosaic's block rule asks of the last two dims
-# (a unit block on the head axis is neither a multiple of 8 nor the
-# full extent and does not lower), and it is one contiguous DMA per
-# page.  The heads are then a static loop inside the kernel.  Per-request
-# raggedness is the same trick as the varlen block-skip index: the
-# k-loop (here the page grid dimension) is bounded by the request's
-# page count — pages past ``kv_len`` are predicated off with
-# ``pl.when`` (and, because table rows pad with page 0, their repeated
-# block index elides the dead DMAs too).  The online-softmax carry
-# lives in VMEM scratch across the page steps of one request (the TPU
-# grid is sequential, innermost-last), exactly like the fused
-# backward's persistent dq accumulator.
+# scalar-prefetch operand and DRIVES THE KERNEL'S OWN DMAs — the pools
+# stay in HBM and a page ``page_table[b, p]`` is copied into VMEM when
+# row b's walk comes to it, so the gather that the generic XLA baseline
+# materialises in HBM never happens.  The kernel's K/V operand is the
+# engine's WHOLE pool, ``[L, n_pages, page_size, h, d]``, every layer
+# of it: the layer to read is a third scalar-prefetch operand and the
+# first index of every copy's source (as an operand and not a constant
+# it leaves the model's L calls one Mosaic kernel, not L of them to
+# compile).  A Mosaic custom call cannot take a view into a larger
+# array, so handing it ``pool[layer]`` makes XLA copy that layer's
+# pages into a fresh buffer before every call (48 copies of 157 MB a
+# decode step at the 1.3B geometry: 23 ms; PERF.md, PR 28); addressed
+# by the copies the bytes are read where they lie.
+#
+# The grid is ``(b, tiles)``: a step is one row of the batch (one tile
+# of its query positions where a chunk is tiled), and a loop inside the
+# step walks that row's LIVE pages, from the first a query row of the
+# step can see to the last, ``P`` of them (a block) a turn; ``P``
+# follows from the shapes (_decode_pages_per_step).  A page is one
+# contiguous DMA, every head of it; the block after (or the next
+# step's first) is in flight while this one is scored.  Per-request
+# raggedness is the walk's bounds: pages past ``kv_len``, before a
+# window or past a tile's last row are neither fetched nor scored, and
+# a row with nothing to see costs a grid step and no loop turn (until
+# PR 30 the grid was ``(b, p_max)``, a 64-token page a step: 896 steps
+# a call at the 1.3B geometry whatever the batch held, and sixteen
+# strided head slices with M = 1 contractions a page; PERF.md).  The
+# online-softmax carry lives in VMEM scratch and is read and written
+# once a block.
 # ---------------------------------------------------------------------------
 
 
@@ -2289,128 +2290,326 @@ def _decode_q_tile(q_len, group):
     """Query positions a grid step of the decode kernel takes.  All of
     them while a K/V head's ``group * q_len`` rows are at most 512 (plain
     decode, a verify window, the multi-head chunks of old): one step a
-    page, as ever.  Beyond that (a grouped-query chunk: 6 x 2,048 rows
-    a K/V head, whose blocks and accumulators would not fit in VMEM)
-    the largest halving of ``q_len`` that fits, kept a multiple of 8."""
+    row of the batch.  Beyond that (a grouped-query chunk: 6 x 2,048
+    rows a K/V head, whose blocks and accumulators would not fit in
+    VMEM) the largest halving of ``q_len`` that fits, kept a multiple
+    of 8."""
     tq = q_len
     while group * tq > 512 and tq % 16 == 0:
         tq //= 2
     return tq
 
 
-def _make_decode_kernel(*, scale, page_size, q_len, h, d, quantized=False,
-                        group=1, tq=None, window=None, has_start=False):
-    """Decode forward: grid (b, p_max); scalar-prefetch operands
-    (page_table [b, p_max], kv_len [b], layer [1], the last read by
-    the index maps alone).  Queries are the LAST ``q_len``
-    positions of the request's ``kv_len``-token cache (their own k/v
-    already appended), so row i's causal limit is column
+# VMEM for the K and V pages in flight: two slots (the block being
+# scored and the one the DMAs are filling) of K and of V.
+_DECODE_BLOCK_BYTES = 8 * 2 ** 20
+# ... and the most columns a block's score tile may have.  The per-head
+# body keeps [rows, columns] float32 scores and their exponentials
+# live, hundreds of rows of them; the all-heads body's columns are
+# (token, head) pairs.
+_DECODE_BLOCK_COLS = 1024
+_DECODE_ALL_HEADS_COLS = 4096
+# Few query rows a K/V head: at most this many and the kernel scores
+# all heads of a page at once (see _make_decode_kernel).
+_DECODE_FEW_ROWS = 8
+# Scoped VMEM the kernel may use (the chip has 128 MiB; Mosaic's default
+# of 16 would not hold the two slots beside a chunk's accumulators).
+_DECODE_VMEM_LIMIT = 64 * 2 ** 20
+
+
+def _decode_body(rows_n, h, quantized):
+    """How the kernel scores a block, from what it can see of the call.
+
+    ``"all_heads"``: few query rows a K/V head (plain decode, a verify
+    window): every head of the block in one contraction, the block
+    read as ``[pages * page_size * h, d]`` rows, which is the block as
+    it lies only where ``h`` fills whole sublane tiles.
+    ``"per_head"``: many rows (a chunk's tile): head by head over the
+    whole block, ``[rows, d] x [d, pages * page_size]`` on the MXU.
+    ``"per_page"``: the rest (a quantized pool, whose codes are scaled
+    per (slot, head) first; few rows over 4 heads, a tp shard): head by
+    head, a page at a time, as the kernel always did."""
+    if rows_n > _DECODE_FEW_ROWS:
+        return "per_page" if quantized else "per_head"
+    return "all_heads" if h % 8 == 0 and not quantized else "per_page"
+
+
+def _decode_pages_per_step(page_size, h, d, itemsize, p_max, body):
+    """``P``, the pages of one row that a turn of the decode kernel's
+    page loop brings and scores together: as many as the VMEM set aside
+    for them holds twice over (K and V, two slots), no more columns
+    than the score tile may have, no more than a row's table has."""
+    page_bytes = page_size * h * d * itemsize
+    pages = _DECODE_BLOCK_BYTES // (4 * page_bytes)
+    if body == "all_heads":
+        pages = min(pages, _DECODE_ALL_HEADS_COLS // (page_size * h))
+    else:
+        pages = min(pages, _DECODE_BLOCK_COLS // page_size)
+    return max(1, min(pages, p_max))
+
+
+def _make_decode_kernel(*, scale, page_size, q_len, h, d, pages, p_max,
+                        quantized=False, group=1, tq=None, window=None,
+                        has_start=False, body="per_page"):
+    """Decode forward: grid (b, tiles); scalar-prefetch operands
+    (page_table [b, p_max], kv_len [b], layer [1]).  Queries are the
+    LAST ``q_len`` positions of the request's ``kv_len``-token cache
+    (their own k/v already appended), so row i's causal limit is column
     ``kv_len - q_len + i``.
 
-    The K/V blocks arrive with layer and page squeezed away: one page,
-    ``[page_size, h, d]``.  ``quantized`` adds two per-(page, slot, head)
-    fp32 scale operands (blocks ``[page_size, h]``, by the same page
-    index) and dequantizes K/V *in-register* right
-    after the page DMA — the narrow pool bytes are what crosses HBM,
-    the fp32 view never exists outside VMEM (r17).
+    The pools stay in HBM (``pl.ANY``): a step walks ITS OWN live pages,
+    from the first a query row of it can see to the last, ``pages`` of
+    them (a block) a turn of a loop inside the kernel.  Each page of
+    the block is one DMA, found through the page table, into one of two
+    VMEM slots ``[pages, page_size, h, d]``; the block after (or the
+    first block of the next grid step, another row of the batch) is in
+    flight while this one is scored.  A page past the row's last, or
+    before its window, inside a live block is not fetched and its
+    columns are masked; a row with nothing to see costs a grid step and
+    no loop turn.  ``quantized`` adds the two per-(page, slot, head)
+    fp32 scale planes of ONE layer, fetched beside the pages, and
+    dequantizes K/V in VMEM: the narrow bytes are what crosses HBM.
+
+    Three bodies score a block, chosen by :func:`_decode_body`.  Few
+    rows: every head of the block at once (see ``all_heads``).  Many
+    (a chunk's tile): head by head, ``[rows, d] x [d, columns]`` on the
+    MXU.  Either way the softmax state (``m``, ``l``, ``acc``) is read
+    and written once a block.
 
     Grouped-query heads (``group`` > 1): ``h`` counts K/V heads and the
     q block's rows are ``(position, head of the group)``, position
-    major, so row ``r`` is query position ``r // group``: the group's
-    heads share one dot against the page.  ``tq`` < ``q_len`` adds a
-    grid dimension over tiles of ``tq`` query positions, (b, tiles,
-    p_max).  ``window``: row i also loses the columns at or before
-    ``kv_len - q_len + i - window``.  ``has_start``: a fourth prefetch
-    operand ``start [b]``, the absolute position of the table's first
-    column (a window pool's table holds only the pages still in the
-    window).  A grid step whose page lies wholly outside what its rows
-    can see does nothing.  With ``group`` 1, one tile, no window and no
-    start this is the kernel it always was."""
+    major, so row ``r`` is query position ``r // group``.  ``tq`` <
+    ``q_len`` makes the second grid dimension run over tiles of ``tq``
+    query positions.  ``window``: row i also loses the columns at or
+    before ``kv_len - q_len + i - window``.  ``has_start``: a fourth
+    prefetch operand ``start [b]``, the absolute position of the
+    table's first column (a window pool's table holds only the pages
+    still in the window)."""
     tq = q_len if tq is None else tq
-    tiled = tq != q_len
     rows_n = group * tq
+    P = pages
+    chunk_pages = P if body == "per_head" else 1
 
     def kernel(pt_ref, kl_ref, layer_ref, *rest):
         if has_start:
             st_ref, *rest = rest
-        q_ref, k_ref, v_ref, *rest = rest
+        q_ref, k_hbm, v_hbm, *rest = rest
         if quantized:
-            ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-        else:
-            o_ref, m_ref, l_ref, acc_ref = rest
-        b_idx = pl.program_id(0)
-        p = pl.program_id(2 if tiled else 1)
-        n_p = pl.num_programs(2 if tiled else 1)
-        kv = kl_ref[b_idx]
-        # the table's column c is absolute position col0 + c
-        col0 = st_ref[b_idx] if has_start else 0
-        pages_used = (kv - col0 + page_size - 1) // page_size
-        # the first query position of this step's rows
-        row0 = kv - q_len + (pl.program_id(1) * tq if tiled else 0)
+            ks_hbm, vs_hbm, *rest = rest
+        o_ref, k_buf, v_buf, *rest = rest
+        if quantized:
+            ks_buf, vs_buf, *rest = rest
+        sem, flight_ref, m_ref, l_ref, acc_ref = rest
+        b_idx, t_idx = pl.program_id(0), pl.program_id(1)
+        n_b, n_t = pl.num_programs(0), pl.num_programs(1)
+        layer = layer_ref[0]
 
-        @pl.when(p == 0)
-        def _():
-            m_ref[...] = jnp.full((h, rows_n, 1), _NEG_INF, jnp.float32)
-            l_ref[...] = jnp.zeros((h, rows_n, 1), jnp.float32)
-            acc_ref[...] = jnp.zeros((h, rows_n, d), jnp.float32)
+        def first_col(bi):
+            """The table's column c is absolute position first_col + c."""
+            return st_ref[bi] if has_start else 0
 
-        live = p < pages_used
-        if tiled:
-            # pages past the tile's last row score nothing
-            live &= col0 + p * page_size <= row0 + tq - 1
-        if window is not None:
-            # pages wholly before the first row's window score nothing
-            live &= col0 + (p + 1) * page_size - 1 > row0 - window
+        def first_row(bi, ti):
+            """The first query position of step (bi, ti)'s rows."""
+            return kl_ref[bi] - q_len + ti * tq
 
-        @pl.when(live)
-        def _():
-            for hi in range(h):
-                q = q_ref[0, hi]          # [rows_n, d]
-                k = k_ref[:, hi, :]       # [page_size, d]
-                v = v_ref[:, hi, :]
+        def span(bi, ti):
+            """Step (bi, ti)'s first live page, its last, and how many
+            blocks they make (0: nothing to see)."""
+            col0, row0 = first_col(bi), first_row(bi, ti)
+            # the last row sees up to its own column (which is under
+            # kv_len, so garbage past the ragged end is never fetched)
+            last = jax.lax.min(row0 + tq - 1 - col0,
+                               p_max * page_size - 1)
+            lo = 0
+            if window is not None:
+                # pages wholly before the first row's window score nothing
+                lo = jax.lax.div(
+                    jax.lax.max(row0 - window + 1 - col0, 0), page_size)
+            hi = jax.lax.div(jax.lax.max(last, 0), page_size)
+            n = jnp.where(last >= 0, jax.lax.div(hi - lo, P) + 1, 0)
+            return lo, hi, n
+
+        def live_pages(lo, hi, i):
+            """Block ``i``'s first page and how many of its pages are
+            live (the last block of a walk may end early)."""
+            first = lo + i * P
+            return first, jax.lax.min(P, hi - first + 1)
+
+        def block_dma(bi, lo, hi, i, slot, wait=False):
+            """Start (or wait for) the copies of block ``i``'s live
+            pages into ``slot``."""
+            first, live = live_pages(lo, hi, i)
+
+            def page_dma(j, _):
+                # a wait needs the copy's shape and semaphore only
+                page = 0 if wait else pt_ref[bi, first + j]
+                copies = [(k_hbm.at[layer, page], k_buf.at[slot, j], 0),
+                          (v_hbm.at[layer, page], v_buf.at[slot, j], 1)]
                 if quantized:
-                    q = q.astype(jnp.float32)
-                    k = k.astype(jnp.float32) * ks_ref[:, hi][:, None]
-                    v = v.astype(jnp.float32) * vs_ref[:, hi][:, None]
-                s = jax.lax.dot_general(
-                    q, k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
-                rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-                if group > 1:
-                    rows = rows // group
-                cols = p * page_size + jax.lax.broadcasted_iota(
-                    jnp.int32, s.shape, 1)
-                if has_start:
-                    cols = cols + col0
-                # one mask does both jobs: the causal limit for the
-                # q_len tail AND the kv_len cutoff (row i's limit
-                # kv - q_len + i is < kv, so garbage past the ragged
-                # end never scores)
-                limit = row0 + rows
-                s = jnp.where(cols <= limit, s, _NEG_INF)
-                if window is not None:
-                    s = jnp.where(cols > limit - window, s, _NEG_INF)
-                m_prev = m_ref[hi]
-                m_new = jnp.maximum(m_prev,
-                                    jnp.max(s, axis=-1, keepdims=True))
-                pexp = _masked_exp(s, m_new)
-                # a page whose every column is masked for some row
-                # leaves that row's m at -inf: guard the rescale like
-                # _merge_parts
-                alpha = jnp.where(m_prev <= _NEG_INF / 2, 0.0,
-                                  jnp.exp(m_prev - m_new))
-                l_ref[hi] = alpha * l_ref[hi] + jnp.sum(
-                    pexp, axis=-1, keepdims=True)
-                acc_ref[hi] = acc_ref[hi] * alpha + jax.lax.dot_general(
-                    pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                m_ref[hi] = m_new
+                    copies += [(ks_hbm.at[page], ks_buf.at[slot, j], 2),
+                               (vs_hbm.at[page], vs_buf.at[slot, j], 3)]
+                for src, dst, s in copies:
+                    copy = pltpu.make_async_copy(src, dst, sem.at[slot, s])
+                    copy.wait() if wait else copy.start()
+                return 0
 
-        @pl.when(p == n_p - 1)
+            jax.lax.fori_loop(0, live, page_dma, 0)
+
+        lo, hi, n_blocks = span(b_idx, t_idx)
+        row0, col0 = first_row(b_idx, t_idx), first_col(b_idx)
+
+        @pl.when((b_idx == 0) & (t_idx == 0))
         def _():
-            l = l_ref[...]
-            l_safe = jnp.where(l == 0, 1.0, l)
-            o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+            # flight_ref: the slot the next block lands in, and whether
+            # the step before has already sent for this step's first
+            flight_ref[0] = 0
+            flight_ref[1] = 0
+            if body != "per_page":
+                # a page of a live block that is never fetched is masked
+                # (its scores may be anything, its probabilities are 0),
+                # but 0 x NaN is NaN: the values the slots hold have to
+                # be finite from the start
+                def zero(j, _):
+                    for slot in range(2):
+                        v_buf[slot, j] = jnp.zeros(v_buf.shape[2:],
+                                                   v_buf.dtype)
+                    return 0
+
+                jax.lax.fori_loop(0, P, zero, 0)
+
+        m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+        slot0 = flight_ref[0]
+
+        @pl.when((n_blocks > 0) & (flight_ref[1] == 0))
+        def _():
+            block_dma(b_idx, lo, hi, 0, slot0)
+
+        def attend(hh, q, k, v, seen):
+            """One online-softmax update of state row ``hh``: q [rows,
+            d] against k/v [columns, d], ``seen`` [rows, columns]."""
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(seen, s, _NEG_INF)
+            m_prev = m_ref[hh]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(s, axis=-1, keepdims=True))
+            pexp = _masked_exp(s, m_new)
+            # a block whose every column is masked for some row leaves
+            # that row's m at -inf: guard the rescale like _merge_parts
+            alpha = jnp.where(m_prev <= _NEG_INF / 2, 0.0,
+                              jnp.exp(m_prev - m_new))
+            l_ref[hh] = alpha * l_ref[hh] + jnp.sum(
+                pexp, axis=-1, keepdims=True)
+            acc_ref[hh] = acc_ref[hh] * alpha + jax.lax.dot_general(
+                pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[hh] = m_new
+
+        def visible(pos, cols):
+            """One mask does both jobs: the causal limit for the q_len
+            tail AND the kv_len cutoff (query position i's limit
+            kv - q_len + i is < kv, so garbage past the ragged end, in
+            a page that was fetched or one that was not, never
+            scores)."""
+            limit = row0 + pos
+            seen = cols <= limit
+            if window is not None:
+                seen &= cols > limit - window
+            return seen
+
+        def all_heads(slot, col_base):
+            """The block as it lies, ``[pages * page_size * h, d]``:
+            a column is a (token, head) pair, a row a (head, query
+            row) pair, and a row sees the columns of its own head.
+            One contraction scores every head against the whole block
+            and one more sums the values: the MXU multiplies h times
+            what it must, and nothing is sliced, looped or relaid."""
+            n_cols = P * page_size * h
+            row = jax.lax.broadcasted_iota(jnp.int32, (h * rows_n, 1), 0)
+            col = jax.lax.broadcasted_iota(jnp.int32, (1, n_cols), 1)
+            # lax.div and lax.rem: nothing here is negative, and jnp's
+            # floor fix-up costs six sign() helpers a call to lower
+            div, rem = jax.lax.div, jax.lax.rem
+            seen = (div(row, rows_n) == rem(col, h)) & visible(
+                div(rem(row, rows_n), group), col_base + div(col, h))
+            attend(0, q_ref[0, 0], k_buf[slot].reshape(n_cols, d),
+                   v_buf[slot].reshape(n_cols, d), seen)
+
+        def per_head(slot, j0, col_base):
+            """Pages ``[j0, j0 + chunk_pages)`` of the slot, a head at
+            a time: ``[rows_n, d] x [d, columns]`` is an MXU shape
+            where the rows are a chunk's."""
+            n_cols = chunk_pages * page_size
+            pos = jax.lax.div(jax.lax.broadcasted_iota(
+                jnp.int32, (rows_n, n_cols), 0), group)
+            cols = col_base + jax.lax.broadcasted_iota(
+                jnp.int32, (rows_n, n_cols), 1)
+            seen = visible(pos, cols)
+            for hh in range(h):
+                q = q_ref[0, hh]          # [rows_n, d]
+                if body == "per_head":
+                    k = k_buf[slot, :, :, hh, :].reshape(n_cols, d)
+                    v = v_buf[slot, :, :, hh, :].reshape(n_cols, d)
+                else:
+                    k = k_buf[slot, j0, :, hh, :]
+                    v = v_buf[slot, j0, :, hh, :]
+                if quantized:   # always page by page (_decode_body)
+                    q = q.astype(jnp.float32)
+                    k = (k.astype(jnp.float32)
+                         * ks_buf[slot, j0, :, hh][:, None])
+                    v = (v.astype(jnp.float32)
+                         * vs_buf[slot, j0, :, hh][:, None])
+                attend(hh, q, k, v, seen)
+
+        def turn(i, _):
+            slot = jax.lax.rem(slot0 + i, 2)
+
+            @pl.when(i + 1 < n_blocks)
+            def _():
+                block_dma(b_idx, lo, hi, i + 1, 1 - slot)
+
+            @pl.when(i + 1 == n_blocks)
+            def _():
+                # the last block of this step: send for the first of
+                # the next step's, another tile or another row
+                wrap = t_idx + 1 == n_t
+                nb_ = jnp.where(wrap, b_idx + 1, b_idx)
+                nt_ = jnp.where(wrap, 0, t_idx + 1)
+                there = nb_ < n_b
+                nb_ = jax.lax.min(nb_, n_b - 1)
+                nlo, nhi, nn = span(nb_, nt_)
+                sent = there & (nn > 0)
+
+                @pl.when(sent)
+                def _():
+                    block_dma(nb_, nlo, nhi, 0, 1 - slot)
+
+                flight_ref[0] = 1 - slot
+                flight_ref[1] = sent.astype(jnp.int32)
+
+            block_dma(b_idx, lo, hi, i, slot, wait=True)
+            first_pg, live = live_pages(lo, hi, i)
+            if body == "all_heads":
+                all_heads(slot, col0 + first_pg * page_size)
+            elif body == "per_head":
+                per_head(slot, 0, col0 + first_pg * page_size)
+            else:
+                def page(j, _):
+                    per_head(slot, j, col0 + (first_pg + j) * page_size)
+                    return 0
+
+                jax.lax.fori_loop(0, live, page, 0)
+            return 0
+
+        jax.lax.fori_loop(0, n_blocks, turn, 0)
+
+        l = l_ref[...]
+        l_safe = jnp.where(l == 0, 1.0, l)
+        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
     return kernel
 
@@ -2448,25 +2647,26 @@ def _flash_decode_pallas(q, k_pages, v_pages, page_table, kv_len, scale,
     quantized = k_scale is not None
     has_start = kv_start is not None
     tq = _decode_q_tile(q_len, group)
-    tiled = tq != q_len
+    body = _decode_body(group * q_len, h, quantized)
+    pages = _decode_pages_per_step(page_size, h, d, k_pages.dtype.itemsize,
+                                   p_max, body)
     if group > 1:
         q = _group_rows(q, group)
+    # the softmax state's rows: a head's, or every head's in one where
+    # the kernel scores all heads at once (a free view of q)
+    state = (h, group * tq)
+    if body == "all_heads":
+        state = (1, h * group * tq)
+    grouped_shape, q = q.shape, q.reshape((b,) + state[:1] + (-1, d))
     # index maps take the grid indices, then the prefetch operands
-    if tiled:
-        q_map = lambda bi, t, p, pt, *_: (bi, 0, t, 0)
-        page_map = lambda bi, t, p, pt, kl, ly, *_: (
-            ly[0], pt[bi, p], 0, 0, 0)
-        scale_map = lambda bi, t, p, pt, *_: (pt[bi, p], 0, 0)
-        grid = (b, q_len // tq, p_max)
-    else:
-        q_map = lambda bi, p, pt, *_: (bi, 0, 0, 0)
-        page_map = lambda bi, p, pt, kl, ly, *_: (ly[0], pt[bi, p], 0, 0, 0)
-        scale_map = lambda bi, p, pt, *_: (pt[bi, p], 0, 0)
-        grid = (b, p_max)
-    q_spec = pl.BlockSpec((1, h, group * tq, d), q_map)
-    page_spec = pl.BlockSpec((None, None, page_size, h, d), page_map)
-    in_specs = [q_spec, page_spec, page_spec]
+    q_spec = pl.BlockSpec((1,) + state + (d,),
+                          lambda bi, t, *_: (bi, 0, t, 0))
+    pool_spec = pl.BlockSpec(memory_space=pltpu.HBM)
+    in_specs = [q_spec, pool_spec, pool_spec]
     operands = [q, k_pages, v_pages]
+    slots = (2, pages, page_size, h)
+    scratch = [pltpu.VMEM(slots + (d,), k_pages.dtype),
+               pltpu.VMEM(slots + (d,), v_pages.dtype)]
     if quantized:
         # The scale planes, unlike the pages, go in one layer at a time.
         # XLA keeps an fp32 [L, n_pages, page_size, h] plane in a tiled
@@ -2474,37 +2674,48 @@ def _flash_decode_pallas(q, k_pages, v_pages, page_table, kv_len, scale,
         # row-major it pads eightfold) and Mosaic asks for the row-major
         # one, so the operand is laid out anew before every call
         # whatever is passed; of one layer that is 1/L of the work and
-        # of the temporary (PERF.md, PR 28).
-        scale_spec = pl.BlockSpec((None, page_size, h), scale_map)
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scale[layer].astype(jnp.float32),
-                     v_scale[layer].astype(jnp.float32)]
+        # of the temporary (PERF.md, PR 28).  The padding is spelled
+        # out here: a page's [page_size, h] plane cut out of HBM by a
+        # DMA has to be whole lane tiles.
+        lanes = -(-h // LANE) * LANE
+        plane = lambda s: jnp.pad(s[layer].astype(jnp.float32),
+                                  ((0, 0), (0, 0), (0, lanes - h)))
+        in_specs += [pool_spec, pool_spec]
+        operands += [plane(k_scale), plane(v_scale)]
+        scratch += [pltpu.VMEM(slots[:3] + (lanes,), jnp.float32),
+                    pltpu.VMEM(slots[:3] + (lanes,), jnp.float32)]
     prefetch = [page_table.astype(jnp.int32), kv_len.astype(jnp.int32),
                 jnp.full((1,), layer, jnp.int32)]
     if has_start:
         prefetch.append(kv_start.astype(jnp.int32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=grid,
+        grid=(b, q_len // tq),
         in_specs=in_specs,
         out_specs=q_spec,
-        scratch_shapes=[
-            pltpu.VMEM((h, group * tq, 1), jnp.float32),
-            pltpu.VMEM((h, group * tq, 1), jnp.float32),
-            pltpu.VMEM((h, group * tq, d), jnp.float32),
+        scratch_shapes=scratch + [
+            pltpu.SemaphoreType.DMA((2, 4 if quantized else 2)),
+            pltpu.SMEM((2,), jnp.int32),
+            pltpu.VMEM(state + (1,), jnp.float32),
+            pltpu.VMEM(state + (1,), jnp.float32),
+            pltpu.VMEM(state + (d,), jnp.float32),
         ],
     )
     o = pl.pallas_call(
         _make_decode_kernel(scale=scale, page_size=page_size,
-                            q_len=q_len, h=h, d=d, quantized=quantized,
+                            q_len=q_len, h=h, d=d, pages=pages,
+                            p_max=p_max, quantized=quantized,
                             group=group, tq=tq, window=window,
-                            has_start=has_start),
+                            has_start=has_start, body=body),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         name="flash_decode_window" if window is not None
         else "flash_decode",
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_DECODE_VMEM_LIMIT),
         interpret=use_interpret(),
-    )(*prefetch, *operands)
+    )(*prefetch, *operands).reshape(grouped_shape)
     return _ungroup_rows(o, group) if group > 1 else o
 
 

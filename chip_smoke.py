@@ -7,7 +7,7 @@ One process, one pass, no options.  It needs a TPU: where
 ``jax.default_backend()`` is anything else it exits 2 before doing any
 work, and it has no CPU mode.  With random weights made from a seed it
 
-* checks one forward+backward of packed-QKV attention and four
+* checks one forward+backward of packed-QKV attention and five
   ``flash_decode`` calls against the XLA routes at the flagship shapes;
 * trains the full-width GPT-1.3B flagship step (``bf16_fit`` ZeRO plan)
   for a few steps on a fixed batch: finite, falling loss;
@@ -146,33 +146,46 @@ def leg_kernels(*, batch, seq, heads, head_dim, block, pages, page_size,
     rng = np.random.RandomState(seed)
     table = jnp.asarray(rng.randint(
         1, pages, (max_batch, pages_per_request)), jnp.int32)
-    for name, q_len, quantized in (("decode", 1, False),
-                                   ("verify", 5, False),
-                                   ("chunk", 128, False),
-                                   ("decode_int8", 1, True)):
+    # the last: grouped-query heads (six to a K/V head, Trinity's 48 on
+    # 8 at the flagship's 16) under a window, over the compact table a
+    # window pool hands over: kv_len runs past what the table holds
+    window = (pages_per_request - 2) * page_size
+    for name, q_len, quantized, group in (("decode", 1, False, 1),
+                                          ("verify", 5, False, 1),
+                                          ("chunk", 128, False, 1),
+                                          ("decode_int8", 1, True, 1),
+                                          ("decode_gqa_window", 1, False, 6)):
+        kv_heads = heads if group == 1 else max(1, heads // 2)
         q = jax.random.normal(
-            keys[2], (max_batch, heads, q_len, head_dim), jnp.bfloat16)
-        pool_shape = (pages, page_size, heads, head_dim)
-        kv_len = jnp.asarray(rng.randint(
-            q_len, pages_per_request * page_size + 1, (max_batch,)),
-            jnp.int32)
+            keys[2], (max_batch, kv_heads * group, q_len, head_dim),
+            jnp.bfloat16)
+        pool_shape = (pages, page_size, kv_heads, head_dim)
+        kv_len = rng.randint(
+            q_len, (1 if group == 1 else 3) * pages_per_request * page_size
+            + 1, (max_batch,))
+        kw, win = {}, None
+        if group > 1:
+            win = window
+            kw = dict(kv_start=jnp.asarray(
+                np.maximum(0, kv_len - q_len - window + 1)
+                // page_size * page_size, jnp.int32))
+        kv_len = jnp.asarray(kv_len, jnp.int32)
         if quantized:
             k = jax.random.randint(keys[3], pool_shape, -127, 128, jnp.int8)
             v = jax.random.randint(keys[4], pool_shape, -127, 128, jnp.int8)
-            scales = dict(
+            kw.update(
                 k_scale=jax.random.uniform(keys[5], pool_shape[:3]) / 64,
                 v_scale=jax.random.uniform(keys[6], pool_shape[:3]) / 64)
         else:
             k = jax.random.normal(keys[3], pool_shape, jnp.bfloat16)
             v = jax.random.normal(keys[4], pool_shape, jnp.bfloat16)
-            scales = {}
         routes.setdefault("decode", flash_decode_route(q, k))
 
         def decode(**route):
             # a fresh jit each time: the route is chosen while tracing
             with routing_override(**route):
-                return jax.jit(lambda a, kw: flash_decode(*a, **kw))(
-                    (q, k, v, table, kv_len), scales)
+                return jax.jit(lambda a, kw: flash_decode(
+                    *a, window=win, **kw))((q, k, v, table, kv_len), kw)
 
         errors[name] = rel_l2(decode(), decode(decode="xla"))
 

@@ -46,7 +46,8 @@ def test_kernels_leg():
     # off the TPU both sides of each comparison are the XLA route
     assert out["routes"] == {"qkv": "generic", "decode": "xla"}
     assert set(out["rel_l2"]) == {"qkv_fwd", "qkv_bwd", "decode", "verify",
-                                  "chunk", "decode_int8"}
+                                  "chunk", "decode_int8",
+                                  "decode_gqa_window"}
     assert max(out["rel_l2"].values()) == 0.0
 
 

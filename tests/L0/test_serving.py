@@ -219,17 +219,21 @@ class TestFlashDecodeParity:
 # ---------------------------------------------------------------------------
 
 
-def _whole_pool_state(rng, q_len, quantized):
+def _whole_pool_state(rng, q_len, quantized, h=2):
     """Three rows over a random three-layer pool (every layer other
     bytes, so a read of the wrong layer cannot agree): one whose whole
     sequence is shorter than the query window, one idle row (an
     all-scratch page row, as the engine pads them) and one crossing
-    two page boundaries."""
-    page_size, p_max, h, d = 32, 3, 2, 8
+    two page boundaries.  ``quantized``: False, True (int8 codes) or
+    "fp8"."""
+    page_size, p_max, d = 32, 3, 8
     shape = (3, 7, page_size, h, d)     # [L, n_pages, page_size, h, d]
     if quantized:
-        k, v = (jnp.asarray(rng.randint(-127, 128, shape), jnp.int8)
-                for _ in range(2))
+        code = ((lambda: jnp.asarray(rng.randn(*shape), jnp.float8_e4m3fn))
+                if quantized == "fp8" else
+                (lambda: jnp.asarray(rng.randint(-127, 128, shape),
+                                     jnp.int8)))
+        k, v = code(), code()
         scales = {name: jnp.asarray(rng.uniform(0.01, 0.1, shape[:-1]),
                                     jnp.float32)
                   for name in ("k_scale", "v_scale")}
@@ -249,14 +253,29 @@ class TestFlashDecodeWholePool:
     @pytest.mark.parametrize("layer", [0, 1, 2],
                              ids=["first", "middle", "last"])
     @pytest.mark.parametrize("q_len", [1, 4])
-    @pytest.mark.parametrize("quantized", [False, True],
-                             ids=["bf16", "int8"])
+    @pytest.mark.parametrize("quantized", [False, True, "fp8"],
+                             ids=["bf16", "int8", "fp8"])
     @pytest.mark.parametrize("route", ["decode", "xla"])
     def test_layer_of_whole_pool_equals_its_slice(self, route, quantized,
                                                   q_len, layer):
-        rng = np.random.RandomState(17 * q_len + quantized)
+        self._whole_equals_slice(route, quantized, q_len, layer, heads=2)
+
+    @pytest.mark.parametrize("layer", [0, 2], ids=["first", "last"])
+    @pytest.mark.parametrize("q_len", [1, 4])
+    @pytest.mark.parametrize("quantized", [False, True, "fp8"],
+                             ids=["bf16", "int8", "fp8"])
+    @pytest.mark.parametrize("heads", [8, 16])
+    def test_layer_of_whole_pool_all_heads_at_once(self, heads, quantized,
+                                                   q_len, layer):
+        # eight and sixteen heads fill sublane tiles: the kernel scores
+        # them in one contraction (ISSUE 30), two go head by head; the
+        # layer rides in the DMAs' source either way
+        self._whole_equals_slice("decode", quantized, q_len, layer, heads)
+
+    def _whole_equals_slice(self, route, quantized, q_len, layer, heads):
+        rng = np.random.RandomState(17 * q_len + bool(quantized))
         q, k, v, table, kv_len, scales = _whole_pool_state(
-            rng, q_len, quantized)
+            rng, q_len, quantized, heads)
         with routing_override(decode=route):
             assert flash_decode_route(q, k) == route
             whole = flash_decode(q, k, v, table, kv_len, layer=layer,
@@ -269,6 +288,14 @@ class TestFlashDecodeWholePool:
         assert np.any(whole[2] != 0) and np.all(np.isfinite(whole))
         # row 0's window reaches one column short of its last query row
         assert np.all(whole[0, :, :1] == 0)
+        if route == "decode":
+            with routing_override(decode="xla"):
+                ref = np.asarray(flash_decode(q, k, v, table, kv_len,
+                                              layer=layer, **scales),
+                                 np.float32)
+            # the kernel rounds the probabilities to the pool's dtype
+            # before it sums the values: one ulp of the output's binade
+            assert np.max(np.abs(whole - ref)) <= 2 * _bf16_ulp_bound(ref)
 
     def test_layer_outside_the_pool_raises(self):
         q, k, v, table, kv_len, _ = _whole_pool_state(

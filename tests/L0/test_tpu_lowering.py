@@ -106,6 +106,44 @@ def test_paged_decode_lowers_on_the_whole_pool(pool_dtype, layer):
     assert call.count(f"tensor<{whole}x") == 2, call[-600:]
 
 
+# the shapes the cells run (benchmark/configs/gpt1p3b-serve.json,
+# trinity-large-serve-ep8-l5.json): batch, query heads, q_len, the pool,
+# p_max, window; a window pool's table comes with kv_start
+CELL_GEOMETRIES = {
+    "gpt_decode": (32, 16, 1, (24, 600, 64, 16, 128), 28, None),
+    "trinity_decode_full": (64, 48, 1, (1, 6144, 64, 8, 128), 264, None),
+    "trinity_decode_window": (64, 48, 1, (4, 3072, 64, 8, 128), 97, 4096),
+    "trinity_chunk_full": (1, 48, 2048, (1, 6144, 64, 8, 128), 264, None),
+    "trinity_chunk_window": (1, 48, 2048, (4, 3072, 64, 8, 128), 97, 4096),
+}
+
+
+@pytest.mark.parametrize("name", list(CELL_GEOMETRIES))
+def test_paged_decode_lowers_at_the_cells_geometries(name):
+    # all heads at once over blocks of 4 and 8 pages, and the chunk's
+    # tiles of 64 positions x 6 heads over blocks of 16
+    b, hq, q_len, pool_shape, p_max, window = CELL_GEOMETRIES[name]
+    q = SDS((b, hq, q_len, 128), BF16)
+    pool = SDS(pool_shape, BF16)
+    args = [q, pool, pool, SDS((b, p_max), jnp.int32), SDS((b,), jnp.int32)]
+    if window is not None:
+        args.append(SDS((b,), jnp.int32))
+    assert flash_decode_route(q, pool) == "decode"
+
+    def fn(q, k, v, pt, kl, *start):
+        return flash_decode(q, k, v, pt, kl, layer=pool_shape[0] - 1,
+                            window=window,
+                            kv_start=start[0] if start else None)
+
+    calls = [line for line in _tpu_text(fn, *args).splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1
+    kernel = "flash_decode_window" if window is not None else "flash_decode"
+    assert f'kernel_name = "{kernel}"' in calls[0]
+    whole = "x".join(map(str, pool_shape))
+    assert calls[0].count(f"tensor<{whole}x") == 2, calls[0][-600:]
+
+
 # -- generic flash attention: block-skip routes with more than one block ----
 
 def _seg_attention(q, k, v, seg, **kw):
