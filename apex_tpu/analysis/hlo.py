@@ -71,10 +71,9 @@ __all__ = [
 
 CONTRACTS_FORMAT = 1
 
-#: Provenance stamp written into every contracts file (the BENCH_r10/
-#: r12 ``geometry: "cpu-toy"`` discipline): contract byte/count
-#: numbers come from CPU-lowerable toy geometry and must not be read
-#: as flagship-scale truth.
+#: Provenance stamp written into every contracts file: contract
+#: byte/count numbers come from CPU-lowerable toy geometry and must
+#: not be read as flagship-scale truth.
 DEFAULT_GEOMETRY = "cpu-toy"
 
 

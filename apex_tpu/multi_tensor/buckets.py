@@ -16,8 +16,8 @@ reduce-scatter + all-gather **per bucket**, and XLA's latency-hiding
 scheduler interleaves the smaller collectives with backward/optimizer
 compute instead of queueing one buffer-sized transfer behind all of it.
 The ``python -m apex_tpu.analysis hlo`` contract pins the resulting
-per-bucket inventory; ``telemetry regress`` gates the measured
-exposed-collective wall.
+per-bucket inventory; the exposed-collective wall is not measured
+(no cell of the benchmark spans chips yet).
 
 Layout contract (the part that must NOT leak into checkpoints).  The
 canonical ZeRO ownership is the C-order contract of

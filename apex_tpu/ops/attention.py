@@ -650,8 +650,8 @@ def _seed_spec_arg(dropout_rate, dropout_seed):
 # (grid-scheduled online kernel reading the skip index), "stream" (the
 # generic grid kernel), "xla" (blockwise fallback).  Backward routes:
 # "tiles", "grid_skip", "grid", "xla".  ``flash_attention_route``
-# exposes the decision for tests and benches; ``routing_override``
-# forces one (the bench's fast-vs-generic baseline).
+# exposes the decision for tests; ``routing_override`` forces one
+# (the reference side of a parity test or of ``chip_smoke.py``'s A/B).
 # ---------------------------------------------------------------------------
 
 _ROUTE_OVERRIDE = {"fwd": None, "bwd": None, "decode": None}
@@ -660,8 +660,8 @@ _ROUTE_OVERRIDE = {"fwd": None, "bwd": None, "decode": None}
 @contextlib.contextmanager
 def routing_override(fwd=None, bwd=None, decode=None):
     """Force the fwd/bwd/decode kernel route inside the block
-    (trace-time effect; use around ``jax.jit`` tracing, e.g. the
-    bench's forced generic-grid baseline).  Values: fwd ∈ {"varlen",
+    (trace-time effect; use around ``jax.jit`` tracing, e.g. a
+    parity test's forced reference route).  Values: fwd ∈ {"varlen",
     "tiles", "stream_skip", "stream", "xla"}, bwd ∈ {"tiles",
     "grid_skip", "grid", "xla"}, decode ∈ {"decode", "xla"}.  A forced
     Pallas fwd/bwd route still requires the shape to be

@@ -389,7 +389,8 @@ def top_ops_report(fn: Callable, *args, steps: int = 3,
             for _ in range(steps):
                 out = fn(*args, **kwargs)
             jax.block_until_ready(out)
-            # a value fetch as well (same discipline as bench.py)
+            # a value fetch as well: the capture ends on a result
+            # the host has seen
             for leaf in jax.tree_util.tree_leaves(out):
                 if hasattr(leaf, "astype"):
                     float(abs(leaf).max())

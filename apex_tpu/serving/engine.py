@@ -72,8 +72,7 @@ per-request TTFT/TPOT and, when the request carried a deadline, a
 page-pool occupancy), plus the ISSUE 10 resilience set
 (``request_reject``, ``request_timeout``, ``serving_recovery``) — so
 ``python -m apex_tpu.telemetry summarize`` renders a serving line and
-the bench's stream is schema-validated by the existing ``validate``
-CLI.
+the stream is schema-validated by the existing ``validate`` CLI.
 
 **Architectures and page lifetimes (ISSUE 29).**  The model half is a
 :class:`~apex_tpu.serving.model.PagedDecoder` over whatever block the
@@ -200,7 +199,7 @@ def poisson_trace(seed: int, n_requests: int, *, rate: float,
                   rid_base: int = 0) -> List[Request]:
     """Seeded Poisson arrival trace: exponential inter-arrival gaps at
     ``rate`` requests/s, uniform prompt lengths and generation budgets.
-    Deterministic in ``seed`` — the serving bench's workload and the
+    Deterministic in ``seed`` — ``chip_smoke.py``'s traffic and the
     scheduler determinism test share this generator.
 
     ``deadline_s`` — optional (lo, hi) uniform completion-deadline

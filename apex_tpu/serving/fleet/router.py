@@ -134,7 +134,7 @@ class FleetRouter:
     :class:`~apex_tpu.serving.engine.SimClock` (which ticks per
     engine step, i.e. N ticks per round) would charge N replicas N×
     the time of one; a router-ticked clock charges one round one
-    tick regardless of fleet width (bench_fleet measures TTFT on
+    tick regardless of fleet width (the fleet tests read TTFT on
     exactly this)."""
 
     def __init__(self, replicas: Sequence[ReplicaProxy], *,
@@ -519,8 +519,8 @@ class FleetRouter:
 
 def rolling_restart(router: FleetRouter, *, serve_between: int = 0) -> None:
     """Drain, migrate, restart, readmit — one replica at a time, so
-    N-1 replicas keep serving and p99 TTFT holds (the bench_fleet
-    restart segment gates this).  Each replica's turn: fence with
+    N-1 replicas keep serving (tests/L0/test_serving_fleet.py pins
+    the streams bitwise across it).  Each replica's turn: fence with
     ``cause="rolling_restart"`` (out of rotation + ``replica_fence``
     event), migrate its live requests onto the still-healthy peers,
     rebuild its engine from the factory (fresh warmup — zero compiles

@@ -33,8 +33,7 @@ capture, per-op attribution) is :mod:`apex_tpu.profiling`:
   (:func:`~apex_tpu.telemetry.tracing.maybe_dump_flight_record`);
 - **CLI** — ``python -m apex_tpu.telemetry summarize run.jsonl``
   (p50/p95/p99 step time, goodput %, phase breakdown, event counts,
-  ``--diff`` A/B; ``regress A.json B.json --max-regress PCT`` — the
-  BENCH-record CI gate; ``trace STREAM.jsonl...`` — span-tree
+  ``--diff`` A/B; ``trace STREAM.jsonl...`` — span-tree
   reconstruction + TTFT decomposition).
 
 See ``docs/telemetry.md`` for the event schema and wiring examples.
@@ -60,9 +59,6 @@ from apex_tpu.telemetry.phases import (  # noqa: F401
     phase,
 )
 from apex_tpu.telemetry.recorder import FlightRecorder  # noqa: F401
-from apex_tpu.telemetry.regress import (  # noqa: F401
-    load_multichip_record,
-)
 from apex_tpu.telemetry.sampler import (  # noqa: F401
     JaxProfilerTracer,
     ProfileSampler,

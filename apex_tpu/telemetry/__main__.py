@@ -9,11 +9,6 @@ Subcommands:
   record(s) for tooling.
 - ``validate FILE.jsonl`` — schema-check every event (exit 1 on the
   first violation); works on run streams and postmortem files alike.
-- ``regress A.json B.json --max-regress PCT`` — BENCH-record CI gate
-  (ISSUE 9): compares two committed ``BENCH_r*.json`` key files with
-  per-key direction rules and exits 1 when any gated key regressed
-  more than PCT percent (``--keys`` restricts and makes the named keys
-  mandatory; ``--verbose`` prints every compared row).
 - ``trace STREAM.jsonl [STREAM2.jsonl ...]`` — reconstruct per-request
   span trees from any set of per-replica streams (ISSUE 19): renders
   each request's causal tree, marks the critical path, and prints the
@@ -51,25 +46,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                            help="schema-check every event in a file")
     p_val.add_argument("jsonl")
 
-    p_reg = sub.add_parser(
-        "regress", help="BENCH-record regression gate (exit 1 on a "
-                        "gated-key regression beyond --max-regress)")
-    p_reg.add_argument("a", help="baseline BENCH_r*.json (A)")
-    p_reg.add_argument("b", help="candidate BENCH_r*.json (B)")
-    p_reg.add_argument("--max-regress", type=float, default=5.0,
-                       metavar="PCT",
-                       help="tolerated regression percent on any gated "
-                            "key (default 5)")
-    p_reg.add_argument("--keys", default=None,
-                       help="comma-separated exact keys to gate "
-                            "(missing key = failure); default: every "
-                            "gated key present in both files")
-    p_reg.add_argument("--json", action="store_true",
-                       help="emit the comparison rows as JSON")
-    p_reg.add_argument("--verbose", action="store_true",
-                       help="print every compared row, not just "
-                            "failures")
-
     p_tr = sub.add_parser(
         "trace", help="reconstruct per-request span trees from one or "
                       "more per-replica streams (exit 1 on broken "
@@ -90,28 +66,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
         return run_trace_cli(args.jsonl, rid=args.rid,
                              as_json=args.json)
-
-    if args.cmd == "regress":
-        from apex_tpu.telemetry.regress import (
-            compare_bench, format_regress, load_bench_keys)
-
-        try:
-            ka, kb = load_bench_keys(args.a), load_bench_keys(args.b)
-        except (OSError, ValueError, json.JSONDecodeError) as e:
-            print(f"ERROR: {e}", file=sys.stderr)
-            return 2
-        keys = ([k.strip() for k in args.keys.split(",") if k.strip()]
-                if args.keys else None)
-        rows, failures = compare_bench(ka, kb, args.max_regress, keys=keys)
-        if args.json:
-            print(json.dumps({"max_regress_pct": args.max_regress,
-                              "rows": rows,
-                              "failures": [r["key"] for r in failures]},
-                             indent=1))
-        else:
-            print(format_regress(rows, failures, args.max_regress,
-                                 verbose=args.verbose))
-        return 1 if failures else 0
 
     if args.cmd == "validate":
         from apex_tpu.telemetry.schema import SchemaError, validate_jsonl

@@ -72,9 +72,9 @@ def req(*types, choices=()) -> FieldSpec:
 #: required fields always and optional fields whenever present.
 EVENT_FIELDS: Dict[str, Dict[str, FieldSpec]] = {
     # loop (re)entered: config snapshot, start step.  workload/config/
-    # fast come from the bench and example entrypoints (bench.py,
-    # pretrain_gpt.py) — the table covers EVERY producer in the repo,
-    # not just the apex_tpu package, or TL001 flags them
+    # fast come from the example entrypoints (pretrain_gpt.py) — the
+    # table covers EVERY producer in the repo, not just the apex_tpu
+    # package, or TL001 flags them
     "run_start": {
         "save_every": opt(int),
         "async_saves": opt(bool),

@@ -399,8 +399,8 @@ def format_trace(trace: Trace) -> str:
 
 def run_trace_cli(paths: Sequence[str], *, rid: Optional[int] = None,
                   as_json: bool = False, echo=print) -> int:
-    """``python -m apex_tpu.telemetry trace`` body.  Exit codes follow
-    the regress convention: 0 = complete trees and every decomposition
+    """``python -m apex_tpu.telemetry trace`` body.  Exit codes:
+    0 = complete trees and every decomposition
     sums to its measured TTFT; 1 = structural problems (orphans,
     dangling parents, kind drift) or a sum outside
     :data:`TTFT_SUM_TOLERANCE_MS`; 2 = an unreadable stream."""

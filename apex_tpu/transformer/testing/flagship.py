@@ -82,11 +82,10 @@ __all__ = [
 
 
 # The flagship shape (ISSUE 2): 16 heads at h=2048 give d=128 — full MXU
-# contraction-lane fill, the regime where the flash kernels measure
-# 0.67 of roof (BENCH_r05 flash_attention_s4096) vs 0.90-of-a-54.9-TF-
-# floor at d=64.  Block 256: the packed-QKV kernels' whole-sequence
-# working set at 3·128 lanes exceeds the VMEM budget at the 512 library
-# default but fits at 256 (ops.attention._qkv_packed_block shrinks
+# contraction-lane fill, which d=64 leaves half empty (PERF.md:
+# ``train_attn_roofline``).  Block 256: the packed-QKV kernels'
+# whole-sequence working set at 3·128 lanes exceeds the VMEM budget at
+# the 512 library default but fits at 256 (ops.attention._qkv_packed_block shrinks
 # automatically; the config pins it so the routing is explicit).
 GPT1P3B_KW = dict(
     num_layers=24,
@@ -249,10 +248,10 @@ def build_flagship_train_step(
     contiguous shard of the master flat buffer, so the opt_state leaves
     are ``[dp, pp, tp, shard]`` stacks with spec
     ``P("data", "pipeline", "tensor")``.  ``pp`` must be 1 for the
-    *train step* (pipeline schedules stay in ``bench_gpt_3d``'s
-    pipeline segment; the checkpoint / reshard machinery handles
-    pp > 1 states).  ``mesh_shape=None`` keeps the historical
-    single-axis layout byte-for-byte.
+    *train step* (the pipeline schedules are separate:
+    ``transformer.pipeline_parallel``; the checkpoint / reshard
+    machinery handles pp > 1 states).  ``mesh_shape=None`` keeps the
+    historical single-axis layout byte-for-byte.
 
     ``bucket_bytes`` (3-D path only, ISSUE 15) selects the gradient
     data path:

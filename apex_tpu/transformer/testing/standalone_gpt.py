@@ -446,8 +446,8 @@ class ParallelTransformer:
                 # and gelu from the recompute.  The qkv and proj GEMMs
                 # STILL recompute — the flash custom_vjp saves only
                 # (o, lse), and its backward consumes q/k/v, which must
-                # be rebuilt (bench.py's gpt_analytic_flops keeps their
-                # 4h² in the recompute term accordingly).  Costs
+                # be rebuilt (their 4h² stays in the recompute
+                # term of any hardware-FLOP count).  Costs
                 # +b·s·4h·2B per layer over attn_res (64 MB at the
                 # 350M bench shape); measured LOSING to attn_res at
                 # B=8/16 (r5 sweep)
@@ -459,8 +459,8 @@ class ParallelTransformer:
                 # recompute of ops DOWNSTREAM of the saved output — the
                 # flash custom_vjp backward still needs its (o, lse)
                 # residuals, so remat re-runs the kernel to rebuild them
-                # (only attn_res skips the kernel re-run; bench.py's
-                # hw-flops accounting sets remat_attn=True here).
+                # (only attn_res skips the kernel re-run; a
+                # hardware-FLOP count includes it here).
                 # Measured ~7% off the step at B=8 (r4 sweep)
                 policy = jax.checkpoint_policies.save_only_these_names(
                     "attn_out")

@@ -234,7 +234,7 @@ def leg_train(cfg, *, batch_per_chip, seq, steps, mesh_shape=None,
 
 
 def pool_geometry(*, page_size, max_batch, prompt_len, max_new, **_):
-    """(pages, pages per request) as ``bench_serving`` sizes the pool:
+    """(pages, pages per request):
     1.5x the worst footprint of ``max_batch`` requests, plus the
     scratch page."""
     per_request = -(-(prompt_len[1] + max_new[1]) // page_size)

@@ -501,14 +501,13 @@ def test_optional_fields_typed_when_present():
 
 
 def test_repo_lints_clean_against_committed_baseline():
-    # the gate covers every PRODUCT surface: the package, the bench
-    # driver, and the example entrypoints.  tests/ stay out of scope —
+    # the gate covers every PRODUCT surface: the package and the
+    # example entrypoints.  tests/ stay out of scope —
     # they deliberately contain the rules' negative fixtures (unknown
     # event types, undonated jits) as test data
     baseline = Baseline.load(
         os.path.join(REPO_ROOT, "analysis_baseline.json"))
     res = lint_paths([os.path.join(REPO_ROOT, "apex_tpu"),
-                      os.path.join(REPO_ROOT, "bench.py"),
                       os.path.join(REPO_ROOT, "examples")],
                      baseline=baseline)
     assert res.findings == [], "\n".join(f.format() for f in res.findings)

@@ -58,7 +58,6 @@ class TestFlashAttention:
             flash_attention(q, k, v, causal=True), _naive(q, k, v, True),
             rtol=1e-4, atol=1e-5)
 
-    @pytest.mark.slow  # heaviest interpret/parity tier (ISSUE 6 wall-clock)
     def test_packed_qkv_matches_naive(self):
         # the r5 transpose-free entry point: [b, s, nh*(q|k|v)] in the
         # Megatron interleaved projection layout -> context [b, s, h].
@@ -84,7 +83,6 @@ class TestFlashAttention:
         g2 = jax.grad(loss_ref)(qkv)
         np.testing.assert_allclose(g1, g2, rtol=1e-3, atol=1e-4)
 
-    @pytest.mark.slow  # interpret-mode packed-QKV kernels (ISSUE 2 CI satellite)
     def test_packed_qkv_kernels_interpret_mode(self):
         # CI coverage for the packed Pallas kernels themselves (the
         # public wrapper routes to the fallback off-TPU): drive the
@@ -150,7 +148,6 @@ class TestFlashAttention:
         # an unalignable shape yields None (generic path)
         assert pick(8, 1000, 16, 64, 512, True, 0.0, jnp.bfloat16) is None
 
-    @pytest.mark.slow  # interpret-mode packed-QKV kernels, like its sibling
     def test_packed_qkv_lse_residual_is_logical_size(self):
         # ADVICE r5: the attn_res remat policy used to save the raw
         # [b, n_hg, group, n_b, 8, block] lse slab — an 8x residual from
@@ -204,7 +201,6 @@ class TestFlashAttention:
         # 128-multiple block with several q-blocks — allowed
         assert ok(sd(512), sd(512), None, 128, 128)
 
-    @pytest.mark.slow  # heaviest interpret/parity tier (ISSUE 6 wall-clock)
     def test_causal_sq_longer_than_sk(self):
         # causal cross-attention with sq > sk: the leading q rows attend
         # to nothing (fully masked) — the unrolled-tiles kernels must
@@ -277,7 +273,6 @@ class TestFlashAttention:
         for a, b in zip(g1, g2):
             np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-4)
 
-    @pytest.mark.slow  # heaviest interpret/parity tier (ISSUE 6 wall-clock)
     def test_pallas_interpret_path_matches(self):
         # exercise the Pallas kernel in interpret mode explicitly
         from apex_tpu.ops.attention import _flash_fwd_pallas
@@ -301,7 +296,6 @@ class TestFlashAttention:
         # batch selectors of the two BlockSpec families must not cross
         (False, True, True),
     ])
-    @pytest.mark.slow  # interpret-mode Pallas backward cells (ISSUE 2 CI satellite)
     def test_pallas_bwd_interpret_matches(self, causal, with_mask, with_seg):
         """The Pallas dq/dkv kernels (interpret mode) against jax.grad of
         the naive reference — every mask/seg/causal combination."""
@@ -484,7 +478,6 @@ class TestVarlenFastPath:
         lq, _ = _segment_block_bounds(seg_q, seg_k, 16, 8)
         assert np.asarray(lq)[0, 0].tolist() == [0, 5]  # 40/8 = 5 blocks
 
-    @pytest.mark.slow  # interpret-mode Pallas varlen kernels (ISSUE 5)
     @pytest.mark.parametrize("route", ["varlen", "stream_skip"])
     def test_varlen_fwd_kernels_interpret_match(self, route):
         from apex_tpu.ops.attention import _flash_fwd_pallas
@@ -502,7 +495,6 @@ class TestVarlenFastPath:
                                    rtol=1e-4, atol=1e-5)
         assert lse.shape == (bh, s)
 
-    @pytest.mark.slow  # interpret-mode Pallas varlen kernels (ISSUE 5)
     def test_varlen_grid_skip_bwd_interpret_matches(self):
         from apex_tpu.ops.attention import (_flash_bwd_pallas,
                                             _flash_fwd_pallas)
@@ -524,7 +516,6 @@ class TestVarlenFastPath:
         np.testing.assert_allclose(dk, gk, rtol=1e-3, atol=1e-4)
         np.testing.assert_allclose(dv, gv, rtol=1e-3, atol=1e-4)
 
-    @pytest.mark.slow  # interpret-mode packed varlen kernels (ISSUE 5)
     @pytest.mark.parametrize("causal", [False, True])
     def test_packed_qkv_varlen_interpret_matches(self, causal):
         """In-kernel segment masking on the packed-QKV kernels (the
@@ -562,7 +553,6 @@ class TestVarlenFastPath:
         dref = jax.grad(lambda x: jnp.sum(ref(x) * dctx))(qkv)
         np.testing.assert_allclose(dqkv, dref, rtol=1e-3, atol=1e-4)
 
-    @pytest.mark.slow  # heaviest interpret/parity tier (ISSUE 6 wall-clock)
     def test_qkv_wrapper_segments_fallback_matches(self):
         """Public flash_attention_qkv(segment_ids=...) — off-TPU this
         takes the generic fallback with identical math; grads flow."""
@@ -587,7 +577,6 @@ class TestVarlenFastPath:
         gr = jax.grad(lambda x: jnp.sum(ref(x) ** 2))(qkv)
         np.testing.assert_allclose(g, gr, rtol=1e-3, atol=1e-4)
 
-    @pytest.mark.slow  # interpret-mode zero-trip edge (ISSUE 5)
     def test_varlen_fully_masked_block_emits_zeros(self):
         """A q-block whose segment has no matching keys anywhere gets a
         zero-trip skip loop: zeros out, -inf lse, finite (zero) grads —
@@ -696,7 +685,6 @@ class TestRingAttention:
         np.testing.assert_allclose(out, _naive(q, k, v, causal=True),
                                    rtol=1e-4, atol=1e-5)
 
-    @pytest.mark.slow  # heaviest 8-device ring bwd (ISSUE 6 wall-clock)
     def test_grads_flow_through_ring(self, mesh):
         q = jax.random.normal(jax.random.PRNGKey(0), (1, 32, 8))
         k = jax.random.normal(jax.random.PRNGKey(1), (1, 32, 8))
@@ -799,7 +787,6 @@ class TestMultiheadAttnModules:
         assert not np.allclose(o1, o2)
 
 
-@pytest.mark.slow  # heaviest interpret/parity tier (ISSUE 6 wall-clock)
 def test_trainable_mask_bias_gets_gradient():
     """mask_is_constant=False must produce a real (nonzero) bias gradient
     (ADVICE r2: the default path silently returns zeros for it)."""
@@ -867,7 +854,6 @@ class TestKernelDropout:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-4, atol=1e-5)
 
-    @pytest.mark.slow  # heaviest interpret/parity tier (ISSUE 6 wall-clock)
     def test_grads_match_dense_reference(self):
         from apex_tpu.ops.attention import (_dropout_keep_full,
                                             flash_attention)
@@ -905,7 +891,6 @@ class TestKernelDropout:
             flash_attention(q, k, v, dropout_rate=0.1)
 
 
-@pytest.mark.slow  # interpret-mode dropout kernels (ISSUE 2 CI satellite)
 def test_pallas_dropout_kernels_interpret_match_dense():
     """The Pallas fwd + dq/dkv kernels WITH in-kernel dropout (interpret
     mode) against the dense masked reference using the same hash mask —

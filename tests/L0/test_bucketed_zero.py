@@ -29,6 +29,7 @@ import numpy as np
 import pytest
 
 from apex_tpu import checkpoint as ckpt
+from apex_tpu.analysis.hlo import collective_inventory
 from apex_tpu.contrib.optimizers import (
     DistributedFusedLAMB,
 )
@@ -399,14 +400,24 @@ def test_e5m2_allgather_refuses_bucketed_step():
 
 
 def test_bucketed_step_records_its_plan():
-    """FlagshipSetup carries the compiled plan (bench_gpt_3d echoes it
-    into the record); the legacy control carries None."""
+    """FlagshipSetup carries the compiled plan, the compiled step has
+    one reduce-scatter and one all-gather a bucket and its loss falls;
+    the legacy control carries None."""
     cfg = gpt1p3b_config(bf16=False, **TOY_KW)
     fs = build_flagship_train_step(
         cfg, plan="fp32", lr=1e-3, devices=jax.devices()[:4],
-        donate=False, mesh_shape=(2, 2, 1), bucket_bytes=1 << 20)
+        donate=False, mesh_shape=(2, 2, 1), bucket_bytes=1 << 18)
     assert fs.bucket_plan is not None
     assert fs.bucket_plan.world == 4
+    nb = fs.bucket_plan.num_buckets
+    assert nb > 1, "the toy cap must actually bucket the buffer"
+    tokens, labels = _batch(cfg)
+    inv = collective_inventory(fs.step.lower(
+        fs.params, fs.opt_state, tokens, labels).compile().as_text())
+    assert inv["reduce-scatter"]["count"] == nb, inv
+    assert inv["all-gather"]["count"] == nb, inv
+    _, _, losses = _run(fs, tokens, labels)
+    assert np.all(np.isfinite(losses)) and losses[-1] < losses[0], losses
     fs_legacy = build_flagship_train_step(
         cfg, plan="fp32", lr=1e-3, devices=jax.devices()[:4],
         donate=False, mesh_shape=(2, 2, 1), bucket_bytes=None)

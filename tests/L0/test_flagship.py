@@ -1,7 +1,7 @@
 """GPT-1.3B flagship machinery tests (ISSUE 2 tentpole).
 
-The full 1.3B shape only runs on hardware (bench.py gpt1p3b_*); here the
-same construction — d=128 head geometry, ZeRO-sharded FusedAdam over the
+The full 1.3B shape only runs on hardware (benchmark/, chip_smoke.py); here
+the same construction — d=128 head geometry, ZeRO-sharded FusedAdam over the
 mesh "data" axis, fit-plan dtypes — runs at toy width/depth on the
 emulated 8-device mesh, with the acceptance parity check:
 ZeRO-sharded step vs unsharded FusedAdam, max|dw| ≤ 1e-3.

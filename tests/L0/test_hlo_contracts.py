@@ -269,8 +269,8 @@ def test_committed_contracts_pin_the_properties_that_matter():
 
 def test_contracts_geometry_stamp():
     """Satellite: the committed file self-declares cpu-toy provenance
-    (the BENCH_r10/r12 lesson — absolute bytes are gate fixtures, not
-    flagship-scale truth), and an unstamped file refuses to load."""
+    (absolute bytes are gate fixtures, not flagship-scale truth), and
+    an unstamped file refuses to load."""
     doc = json.load(open(CONTRACTS))
     assert doc["format"] == 1
     assert doc["geometry"] == "cpu-toy"

@@ -82,7 +82,6 @@ class TestGPTTensorParallel:
         parallel_state.destroy_model_parallel()
         np.testing.assert_allclose(out, ref, rtol=2e-4, atol=1e-5)
 
-    @pytest.mark.slow  # heaviest 8-device parity tier (ISSUE 6 wall-clock)
     def test_gpt_grads_flow(self):
         cfg = GPTConfig(num_layers=2, hidden_size=32, num_attention_heads=4,
                         vocab_size=VOCAB, max_position_embeddings=SEQ,
@@ -116,7 +115,6 @@ class TestGPTTensorParallel:
 
 
 class TestGPTPipeline:
-    @pytest.mark.slow  # heaviest 8-device parity tier (ISSUE 6 wall-clock)
     def test_pp4_loss_matches_single_stage(self):
         # the reference's headline assertion (run_megatron_gpt_pipeline.py:78):
         # pipeline-parallel GPT loss == single-stage loss
@@ -167,7 +165,6 @@ class TestGPTPipeline:
         parallel_state.destroy_model_parallel()
         np.testing.assert_allclose(out, ref, rtol=2e-4, atol=1e-5)
 
-    @pytest.mark.slow  # heaviest 8-device parity tier (ISSUE 6 wall-clock)
     def test_pp_training_decreases_loss(self):
         PP = 2
         N_MICRO = 4
@@ -284,7 +281,6 @@ class TestBert:
         parallel_state.destroy_model_parallel()
 
     # slow since PR 22: pays for test_tpu_lowering / test_chip_smoke in tier-1
-    @pytest.mark.slow
     def test_bert_forward_and_loss(self):
         cfg = BertConfig(num_layers=2, hidden_size=32, num_attention_heads=4,
                          vocab_size=VOCAB, max_position_embeddings=SEQ,
@@ -314,7 +310,6 @@ class TestBert:
         assert np.isfinite(float(loss))
         assert binary.shape == (B, 2)
 
-    @pytest.mark.slow  # heaviest 8-device parity tier (ISSUE 6 wall-clock)
     def test_bert_flash_matches_softmax_path(self):
         """BERT's key-padding mask through the flash path (segment ids
         with all-ones query ids — the FMHA varlen role, r5) must match
@@ -353,7 +348,6 @@ class TestBert:
                                    np.asarray(l_soft),
                                    rtol=2e-3, atol=2e-3)
 
-    @pytest.mark.slow  # heaviest 8-device parity tier (ISSUE 6 wall-clock)
     def test_bert_tp_matches_tp1(self):
         cfg1 = BertConfig(num_layers=1, hidden_size=32, num_attention_heads=4,
                           vocab_size=VOCAB, max_position_embeddings=SEQ,
@@ -409,7 +403,6 @@ class TestFlashAndRemat:
         parallel_state.destroy_model_parallel()
         return float(out)
 
-    @pytest.mark.slow  # heaviest 8-device parity tier (ISSUE 6 wall-clock)
     def test_flash_and_remat_match_reference_path(self):
         kw = dict(num_layers=2, hidden_size=32, num_attention_heads=4,
                   vocab_size=VOCAB, max_position_embeddings=SEQ, tp_size=1)
@@ -424,7 +417,6 @@ class TestFlashAndRemat:
         np.testing.assert_allclose(flash, base, rtol=2e-5, atol=2e-6)
         np.testing.assert_allclose(remat, base, rtol=2e-5, atol=2e-6)
 
-    @pytest.mark.slow  # heaviest interpret/parity tier (ISSUE 6 wall-clock)
     def test_causal_model_keeps_causality_with_padding_mask(self):
         """A causal model handed an ADDITIONAL [b,1,1,s] padding mask
         must stay causal on the flash path (r5 review finding: the
@@ -680,7 +672,6 @@ class TestMoEGPT:
                          tp_size=tp, num_experts=4,
                          moe_capacity_factor=8.0)
 
-    @pytest.mark.slow  # heaviest interpret/parity tier (ISSUE 6 wall-clock)
     def test_moe_gpt_trains(self):
         from apex_tpu import optimizers
 
@@ -727,7 +718,6 @@ class TestMoEGPT:
         parallel_state.destroy_model_parallel()
         assert np.isfinite(float(loss)) and float(loss) < first
 
-    @pytest.mark.slow  # 8-device MoE TP parity (ISSUE 2 CI satellite)
     def test_moe_gpt_tp2_matches_tp1(self):
         """Experts replicated across TP: tp=2 must equal tp=1 exactly
         (gate runs on the TP-replicated hidden, routing agrees)."""

@@ -91,8 +91,6 @@ class TestSwitchMLP:
                                    np.asarray(dense)[kept],
                                    rtol=1e-5, atol=1e-5)
 
-    # slow since PR 22: pays for test_tpu_lowering / test_chip_smoke in tier-1
-    @pytest.mark.slow
     def test_gradients_flow(self):
         moe = SwitchMLP(_cfg())
         params = moe.init_master(jax.random.PRNGKey(0))
@@ -135,3 +133,52 @@ class TestSwitchMLP:
         _, ref_aux = moe.apply(master, h)
         np.testing.assert_allclose(float(auxes[0]), float(ref_aux),
                                    rtol=1e-5)
+
+    def test_tp_times_ep_composition_gradients_flow(self):
+        """A column/row-sharded dense block over a "tensor" axis feeding
+        the Switch layer over an "expert" axis in one shard_map, gradients
+        through both (no other test composes the two axes)."""
+        tp, ep = 2, 4
+        moe = SwitchMLP(MoEConfig(hidden_size=H, ffn_hidden_size=F,
+                                  num_experts=2 * ep, capacity_factor=8.0))
+        kk = jax.random.split(jax.random.PRNGKey(0), 4)
+        col_w = jax.random.normal(kk[0], (H, F)) * 0.1
+        row_w = jax.random.normal(kk[1], (F, H)) * 0.1
+        master = moe.init_master(kk[2])
+        h = jax.random.normal(kk[3], (32, H))
+
+        def rank(t, e):
+            return {"col_w": col_w.reshape(H, tp, F // tp)[:, t],
+                    "row_w": row_w.reshape(tp, F // tp, H)[t],
+                    "moe": moe.shard_master(master, e, ep)}
+
+        def stack(xs):
+            return jax.tree_util.tree_map(lambda *ys: jnp.stack(ys), *xs)
+
+        stacked = stack([stack([rank(t, e) for e in range(ep)])
+                         for t in range(tp)])
+        mesh = Mesh(np.array(jax.devices()[:tp * ep]).reshape(tp, ep),
+                    ("tensor", "expert"))
+
+        def inner(p, h):
+            p = jax.tree_util.tree_map(lambda a: a[0, 0], p)
+
+            def loss(p):
+                y = jax.lax.psum(jax.nn.gelu(h @ p["col_w"]) @ p["row_w"],
+                                 "tensor")
+                out, aux = moe.apply(p["moe"], y, axis_name="expert")
+                return jax.lax.psum(jnp.sum(out ** 2),
+                                    ("tensor", "expert")) / tp + 0.01 * aux
+
+            l, g = jax.value_and_grad(loss)(p)
+            return l, jax.tree_util.tree_map(lambda a: a[None, None], g)
+
+        l, g = jax.jit(shard_map(
+            inner, mesh=mesh, in_specs=(P("tensor", "expert"), P()),
+            out_specs=(P(), P("tensor", "expert")),
+            check_rep=False))(stacked, h)
+        assert np.isfinite(float(l))
+        for leaf in (g["col_w"], g["row_w"],
+                     *jax.tree_util.tree_leaves(g["moe"]["experts"])):
+            gm = float(jnp.abs(leaf).max())
+            assert np.isfinite(gm) and gm > 0

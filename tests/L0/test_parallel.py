@@ -89,8 +89,6 @@ class TestAllReduceGrads:
 
 
 class TestSyncBatchNorm:
-    # slow since PR 22: pays for test_tpu_lowering / test_chip_smoke in tier-1
-    @pytest.mark.slow
     def test_matches_full_batch_bn(self, mesh):
         # reference tests/distributed/synced_batchnorm: SyncBN over N devices
         # must equal single-device BN over the full batch.

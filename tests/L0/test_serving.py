@@ -138,7 +138,6 @@ def _bf16_ulp_bound(ref):
 
 
 class TestFlashDecodeParity:
-    @pytest.mark.slow  # interpret-mode Pallas sweep (PR 6 wall-clock tier)
     @pytest.mark.parametrize("q_len", [1, 4])
     @pytest.mark.parametrize("page_size", [64, 128])
     def test_kernel_matches_xla_on_ragged_pages(self, q_len, page_size):
@@ -724,7 +723,6 @@ class TestServingEngine:
                                       max_batch=2, max_new=4, mppr=2)
         assert kern_out == xla_out
 
-    @pytest.mark.slow  # long Poisson trace end-to-end (PR 6 wall-clock)
     def test_poisson_trace_serve_deterministic(self, serving_params):
         def run():
             eng = ServingEngine(CFG, serving_params, num_pages=17,

@@ -29,7 +29,6 @@ from apex_tpu.serving.fleet import (FENCED, ChaosTransport, DisaggRouter,
                                     register_error)
 from apex_tpu.serving.fleet.transport import FAULTS
 from apex_tpu.serving.kv_cache import verify_page_payload
-from apex_tpu.telemetry.regress import key_direction
 from apex_tpu.telemetry.summarize import summarize_events
 
 pytestmark = [pytest.mark.serving, pytest.mark.fleet]
@@ -891,7 +890,7 @@ class TestCapacityRefusal:
 
 
 # ---------------------------------------------------------------------------
-# Telemetry: schema, summary, regression directions
+# Telemetry: schema, summary
 # ---------------------------------------------------------------------------
 
 
@@ -935,13 +934,3 @@ class TestShipTelemetry:
         quiet = summarize_events([{"type": "request_retire"}])
         assert quiet["serving_ship_success_rate"] is None
         assert quiet["serving_ship_fallback_rate"] is None
-
-    def test_ship_fallback_rate_direction_rule(self):
-        # the r18 gate family: fallbacks are degradation — DOWN is
-        # better (note _hit_rate$ is HIGHER; a fallback is a miss)
-        assert key_direction("fleet_ship_fallback_rate") == "lower"
-        assert key_direction("serving_ship_fallback_rate") == "lower"
-        # the companion retry rate is deliberately UNGATED: the right
-        # retry count depends on the injected fault rate
-        assert key_direction("fleet_ship_retry_rate") is None
-        assert key_direction("fleet_kv_ships") is None

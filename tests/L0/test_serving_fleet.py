@@ -463,7 +463,7 @@ class TestRollingRestart:
 
 class TestSpecChunkedFleet:
     def test_migration_bitwise_with_spec_and_chunked(self, serving_params):
-        """The tentpole cross-check at tier-1 scale (the MULTICHIP
+        """The tentpole cross-check at tier-1 scale (the dry-run's
         chaos_fleet leg runs the bigger version): spec+chunked
         replicas, kill one mid-decode, control is a PLAIN engine —
         valid because draft-verify and chunked prefill are

@@ -262,8 +262,6 @@ class TestBitwiseContract:
         assert _streams(tr) == control_tokens["short"]
         assert eng.cache.pages_used == 0
 
-    @pytest.mark.slow  # burst-arrival sweep (ISSUE 12 wall discipline;
-    # the mid-draft preemption pin above stays in tier-1)
     def test_preemption_of_mid_chunk_request_restarts_cleanly(
             self, serving_params):
         # a BURST of long arrivals over a pool too small to hold them:
@@ -284,8 +282,6 @@ class TestBitwiseContract:
         assert _streams(tr2) == control
         assert eng.cache.pages_used == 0
 
-    @pytest.mark.slow  # three full engine runs; the eos-truncation
-    # RULE is pinned fast by TestCommitTokens::test_eos_truncates
     def test_eos_mid_commit_matches_plain_greedy(self, serving_params):
         # pick a token the model emits mid-stream and rerun with it as
         # EOS on BOTH engines: the speculative commit must truncate at
@@ -304,8 +300,6 @@ class TestBitwiseContract:
         eos = free[0][4]
         assert run(SpecConfig(k=4), eos) == run(None, eos)
 
-    @pytest.mark.slow  # interpret-mode Pallas at q_len=k+1 (the PR 6
-    # wall tier; the q_len>1 kernel parity sweep also covers this math)
     def test_decode_route_ab_identical_tokens_with_spec(
             self, serving_params):
         # the verify launch at q_len = k+1 through the Pallas decode
@@ -569,14 +563,7 @@ class TestChunkedPrefill:
 
 
 class TestSnapshotRestore:
-    @pytest.mark.parametrize("cut", [
-        1, 3,
-        # the deeper cut points replay most of the trace each — slow
-        # tier (nightly), the early boundaries stay in tier-1
-        pytest.param(2, marks=pytest.mark.slow),
-        pytest.param(5, marks=pytest.mark.slow),
-        pytest.param(8, marks=pytest.mark.slow),
-    ])
+    @pytest.mark.parametrize("cut", [1, 2, 3, 5, 8])
     def test_round_trip_mid_chunk_and_mid_draft(
             self, serving_params, control_tokens, cut):
         """Snapshot a spec+chunked engine at boundary ``cut`` — with

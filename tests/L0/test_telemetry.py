@@ -640,31 +640,6 @@ def test_validate_cli_flags_bad_stream(tmp_path, capsys):
 # ------------------------------------------------------ overhead bound
 
 
-def test_bench_bert_telemetry_stream_validates(tmp_path, monkeypatch):
-    """The BERT-Large flagship bench (ISSUE 5) writes a
-    telemetry/bert_large.jsonl stream; it must pass the strict schema
-    validator (`python -m apex_tpu.telemetry validate`) and surface the
-    bert_large_goodput / bert_large_step_ms_p95 record keys — exercised
-    through the bench's own _BenchTelemetry wrapper, not a lookalike."""
-    monkeypatch.setenv("BENCH_TELEMETRY_DIR", str(tmp_path))
-    import bench
-
-    bt = bench._BenchTelemetry("bert_large")
-    assert bt._dead is None, bt._dead
-    bt.compile_pause(0.5)
-    bt.trial(4, 0.8, scalars={"loss": 3.25})
-    bt.trial(4, 0.7, scalars={"loss": 3.11})
-    keys = bt.finish()
-    path = os.path.join(str(tmp_path), "bert_large.jsonl")
-    # strict schema check — the exact code path of the validate CLI
-    assert tele.validate_jsonl(path) > 0
-    from apex_tpu.telemetry.__main__ import main as tele_cli
-    assert tele_cli(["validate", path]) == 0
-    assert keys["bert_large_goodput"] is not None
-    assert keys["bert_large_step_ms_p95"] is not None
-    assert keys["bert_large_telemetry_file"] == "bert_large.jsonl"
-
-
 @pytest.mark.chaos
 def test_telemetry_overhead_at_most_one_percent_of_step(tmp_path):
     """ISSUE 4 satellite: the per-step telemetry work (one step_done

@@ -34,8 +34,6 @@ from apex_tpu.serving.fleet import (FENCED, ChaosTransport, DisaggRouter,
                                     ReplicaProxy)
 from apex_tpu.telemetry.__main__ import main as tel_main
 from apex_tpu.telemetry.recorder import FlightRecorder
-from apex_tpu.telemetry.regress import (GATED_LOWER, compare_bench,
-                                        key_direction)
 from apex_tpu.telemetry.schema import load_jsonl, validate_events
 from apex_tpu.telemetry.summarize import (format_diff, format_summary,
                                           summarize_events)
@@ -48,9 +46,6 @@ from apex_tpu.telemetry.tracing import (SPAN_KINDS, TTFT_SUM_TOLERANCE_MS,
                                         validate_trace)
 
 pytestmark = [pytest.mark.serving, pytest.mark.tracing]
-
-REPO = os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__))))
 
 CFG = ServingModelConfig(vocab_size=64, hidden_size=32, num_heads=4,
                          num_layers=2, max_position=96)
@@ -367,8 +362,7 @@ def _storm_fleet(params, *, deadline_s=None, tmp_path=None):
     """1 prefill + 1 decode replica; the first two kv_page messages
     drop in flight, so the single shipment retries twice (backoff 2
     then 4 rounds) before landing — a deterministic ship storm.  The
-    router ticks the shared clock once per ROUND (the bench_fleet
-    idiom): backoff rounds cost wall time even while every engine
+    router ticks the shared clock once per ROUND: backoff rounds cost wall time even while every engine
     idles, which is exactly the wall the ship decomposition must
     surface."""
     sinks = [tel.MemorySink()]
@@ -646,86 +640,6 @@ class TestTraceCli:
         with open(path, "a") as f:
             f.write('{"type": "span", "rid"')   # the crash mid-line
         assert run_trace_cli([path], echo=lambda *_: None) == 0
-
-
-# ---------------------------------------------------------------------------
-# Regress gate: the decomposition key family
-# ---------------------------------------------------------------------------
-
-
-class TestRegressGate:
-    def test_ttft_decomposition_direction_rules(self):
-        # pinned by name from the GATED_LOWER comment in regress.py
-        for tier in ("fleet", "serving"):
-            for comp in ("queue", "prefill", "ship", "decode_wait"):
-                assert key_direction(f"{tier}_ttft_{comp}_ms") == "lower"
-        assert r"ttft_\w*(queue|prefill|ship|decode_wait)_ms$" \
-            in GATED_LOWER
-
-    def test_vanished_decomposition_key_fails_gate(self):
-        a = {"fleet_ttft_ship_ms": 12.0, "fleet_ttft_queue_ms": 3.0}
-        b = {"fleet_ttft_queue_ms": 3.0}
-        rows, failures = compare_bench(a, b, 10.0,
-                                       keys=["fleet_ttft_ship_ms"])
-        assert len(failures) == 1
-        assert failures[0]["error"] == "missing from B"
-
-    def test_ship_wall_moving_off_zero_is_unbounded_regression(self):
-        rows, failures = compare_bench({"fleet_ttft_ship_ms": 0.0},
-                                       {"fleet_ttft_ship_ms": 50.0},
-                                       10.0)
-        assert len(failures) == 1
-        assert failures[0]["delta_pct"] == float("-inf")
-
-    def test_regress_ttft_keys_mandatory_on_committed_r19_pair(self,
-                                                               capsys):
-        """r19 satellite 6: the TTFT decomposition family is MANDATORY
-        over the committed r19 pair (A = 4 colocated replicas, B = the
-        same four split 2 prefill + 2 decode, same offered load as the
-        r18 pair, both cpu-toy geometry-stamped).  Three facts on
-        committed data: (1) queue/prefill/ship medians gate clean at
-        ``--keys`` (ship identically 0.0 on BOTH sides — the colocated
-        sanity control, and on the disagg side export→import lands
-        inside one 10 ms virtual round); (2) the gate has TEETH — the
-        decode-wait component is where the shipping round is priced,
-        so including it fails the gate with the moved-off-zero
-        unbounded delta, with every other row still present and
-        directed lower-is-better; (3) a vanished mandatory key is a
-        failure, not a skip."""
-        a = os.path.join(REPO, "BENCH_r19_fleet.json")
-        b = os.path.join(REPO, "BENCH_r19b_fleet.json")
-        gate = ("fleet_ttft_queue_ms,fleet_ttft_prefill_ms,"
-                "fleet_ttft_ship_ms")
-        assert tel_main(["regress", a, b, "--max-regress", "25",
-                         "--keys", gate]) == 0
-        capsys.readouterr()
-        rc = tel_main(["regress", a, b, "--max-regress", "25", "--json",
-                       "--keys", gate + ",fleet_ttft_decode_wait_ms"])
-        rec = json.loads(capsys.readouterr().out)
-        assert rc == 1
-        by_key = {r["key"]: r for r in rec["rows"]}
-        for comp in ("queue", "prefill", "ship", "decode_wait"):
-            assert by_key[f"fleet_ttft_{comp}_ms"]["direction"] == "lower"
-        assert rec["failures"] == ["fleet_ttft_decode_wait_ms"]
-        wait = by_key["fleet_ttft_decode_wait_ms"]
-        assert wait["ok"] is False
-        assert wait["delta_pct"] == float("-inf")
-        ka, kb = (json.load(open(p)) for p in (a, b))
-        assert ka["fleet_config"]["mode"] == "colocated"
-        assert kb["fleet_config"]["mode"] == "disagg"
-        assert kb["fleet_config"]["prefill_replicas"] == 2
-        for rec_ in (ka, kb):
-            assert rec_["fleet_config"]["geometry"] == "cpu-toy"
-            assert rec_["fleet_traced_requests"] == rec_["fleet_requests"]
-            # colocated sanity control: no shipping wall in TTFT —
-            # and the disagg round-clock side agrees (see docstring)
-            assert rec_["fleet_ttft_ship_ms"] == 0.0
-        assert ka["fleet_ttft_decode_wait_ms"] == 0.0
-        assert kb["fleet_ttft_decode_wait_ms"] == 10.0
-        assert kb["fleet_kv_ships"] == kb["fleet_requests"]
-        # ...and a vanished mandatory key is a failure, not a skip
-        assert tel_main(["regress", a, b, "--max-regress", "25",
-                         "--keys", "fleet_ttft_ship_ms,gone_key"]) == 1
 
 
 # ---------------------------------------------------------------------------

@@ -20,6 +20,8 @@ per-platform+jax-version contract, which is the CI environment's.
 
 import os
 
+import jax
+import jaxlib
 import pytest
 
 from tests.L1.common.harness import (
@@ -53,8 +55,10 @@ CELLS = {
 
 def _check(name, traj):
     if REGEN:
-        save_baseline(name, traj, meta=f"cell {name}; see module "
-                      "docstring for the regeneration protocol")
+        save_baseline(name, traj, meta=(
+            f"cell {name}; jax {jax.__version__}, jaxlib "
+            f"{jaxlib.__version__}; see module docstring for the "
+            "regeneration protocol"))
         pytest.skip(f"baseline {name} regenerated — commit the diff")
     stored = load_baseline(name)
     assert stored is not None, (

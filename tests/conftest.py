@@ -50,3 +50,14 @@ def chaos_ckpt_dir(tmp_path):
         _ckpt_mod.set_fault_hook(None)
         _async.drain(ignore_errors=True)
         shutil.rmtree(d, ignore_errors=True)
+
+
+@pytest.fixture(autouse=True)
+def _model_parallel_state_torn_down():
+    """No test inherits another's model-parallel mesh: a test that fails
+    between ``initialize_model_parallel`` and its own teardown would
+    otherwise fail whichever tp test shares its xdist worker."""
+    yield
+    parallel_state = sys.modules.get("apex_tpu.transformer.parallel_state")
+    if parallel_state is not None:
+        parallel_state.destroy_model_parallel()
