@@ -8,8 +8,10 @@ each traced ONCE for the engine's lifetime — the table in
 docs/serving.md "The compiled-shapes contract" is machine-checked
 against this module.  The two workhorses:
 
-* **prefill** — a fixed-width packed row (``[1, prefill_budget]``
-  tokens + segment ids + per-segment positions) through
+* **prefill** — a packed row (``[1, S]`` tokens + segment ids +
+  per-segment positions) of one of a fixed ladder of widths
+  (:func:`prefill_ladder`: ``prefill_budget`` and its half, the
+  narrowest that holds the request's own context) through
   :meth:`~apex_tpu.serving.model.PagedDecoder.prefill`, returning the
   greedy next-token per position and per-layer K/V, which the engine
   scatters into the request's freshly allocated pages (the
@@ -57,13 +59,20 @@ the attention contraction reduces over the packed axis, and XLA's
 blocked reduction groups differently depending on where in the row a
 segment starts (measured: a segment at offset 17 differs from offset 0
 in the last ulp, enough to flip a greedy tie).  So the engine prefills
-each admitted request in its OWN fixed-width row at offset 0: the
-varlen packed machinery (segment ids mask the padding) with exactly
-one segment per row.  Admission still batches — the scheduler admits
-many requests per step — but each prefill launch serves one request.
-The multi-segment form of :meth:`PagedDecoder.prefill` remains
-available for throughput-over-isolation deployments; the engine does
-not use it (docs/serving.md, "Prefill isolation").
+each admitted request in its OWN row at offset 0: the varlen packed
+machinery (segment ids mask the padding) with exactly one segment per
+row.  The row is one of a fixed ladder of widths, chosen by the
+request's own context length and by nothing else (ISSUE 32): a row as
+wide as the longest prompt allowed multiplies mostly padding, and a
+width that follows only the request is the same whether the request is
+served alone or in a batch, so the bitwise contract holds by
+construction.  Packing several waiting prompts into one row would take
+out more padding and stays refused, for the reason above.  Admission
+still batches — the scheduler admits many requests per step — but each
+prefill launch serves one request.  The multi-segment form of
+:meth:`PagedDecoder.prefill` remains available for throughput-over-
+isolation deployments; the engine does not use it (docs/serving.md,
+"Prefill isolation").
 
 Telemetry: every lifecycle edge lands on the PR 4 bus as one of the
 serving event types — ``request_admit``, ``request_retire`` (with
@@ -109,6 +118,7 @@ semantics".
 
 from __future__ import annotations
 
+import bisect
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -116,6 +126,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from apex_tpu.ops import flash_attention_route
 from apex_tpu.serving.kv_cache import (PagedKVCache, PagePoolCorruption,
                                        PagePoolExhausted, PrefixIndex,
                                        WindowPool, verify_page_payload)
@@ -226,6 +237,44 @@ def poisson_trace(seed: int, n_requests: int, *, rate: float,
     return out
 
 
+def prefill_route(cfg: ServingModelConfig, tp: int, width: int) -> str:
+    """The attention forward's kernel route for one shard of a
+    ``[1, width]`` prefill row of ``cfg`` (``varlen`` on the TPU at the
+    serving widths, ``xla`` off it)."""
+    sds = jax.ShapeDtypeStruct
+    return flash_attention_route(
+        sds((cfg.num_heads // tp, width, cfg.head_dim), cfg.dtype),
+        sds((max(1, cfg.kv_heads // tp), width, cfg.head_dim), cfg.dtype),
+        segment_ids=True)["fwd"]
+
+
+#: No rung of the prefill ladder is narrower: below about this many
+#: tokens a row's time is the reading of the weights, whatever its width.
+MIN_PREFILL_ROW = 128
+
+
+def prefill_ladder(budget: int,
+                   route: Callable[[int], str]) -> Tuple[int, ...]:
+    """The widths a whole-row prefill is launched at, ascending, the
+    last of them ``budget``: the widest row and its half.  The half is
+    left out where it is narrower than :data:`MIN_PREFILL_ROW`, or
+    where the attention forward would not take it the way it takes the
+    ``budget`` row (``route(width)``, the kernel route of a row that
+    wide: a rung that fell to another route would be slower, and
+    rounded otherwise, than the row it replaces).
+
+    Two rungs and not four, because a rung is paid for in every
+    process's set-up: at 24 layers one more tracing, lowering and load
+    of the cached executable is 1.8-2.5 s (``PERF.md``, PR 32), and
+    the half alone takes out five sixths of the time the quarters
+    would.  Derived from ``budget`` alone, so an engine and its
+    rebuild after :meth:`ServingEngine.recover` launch the same rows."""
+    half = -(-budget // 2)
+    if half >= MIN_PREFILL_ROW and route(half) == route(budget):
+        return (half, budget)
+    return (budget,)
+
+
 class ServingEngine:
     """Continuous-batching inference over a paged KV cache.
 
@@ -239,10 +288,12 @@ class ServingEngine:
     ``window_pages`` the second pool of a model whose sliding-window
     layers keep only a window of tokens (docs/serving.md "Two page
     lifetimes"; as many as ``num_pages`` where not given);
-    ``prefill_budget`` fixes the packed prefill row width (defaults to
-    ``cfg.max_position``) and bounds prompt+generation per request
-    unless prefill is chunked (a model without a position table chunks
-    by default and is bounded by :attr:`max_context`);
+    ``prefill_budget`` is the widest packed prefill row (defaults to
+    ``cfg.max_position``; a request's row is the narrowest rung of
+    :attr:`prefill_widths` that holds its context) and the scheduler's
+    prefill-token budget a boundary, and it bounds prompt+generation
+    per request unless prefill is chunked (a model without a position
+    table chunks by default and is bounded by :attr:`max_context`);
     ``max_batch`` fixes the decode batch width.  ``telemetry`` is an
     optional :class:`~apex_tpu.telemetry.TelemetryBus`; ``clock`` an
     optional ``() -> float`` (tests pass :class:`SimClock` for
@@ -348,6 +399,11 @@ class ServingEngine:
             self._mesh = tensor_parallel_mesh(self.tp)
             self._tp_axis = TENSOR_AXIS
             self.params = shard_params_tp(self.params, self.tp)
+        #: the ladder of row widths (ISSUE 32): a request's whole-row
+        #: prefill takes the narrowest that holds its context
+        self.prefill_widths = prefill_ladder(
+            self.prefill_budget,
+            lambda width: prefill_route(cfg, self.tp, width))
         if max_pages_per_request is None:
             # a chunked engine serves requests WIDER than the prefill
             # row (that is the point of chunking), so its page-table
@@ -785,8 +841,9 @@ class ServingEngine:
         spent.
 
         The compiled set is FIXED and documented (docs/serving.md
-        "The compiled-shapes contract"): the prefill row, the decode
-        step, the admission scatter (``PagedKVCache.write_tokens`` —
+        "The compiled-shapes contract"): the prefill row at every
+        width of :attr:`prefill_widths`, the decode step, the admission
+        scatter at the same widths (``PagedKVCache.write_tokens`` —
         the one warmup originally missed, surfacing as a hidden ~70 ms
         compile on the first admission's TTFT; caught by the
         hot_path_guard serving-lifetime pin, ISSUE 11), plus — when
@@ -796,16 +853,16 @@ class ServingEngine:
         page 0, which no reader ever sees; the zero-compiles-after-
         warmup pin runs a speculative + chunked trace too."""
         t0 = time.perf_counter()
-        z = jnp.zeros((1, self.prefill_budget), jnp.int32)
-        _, *kv0 = self._prefill_fn(self.params, z, z, z, np.int32(0))
-        # warm the admission scatter with its real shapes: the warmup
-        # prefill's K/V row scattered into the scratch page
-        S = self.prefill_budget
-        zs = np.zeros((S,), np.int32)
-        self.cache.write_tokens(kv0[0], kv0[1], zs, zs)
         wpool = self.cache.window_pool
-        if wpool is not None:
-            wpool.write_tokens(kv0[2], kv0[3], zs, zs)
+        for S in self.prefill_widths:
+            z = jnp.zeros((1, S), jnp.int32)
+            _, *kv0 = self._prefill_fn(self.params, z, z, z, np.int32(0))
+            # warm the admission scatter with its real shapes: the
+            # warmup prefill's K/V row scattered into the scratch page
+            zs = np.zeros((S,), np.int32)
+            self.cache.write_tokens(kv0[0], kv0[1], zs, zs)
+            if wpool is not None:
+                wpool.write_tokens(kv0[2], kv0[3], zs, zs)
         b = self.max_batch
         p_max = self.cache.max_pages_per_request
 
@@ -855,13 +912,21 @@ class ServingEngine:
         jax.block_until_ready(self.cache.k)
         return time.perf_counter() - t0
 
+    def prefill_width(self, context_len: int) -> int:
+        """The row a context of ``context_len`` tokens is prefilled in:
+        the narrowest rung of :attr:`prefill_widths` that holds it.  A
+        function of the request's own length and of nothing else, so
+        the row is the same served alone or in a batch."""
+        return self.prefill_widths[
+            bisect.bisect_left(self.prefill_widths, context_len)]
+
     def _prefill_request(self, req: Request) -> None:
-        """One fixed-width prefill for one request: compute K/V for the
-        whole context (prompt + pre-preemption tokens), scatter it into
-        the request's pages, sample the next token."""
-        S = self.prefill_budget
+        """One prefill row for one request: compute K/V for the whole
+        context (prompt + pre-preemption tokens), scatter it into the
+        request's pages, sample the next token."""
         ctx = req.context
         C = len(ctx)
+        S = self.prefill_width(C)
         ps = self.cache.page_size
         # reserve-at-admit invariant (ISSUE 10 satellite): admission
         # allocated this request's context pages; prefill must never

@@ -18,10 +18,11 @@ asks which it got.
 
 Two entry points mirror the two phases of continuous batching:
 
-* :meth:`PagedDecoder.prefill` — the ADMISSION path.  A fixed-width
-  packed token row with segment ids (the PR 5 varlen packed path:
-  cross-segment tiles are masked in-kernel and skipped by the
-  block-skip index on TPU) — one fixed-shape forward per call, no
+* :meth:`PagedDecoder.prefill` — the ADMISSION path.  A packed token
+  row with segment ids (the PR 5 varlen packed path: cross-segment
+  tiles are masked in-kernel and skipped by the block-skip index on
+  TPU) — one fixed-shape forward per width it is called at, and the
+  engine calls it at a short fixed ladder of widths, all warmed, so no
   recompiles.  The row format carries ANY number of segments, but the
   engine feeds ONE request per row: a multi-segment row is not
   offset-invariant at the last ulp (the attention contraction's
@@ -89,6 +90,7 @@ bf16 for TPU throughput via ``ServingModelConfig(dtype=...)``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import NamedTuple, Optional, Tuple
 
@@ -517,16 +519,29 @@ class PagedDecoder:
         kept = {False: ([], []), True: ([], [])}
         stats = []
 
+        # a jit of this trace's own: the layers of one geometry (a
+        # window or none) share one tracing and one lowering of the
+        # kernel, where every layer's call used to bring its own
+        # (ISSUE 32: of a 24-layer row's 2.5-4.4 s of warm set-up on
+        # the chip, at every width the engine warms).  XLA inlines the
+        # calls, so the executable computes what it did.  Not a
+        # module-level jit: the route is chosen while tracing
+        # (``routing_override``), and a cache that outlived this trace
+        # would hand the next one a route it did not choose.
+        @functools.partial(jax.jit, static_argnames="window")
+        def attention(q, k, v, seg, window):
+            return flash_attention(
+                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                v.transpose(0, 2, 1, 3), causal=True,
+                segment_ids=seg, window=window)
+
         def attend_in(li):
             window = self.windows[li]
             ks, vs = kept[window is not None]
 
             def attend(q, k, v):
                 b, s = q.shape[:2]
-                ctx = flash_attention(
-                    q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                    v.transpose(0, 2, 1, 3), causal=True,
-                    segment_ids=seg, window=window)
+                ctx = attention(q, k, v, seg, window=window)
                 ks.append(k)
                 vs.append(v)
                 return ctx.transpose(0, 2, 1, 3).reshape(b, s, -1)

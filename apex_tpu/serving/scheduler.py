@@ -17,10 +17,11 @@ so a seeded arrival trace replays bit-identically):
 * **admission**: FIFO over the waiting queue while (a) a batch slot is
   open, (b) this step's prefill-token budget has room for the
   request's context, and (c) the pool can supply its context pages.
-  ``prefill_budget`` plays two roles: per REQUEST it is the fixed
-  prefill row width (``submit`` rejects contexts that could outgrow
-  it), and per STEP it caps the total prefill tokens admitted between
-  two decode steps — each admission is its own fixed-width launch (the
+  ``prefill_budget`` plays two roles: per REQUEST it is the widest
+  prefill row (``submit`` rejects contexts that could outgrow it; the
+  engine launches the narrowest of its ladder of widths that holds the
+  request), and per STEP it caps the total prefill tokens admitted
+  between two decode steps — each admission is its own launch (the
   engine's isolation contract), so the step cap is not a packing
   constraint but head-of-line-latency control: admitting unbounded
   prefill work in one step would stall every running request's next

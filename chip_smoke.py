@@ -14,6 +14,9 @@ work, and it has no CPU mode.  With random weights made from a seed it
 * serves a seeded Poisson trace on the 1.3B-geometry ``ServingEngine``,
   then the same prompts again: every request completes, nothing
   compiles after warm-up, the streams repeat;
+* prefills one prompt a rung of the engine's ladder of row widths, at
+  the rung and at the widest row: the same first token, the same K/V
+  to bf16 rounding, every rung on the widest row's kernel route;
 * warms the verify, chunk and int8 executables at two layers;
 * with four or more devices, repeats train on a ``(2, 2, 1)`` mesh and
   serve at ``tp=4`` and checks that state is spread over the mesh.
@@ -47,6 +50,7 @@ from apex_tpu.ops import (flash_attention_qkv, flash_attention_qkv_route,
                           flash_decode_route, routing_override)
 from apex_tpu.serving import (ServingEngine, ServingModelConfig, SpecConfig,
                               poisson_trace)
+from apex_tpu.serving.engine import prefill_route
 from apex_tpu.transformer import parallel_state
 from apex_tpu.transformer.testing import (build_flagship_train_step,
                                           gpt1p3b_config)
@@ -308,6 +312,49 @@ def leg_serve(cfg, *, requests, seed, rate, prompt_len, max_new, page_size,
             "streams": streams}
 
 
+def leg_prefill_rungs(cfg, *, seed, page_size, max_batch, prompt_len,
+                      max_new, **_) -> dict:
+    """One prompt a rung of the engine's prefill ladder, through the
+    engine's own executable at the rung's width and at the widest: a
+    row as wide as the prompt needs must serve what the full row did.
+    The route of every rung is reported, so that a rung a new compiler
+    takes another way shows here and not in a cell's numbers."""
+    eng = _engine(cfg, page_size=page_size, max_batch=max_batch,
+                  prompt_len=prompt_len, max_new=max_new)
+    widest = eng.prefill_widths[-1]
+    rng = np.random.RandomState(seed)
+
+    def row(prompt, width):
+        n = len(prompt)
+        tokens, seg, positions = np.zeros((3, 1, width), np.int32)
+        tokens[0, :n], seg[0, :n], positions[0, :n] = prompt, 1, np.arange(n)
+        first, k, v = eng._prefill_fn(
+            eng.params, jnp.asarray(tokens), jnp.asarray(seg),
+            jnp.asarray(positions), np.int32(n - 1))
+        return int(first), k[:, :n], v[:, :n]
+
+    rungs = {}
+    for width in eng.prefill_widths:
+        # a context this rung is the narrowest for
+        prompt = rng.randint(0, cfg.vocab_size, width - 3)
+        require(eng.prefill_width(len(prompt)) == width,
+                f"prefill rungs: {len(prompt)} tokens do not take the "
+                f"{width} row of {eng.prefill_widths}")
+        first, k, v = row(prompt, width)
+        first_wide, k_wide, v_wide = row(prompt, widest)
+        rungs[width] = {"route": prefill_route(cfg, eng.tp, width),
+                        "first": first, "first_widest": first_wide,
+                        "k": rel_l2(k, k_wide), "v": rel_l2(v, v_wide)}
+    for width, r in rungs.items():
+        require(r["first"] == r["first_widest"],
+                f"prefill rungs: the {width} row serves token {r['first']}, "
+                f"the {widest} row {r['first_widest']}")
+        require(max(r["k"], r["v"]) <= FWD_TOL,
+                f"prefill rungs: K/V of the {width} row differ from the "
+                f"{widest} row's by {max(r['k'], r['v']):.3e}")
+    return {"layers": cfg.num_layers, "widest": widest, "rungs": rungs}
+
+
 def leg_warm(cfg, *, spec_k, chunk_size, seed, rate, prompt_len, max_new,
              page_size, max_batch) -> dict:
     """The verify, chunk and int8 executables: compile, then a short
@@ -401,6 +448,13 @@ def main() -> int:
     print(f"routes: {json.dumps(routes)}", flush=True)
     require(routes == ROUTES_ON_TPU,
             f"routes {routes} are not {ROUTES_ON_TPU}")
+
+    rungs = _report("prefill_rungs", leg_prefill_rungs(
+        _serving_config(WARM_LAYERS), seed=2, **SERVE_TRAFFIC))["rungs"]
+    require({r["route"] for r in rungs.values()}
+            == {ROUTES_ON_TPU["prefill_fwd"]},
+            f"prefill rungs: a rung left the "
+            f"{ROUTES_ON_TPU['prefill_fwd']} route: {rungs}")
 
     _report("warm", leg_warm(
         _serving_config(WARM_LAYERS), spec_k=4, chunk_size=128, seed=1,

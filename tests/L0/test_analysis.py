@@ -670,6 +670,28 @@ def test_serving_lifetime_zero_compiles_after_warmup(serving_cfg):
 
 
 @pytest.mark.serving
+def test_serving_lifetime_zero_compiles_on_any_rung_of_the_prefill_ladder():
+    """ISSUE 32: a prefill row is one of a ladder of widths, by the
+    request's own length.  warmup launches every rung, and the
+    admission scatter at every rung's shape, so a trace whose prompts
+    land on each of them compiles NOTHING."""
+    from apex_tpu.serving.model import ServingModelConfig
+
+    eng = _make_engine(ServingModelConfig(
+        vocab_size=64, hidden_size=32, num_heads=4, num_layers=2,
+        max_position=256))
+    assert eng.prefill_widths == (128, 256)
+    eng.warmup()
+    with hot_path_guard("serving lifetime, every rung",
+                        transfers=None) as g:
+        for n in (3, 128, 129, 150, 193, 60):
+            eng.submit([1 + i % 50 for i in range(n)], max_new_tokens=3)
+        finished = eng.run()
+    assert len(finished) == 6
+    assert g.recompiles == 0 and g.syncs == []
+
+
+@pytest.mark.serving
 def test_spec_serving_lifetime_zero_compiles_after_warmup(serving_cfg):
     """ISSUE 12: the compiled-shapes contract over the GROWN executable
     set — warmup also compiles the speculative verify step

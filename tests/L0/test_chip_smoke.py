@@ -71,6 +71,21 @@ def test_serve_leg(tp):
     assert out["routes"] == {"decode": "xla", "prefill_fwd": "xla"}
 
 
+def test_prefill_rungs_leg():
+    # a row of 256 and its half; fp32 on the CPU, where the narrower
+    # row agrees with the widest to rounding
+    cfg = ServingModelConfig(vocab_size=64, hidden_size=32, num_heads=4,
+                             num_layers=2, max_position=256)
+    out = chip_smoke.leg_prefill_rungs(cfg, seed=2, **TOY_TRAFFIC)
+    assert out["widest"] == 256 and set(out["rungs"]) == {128, 256}
+    for width, rung in out["rungs"].items():
+        assert rung["route"] == "xla"
+        assert rung["first"] == rung["first_widest"]
+        assert max(rung["k"], rung["v"]) < 1e-5
+    assert out["rungs"][256]["k"] == 0.0
+    json.dumps(out)
+
+
 def test_warm_leg():
     out = chip_smoke.leg_warm(TOY_SERVE, spec_k=2, chunk_size=16, seed=1,
                               **TOY_TRAFFIC)

@@ -622,11 +622,17 @@ def _prompts(n=4):
             for i in range(n)]
 
 
+# a prefill row wide enough for a ladder of widths (ISSUE 32): rungs of
+# 128 and 256 tokens, where CFG's row of 96 is its own only rung
+CFG_LADDER = ServingModelConfig(vocab_size=64, hidden_size=32, num_heads=4,
+                                num_layers=2, max_position=256)
+
+
 def _run_engine(params, prompts, *, max_batch=4, num_pages=64,
-                max_new=12, mppr=None, telemetry=None, eos=None):
-    eng = ServingEngine(CFG, params, num_pages=num_pages, page_size=8,
+                max_new=12, mppr=None, telemetry=None, eos=None, cfg=CFG):
+    eng = ServingEngine(cfg, params, num_pages=num_pages, page_size=8,
                         max_batch=max_batch, max_pages_per_request=mppr,
-                        prefill_budget=CFG.max_position,
+                        prefill_budget=cfg.max_position,
                         telemetry=telemetry, clock=SimClock())
     reqs = [eng.submit(p, max_new, eos_id=eos) for p in prompts]
     eng.run()
@@ -634,17 +640,34 @@ def _run_engine(params, prompts, *, max_batch=4, num_pages=64,
 
 
 class TestServingEngine:
-    def test_batched_matches_sequential_bitwise(self, serving_params):
+    @pytest.mark.parametrize("ladder", [False, True],
+                             ids=["one_rung", "every_rung"])
+    def test_batched_matches_sequential_bitwise(self, serving_params,
+                                                ladder):
         # THE acceptance criterion: continuous batching must not
-        # perturb any request's greedy stream — token-for-token
+        # perturb any request's greedy stream — token-for-token.  With
+        # a ladder of prefill rows the requests land on every rung, and
+        # on the same rung alone as in the batch (ISSUE 32).
+        kw = {}
         prompts = _prompts(4)
-        batched, engB = _run_engine(serving_params, prompts, max_batch=4)
+        if ladder:
+            kw = dict(cfg=CFG_LADDER, num_pages=160)
+            serving_params = init_params(CFG_LADDER, seed=0)
+            rng = np.random.RandomState(7)
+            prompts = [[int(x) for x in rng.randint(0, 64, n)]
+                       for n in (9, 128, 129, 192, 240)]
+        batched, engB = _run_engine(serving_params, prompts, max_batch=4,
+                                    **kw)
         sequential = [
-            _run_engine(serving_params, [p], max_batch=1)[0][0]
+            _run_engine(serving_params, [p], max_batch=1, **kw)[0][0]
             for p in prompts]
         assert batched == sequential
         assert all(len(g) == 12 for g in batched)
         assert engB.cache.pages_used == 0  # retirement drained the pool
+        if ladder:
+            assert engB.prefill_widths == (128, 256)
+            assert [engB.prefill_width(len(p)) for p in prompts] == [
+                128, 128, 256, 256, 256]
 
     def test_isolation_one_vs_crowd(self, serving_params):
         # one request's pages must never leak into another's attention:

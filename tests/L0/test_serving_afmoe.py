@@ -19,6 +19,7 @@ if BENCH not in sys.path:
 
 from reference import afmoe_serve as ref  # noqa: E402
 
+from apex_tpu.analysis import hot_path_guard  # noqa: E402
 from apex_tpu.ops.attention import routing_override  # noqa: E402
 from apex_tpu.serving import (ServingEngine, ServingModelConfig,  # noqa: E402
                               SimClock, SpecConfig)
@@ -197,6 +198,36 @@ def test_engine_serves_what_the_reference_puts_first(params):
     for req in reqs:
         assert req.finish_reason == "length"
         assert reference_gap(params, req) < 1e-4
+    assert eng.cache.pages_used == 0
+    assert eng.cache.window_pool.pages_used == 0
+
+
+def test_a_ladder_of_prefill_rows_fills_both_pools_as_the_one_row_did(params):
+    # ISSUE 32: a row as wide as the prompt needs (a 256 row or its half),
+    # scattered into the full pool and the window's tail at that width;
+    # longer prompts still go by chunks of the widest
+    PHASE_RING.clear()
+    eng = engine(params, prefill_budget=256, num_pages=200, window_pages=60,
+                 max_pages_per_request=48)
+    assert eng.prefill_widths == (128, 256)
+    assert eng.chunk_size == 256
+    eng.warmup()
+    lens = (9, 128, 150, 250, 300)
+    with hot_path_guard("afmoe, every rung", transfers=None) as g:
+        reqs = [eng.submit(p, 6) for p in prompts(11, lens)]
+        eng.run()
+    assert g.recompiles == 0
+    for req in reqs:
+        assert req.finish_reason == "length"
+        assert reference_gap(params, req) < 1e-4
+    rows = {}
+    for r in PHASE_RING.snapshot():
+        if r.name == "engine.prefill":
+            rows.setdefault(r.attrs["rid"], []).append(
+                (r.attrs["C"], r.attrs["S"]))
+    assert [rows[req.rid] for req in reqs] == [
+        [(9, 128)], [(128, 128)], [(150, 256)], [(250, 256)],
+        [(256, 256), (44, 256)]]          # the last by chunks
     assert eng.cache.pages_used == 0
     assert eng.cache.window_pool.pages_used == 0
 

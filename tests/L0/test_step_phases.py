@@ -236,6 +236,36 @@ def test_real_plus_padded_prefill_tokens_are_prefills_times_the_row(params):
     assert sum(r.attrs["retired"] for r in steps) == len(reqs)
 
 
+def test_the_prefill_spans_S_is_the_width_of_the_row_launched():
+    # ISSUE 32: the row is the narrowest rung of the engine's ladder that
+    # holds the context, and `S` says which, not `prefill_budget`
+    cfg = ServingModelConfig(vocab_size=64, hidden_size=32, num_heads=4,
+                             num_layers=2, max_position=256)
+    PHASE_RING.clear()
+    eng = ServingEngine(cfg, init_params(cfg, seed=0), num_pages=160,
+                        page_size=8, max_batch=4, clock=SimClock())
+    launched = []
+    inner = eng._prefill_fn
+
+    def spy(p, tokens, *rest):
+        launched.append(int(tokens.shape[1]))
+        return inner(p, tokens, *rest)
+
+    eng._prefill_fn = spy
+    lengths = (7, 128, 129, 200, 192, 250)
+    for n in lengths:
+        eng.submit([1 + i % 50 for i in range(n)], 4)
+    eng.run()
+    prefills = [r for r in PHASE_RING.snapshot()
+                if r.name == "engine.prefill"]
+    assert [r.attrs["C"] for r in prefills] == list(lengths)
+    assert [r.attrs["S"] for r in prefills] == launched
+    assert launched == [128, 128, 256, 256, 256, 256]
+    assert launched == [eng.prefill_width(n) for n in lengths]
+    padded = sum(r.attrs["S"] - r.attrs["C"] for r in prefills)
+    assert padded < len(prefills) * eng.prefill_budget - sum(lengths)
+
+
 def test_phase_ms_passes_the_schema_and_sums_to_no_more_than_step_ms(params):
     bus, mem = _bus()
     _, _, records = _run(params, telemetry=bus)
