@@ -14,6 +14,8 @@ from apex_tpu.ops.attention import (  # noqa: F401
     flash_attention_route,
     flash_attention_varlen,
     flash_decode,
+    flash_decode_latent,
+    flash_decode_latent_route,
     flash_decode_route,
     ring_attention,
     routing_override,

@@ -2906,6 +2906,287 @@ def flash_decode(
 
 
 # ---------------------------------------------------------------------------
+# Paged decode over a LATENT page (ISSUE 33): one vector a token, shared
+# by every query head, whose first ``v_dim`` numbers are also the value
+# (multi-head latent attention in its absorbed form).  K and V are one
+# operand: a page crosses HBM once and is scored and summed from the
+# same VMEM slot.  ``h`` query heads over one key is grouped-query
+# attention with one K/V head, so a row of the batch brings ``h * q_len``
+# query rows to every column: at 128 heads the call sits at the ridge
+# of the roofline at one query a row and is compute-bound beyond it, and
+# every contraction is ``[rows, width] x [width, columns]`` on the MXU.
+# The page walk, the two slots and the hand-over of the first block to
+# the step before are ``_make_decode_kernel``'s.
+# ---------------------------------------------------------------------------
+
+# Query rows (position, head) a grid step of the latent kernel takes at
+# most, and the most columns of a block's score tile (on the v5e, 64
+# rows at 17k of context: 3.6 / 3.0 / 3.1 ms a call at 512 / 1,024 /
+# 2,048 columns; a 512-token chunk 19.1 / 16.1 / 16.9 ms at 256 / 512 /
+# 1,024 rows; skipping the mask on blocks wholly under the causal edge
+# made both slower; PERF.md, PR 33).
+_LATENT_ROWS = 512
+_LATENT_BLOCK_COLS = 1024
+
+
+def _latent_q_tile(q_len, heads):
+    """Query positions a grid step takes: the largest halving of
+    ``q_len`` whose ``heads * tq`` rows fit ``_LATENT_ROWS``."""
+    tq = q_len
+    while heads * tq > _LATENT_ROWS and tq % 2 == 0:
+        tq //= 2
+    return tq
+
+
+def _make_latent_decode_kernel(*, scale, page_size, q_len, heads, width,
+                               v_dim, pages, p_max, tq):
+    """grid (b, q_len // tq); scalar prefetch (page_table [b, p_max],
+    kv_len [b], layer [1], q_start [b]).  The q block is ``[tq * heads,
+    width]``, position major (row ``r`` is query position ``r //
+    heads``); the pool stays in HBM and a step walks its own live
+    pages, ``pages`` a turn, each one DMA ``[page_size, width]`` into
+    one of two slots.  A step whose query positions all lie before
+    ``q_start`` (the front padding of a chunk) walks nothing."""
+    rows_n = heads * tq
+    P = pages
+    n_cols = P * page_size
+
+    def kernel(pt_ref, kl_ref, layer_ref, qs_ref, q_ref, kv_hbm, o_ref,
+               kv_buf, sem, flight_ref, m_ref, l_ref, acc_ref):
+        b_idx, t_idx = pl.program_id(0), pl.program_id(1)
+        n_b, n_t = pl.num_programs(0), pl.num_programs(1)
+        layer = layer_ref[0]
+
+        def first_row(bi, ti):
+            return kl_ref[bi] - q_len + ti * tq
+
+        def span(bi, ti):
+            """The step's last live page and how many blocks lead to it
+            (0: nothing to see)."""
+            last = jax.lax.min(first_row(bi, ti) + tq - 1,
+                               p_max * page_size - 1)
+            hi = jax.lax.div(jax.lax.max(last, 0), page_size)
+            live = (last >= 0) & ((ti + 1) * tq > qs_ref[bi])
+            return hi, jnp.where(live, jax.lax.div(hi, P) + 1, 0)
+
+        def block_dma(bi, hi, i, slot, wait=False):
+            first = i * P
+            live = jax.lax.min(P, hi - first + 1)
+
+            def page_dma(j, _):
+                page = 0 if wait else pt_ref[bi, first + j]
+                copy = pltpu.make_async_copy(
+                    kv_hbm.at[layer, page], kv_buf.at[slot, j],
+                    sem.at[slot])
+                copy.wait() if wait else copy.start()
+                return 0
+
+            jax.lax.fori_loop(0, live, page_dma, 0)
+
+        hi, n_blocks = span(b_idx, t_idx)
+        row0 = first_row(b_idx, t_idx)
+
+        @pl.when((b_idx == 0) & (t_idx == 0))
+        def _():
+            flight_ref[0] = 0
+            flight_ref[1] = 0
+
+            # a page of a live block that is never fetched is masked,
+            # but 0 x NaN is NaN: the slots start finite
+            def zero(j, _):
+                for slot in range(2):
+                    kv_buf[slot, j] = jnp.zeros(kv_buf.shape[2:],
+                                                kv_buf.dtype)
+                return 0
+
+            jax.lax.fori_loop(0, P, zero, 0)
+
+        m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+        slot0 = flight_ref[0]
+
+        @pl.when((n_blocks > 0) & (flight_ref[1] == 0))
+        def _():
+            block_dma(b_idx, hi, 0, slot0)
+
+        pos = jax.lax.div(jax.lax.broadcasted_iota(
+            jnp.int32, (rows_n, n_cols), 0), heads)
+        col = jax.lax.broadcasted_iota(jnp.int32, (rows_n, n_cols), 1)
+
+        def turn(i, _):
+            slot = jax.lax.rem(slot0 + i, 2)
+
+            @pl.when(i + 1 < n_blocks)
+            def _():
+                block_dma(b_idx, hi, i + 1, 1 - slot)
+
+            @pl.when(i + 1 == n_blocks)
+            def _():
+                wrap = t_idx + 1 == n_t
+                nb_ = jnp.where(wrap, b_idx + 1, b_idx)
+                nt_ = jnp.where(wrap, 0, t_idx + 1)
+                there = nb_ < n_b
+                nb_ = jax.lax.min(nb_, n_b - 1)
+                nhi, nn = span(nb_, nt_)
+                sent = there & (nn > 0)
+
+                @pl.when(sent)
+                def _():
+                    block_dma(nb_, nhi, 0, 1 - slot)
+
+                flight_ref[0] = 1 - slot
+                flight_ref[1] = sent.astype(jnp.int32)
+
+            block_dma(b_idx, hi, i, slot, wait=True)
+            # the block serves as K, whole, and its first v_dim lanes as V
+            kv = kv_buf[slot].reshape(n_cols, width)
+            s = jax.lax.dot_general(
+                q_ref[0], kv, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            # the causal limit of the q_len tail is also the kv_len
+            # cutoff: a column past it, fetched or not, never scores
+            s = jnp.where(i * n_cols + col <= row0 + pos, s, _NEG_INF)
+            m_prev = m_ref[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            pexp = _masked_exp(s, m_new)
+            alpha = jnp.where(m_prev <= _NEG_INF / 2, 0.0,
+                              jnp.exp(m_prev - m_new))
+            l_ref[...] = alpha * l_ref[...] + jnp.sum(
+                pexp, axis=-1, keepdims=True)
+            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+                pexp.astype(kv.dtype), kv[:, :v_dim],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[...] = m_new
+            return 0
+
+        jax.lax.fori_loop(0, n_blocks, turn, 0)
+
+        l = l_ref[...]
+        o_ref[0] = (acc_ref[...] / jnp.where(l == 0, 1.0, l)).astype(
+            o_ref.dtype)
+
+    return kernel
+
+
+def _flash_decode_latent_pallas(q, kv_pages, page_table, kv_len, scale,
+                                layer, v_dim, q_start):
+    b, q_len, heads, width = q.shape
+    page_size = kv_pages.shape[2]
+    p_max = page_table.shape[1]
+    tq = _latent_q_tile(q_len, heads)
+    pages = max(1, min(_LATENT_BLOCK_COLS // page_size, p_max))
+    rows_n = heads * tq
+    q_spec = pl.BlockSpec((1, rows_n, width), lambda bi, t, *_: (bi, t, 0))
+    o_spec = pl.BlockSpec((1, rows_n, v_dim), lambda bi, t, *_: (bi, t, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(b, q_len // tq),
+        in_specs=[q_spec, pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=o_spec,
+        scratch_shapes=[
+            pltpu.VMEM((2, pages, page_size, width), kv_pages.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((2,), jnp.int32),
+            pltpu.VMEM((rows_n, 1), jnp.float32),
+            pltpu.VMEM((rows_n, 1), jnp.float32),
+            pltpu.VMEM((rows_n, v_dim), jnp.float32),
+        ],
+    )
+    o = pl.pallas_call(
+        _make_latent_decode_kernel(
+            scale=scale, page_size=page_size, q_len=q_len, heads=heads,
+            width=width, v_dim=v_dim, pages=pages, p_max=p_max, tq=tq),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, q_len * heads, v_dim), q.dtype),
+        name="flash_decode_latent",
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_DECODE_VMEM_LIMIT),
+        interpret=use_interpret(),
+    )(page_table, kv_len, jnp.full((1,), layer, jnp.int32), q_start,
+      q.reshape(b, q_len * heads, width), kv_pages)
+    return o.reshape(b, q_len, heads, v_dim)
+
+
+def _paged_latent_attention_xla(q, kv_pages, page_table, kv_len, scale,
+                                layer, v_dim, q_start):
+    """The generic baseline: the row's pages gathered into one
+    ``[b, p_max * page_size, width]`` view, plain masked attention in
+    float32, the same mathematics as the kernel (``q_start`` saves it
+    nothing: it computes the padding's rows like the others)."""
+    b, q_len, heads, width = q.shape
+    kc = kv_pages[layer][page_table].reshape(b, -1, width).astype(
+        jnp.float32)
+    s = jnp.einsum("bqhd,bkd->bhqk", q.astype(jnp.float32), kc) * scale
+    rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 3)
+    limit = (kv_len - q_len)[:, None, None, None] + rows
+    s = jnp.where(cols <= limit, s, _NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = _masked_exp(s, m)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    o = jnp.einsum("bhqk,bkd->bqhd", p / jnp.where(l == 0, 1.0, l),
+                   kc[..., :v_dim])
+    return o.astype(q.dtype)
+
+
+def flash_decode_latent_route(q, kv_pages) -> str:
+    """``"decode"`` (the Pallas kernel) or ``"xla"``: as
+    :func:`flash_decode_route`, and ``routing_override(decode=...)``
+    forces either.  The kernel wants pages of whole sublane tiles and a
+    value that is whole lane tiles of the vector."""
+    forced = _ROUTE_OVERRIDE["decode"]
+    if forced == "xla":
+        return "xla"
+    grain = 32 // max(1, jnp.dtype(kv_pages.dtype).itemsize)
+    if kv_pages.shape[2] % grain:
+        return "xla"
+    if forced is None and jax.default_backend() != "tpu":
+        return "xla"
+    return "decode"
+
+
+def flash_decode_latent(q, kv_pages, page_table, kv_len, *, v_dim: int,
+                        scale: float, layer: int = 0, q_start=None):
+    """Decode-mode attention against a paged LATENT cache: every query
+    head scores the same key, and the key's first ``v_dim`` numbers are
+    the value.
+
+    ``q`` ``[b, q_len, heads, width]``: the last ``q_len`` positions of
+    each request, position major (as a projection leaves them; no
+    transpose on either side).  ``kv_pages`` ``[L, n_pages, page_size,
+    width]``: the one pool, whole, of which the static ``layer`` is
+    read.  ``page_table`` ``[b, p_max]`` and ``kv_len`` ``[b]`` as in
+    :func:`flash_decode`: the tokens' vectors are already appended, row
+    ``i`` sees columns ``[0, kv_len - q_len + i]``, a row with nothing
+    to see returns zeros.  ``q_start`` ``[b]`` (0 where not given): the
+    query positions before it are a chunk's front padding, and what is
+    returned for them is unspecified (the kernel skips the steps that
+    hold nothing else and returns zeros there).  Returns ``[b, q_len,
+    heads, v_dim]``: the probabilities' sum over the values, not yet
+    projected up."""
+    if not 0 <= layer < kv_pages.shape[0]:
+        raise ValueError(f"layer {layer} is not one of the pool's "
+                         f"{kv_pages.shape[0]}")
+    if q.shape[-1] != kv_pages.shape[-1] or v_dim > q.shape[-1]:
+        raise ValueError(
+            f"query width {q.shape[-1]}, page width {kv_pages.shape[-1]}, "
+            f"value width {v_dim}")
+    kv_len = jnp.asarray(kv_len, jnp.int32)
+    page_table = jnp.asarray(page_table, jnp.int32)
+    q_start = (jnp.zeros_like(kv_len) if q_start is None
+               else jnp.asarray(q_start, jnp.int32))
+    attend = (_flash_decode_latent_pallas
+              if flash_decode_latent_route(q, kv_pages) == "decode"
+              else _paged_latent_attention_xla)
+    return attend(q, kv_pages, page_table, kv_len, float(scale), layer,
+                  v_dim, q_start)
+
+
+# ---------------------------------------------------------------------------
 # Ring attention — sequence/context parallelism over a mesh axis
 # ---------------------------------------------------------------------------
 

@@ -31,6 +31,10 @@ Three composable layers, bottom-up:
   grouped-query layers, a dropless top-k expert layer that holds a
   share of the experts: :mod:`apex_tpu.serving.experts`) over two page
   lifetimes (:class:`WindowPool` beside the :class:`PagedKVCache`).
+* ISSUE 33: a third — :class:`DeepseekV2Config` / ``DeepseekV2Block``
+  (multi-head latent attention over a one-operand latent page,
+  expanded at prefill and absorbed at decode; a group-limited softmax
+  router over the same expert layer), which carries ``prefix_sharing``.
 
 See docs/serving.md for the page-table layout, the admission policy,
 decode routing, speculative decoding, prefix sharing, the quantized
@@ -54,6 +58,7 @@ from apex_tpu.serving.kv_cache import (  # noqa: F401
 )
 from apex_tpu.serving.model import (  # noqa: F401
     AfmoeConfig,
+    DeepseekV2Config,
     PagedDecoder,
     ServingModelConfig,
     init_params,
@@ -89,6 +94,7 @@ __all__ = [
     "WindowPool",
     "quantize_tokens",
     "AfmoeConfig",
+    "DeepseekV2Config",
     "PagedDecoder",
     "ServingModelConfig",
     "init_params",

@@ -240,7 +240,9 @@ def poisson_trace(seed: int, n_requests: int, *, rate: float,
 def prefill_route(cfg: ServingModelConfig, tp: int, width: int) -> str:
     """The attention forward's kernel route for one shard of a
     ``[1, width]`` prefill row of ``cfg`` (``varlen`` on the TPU at the
-    serving widths, ``xla`` off it)."""
+    serving widths, ``xla`` off it).  ``cfg`` says what heads the
+    forward sees: a latent model's are its expanded ones, at the padded
+    width (:attr:`DeepseekV2Config.head_dim`)."""
     sds = jax.ShapeDtypeStruct
     return flash_attention_route(
         sds((cfg.num_heads // tp, width, cfg.head_dim), cfg.dtype),
@@ -353,7 +355,8 @@ class ServingEngine:
                 raise ValueError(
                     f"{cfg.name}: the engine option {option!r} is not "
                     "supported for this model (docs/serving.md, "
-                    "\"What afmoe refuses\")")
+                    "\"What afmoe refuses\", \"What DeepSeek-V2 "
+                    "refuses\")")
         self.params = params if params is not None else init_params(cfg, seed)
         if prefill_budget is None and cfg.max_position is None:
             raise ValueError(f"{cfg.name}: no position table to take the "
@@ -469,10 +472,13 @@ class ServingEngine:
         # hand the decoder what the cache is made of.
         n_pool = len(self._pool_state())
         n_stat = 1 if decoder.stat_names else 0
+        latent = decoder.latent
 
         def carries(args):
             """(pools, the other operands, decoder keywords)."""
             pools, rest = args[:n_pool], args[n_pool:]
+            if latent:      # one operand: the decoder's v_pool is None
+                pools = (pools[0], None)
             kw = {}
             if quant:
                 kw.update(k_scale=pools[2], v_scale=pools[3])
@@ -641,7 +647,7 @@ class ServingEngine:
                              "layer that keeps every token")
         geometry = dict(page_size=page_size, num_heads=cfg.kv_heads,
                         head_dim=cfg.head_dim, dtype=cfg.dtype,
-                        crc_pages=crc_pages)
+                        crc_pages=crc_pages, latent_dim=cfg.latent_dim)
         cache = PagedKVCache(
             num_layers=dec.full_layers, num_pages=num_pages,
             max_pages_per_request=max_pages_per_request,
@@ -669,12 +675,12 @@ class ServingEngine:
 
     def _pool_state(self) -> Tuple:
         """The pool loop-carry operands in executable order —
-        ``(k, v)``, then, quantized, ``(k_scale, v_scale)``, then the
-        window pool's ``(k, v)`` where there is one."""
-        pools = (self.cache.k, self.cache.v)
-        if self.kv_quant is not None:
-            pools += (self.cache.k_scale, self.cache.v_scale)
-        wpool = self.cache.window_pool
+        ``(k, v)`` (a latent pool: ``k`` alone), then, quantized,
+        ``(k_scale, v_scale)``, then the window pool's ``(k, v)`` where
+        there is one."""
+        cache = self.cache
+        pools = tuple(getattr(cache, name) for name in cache.operands)
+        wpool = cache.window_pool
         if wpool is not None:
             pools += (wpool.k, wpool.v)
         return pools
@@ -688,11 +694,8 @@ class ServingEngine:
         wpool = self.cache.window_pool
         if wpool is not None:
             wpool.k, wpool.v = pools[-2:]
-        if self.kv_quant is not None:
-            (self.cache.k, self.cache.v,
-             self.cache.k_scale, self.cache.v_scale) = pools[:4]
-        else:
-            self.cache.k, self.cache.v = pools[:2]
+        for name, pool in zip(self.cache.operands, pools):
+            setattr(self.cache, name, pool)
         return rest
 
     def _tables(self, reqs: Sequence[Request], rows: int) -> Tuple:
@@ -860,9 +863,7 @@ class ServingEngine:
             # warm the admission scatter with its real shapes: the
             # warmup prefill's K/V row scattered into the scratch page
             zs = np.zeros((S,), np.int32)
-            self.cache.write_tokens(kv0[0], kv0[1], zs, zs)
-            if wpool is not None:
-                wpool.write_tokens(kv0[2], kv0[3], zs, zs)
+            self._scatter_row(kv0, zs, zs, zs)
         b = self.max_batch
         p_max = self.cache.max_pages_per_request
 
@@ -911,6 +912,16 @@ class ServingEngine:
             self.cache.warm_export()
         jax.block_until_ready(self.cache.k)
         return time.perf_counter() - t0
+
+    def _scatter_row(self, kv, pages, offsets, wpages) -> None:
+        """A prefill row's stacks into the pool(s) they are for: K and V
+        (a latent model: its one stack), then the window layers' into
+        the window pool at ``wpages``."""
+        self.cache.write_tokens(
+            kv[0], None if self.decoder.latent else kv[1], pages, offsets)
+        if self.cache.window_pool is not None:
+            self.cache.window_pool.write_tokens(kv[2], kv[3], wpages,
+                                                offsets)
 
     def prefill_width(self, context_len: int) -> int:
         """The row a context of ``context_len`` tokens is prefilled in:
@@ -967,21 +978,24 @@ class ServingEngine:
                 idx = np.arange(C)
                 pages[:C] = np.asarray(req.pages, np.int32)[idx // ps]
                 offsets[:C] = idx % ps
-                self.cache.write_tokens(kv[0], kv[1], pages, offsets)
+                wpages = None
                 if wpool is not None:
                     # the window layers' K/V, into the pages of the
                     # window's tail; what lies before it is never read
                     wpages = np.zeros((S,), np.int32)
                     wpages[:C], _ = wpool.write_targets(req.window, idx)
-                    wpool.write_tokens(kv[2], kv[3], wpages, offsets)
+                self._scatter_row(kv, pages, offsets, wpages)
             req.kv_len = C
             self._register_prefix(ctx, req.pages)
+            if self.prefix_index is not None:
+                # a whole row computes every token of its context
+                span.attrs.update(shared=0, ctx=C)
             with phase("prefill.fetch"):
                 # the wait for the device
                 first = int(next_tok)
                 # the block's counters follow the K/V of the pool(s)
                 span.attrs.update(self._stats(
-                    kv[2 if wpool is None else 4:]))
+                    kv[self.decoder.kv_stacks:]))
             req.generated.append(first)
             if req.first_token_t is None:
                 req.first_token_t = self.clock()
@@ -1219,12 +1233,17 @@ class ServingEngine:
             req.kv_len = start + n
             req.prefill_pos = start + n
             self._release_windows([req])
+            if self.prefix_index is not None:
+                # the tokens of the context that rode in on shared pages
+                span.attrs["shared"] = req.prefix_tokens
             if req.prefill_pos >= len(ctx):
                 # prefill complete: sample the first token and leave
                 # chunked mode — the request decodes from the next
                 # boundary
                 req.prefill_pos = None
                 self._register_prefix(ctx, req.pages)
+                if self.prefix_index is not None:
+                    span.attrs["ctx"] = len(ctx)    # the prompt is finished
                 with phase("prefill.fetch"):
                     first = int(np.asarray(next_tok)[0])
                     per_chunk = [self._stats((a,)) for a in
@@ -1472,6 +1491,9 @@ class ServingEngine:
         progress = bool(done_at_prefill) or progress
         counters["retired"] = len(done) + len(done_at_prefill)
         self._held_pages(counters)
+        if self.prefix_index is not None:
+            counters["prefix_entries"] = len(self.prefix_index)
+            counters["prefix_pages_shared"] = self.cache.pages_shared
         evicted: List[Request] = []
         drafts: Dict[int, List[int]] = {}
         if self.sched.running and not self.prefill_only:

@@ -14,6 +14,9 @@ models, as one chip of an expert-parallel deployment runs it.
   their part of the result for the (token, expert) pairs that land on
   them; what the absent experts would add is left out, as it is on one
   chip before the exchange, and no code stands in for the other chips.
+* A second router, :func:`route_grouped` (DeepSeek-V2): a softmax, the
+  experts in groups of which only the best few may be chosen from, no
+  renormalisation.  What follows the choice is the same code.
 * NO TOKEN IS DROPPED and every shape is static: the ``T * top_k``
   pairs are sorted so that those of held experts come first, expert by
   expert; three :func:`jax.lax.ragged_dot` calls (gate, up, down) run
@@ -46,6 +49,28 @@ def route(u, router, bias, *, top_k: int, route_scale: float):
     w = jnp.take_along_axis(scores, experts, axis=-1)
     w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * route_scale
     return experts.astype(jnp.int32), w
+
+
+def route_grouped(u, router, *, top_k: int, route_scale: float,
+                  n_group: int, topk_group: int):
+    """The group-limited greedy router (DeepSeek-V2): u ``[T, d]`` ->
+    (experts ``[T, k]`` int32, weights ``[T, k]`` float32).  Softmax
+    over all experts in float32; the experts lie in ``n_group`` equal
+    groups in order, a group scores as its best expert, the
+    ``topk_group`` best groups are kept and the scores outside them set
+    to 0; top-k of what is left; the chosen scores times
+    ``route_scale``, NOT renormalised, no bias."""
+    scores = jax.nn.softmax(jnp.dot(
+        u.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    T, E = scores.shape
+    best = jnp.max(scores.reshape(T, n_group, E // n_group), axis=-1)
+    _, groups = jax.lax.top_k(best, topk_group)
+    kept = jnp.zeros((T, n_group), bool).at[
+        jnp.arange(T)[:, None], groups].set(True)
+    limited = jnp.where(jnp.repeat(kept, E // n_group, axis=1), scores, 0.0)
+    w, experts = jax.lax.top_k(limited, top_k)
+    return experts.astype(jnp.int32), w * route_scale
 
 
 def swiglu(u, p):
@@ -89,17 +114,24 @@ def held_experts(u, experts, weights, p, held: Tuple[int, int],
 
 
 def expert_layer(u, p, *, held: Tuple[int, int], top_k: int,
-                 route_scale: float, valid=None):
+                 route_scale: float, valid=None, groups=None):
     """``sum_i w_i Expert_i(u)`` over the held experts among the chosen,
     plus the shared expert.  ``u`` ``[..., d]``; ``p`` has ``router``
-    ``[d, E]``, ``expert_bias`` ``[E]``, ``experts`` and ``shared``;
-    ``valid`` (bool, ``u``'s lead shape) marks the tokens that are not
-    padding.  Returns (y like ``u``, load ``[n_held]``)."""
+    ``[d, E]``, ``experts`` and ``shared``, and for the sigmoid router
+    ``expert_bias`` ``[E]``; ``groups`` ``(n_group, topk_group)``
+    chooses :func:`route_grouped` instead; ``valid`` (bool, ``u``'s
+    lead shape) marks the tokens that are not padding.  Returns (y like
+    ``u``, load ``[n_held]``)."""
     lead = u.shape[:-1]
     u2 = u.reshape(-1, u.shape[-1])
     with jax.named_scope("moe_router"):
-        experts, weights = route(u2, p["router"], p["expert_bias"],
-                                 top_k=top_k, route_scale=route_scale)
+        if groups is None:
+            experts, weights = route(u2, p["router"], p["expert_bias"],
+                                     top_k=top_k, route_scale=route_scale)
+        else:
+            experts, weights = route_grouped(
+                u2, p["router"], top_k=top_k, route_scale=route_scale,
+                n_group=groups[0], topk_group=groups[1])
     y, load = held_experts(u2, experts, weights, p["experts"], held, valid)
     with jax.named_scope("moe_shared"):
         y = y + swiglu(u2, p["shared"])

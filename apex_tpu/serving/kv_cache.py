@@ -39,6 +39,16 @@ of fixed-size pages:
   a page list in each; the window pool gives pages back as the window
   slides (docs/serving.md, "Two page lifetimes").
 
+* A model with LATENT attention (ISSUE 33: DeepSeek-V2's MLA) keeps
+  ONE vector a token a layer, shared by every head, that serves as key
+  and as value: ``latent_dim=...`` makes the pool one operand ``k``
+  ``[num_layers, num_pages, page_size, width]`` (``width`` the vector
+  rounded up to whole lane tiles, which is how the device lays it out
+  anyway; the padding stays zero) and ``v`` is ``None``.  Allocation,
+  refcounts, copy-on-write, the scatter, defrag and the CRCs act on
+  page ids and on whichever operands there are (:attr:`PagedKVCache.
+  operands`).
+
 The device arrays are functionally updated (``.at[].set``); the cache
 object re-binds them, so callers treat ``cache.k``/``cache.v`` (and,
 quantized, ``cache.k_scale``/``cache.v_scale``) as the current pool
@@ -63,6 +73,20 @@ import numpy as np
 def _scatter_tokens(k_pool, v_pool, k_new, v_new, pages, offsets):
     return (k_pool.at[:, pages, offsets].set(k_new),
             v_pool.at[:, pages, offsets].set(v_new))
+
+
+def latent_width(latent_dim: int) -> int:
+    """The stored width of a latent vector: whole 128-lane tiles."""
+    return -(-latent_dim // 128) * 128
+
+
+def pad_latent(x, width: int):
+    """``x`` ``[..., latent_dim]`` -> ``[..., width]``, zeros after."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
+def _scatter_latent(pool, new, pages, offsets):
+    return pool.at[:, pages, offsets].set(pad_latent(new, pool.shape[-1]))
 
 
 #: qmax per quantization mode: int8 symmetric [-127, 127] (the -128
@@ -176,7 +200,8 @@ class PagedKVCache:
                  page_size: int, num_heads: int, head_dim: int,
                  max_pages_per_request: int,
                  dtype=jnp.float32, crc_pages: bool = False,
-                 quantize: Optional[str] = None):
+                 quantize: Optional[str] = None,
+                 latent_dim: Optional[int] = None):
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is the "
                              "reserved scratch page)")
@@ -195,10 +220,17 @@ class PagedKVCache:
         #: quantized pools store narrow codes plus fp32 scales.
         self.quantize = quantize
         self.dtype = dtype
+        #: numbers a token keeps in a latent pool (None: K and V heads)
+        self.latent_dim = latent_dim
+        if latent_dim is not None and quantize:
+            raise ValueError("a latent pool is not quantized")
         pool_dtype = quant_pool_dtype(quantize) if quantize else dtype
-        shape = (num_layers, num_pages, page_size, num_heads, head_dim)
+        shape = (num_layers, num_pages, page_size) + (
+            (num_heads, head_dim) if latent_dim is None
+            else (latent_width(latent_dim),))
         self.k = jnp.zeros(shape, pool_dtype)
-        self.v = jnp.zeros(shape, pool_dtype)
+        self.v = (jnp.zeros(shape, pool_dtype) if latent_dim is None
+                  else None)
         # the prefill scatter donates the old pool on TPU so the
         # update is in-place — two full-pool copies per admission
         # would otherwise sit on the TTFT-critical path
@@ -211,6 +243,12 @@ class PagedKVCache:
             self._scatter = jax.jit(
                 functools.partial(_scatter_tokens_quant, qmax=self.qmax),
                 donate_argnums=donate)
+        elif latent_dim is not None:
+            self.qmax = None
+            self.k_scale = self.v_scale = None
+            self._scatter = jax.jit(
+                _scatter_latent, donate_argnums=(0,)
+                if jax.default_backend() == "tpu" else ())
         else:
             self.qmax = None
             self.k_scale = self.v_scale = None
@@ -240,6 +278,21 @@ class PagedKVCache:
         #: the pool of the layers that keep only a window of tokens,
         #: where the model has such layers (the engine sets it)
         self.window_pool: Optional["WindowPool"] = None
+
+    @property
+    def operands(self) -> Tuple[str, ...]:
+        """The names of the pool's device arrays, in executable order:
+        ``k`` and ``v`` (a latent pool: ``k`` alone), then a quantized
+        pool's scale planes."""
+        names = ("k",) if self.v is None else ("k", "v")
+        if self.quantize:
+            names += ("k_scale", "v_scale")
+        return names
+
+    def _each_operand(self, fn) -> None:
+        """Rebind every operand to ``fn`` of it."""
+        for name in self.operands:
+            setattr(self, name, fn(getattr(self, name)))
 
     # -- accounting ------------------------------------------------------
 
@@ -312,11 +365,7 @@ class PagedKVCache:
         [new] = self.allocate(1, owner)
         src = jnp.int32(page)
         dst = jnp.int32(new)
-        self.k = self._copy(self.k, src, dst)
-        self.v = self._copy(self.v, src, dst)
-        if self.quantize:
-            self.k_scale = self._copy(self.k_scale, src, dst)
-            self.v_scale = self._copy(self.v_scale, src, dst)
+        self._each_operand(lambda a: self._copy(a, src, dst))
         self._ref[page] -= 1
         # content moved verbatim, so the copy inherits the digest
         if page in self._crc:
@@ -387,7 +436,8 @@ class PagedKVCache:
             t[i, :len(pages)] = pages
         return jnp.asarray(t)
 
-    def write_tokens(self, k_new: jnp.ndarray, v_new: jnp.ndarray,
+    def write_tokens(self, k_new: jnp.ndarray,
+                     v_new: Optional[jnp.ndarray],
                      pages: jnp.ndarray, offsets: jnp.ndarray) -> None:
         """Scatter per-token K/V into the pool (the prefill fill path).
 
@@ -395,7 +445,9 @@ class PagedKVCache:
         in the COMPUTE dtype; token t lands in ``(pages[t],
         offsets[t])``.  Padding positions point at the scratch page 0.
         Quantized pools quantize-on-write: codes and per-(slot, head)
-        scales are produced on device and scattered together."""
+        scales are produced on device and scattered together.  A latent
+        pool takes ``k_new`` ``[num_layers, T, latent_dim]`` and no
+        ``v_new``."""
         touched = ({int(p) for p in np.asarray(pages).ravel()} - {0}
                    if self.crc_pages else ())
         pages = jnp.asarray(pages, jnp.int32)
@@ -404,6 +456,8 @@ class PagedKVCache:
             self.k, self.v, self.k_scale, self.v_scale = self._scatter(
                 self.k, self.v, self.k_scale, self.v_scale,
                 k_new, v_new, pages, offsets)
+        elif self.v is None:
+            self.k = self._scatter(self.k, k_new, pages, offsets)
         else:
             self.k, self.v = self._scatter(
                 self.k, self.v, k_new, v_new, pages, offsets)
@@ -420,11 +474,7 @@ class PagedKVCache:
         Called from ``ServingEngine.warmup`` when prefix sharing is
         on; part of the zero-compiles-after-warmup contract."""
         z = jnp.int32(0)
-        self.k = self._copy(self.k, z, z)
-        self.v = self._copy(self.v, z, z)
-        if self.quantize:
-            self.k_scale = self._copy(self.k_scale, z, z)
-            self.v_scale = self._copy(self.v_scale, z, z)
+        self._each_operand(lambda a: self._copy(a, z, z))
 
     def warm_import(self) -> None:
         """Compile the shipped-page import executable
@@ -459,6 +509,12 @@ class PagedKVCache:
 
     # -- page shipping (r18 disaggregation) ------------------------------
 
+    def _no_latent_shipping(self) -> None:
+        if self.v is None:
+            raise ValueError("a latent pool's pages are not exported or "
+                             "imported (docs/serving.md, \"The latent "
+                             "page\")")
+
     def export_page_bytes(self, page: int) -> Dict[str, int]:
         """Serialize one page for shipping: C-order K/V page slices
         (quantized: the narrow codes, plus the fp32 scale planes as
@@ -467,6 +523,7 @@ class PagedKVCache:
         verifies them host-side (:func:`verify_page_payload`) before
         its pool ever sees the bytes, and records them as the imported
         page's read-back digest."""
+        self._no_latent_shipping()
         k = np.ascontiguousarray(np.asarray(self.k[:, page:page + 1]))
         v = np.ascontiguousarray(np.asarray(self.v[:, page:page + 1]))
         kb, vb = k.tobytes(), v.tobytes()
@@ -493,6 +550,7 @@ class PagedKVCache:
         over a locally prefilled one.  Callers verify the payload
         first (:func:`verify_page_payload`); this method trusts it and
         records the shipped CRCs as the page's read-back digest."""
+        self._no_latent_shipping()
         pshape = (self.num_layers, 1, self.page_size,
                   self.num_heads, self.head_dim)
         dst = jnp.int32(page)
@@ -527,9 +585,14 @@ class PagedKVCache:
         too (params 0-3 alias outputs 0-3)."""
         sds = jax.ShapeDtypeStruct
         pool = sds(self.k.shape, self.k.dtype)
+        idx = sds((n_tokens,), jnp.int32)
+        if self.v is None:
+            new = sds((self.num_layers, n_tokens, self.latent_dim),
+                      self.dtype)
+            return jax.jit(_scatter_latent, donate_argnums=(0,)
+                           if donate else ()).lower(pool, new, idx, idx)
         new = sds((self.num_layers, n_tokens, self.num_heads,
                    self.head_dim), self.dtype)
-        idx = sds((n_tokens,), jnp.int32)
         if self.quantize:
             scale = sds(self.k_scale.shape, jnp.float32)
             jitted = jax.jit(
@@ -548,8 +611,10 @@ class PagedKVCache:
         (quantized: codes AND scale planes — content identity includes
         the scales, or a flipped scale bit would read back clean)."""
         k = np.ascontiguousarray(np.asarray(self.k[:, page]))
-        v = np.ascontiguousarray(np.asarray(self.v[:, page]))
-        kb, vb = k.tobytes(), v.tobytes()
+        kb = k.tobytes()
+        if self.v is None:      # a latent page is key and value at once
+            return (zlib.crc32(kb), 0)
+        vb = np.ascontiguousarray(np.asarray(self.v[:, page])).tobytes()
         if self.quantize:
             # same sanctioned read-back as the code planes above —
             # device ``.tobytes()`` pulls the scale slice directly
@@ -624,11 +689,7 @@ class PagedKVCache:
         # pages outside the live prefix keep whatever content the
         # gather assigns them — they are free, nothing reads them
         src_j = jnp.asarray(src, jnp.int32)
-        self.k = self.k[:, src_j]
-        self.v = self.v[:, src_j]
-        if self.quantize:
-            self.k_scale = self.k_scale[:, src_j]
-            self.v_scale = self.v_scale[:, src_j]
+        self._each_operand(lambda a: a[:, src_j])
         self._owner = {mapping[p]: o for p, o in self._owner.items()
                        if p in mapping}
         self._ref = {mapping[p]: r for p, r in self._ref.items()
@@ -766,8 +827,8 @@ class PrefixIndex:
             raise ValueError("max_entries must be >= 1")
         self.cache = cache
         self.max_entries = int(max_entries)
-        self._entries: "OrderedDict[Tuple[int, ...], List[int]]" = \
-            OrderedDict()
+        # key -> (the entry's pages, its tokens as one bytes key a page)
+        self._entries: "OrderedDict[Tuple[int, ...], Tuple[List[int], List[bytes]]]" = OrderedDict()
 
     def __len__(self) -> int:
         return len(self._entries)
@@ -775,6 +836,13 @@ class PrefixIndex:
     @property
     def entries(self) -> List[Tuple[int, ...]]:
         return list(self._entries)
+
+    def _blocks(self, tokens: Sequence[int]) -> List[bytes]:
+        """``tokens``' whole pages, each page's tokens as one key."""
+        ps = self.cache.page_size
+        arr = np.asarray(tokens, np.int64)
+        return [arr[i:i + ps].tobytes()
+                for i in range(0, len(arr) - ps + 1, ps)]
 
     def register(self, tokens: Sequence[int],
                  pages: Sequence[int]) -> bool:
@@ -793,7 +861,7 @@ class PrefixIndex:
                 f"register: {len(pages)} pages for a {len(key)}-token "
                 f"context (expected {self.cache.pages_needed(len(key))})")
         self.cache.share(pages)
-        self._entries[key] = list(pages)
+        self._entries[key] = (list(pages), self._blocks(key))
         while len(self._entries) > self.max_entries:
             self.evict_one()
         return True
@@ -808,15 +876,26 @@ class PrefixIndex:
         shared page also holds the ENTRY's diverging tokens past ``m``
         — safe, because readers mask by their own ``kv_len`` and the
         new reader's first write into that page copies it first
-        (copy-on-write)."""
-        ctx = tuple(int(t) for t in tokens)
+        (copy-on-write).
+
+        Entries are compared a PAGE at a time (a page's tokens are one
+        key), and token by token only inside the page where an entry
+        parts from the context: ``entries x pages`` comparisons, where a
+        walk over tokens made a 16k-token document tens of milliseconds
+        of every admission."""
+        ps = self.cache.page_size
+        ctx = [int(t) for t in tokens]
+        blocks = self._blocks(ctx)
         best_m, best_pages = 0, []
-        for key, pages in self._entries.items():
+        for key, (pages, key_blocks) in self._entries.items():
             lim = min(len(key), len(ctx) - 1)
-            m = 0
+            p, whole = 0, lim // ps
+            while p < whole and key_blocks[p] == blocks[p]:
+                p += 1
+            m = p * ps
             while m < lim and key[m] == ctx[m]:
                 m += 1
-            if m >= self.cache.page_size and m > best_m:
+            if m >= ps and m > best_m:
                 best_m = m
                 best_pages = pages[:self.cache.pages_needed(m)]
         return best_m, list(best_pages)
@@ -828,7 +907,7 @@ class PrefixIndex:
         index can never free a page out from under a request)."""
         if not self._entries:
             return 0
-        _, pages = self._entries.popitem(last=False)
+        _, (pages, _) = self._entries.popitem(last=False)
         before = self.cache.pages_free
         self.cache.free(pages)
         return self.cache.pages_free - before

@@ -98,9 +98,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from apex_tpu.ops import flash_attention, flash_decode
+from apex_tpu.ops import flash_attention, flash_decode, flash_decode_latent
 from apex_tpu.serving.experts import expert_layer, swiglu
-from apex_tpu.serving.kv_cache import quantize_tokens
+from apex_tpu.serving.kv_cache import (latent_width, pad_latent,
+                                       quantize_tokens)
 
 
 def quant_qmax(dtype) -> float:
@@ -175,6 +176,10 @@ class ServingModelConfig:
     def layer_windows(self) -> Tuple[Optional[int], ...]:
         """Per layer, how many tokens back it sees (None: all)."""
         return (None,) * self.num_layers
+
+    #: numbers a token keeps as ONE key-and-value vector (None: K and
+    #: V heads, two operands)
+    latent_dim = None
 
     def block(self) -> "GPTBlock":
         return GPTBlock(self)
@@ -300,6 +305,7 @@ class AfmoeConfig:
     name = "afmoe"
     #: no learned position table
     max_position = None
+    latent_dim = None
 
     @property
     def num_layers(self) -> int:
@@ -443,6 +449,245 @@ class AfmoeBlock:
         return x @ params["head"]
 
 
+# -- deepseek_v2: the third block, latent attention ---------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class DeepseekV2Config:
+    """Geometry of a ``deepseek_v2`` decoder as one chip of its
+    expert-parallel deployment holds it: ``n_routed_experts`` is the
+    router's width, ``experts_held`` the ``[lo, hi)`` of them whose
+    weights are here, ``vocab_size`` this chip's slice.  Attention is
+    multi-head LATENT attention: a token keeps ``kv_lora_rank +
+    qk_rope_head_dim`` numbers a layer (:attr:`latent_dim`), one vector
+    for all heads.  No position table: a request is bounded by its
+    pages."""
+
+    vocab_size: int
+    hidden_size: int
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    num_layers: int
+    first_k_dense_replace: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    n_routed_experts: int
+    experts_held: Tuple[int, int]
+    top_k: int
+    n_group: int
+    topk_group: int
+    routed_scaling_factor: float
+    n_shared_experts: int
+    rope_theta: float = 10000.0
+    rope_factor: float = 40.0
+    rope_original_max: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 0.707
+    rope_mscale_all_dim: float = 0.707
+    rms_norm_eps: float = 1e-6
+    dtype: object = jnp.float32
+
+    name = "deepseek_v2"
+    max_position = None
+
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def head_dim(self) -> int:
+        """The width a whole-row prefill's heads have in the attention
+        forward: Q/K (``qk_head_dim``) and V (``v_head_dim``) both
+        zero-padded to whole lane tiles of one width."""
+        return latent_width(max(self.qk_head_dim, self.v_head_dim))
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_heads
+
+    @property
+    def layer_windows(self) -> Tuple[Optional[int], ...]:
+        return (None,) * self.num_layers
+
+    @property
+    def softmax_scale(self) -> float:
+        """``qk_head_dim ** -0.5 * m * m``, ``m`` YaRN's
+        ``0.1 * mscale_all_dim * ln(factor) + 1``."""
+        m = yarn_mscale(self.rope_factor, self.rope_mscale_all_dim)
+        return self.qk_head_dim ** -0.5 * m * m
+
+    def block(self) -> "DeepseekV2Block":
+        return DeepseekV2Block(self)
+
+    def init_params(self, seed: int = 0):
+        """Seeded parameters in the block's layout (the reference's):
+        matrices normal with std 1/sqrt(fan_in), gains 1 + 0.02 normal.
+        The up-projection of the latent is held as its two column
+        blocks, ``wuk`` (the heads' keys) and ``wuv`` (their values)."""
+        d, dt, nh = self.hidden_size, self.dtype, self.num_heads
+        n_held = self.experts_held[1] - self.experts_held[0]
+        keys = iter(jax.random.split(jax.random.PRNGKey(seed), 4096))
+
+        def mat(*shape):
+            return (jax.random.normal(next(keys), shape, jnp.float32)
+                    / math.sqrt(shape[-2])).astype(dt)
+
+        def gain(n):
+            return (1.0 + 0.02 * jax.random.normal(
+                next(keys), (n,), jnp.float32)).astype(dt)
+
+        def mlp(*lead, f):
+            return {"wg": mat(*lead, d, f), "wu": mat(*lead, d, f),
+                    "wd": mat(*lead, f, d)}
+
+        layers = []
+        for i in range(self.num_layers):
+            layer = {"g1": gain(d), "g2": gain(d),
+                     "gq": gain(self.q_lora_rank),
+                     "gkv": gain(self.kv_lora_rank),
+                     "wdq": mat(d, self.q_lora_rank),
+                     "wuq": mat(self.q_lora_rank, nh * self.qk_head_dim),
+                     "wdkv": mat(d, self.latent_dim),
+                     "wuk": mat(self.kv_lora_rank,
+                                nh * self.qk_nope_head_dim),
+                     "wuv": mat(self.kv_lora_rank, nh * self.v_head_dim),
+                     "wo": mat(nh * self.v_head_dim, d)}
+            if i < self.first_k_dense_replace:
+                layer["mlp"] = mlp(f=self.intermediate_size)
+            else:
+                f = self.moe_intermediate_size
+                layer["moe"] = {
+                    "router": mat(d, self.n_routed_experts),
+                    "experts": mlp(n_held, f=f),
+                    "shared": mlp(f=f * self.n_shared_experts)}
+            layers.append(layer)
+        embed = (jax.random.normal(next(keys), (self.vocab_size, d),
+                                   jnp.float32) / math.sqrt(d)).astype(dt)
+        return {"embed": embed, "head": mat(d, self.vocab_size),
+                "norm_f": gain(d), "layers": layers}
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, *, theta: float, factor: float,
+                  original_max: int, beta_fast: float, beta_slow: float):
+    """The ``dim // 2`` rotary frequencies under YaRN, float64 numpy:
+    ``f_i = theta ** (-2i / dim)``, divided by ``factor`` for the pairs
+    past ``high`` (``ramp`` 1), untouched before ``low``, blended
+    between.  Returns (inv_freq, low, high)."""
+    i = np.arange(dim // 2, dtype=np.float64)
+    f = theta ** (-2.0 * i / dim)
+    corr = lambda r: (dim * math.log(original_max / (2 * math.pi * r))
+                      / (2 * math.log(theta)))
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), dim - 1)
+    ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (f / factor) * ramp + f * (1.0 - ramp), low, high
+
+
+def _rope_pairs(x, positions, inv_freq):
+    """RoPE over ADJACENT pairs ``(2i, 2i + 1)`` of the last dimension,
+    in place; ``positions`` is the lead shape of ``x`` less any head
+    axis between (``x [..., d]`` or ``x [..., h, d]``)."""
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq
+    if x.ndim == ang.ndim + 1:
+        ang = ang[..., None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+class DeepseekV2Block:
+    """The ``deepseek_v2`` block (docs/serving.md, "The latent page"):
+    ``h = x + Attn(RMS(x))``, ``x' = h + MLP(RMS(h))``.
+
+    Attention is latent: the query goes through a low-rank bottleneck
+    with an RMSNorm (``cq``), and a token's keys and values are ONE
+    vector ``[c (kv_lora_rank, after its RMSNorm), k_pe (the rotary key,
+    after RoPE, shared by all heads)]``, which is what the cache holds.
+    The block hands ``attend`` the query's two parts, that vector and
+    the up-projections ``wuk`` / ``wuv``; the DECODER chooses the form:
+    expanded (per-head K and V from ``c``; a whole-row prefill) or
+    absorbed (``wuk`` folded into the query, ``wuv`` applied to the
+    output; over the latent pages).  The MLP is SwiGLU in the first
+    ``first_k_dense_replace`` layers and after them the dropless expert
+    layer under the group-limited softmax router, with the shared
+    experts as one SwiGLU."""
+
+    refuses = ("tp", "kv_quant", "speculation", "prefill_only",
+               "kv_import")
+    stat_names = ("moe_pairs_held", "moe_load_max")
+
+    def __init__(self, cfg: DeepseekV2Config):
+        self.cfg = cfg
+        inv_freq, _, _ = yarn_inv_freq(
+            cfg.qk_rope_head_dim, theta=cfg.rope_theta,
+            factor=cfg.rope_factor, original_max=cfg.rope_original_max,
+            beta_fast=cfg.rope_beta_fast, beta_slow=cfg.rope_beta_slow)
+        self.inv_freq = np.asarray(inv_freq, np.float32)
+        # cos and sin carry mscale / mscale_all_dim, 1 where they agree
+        self.rope_gain = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+                          / yarn_mscale(cfg.rope_factor,
+                                        cfg.rope_mscale_all_dim))
+
+    def embed(self, params, tokens, positions):
+        return params["embed"][tokens]
+
+    def layer(self, layer, li, x, positions, attend, *, tp_axis=None,
+              valid=None, stats=None):
+        cfg = self.cfg
+        eps, nh = cfg.rms_norm_eps, cfg.num_heads
+        lead = x.shape[:-1]
+        u = _rms(x, layer["g1"], eps)
+        with jax.named_scope("mla_q"):
+            cq = _rms(u @ layer["wdq"], layer["gq"], eps)
+            q = (cq @ layer["wuq"]).reshape(*lead, nh, cfg.qk_head_dim)
+            q_nope = q[..., :cfg.qk_nope_head_dim]
+            q_pe = _rope_pairs(q[..., cfg.qk_nope_head_dim:], positions,
+                               self.inv_freq) * self.rope_gain
+        with jax.named_scope("mla_kv_down"):
+            ckv = u @ layer["wdkv"]
+            c = _rms(ckv[..., :cfg.kv_lora_rank], layer["gkv"], eps)
+            k_pe = _rope_pairs(ckv[..., cfg.kv_lora_rank:], positions,
+                               self.inv_freq) * self.rope_gain
+            latent = jnp.concatenate([c, k_pe.astype(c.dtype)], axis=-1)
+        with jax.named_scope("attn_latent"):
+            ctx = attend(q_nope, q_pe.astype(q.dtype), latent,
+                         layer["wuk"], layer["wuv"],
+                         scale=cfg.softmax_scale)
+        h = x + ctx @ layer["wo"]
+        u = _rms(h, layer["g2"], eps)
+        if "mlp" in layer:
+            m = swiglu(u, layer["mlp"])
+        else:
+            m, load = expert_layer(
+                u, layer["moe"], held=cfg.experts_held, top_k=cfg.top_k,
+                route_scale=cfg.routed_scaling_factor, valid=valid,
+                groups=(cfg.n_group, cfg.topk_group))
+            if stats is not None:
+                stats.append(load)
+        return h + m
+
+    def final_norm(self, params, x):
+        return _rms(x, params["norm_f"], self.cfg.rms_norm_eps)
+
+    def logits(self, params, x):
+        return x @ params["head"]
+
+
 class WindowKV(NamedTuple):
     """The window-lifetime half of a two-lifetime cache as a paged step
     sees it: the pool of the sliding layers (``k``/``v`` ``[L_w,
@@ -478,6 +723,41 @@ class PagedDecoder:
         self.pool_index = tuple(index)
         self.full_layers, self.window_layers = counts[False], counts[True]
         self.stat_names = self.block.stat_names
+        #: a latent model: one vector a token, one pool operand
+        self.latent = cfg.latent_dim is not None
+        #: the K/V stacks a prefill returns after the logits, as many
+        #: as the cache's pools have unquantized operands
+        self.kv_stacks = (1 if self.latent
+                          else 4 if self.window_layers else 2)
+
+    def _expanded(self, attention, seg, kept):
+        """A latent layer's ``attend`` for a whole row, no cache yet:
+        the equations as written.  K and V of every head come from the
+        row's own ``c`` (``wuk``, ``wuv``), the rotary key is every
+        head's; Q/K and V are zero-padded to one width of whole lane
+        tiles, which changes no score and no sum.  The row's latent
+        vectors are kept for the pool."""
+        cfg = self.cfg
+        width, nope = cfg.head_dim, cfg.qk_nope_head_dim
+
+        def attend(q_nope, q_pe, latent, wuk, wuv, *, scale):
+            b, s, nh = q_nope.shape[:3]
+            kept.append(latent)
+            with jax.named_scope("mla_expand"):
+                c = latent[..., :cfg.kv_lora_rank]
+                k_nope = (c @ wuk).reshape(b, s, nh, nope)
+                v = (c @ wuv).reshape(b, s, nh, cfg.v_head_dim)
+                k_pe = jnp.broadcast_to(
+                    latent[..., None, cfg.kv_lora_rank:],
+                    (b, s, nh, cfg.qk_rope_head_dim))
+                q = pad_latent(jnp.concatenate([q_nope, q_pe], -1), width)
+                k = pad_latent(jnp.concatenate([k_nope, k_pe], -1), width)
+                v = pad_latent(v, width)
+            ctx = attention(q, k, v, seg, window=None, scale=scale)
+            ctx = ctx.transpose(0, 2, 1, 3)[..., :cfg.v_head_dim]
+            return ctx.reshape(b, s, -1)
+
+        return attend
 
     def _stats(self, stats):
         """The block's per-launch counters as one int32 vector, in
@@ -528,14 +808,16 @@ class PagedDecoder:
         # module-level jit: the route is chosen while tracing
         # (``routing_override``), and a cache that outlived this trace
         # would hand the next one a route it did not choose.
-        @functools.partial(jax.jit, static_argnames="window")
-        def attention(q, k, v, seg, window):
+        @functools.partial(jax.jit, static_argnames=("window", "scale"))
+        def attention(q, k, v, seg, window, scale=None):
             return flash_attention(
                 q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                 v.transpose(0, 2, 1, 3), causal=True,
-                segment_ids=seg, window=window)
+                segment_ids=seg, window=window, scale=scale)
 
         def attend_in(li):
+            if self.latent:
+                return self._expanded(attention, seg, kept[False][0])
             window = self.windows[li]
             ks, vs = kept[window is not None]
 
@@ -559,7 +841,9 @@ class PagedDecoder:
                 x = jax.lax.dynamic_slice_in_dim(
                     x, jnp.asarray(last_index, jnp.int32), 1, axis=1)
             logits = block.logits(params, x)
-        out = (logits, jnp.stack(kept[False][0]), jnp.stack(kept[False][1]))
+        out = (logits, jnp.stack(kept[False][0]))
+        if not self.latent:
+            out += (jnp.stack(kept[False][1]),)
         if self.window_layers:
             out += (jnp.stack(kept[True][0]), jnp.stack(kept[True][1]))
         if stats:
@@ -612,11 +896,49 @@ class PagedDecoder:
                                 kv_start=window.start)
         stats = []
 
+        def absorbed(pi):
+            """A latent layer's ``attend`` over the latent pages: the
+            token's vector is appended, ``wuk`` is folded into the
+            query (one key of ``latent_dim`` for every head) and
+            ``wuv`` applied to what comes back: the same mathematics as
+            the expanded form, with nothing expanded."""
+            cfg = self.cfg
+            pool = pools[False]
+            rank, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+            # a chunk's rows before its first real one are padding (they
+            # write the scratch page): the kernel walks nothing for them
+            q_start = (None if real.ndim == 1 else
+                       real.shape[1] - jnp.sum(real, axis=1, dtype=jnp.int32))
+
+            def attend(q_nope, q_pe, latent, wuk, wuv, *, scale):
+                nh = q_nope.shape[-2]
+                width = pool[0].shape[-1]
+                pool[0] = pool[0].at[pi, write_pages, write_offsets].set(
+                    pad_latent(latent, width))
+                with jax.named_scope("mla_absorb"):
+                    q_abs = jnp.einsum(
+                        "...hd,chd->...hc", q_nope,
+                        wuk.reshape(rank, nh, nope))
+                    q = pad_latent(jnp.concatenate([q_abs, q_pe], -1), width)
+                o = flash_decode_latent(
+                    q.reshape(b, -1, nh, width), pool[0], page_table,
+                    kv_len, v_dim=rank, scale=scale, layer=pi,
+                    q_start=q_start)
+                with jax.named_scope("mla_absorb"):
+                    ctx = jnp.einsum(
+                        "...hc,chd->...hd", o.reshape(*lead, nh, rank),
+                        wuv.reshape(rank, nh, cfg.v_head_dim))
+                return ctx.reshape(*lead, -1)
+
+            return attend
+
         def attend_in(li):
             w = self.windows[li]
             pool = pools[w is not None]
             pages, offsets = targets[w is not None]
             pi = self.pool_index[li]
+            if self.latent:
+                return absorbed(pi)
 
             def attend(q, k, v):
                 nh, hd = q.shape[-2:]
@@ -645,7 +967,8 @@ class PagedDecoder:
             with jax.named_scope("layer"):
                 x = block.layer(layer, li, x, positions, attend_in(li),
                                 tp_axis=tp_axis, valid=real, stats=stats)
-        out = tuple(pools[False][:4 if quantized else 2])
+        out = tuple(pools[False][:4 if quantized
+                                 else 1 if self.latent else 2])
         if window is not None:
             out += tuple(pools[True][:2])
         if stats:
@@ -681,7 +1004,7 @@ class PagedDecoder:
 
         The append and the attention over the pages are
         :meth:`_paged`'s; :meth:`extend` does the same."""
-        page_size = k_pool.shape[2]
+        page_size = k_pool.shape[2]     # a latent pool's too
         page_slot = positions // page_size
         page_idx = jnp.take_along_axis(
             page_table, page_slot[:, None], axis=1)[:, 0]

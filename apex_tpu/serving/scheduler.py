@@ -133,6 +133,9 @@ class Request:
     # re-admission does its own lookup).  Telemetry-visible as the
     # request_admit event's ``prefix_hit`` bool.
     prefix_hit: bool = False
+    # ... and how many of the context's tokens they were (the
+    # ``engine.prefill`` phase's ``shared``)
+    prefix_tokens: int = 0
     preemptions: int = 0
     admit_t: Optional[float] = None
     first_token_t: Optional[float] = None
@@ -419,6 +422,7 @@ class ContinuousBatchingScheduler:
             req.pages = pages
             req.state = RUNNING
             req.prefix_hit = bool(m)
+            req.prefix_tokens = m
             budget -= need
             if chunked:
                 req.prefill_pos = m
@@ -492,6 +496,7 @@ class ContinuousBatchingScheduler:
         victim.prefill_pos = None
         # re-admission does its own prefix lookup
         victim.prefix_hit = False
+        victim.prefix_tokens = 0
         victim.state = WAITING
         victim.preemptions += 1
         self.waiting.appendleft(victim)

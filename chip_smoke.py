@@ -47,9 +47,10 @@ from apex_tpu import _native
 from apex_tpu.analysis import hot_path_guard
 from apex_tpu.ops import (flash_attention_qkv, flash_attention_qkv_route,
                           flash_attention_route, flash_decode,
-                          flash_decode_route, routing_override)
-from apex_tpu.serving import (ServingEngine, ServingModelConfig, SpecConfig,
-                              poisson_trace)
+                          flash_decode_latent_route, flash_decode_route,
+                          routing_override)
+from apex_tpu.serving import (DeepseekV2Config, PagedDecoder, ServingEngine,
+                              ServingModelConfig, SpecConfig, poisson_trace)
 from apex_tpu.serving.engine import prefill_route
 from apex_tpu.transformer import parallel_state
 from apex_tpu.transformer.testing import (build_flagship_train_step,
@@ -66,6 +67,10 @@ ROUTES_ON_TPU = {"decode": "decode", "qkv": "packed", "prefill_fwd": "varlen"}
 # bf16 against an fp32-accumulating XLA reference: relative L2 error.
 FWD_TOL = 2e-2
 GRAD_TOL = 3e-2
+# Latent attention's two forms, bf16, two layers at the published widths:
+# they round at different places.  Measured (PR 33): 3.7e-2 between
+# them, where each lies 4.1e-2 and 4.3e-2 from the float32 reference.
+LATENT_FORMS_TOL = 6e-2
 # Chips holding equal shards of one program's state.
 BALANCE_FACTOR = 1.5
 
@@ -392,6 +397,88 @@ SERVE_TRAFFIC = dict(rate=8.0, prompt_len=(64, 256), max_new=(16, 64),
                      page_size=64, max_batch=8)
 
 
+def leg_latent(cfg, *, seed, page_size, row, doc_pages, max_new) -> dict:
+    """Latent attention's two forms on the same tokens, and a shared
+    page under a second reader.
+
+    A row of ``row`` tokens goes through the decoder whole (expanded,
+    no cache), then its second half again over the latent pages its
+    first half filled (absorbed): one mathematics, so the logits agree
+    to rounding.  Then an engine with prefix sharing serves a document
+    of ``doc_pages`` pages and a second request on the same document:
+    the second computes only its own tokens, and the document's pages
+    hold bit for bit what they held before it read them."""
+    dec = PagedDecoder(cfg)
+    params = cfg.init_params(seed)
+    rng = np.random.RandomState(seed)
+    seq = rng.randint(0, cfg.vocab_size, row)
+    half = row // 2
+    one = lambda a: jnp.asarray(np.asarray(a, np.int32)[None])
+    logits, latent, _ = jax.jit(dec.prefill)(
+        params, one(seq), one(np.ones(row)), one(np.arange(row)))
+    eng = ServingEngine(
+        cfg, params, num_pages=4 * doc_pages + 8, page_size=page_size,
+        max_batch=2, max_pages_per_request=2 * doc_pages,
+        prefill_budget=half, prefix_sharing=True)
+    cache = eng.cache
+    route = flash_decode_latent_route(
+        jax.ShapeDtypeStruct((1, half, cfg.num_heads, cache.k.shape[-1]),
+                             cfg.dtype), cache.k)
+    pages = cache.allocate(cache.pages_needed(row), 0)
+    idx = np.arange(half)
+    cache.write_tokens(latent[:, 0, :half], None,
+                       np.asarray(pages)[idx // page_size], idx % page_size)
+    pos = np.arange(half, row)
+    out = jax.jit(dec.extend)(
+        params, cache.k, None, one(seq[half:]), one(pos),
+        one(np.asarray(pages)[pos // page_size]), one(pos % page_size),
+        cache.page_table([pages]), jnp.asarray([row], jnp.int32))
+    cache.k = out[1]
+    forms = rel_l2(out[0][0], logits[0, half:])
+    require(forms < LATENT_FORMS_TOL,
+            f"latent: absorbed against expanded {forms:.2e}")
+    cache.free(pages)
+
+    eng.warmup()
+    doc = [int(t) for t in rng.randint(0, cfg.vocab_size,
+                                       doc_pages * page_size)]
+    first = eng.submit(doc, max_new)
+    eng.run()
+    [(held, _)] = eng.prefix_index._entries.values()
+    before = np.asarray(cache.k[:, np.asarray(held)])
+    second = eng.submit(doc + [int(t) for t in rng.randint(
+        0, cfg.vocab_size, page_size // 2)], max_new)
+    with hot_path_guard("latent serving after warm-up", transfers=None,
+                        tripwire=False):
+        eng.run()
+    require(second.prefix_tokens == len(doc),
+            f"latent: the second request shared {second.prefix_tokens} of "
+            f"{len(doc)} tokens")
+    require(np.array_equal(before, np.asarray(cache.k[:, np.asarray(held)])),
+            "latent: a shared page changed under its second reader")
+    for r in (first, second):
+        require(len(r.generated) == max_new,
+                f"latent: request {r.rid} made {len(r.generated)} tokens")
+    return {"layers": cfg.num_layers, "route": route,
+            "rel_l2": {"absorbed_vs_expanded": forms},
+            "shared_tokens": second.prefix_tokens,
+            "pool_width": int(cache.k.shape[-1]),
+            "memory": memory_by_device()}
+
+
+def _latent_config(layers: int) -> DeepseekV2Config:
+    """DeepSeek-V2's published widths, ``layers`` deep (one dense), an
+    eighth of the experts and of the vocabulary."""
+    return DeepseekV2Config(
+        vocab_size=12800, hidden_size=5120, num_heads=128, q_lora_rank=1536,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, num_layers=layers, first_k_dense_replace=1,
+        intermediate_size=12288, moe_intermediate_size=1536,
+        n_routed_experts=160, experts_held=(0, 20), top_k=6, n_group=8,
+        topk_group=3, routed_scaling_factor=16.0, n_shared_experts=2,
+        dtype=jnp.bfloat16)
+
+
 def _serving_config(layers: int) -> ServingModelConfig:
     return ServingModelConfig(51200, 2048, 16, layers, max_position=1024,
                               dtype=jnp.bfloat16)
@@ -459,6 +546,12 @@ def main() -> int:
     _report("warm", leg_warm(
         _serving_config(WARM_LAYERS), spec_k=4, chunk_size=128, seed=1,
         **SERVE_TRAFFIC))
+
+    latent = _report("latent", leg_latent(
+        _latent_config(WARM_LAYERS), seed=3, page_size=64, row=1024,
+        doc_pages=32, max_new=8))
+    require(latent["route"] == ROUTES_ON_TPU["decode"],
+            f"latent: the paged route is {latent['route']}")
 
     if len(devices) >= 4:
         _report("train_mesh", leg_train(

@@ -86,6 +86,24 @@ def test_prefill_rungs_leg():
     json.dumps(out)
 
 
+def test_latent_leg():
+    from apex_tpu.serving import DeepseekV2Config
+
+    cfg = DeepseekV2Config(
+        vocab_size=96, hidden_size=32, num_heads=4, q_lora_rank=24,
+        kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8,
+        v_head_dim=8, num_layers=2, first_k_dense_replace=1,
+        intermediate_size=64, moe_intermediate_size=24, n_routed_experts=16,
+        experts_held=(0, 2), top_k=3, n_group=8, topk_group=3,
+        routed_scaling_factor=16.0, n_shared_experts=2, rope_original_max=32)
+    out = chip_smoke.leg_latent(cfg, seed=3, page_size=8, row=32,
+                                doc_pages=4, max_new=3)
+    assert out["route"] == "xla" and out["shared_tokens"] == 32
+    assert out["pool_width"] == 128
+    assert out["rel_l2"]["absorbed_vs_expanded"] < 1e-5
+    json.dumps(out)
+
+
 def test_warm_leg():
     out = chip_smoke.leg_warm(TOY_SERVE, spec_k=2, chunk_size=16, seed=1,
                               **TOY_TRAFFIC)

@@ -22,8 +22,9 @@ import pytest
 
 from apex_tpu.ops import (flash_attention, flash_attention_qkv,
                           flash_attention_qkv_route, flash_attention_route,
-                          flash_decode, flash_decode_route, layer_norm,
-                          routing_override)
+                          flash_decode, flash_decode_latent,
+                          flash_decode_latent_route, flash_decode_route,
+                          layer_norm, routing_override)
 
 SDS = jax.ShapeDtypeStruct
 BF16 = jnp.bfloat16
@@ -142,6 +143,28 @@ def test_paged_decode_lowers_at_the_cells_geometries(name):
     assert f'kernel_name = "{kernel}"' in calls[0]
     whole = "x".join(map(str, pool_shape))
     assert calls[0].count(f"tensor<{whole}x") == 2, calls[0][-600:]
+
+
+# the latent cell (benchmark/configs/deepseek-v2-serve-ep8-l6.json): 128
+# heads over one vector of 576 numbers, stored 640 wide; a decode step,
+# the chunk, the smoke's chunk
+@pytest.mark.parametrize("b, q_len", [(64, 1), (1, 512), (2, 8)])
+def test_latent_decode_lowers_at_the_cells_geometry(b, q_len):
+    q = SDS((b, q_len, 128, 640), BF16)
+    pool = SDS((6, 4608, 64, 640), BF16)
+    assert flash_decode_latent_route(q, pool) == "decode"
+
+    def fn(q, pool, pt, kl, start):
+        return flash_decode_latent(q, pool, pt, kl, v_dim=512,
+                                   scale=0.114721, layer=5, q_start=start)
+
+    calls = [line for line in _tpu_text(
+        fn, q, pool, SDS((b, 280), jnp.int32), SDS((b,), jnp.int32),
+        SDS((b,), jnp.int32)).splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1
+    assert 'kernel_name = "flash_decode_latent"' in calls[0]
+    # ONE operand is the pool, whole: key and value at once
+    assert calls[0].count("tensor<6x4608x64x640x") == 1, calls[0][-600:]
 
 
 # -- generic flash attention: block-skip routes with more than one block ----
