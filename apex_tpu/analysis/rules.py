@@ -70,6 +70,9 @@ HOT_PATH_FUNCTIONS: Dict[str, Set[str]] = {
     "apex_tpu/serving/engine.py": {
         "_decode_batch", "_prefill_request", "_step_body",
         "_step_phases",
+        # ISSUE 34: the landing of the launch in flight, the one place
+        # a decode step's tokens reach the host
+        "_land",
         # ISSUE 12: the speculative verify step, the chunked-prefill
         # step, and the draft-proposal loop run at every decode
         # boundary — same steady-state heat as _decode_batch

@@ -102,6 +102,17 @@ any profiler session and as records in the one in-memory ring;
 ``decode_step.step_ms`` and ``phase_ms`` are those records
 (docs/telemetry.md "Step phases").
 
+One launch in flight (ISSUE 34): the plain decode launch is pipelined
+one deep.  A step builds launch *n* from counts alone (a row's
+position, ``kv_len`` and page are ``seq_len + in_flight``), dispatches
+it with the token input put together on the device from launch
+*n − 1*'s unfetched output, and only then fetches and commits launch
+*n − 1* (:meth:`ServingEngine._land`), so the host's turn runs under
+the device's step.  First tokens are fetched where they always were;
+what needs landed tokens (a speculative boundary, preemption,
+snapshot, export, adoption) lands the launch first (docs/serving.md
+"One launch in flight").
+
 **Failure semantics (ISSUE 10).** The engine degrades instead of
 falling over: per-request deadlines shed/time out work that can no
 longer meet its SLO, a bounded submit queue rejects overload loudly,
@@ -120,7 +131,8 @@ from __future__ import annotations
 
 import bisect
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -183,6 +195,15 @@ def set_fault_hook(hook: Optional[Callable[[str, int], None]]):
 def _fault_point(event: str, info: int) -> None:
     if _FAULT_HOOK is not None:
         _FAULT_HOOK(event, info)
+
+
+class _Flight(NamedTuple):
+    """The decode launch in flight (ISSUE 34): dispatched, its tokens
+    and the block's counters still on the device."""
+
+    rows: List[Request]           # row i of the launch, in launch order
+    next_tok: Any                 # int32[max_batch], on the device
+    stats: Tuple                  # the block's counters, on the device
 
 
 class SimClock:
@@ -426,14 +447,8 @@ class ServingEngine:
         self.prefix_index = (
             PrefixIndex(self.cache, max_entries=self.prefix_entries)
             if prefix_sharing else None)
-        self.sched = ContinuousBatchingScheduler(
-            self.cache, max_batch=max_batch,
-            prefill_budget=self.prefill_budget,
-            max_position=self.max_context,
-            max_queue=max_queue, preempt_cap=preempt_cap,
-            chunk_size=self.chunk_size,
-            prefix_index=self.prefix_index)
         self.max_batch = max_batch
+        self.sched = self._new_scheduler(max_queue, preempt_cap)
         self.telemetry = telemetry
         self.clock = clock if clock is not None else time.monotonic
         self.shed_min_service_s = float(shed_min_service_s)
@@ -455,6 +470,18 @@ class ServingEngine:
         self.kv_import = bool(kv_import)
         self.recoveries = 0
         self._released_full = 0
+        #: the decode launch in flight, if any (ISSUE 34)
+        self._flight: Optional[_Flight] = None
+        #: what landed since the last ``engine.decode`` span closed: the
+        #: requests, a token each, and the counters of their launch
+        self._landed: List[int] = []
+        self._landed_stats: Dict[str, int] = {}
+        self._retired = 0             # requests retired, ever
+        # the token input of a launch with none in flight before it: an
+        # array of the type, shape and placement of a launch's own
+        # output, which is what every other launch takes (warmup()
+        # replaces it with one)
+        self._no_prev = jnp.zeros((max_batch,), jnp.int32)
         #: per request mid-prefill, its chunks' counters still on the device
         self._chunk_stats: Dict[int, list] = {}
         self.rejected: List[Request] = []
@@ -500,8 +527,15 @@ class ServingEngine:
 
         def _decode(params, *args):
             pools, rest, kw = carries(args)
+            # the token input is put together here, on the device: row
+            # i takes the previous launch's output at src[i], which the
+            # host has not fetched yet, or the host's token where
+            # src[i] < 0 (a row fresh from prefill)
+            tokens, prev, src, *rest = rest
+            tokens = jnp.where(src >= 0, prev[jnp.maximum(src, 0)], tokens)
             logits, *out = decoder.decode(
-                params, pools[0], pools[1], *rest, tp_axis=ax, **kw)
+                params, pools[0], pools[1], tokens, *rest, tp_axis=ax,
+                **kw)
             return (jnp.argmax(logits, axis=-1), *out)
 
         def _verify(params, *args):
@@ -626,7 +660,7 @@ class ServingEngine:
                  if self.kv_quant is not None else (pool, pool))
         outs = (r,) + pools
         _prefill = sm(_prefill, (pspec, r, r, r, r), (r, kv_row, kv_row))
-        _decode = sm(_decode, (pspec,) + pools + (r,) * 4, outs)
+        _decode = sm(_decode, (pspec,) + pools + (r,) * 6, outs)
         _verify = sm(_verify, (pspec,) + pools + (r,) * 6, outs)
         _chunk = sm(_chunk, (pspec,) + pools + (r,) * 6, outs)
         return _prefill, _decode, _verify, _chunk
@@ -662,6 +696,25 @@ class ServingEngine:
                     page_size)),
                 **geometry)
         return cache
+
+    def _new_scheduler(self, max_queue: Optional[int],
+                       preempt_cap: Optional[int]
+                       ) -> ContinuousBatchingScheduler:
+        """The scheduler over the cache as it stands; ``__init__`` and
+        :meth:`recover` build the same.  It keeps chunking (ISSUE 12): a
+        chunk-less rebuild would strand any live request whose context
+        exceeds the prefill row — schedule_prefill could never re-admit
+        it, and FIFO admission would starve everything queued behind it
+        (review-found, pinned)."""
+        sched = ContinuousBatchingScheduler(
+            self.cache, max_batch=self.max_batch,
+            prefill_budget=self.prefill_budget,
+            max_position=self.max_context,
+            max_queue=max_queue, preempt_cap=preempt_cap,
+            chunk_size=self.chunk_size,
+            prefix_index=self.prefix_index)
+        sched.land = self._land_and_retire
+        return sched
 
     @property
     def max_context(self) -> int:
@@ -745,8 +798,9 @@ class ServingEngine:
         row = sds((1, S), i32)
         out = {
             "prefill": (params, row, row, row, sds((), i32)),
-            "decode": ((params,) + pools
-                       + (sds((b,), i32), sds((b,), i32)) + tables(b)),
+            # tokens, the previous launch's, their source rows, positions
+            "decode": ((params,) + pools + (sds((b,), i32),) * 4
+                       + tables(b)),
         }
         if self._verify_fn is not None:
             q = sds((b, self.spec_k + 1), i32)
@@ -873,12 +927,19 @@ class ServingEngine:
             return (jnp.zeros((rows, wpool.max_pages_per_request),
                               jnp.int32), jnp.zeros((rows,), jnp.int32))
 
-        out = self._decode_fn(
-            self.params, *self._pool_state(),
-            jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
-            jnp.zeros((b, p_max), jnp.int32), jnp.ones((b,), jnp.int32),
-            *tables(b))
-        self._stats(self._bind_pools(out[1:]))
+        # twice: the second launch takes the first's tokens as its
+        # token input, as every launch of the serving loop takes the
+        # one before it (ISSUE 34), and the last leaves the array that
+        # stands in where no launch went before
+        for _ in range(2):
+            out = self._decode_fn(
+                self.params, *self._pool_state(),
+                jnp.zeros((b,), jnp.int32), self._no_prev,
+                jnp.full((b,), -1, jnp.int32), jnp.zeros((b,), jnp.int32),
+                jnp.zeros((b, p_max), jnp.int32), jnp.ones((b,), jnp.int32),
+                *tables(b))
+            self._no_prev = out[0]
+            self._stats(self._bind_pools(out[1:]))
         if self._verify_fn is not None:
             qw = self.spec_k + 1
             zq = jnp.zeros((b, qw), jnp.int32)
@@ -1045,11 +1106,19 @@ class ServingEngine:
                     f"{what} would write shared page {int(p)} "
                     "(refcount > 1) — copy-on-write missing")
 
-    def _decode_batch(self, rows: List[Request]) -> Dict[str, int]:
-        """One decode step for ``rows`` (≤ max_batch), idle-padded to
-        the fixed batch width.  Returns the block's counters of the
-        launch, by name (none for a GPT)."""
+    def _decode_batch(self, rows: List[Request]) -> int:
+        """Launch one decode step for ``rows`` (≤ max_batch, idle-padded
+        to the fixed batch width), THEN land the launch before it
+        (ISSUE 34): build from host state alone (a row's position,
+        ``kv_len`` and page follow from ``seq_len + in_flight``),
+        dispatch with the token input put together on the device from
+        the previous launch's unfetched output, and only then fetch and
+        commit that previous launch (:meth:`_land`).  The device queue
+        holds this launch when the previous one ends; the host's turn
+        runs under the device's step.  Returns 1 if a launch was in
+        flight when this one was dispatched, else 0."""
         _fault_point("decode", self.decode_steps)
+        flight = self._flight
         with phase("decode.build"):
             # opt-in read-back validation: the pages this step is about
             # to attend over must still match their recorded CRCs
@@ -1057,33 +1126,72 @@ class ServingEngine:
             b = self.max_batch
             ps = self.cache.page_size
             tokens = np.zeros((b,), np.int32)
+            src = np.full((b,), -1, np.int32)
             positions = np.zeros((b,), np.int32)
             kv_len = np.ones((b,), np.int32)
             written: List[int] = []   # the page each row's new K/V lands in
+            was = ({req.rid: i for i, req in enumerate(flight.rows)}
+                   if flight is not None else {})
             for i, req in enumerate(rows):
-                tokens[i] = req.generated[-1]
-                positions[i] = req.seq_len - 1
-                kv_len[i] = req.seq_len
-                written.append(req.pages[(req.seq_len - 1) // ps])
+                if req.in_flight:
+                    src[i] = was[req.rid]   # its last token: on the device
+                else:
+                    tokens[i] = req.generated[-1]
+                n = req.seq_len + req.in_flight
+                positions[i] = n - 1
+                kv_len[i] = n
+                written.append(req.pages[(n - 1) // ps])
             self._check_private(written, "decode append")
             page_table, wtables = self._tables(rows, b)
         with phase("decode.dispatch"):
             out = self._decode_fn(
-                self.params, *self._pool_state(),
-                jnp.asarray(tokens), jnp.asarray(positions), page_table,
+                self.params, *self._pool_state(), jnp.asarray(tokens),
+                self._no_prev if flight is None else flight.next_tok,
+                jnp.asarray(src), jnp.asarray(positions), page_table,
                 jnp.asarray(kv_len), *wtables)
-            next_tok = out[0]
             stats = self._bind_pools(out[1:])
+            # (opt-in) read back what the launch wrote, so the records
+            # match the pool as every later launch finds it
+            self.cache.refresh_page_crcs(written)
+        self._land()
+        self._flight = _Flight(rows, out[0], stats)
+        self.sched.in_flight = True
+        for req, n in zip(rows, kv_len.tolist()):
+            # the K/V is in the pool for every launch that follows
+            req.kv_len = n
+            req.in_flight = 1
+        return int(flight is not None)
+
+    def _land(self) -> None:
+        """Bring the launch in flight to the host, if there is one:
+        ``decode.fetch`` is the wait for what is left of it, and
+        ``decode.commit`` appends each row's token to ``generated``.  A
+        token whose request finished meanwhile is dropped: the one
+        launched after an EOS that had not landed yet (its K/V went to
+        a page the request then still owned), or a row that timed out.
+        What landed waits in ``_landed`` for the ``engine.decode`` span
+        that reports it."""
+        flight, self._flight = self._flight, None
+        self.sched.in_flight = False
+        if flight is None:
+            return
         with phase("decode.fetch"):
             # the wait for the device
-            next_tok = np.asarray(next_tok)
-            stats = self._stats(stats)
+            next_tok = np.asarray(flight.next_tok)
+            self._landed_stats = self._stats(flight.stats)
         with phase("decode.commit"):
-            self.cache.refresh_page_crcs(written)
-            for i, req in enumerate(rows):
-                req.kv_len = req.seq_len
-                req.generated.append(int(next_tok[i]))
-        return stats
+            for req, tok in zip(flight.rows, next_tok.tolist()):
+                req.in_flight = 0
+                if req.state == RUNNING and not req.done:
+                    req.generated.append(tok)
+                    self._landed.append(req.rid)
+
+    def _land_and_retire(self) -> None:
+        """:meth:`_land`, and retire what the landed tokens finished:
+        what the scheduler calls before it preempts a row that has a
+        token in flight."""
+        self._land()
+        self._retire(self.clock())
 
     def _verify_batch(self, rows: List[Request],
                       drafts: Dict[int, List[int]]
@@ -1283,6 +1391,7 @@ class ServingEngine:
         self._released_full += sum(
             len(r.pages) for r in self.sched.running if r.done)
         done = self.sched.retire_finished(now)
+        self._retired += len(done)
         for req in done:
             if self.proposer is not None:
                 self.proposer.release(req.rid)
@@ -1368,7 +1477,10 @@ class ServingEngine:
 
     def step(self) -> bool:
         """One engine iteration: expire deadlines → retire →
-        admit+prefill → retire → grow/preempt → decode.  Returns True
+        admit+prefill → retire → grow/preempt → launch decode step
+        *n* → land decode step *n − 1* (ISSUE 34: one launch stays in
+        flight, so a token is in ``generated`` one step after its
+        launch; docs/serving.md "One launch in flight").  Returns True
         if any work was done.  With a ``watchdog``, the whole step
         (prefill + decode device work included) runs under an armed
         deadline, so a wedged device step escalates instead of
@@ -1400,8 +1512,11 @@ class ServingEngine:
     def _step_body(self) -> bool:
         """One step as phases (docs/telemetry.md, "Step phases"):
         ``engine.step`` around one ``engine.prefill`` per admitted
-        request, ``engine.grow`` and ``engine.decode``; retirement and
-        admission are its own time and its counters."""
+        request, ``engine.grow`` and ``engine.decode`` (``decode.build``
+        and ``decode.dispatch`` of this step's launch, then
+        ``decode.fetch`` and ``decode.commit`` of the launch before
+        it); retirement and admission are its own time and its
+        counters."""
         cpu0_ns = time.process_time_ns()
         with phase("engine.step", step=self.steps) as span:
             progress = self._step_phases(span.attrs)
@@ -1441,10 +1556,14 @@ class ServingEngine:
         counters["held_uniform"] = counters["held_full"]
 
     def _step_phases(self, counters: Dict[str, int]) -> bool:
+        if self.proposer is not None:
+            # a speculative boundary reads committed tokens (the
+            # proposer) and commits by their values: nothing runs ahead
+            self._land()
+        retired0 = self._retired
         now = self.clock()
         progress = self._expire(now)
-        done = self._retire(now)
-        progress = bool(done) or progress
+        progress = bool(self._retire(now)) or progress
         if self.chunk_size is not None:
             chunk_plan, admitted = self.sched.schedule_prefill()
         else:
@@ -1487,9 +1606,7 @@ class ServingEngine:
             self._chunk_step(req, start, n)
             progress = True
         # a request whose budget was a single token is done at prefill
-        done_at_prefill = self._retire(now)
-        progress = bool(done_at_prefill) or progress
-        counters["retired"] = len(done) + len(done_at_prefill)
+        progress = bool(self._retire(now)) or progress
         self._held_pages(counters)
         if self.prefix_index is not None:
             counters["prefix_entries"] = len(self.prefix_index)
@@ -1509,31 +1626,48 @@ class ServingEngine:
                     extra={rid: len(d) for rid, d in drafts.items()}
                     or None)
         counters["evicted"] = len(evicted)
+        # growth may have landed the launch in flight for a preemption,
+        # and retired what that finished
+        counters["retired"] = self._retired - retired0
         # a prefill_only engine never decodes: finished prefills hold
-        # their first token and wait for export_request to ship them
+        # their first token and wait for export_request to ship them.
+        # A row whose budget the token in flight spends is not launched
+        # again: it waits for that token and retires on it
         rows = ([] if self.prefill_only else
-                [r for r in self.sched.running if r.prefill_pos is None])
-        if rows:
+                [r for r in self.sched.running if r.launchable])
+        if rows or self._flight is not None or self._landed:
             spec_fields = {}
-            with phase("engine.decode", rows=len(rows),
-                       rids=tuple(r.rid for r in rows)) as span:
+            with phase("engine.decode", rows=len(rows)) as span:
+                # rids: the requests whose token LANDED in this span;
+                # committed: how many each, where that is not one
+                committed: Optional[Tuple[int, ...]] = None
+                in_flight = 0
                 if any(r.rid in drafts for r in rows):
-                    drafted, accepted, committed = self._verify_batch(
+                    drafted, accepted, per_row = self._verify_batch(
                         rows, drafts)
-                    new_tokens = sum(committed)
-                    # tokens per row, where that is not one each
-                    span.attrs["committed"] = tuple(committed)
+                    committed = (1,) * len(self._landed) + tuple(per_row)
+                    self._landed.extend(r.rid for r in rows)
+                    span.attrs["committed"] = committed
                     spec_fields = {"spec_verify": True,
                                    "spec_drafted": drafted,
                                    "spec_accepted": accepted}
-                else:
+                elif rows:
                     # every draft came back empty (or speculation is
                     # off): the plain q_len=1 decode executable is
-                    # cheaper
-                    span.attrs.update(self._decode_batch(rows))
-                    new_tokens = len(rows)
-            self._release_windows(rows)
-            self.decode_steps += 1
+                    # cheaper, and runs one launch ahead of the host
+                    in_flight = self._decode_batch(rows)
+                else:
+                    # nothing to launch: the launch in flight is all
+                    # that is left, and its tokens end the step
+                    self._land()
+                new_tokens = (len(self._landed) if committed is None
+                              else sum(committed))
+                span.attrs.update(self._landed_stats, in_flight=in_flight,
+                                  rids=tuple(self._landed))
+                self._landed, self._landed_stats = [], {}
+            if rows:
+                self._release_windows(rows)
+                self.decode_steps += 1
             if self.telemetry is not None:
                 if self.prefix_index is not None:
                     # pages with refcount > 1 right now — the live
@@ -1546,9 +1680,10 @@ class ServingEngine:
                 # request_admit carries preemptions > 0).  step_ms and
                 # phase_ms are the engine.decode phase and its children
                 # as the ring holds them: one measurement for the
-                # operator's stream and the benchmark's readers
+                # operator's stream and the benchmark's readers.
+                # batch is what was launched, new_tokens what landed
                 self._emit("decode_step", batch=len(rows),
-                           new_tokens=new_tokens,
+                           new_tokens=new_tokens, in_flight=in_flight,
                            pool_used=self.cache.pages_used,
                            pool_pages=self.cache.num_pages - 1,
                            evicted=[r.rid for r in evicted],
@@ -1574,7 +1709,10 @@ class ServingEngine:
         checkpointed — the snapshot is a few KB of tokens, not
         gigabytes of HBM.  ``restore`` re-prefills live requests
         through that existing path.  JSON-serializable by construction
-        (pinned in the round-trip test)."""
+        (pinned in the round-trip test).  The launch in flight lands
+        first: the capture holds every token the device has produced."""
+        self._land()
+
         def rec(req: Request, was_running: bool) -> Dict[str, Any]:
             return {
                 "rid": req.rid,
@@ -1608,6 +1746,7 @@ class ServingEngine:
         preemption path, so the continued token streams are bitwise
         the uninterrupted run's.  Returns the restored request
         handles."""
+        self._land()
         if self.sched.running or self.sched.waiting:
             raise RuntimeError(
                 "restore into a busy engine — serving state would be "
@@ -1679,6 +1818,7 @@ class ServingEngine:
         as restore/recover do); already-done records retire
         immediately.  Returns this engine's new request handles — the
         source replica's old handles are dead."""
+        self._land()
         adopted: List[Request] = []
         for r in records:
             req = Request(
@@ -1742,6 +1882,7 @@ class ServingEngine:
         replica); the caller's handle on the DECODE replica is the
         live one after adoption."""
         self._no_window_pages("export_request")
+        self._land()
         req = next((r for r in self.sched.running if r.rid == rid), None)
         if req is None:
             raise ValueError(f"export_request: rid {rid} is not running")
@@ -1804,6 +1945,7 @@ class ServingEngine:
         :class:`AdmissionRefused` — retryable, leaving the engine
         untouched."""
         self._no_window_pages("adopt_prefilled")
+        self._land()
         kv_len = int(kv_len)
         req = Request(
             rid=int(record["rid"]), prompt=list(record["prompt"]),
@@ -1834,10 +1976,10 @@ class ServingEngine:
                     f"adopt_prefilled: rid {req.rid} page {i} failed "
                     "CRC verification — corrupted in flight, refusing "
                     "to adopt")
-        if len(self.sched.running) >= self.max_batch:
+        if self.sched.slots_used >= self.max_batch:
             raise AdmissionRefused(
                 f"adopt_prefilled: decode batch full "
-                f"({len(self.sched.running)}/{self.max_batch})")
+                f"({self.sched.slots_used}/{self.max_batch})")
         try:
             pages = self.cache.allocate(need, req.rid)
         except PagePoolExhausted as e:
@@ -1894,6 +2036,12 @@ class ServingEngine:
         an uninterrupted control).  The caller's :class:`Request`
         handles stay live — this is the in-process twin of
         :meth:`snapshot`/:meth:`restore`."""
+        # a launch in flight is lost with the pool: its rows decode
+        # that token again from what they had committed
+        if self._flight is not None:
+            for req in self._flight.rows:
+                req.in_flight = 0
+            self._flight = None
         running = list(self.sched.running)
         waiting = list(self.sched.waiting)
         old = self.cache
@@ -1912,19 +2060,8 @@ class ServingEngine:
             # (warm-cache opportunism is rebuildable, like KV)
             self.prefix_index = PrefixIndex(
                 self.cache, max_entries=self.prefix_entries)
-        sched = ContinuousBatchingScheduler(
-            self.cache, max_batch=self.max_batch,
-            prefill_budget=self.prefill_budget,
-            max_position=self.max_context,
-            max_queue=self.sched.max_queue,
-            preempt_cap=self.sched.preempt_cap,
-            # the rebuilt scheduler must keep chunking (ISSUE 12): a
-            # chunk-less rebuild would strand any live request whose
-            # context exceeds the prefill row — schedule_prefill could
-            # never re-admit it, and FIFO admission would starve
-            # everything queued behind it (review-found, pinned)
-            chunk_size=self.chunk_size,
-            prefix_index=self.prefix_index)
+        sched = self._new_scheduler(self.sched.max_queue,
+                                    self.sched.preempt_cap)
         sched.finished = self.sched.finished   # history survives
         self.sched = sched
         for req in running:
@@ -2012,6 +2149,7 @@ class ServingEngine:
             if raise_on_stall:
                 raise RuntimeError(
                     f"engine did not drain in {max_steps} steps")
+        self._land()
         self._retire(self.clock())
         return self.sched.finished
 
@@ -2064,5 +2202,6 @@ class ServingEngine:
             if raise_on_stall:
                 raise RuntimeError(
                     f"trace did not drain in {max_steps} steps")
+        self._land()
         self._retire(self.clock())
         return self.sched.finished

@@ -28,7 +28,9 @@ so a seeded arrival trace replays bit-identically):
   token.  First failure stops admission for this step (no out-of-order
   admission — fairness over packing efficiency).
 * **growth**: before each decode step every running request crossing a
-  page boundary gets one page.
+  page boundary gets one page.  The engine keeps one decode launch in
+  flight (ISSUE 34), so a row's next position is ``seq_len +
+  in_flight - 1``: the token in flight is counted, never read.
 * **preemption**: when growth (or nothing-running admission) finds the
   pool empty, the MOST-RECENTLY-admitted running request is evicted —
   its pages are freed, its generated-so-far TOKENS are kept, and it
@@ -40,7 +42,9 @@ so a seeded arrival trace replays bit-identically):
   same computation, not byte-for-byte the same buffers —
   docs/serving.md "Preemption").
 * **retirement**: EOS or ``max_new_tokens`` reached → pages freed (and
-  immediately reusable), terminal state recorded.
+  immediately reusable), terminal state recorded; only on tokens that
+  have landed.  A row whose last token is in flight
+  (:attr:`Request.spent`) holds its pages and no batch slot.
 * **two page lifetimes** (ISSUE 29): where the cache has a
   ``window_pool`` a request holds pages of both kinds and every
   decision above counts both.  The full pool is reserved for the whole
@@ -79,7 +83,7 @@ from __future__ import annotations
 
 import dataclasses
 from collections import deque
-from typing import Deque, Dict, List, Optional
+from typing import Callable, Deque, Dict, List, Optional
 
 from apex_tpu.serving.kv_cache import (
     PagedKVCache,
@@ -121,6 +125,11 @@ class Request:
     # such a pool admits it
     window: Optional[WindowPages] = None
     kv_len: int = 0               # tokens whose K/V sit in the pool
+    # tokens launched and not yet on the host (ISSUE 34): 1 while the
+    # decode launch that holds this row is in flight, else 0.  The
+    # row's next position, ``kv_len`` and page follow from ``seq_len +
+    # in_flight``; ``generated`` holds only what has landed.
+    in_flight: int = 0
     # chunked-prefill cursor (ISSUE 12): tokens of the admission
     # context already computed into pages; None = not mid-chunk (the
     # whole-row path, or prefill complete).  DELIBERATELY not part of
@@ -195,6 +204,23 @@ class Request:
             return True
         return len(self.generated) >= self.max_new_tokens
 
+    @property
+    def spent(self) -> bool:
+        """The token in flight is the last its budget allows: the row
+        is not launched again, it only waits for that token to land."""
+        return (self.in_flight > 0 and len(self.generated)
+                + self.in_flight >= self.max_new_tokens)
+
+    @property
+    def launchable(self) -> bool:
+        """May ride the next decode launch: its prefill is complete, no
+        landed token finished it, and the tokens it has, on the host or
+        in flight, leave its budget room for one more.  (An EOS still
+        in flight cannot be seen from here: such a row is launched once
+        more, and that token is dropped when it lands.)"""
+        return (self.prefill_pos is None and not self.done
+                and not self.spent)
+
 
 class ContinuousBatchingScheduler:
     """Admission/growth/preemption/retirement over a shared page pool."""
@@ -242,6 +268,12 @@ class ContinuousBatchingScheduler:
         self.waiting: Deque[Request] = deque()
         self.running: List[Request] = []   # admission order
         self.finished: List[Request] = []
+        # the engine's decode launch in flight (ISSUE 34): the engine
+        # says here whether it has one, and gives ``land`` to bring its
+        # tokens to the host (and retire what they finish) before a row
+        # of it is preempted
+        self.in_flight = False
+        self.land: Optional[Callable[[], None]] = None
 
     # -- intake ----------------------------------------------------------
 
@@ -352,8 +384,8 @@ class ContinuousBatchingScheduler:
             # a chunk planned before a later one's growth evicted it
             chunks = [c for c in chunks if c[0].state == RUNNING]
         admitted: List[Request] = []
-        while self.waiting and \
-                len(self.running) + len(admitted) < self.max_batch:
+        slots = self.slots_used
+        while self.waiting and slots + len(admitted) < self.max_batch:
             req = self.waiting[0]
             ctx = req.seq_len
             # prefix sharing: the longest indexed prefix of the context
@@ -470,7 +502,8 @@ class ContinuousBatchingScheduler:
     def preempt_one(self) -> Optional[Request]:
         """Evict the most-recently-admitted running request: free its
         pages, keep its tokens, requeue it at the FRONT of the waiting
-        queue.  Returns the victim (or None if nothing runs).
+        queue.  Returns the victim (or None if nothing runs, or if
+        landing the launch in flight retired a request instead).
 
         Anti-livelock aging (ISSUE 10): a request already preempted
         ``preempt_cap`` times is skipped — the victim is the newest
@@ -488,6 +521,15 @@ class ContinuousBatchingScheduler:
                     break
         if victim is None:
             victim = self.running[-1]
+        if victim.in_flight and self.land is not None:
+            # its newest token is still on the device: land the launch
+            # first, so the token is kept.  Where that finished a
+            # request, its pages are back and nobody is evicted yet:
+            # the caller tries again
+            n = len(self.running)
+            self.land()
+            if len(self.running) < n:
+                return None
         self.running.remove(victim)
         self._release(victim)
         victim.kv_len = 0
@@ -524,7 +566,9 @@ class ContinuousBatchingScheduler:
                 self.wpool.grow(req.window, first_query, end, req.rid)
                 break
             except PagePoolExhausted:
-                evicted.append(self.preempt_one())
+                victim = self.preempt_one()
+                if victim is not None:
+                    evicted.append(victim)
         return evicted
 
     def slide_windows(self, reqs) -> int:
@@ -548,19 +592,22 @@ class ContinuousBatchingScheduler:
         at positions ``seq_len .. seq_len + draft - 1``, so drafted
         requests grow to ``pages_needed(seq_len + draft)`` here and
         the engine rolls the rejected tail back afterwards
-        (:meth:`PagedKVCache.free_tail`)."""
+        (:meth:`PagedKVCache.free_tail`).
+
+        A row's next token goes to position ``seq_len + in_flight - 1``
+        (ISSUE 34: the token in flight is counted, its value is not
+        needed); a row that will not be launched (mid-prefill, or its
+        budget spent by the token in flight) takes nothing."""
         evicted: List[Request] = []
         for req in list(self.running):
             if req not in self.running:
                 continue  # evicted while growing an earlier request
-            while req in self.running:
-                want = req.seq_len + (extra.get(req.rid, 0)
-                                      if extra else 0)
+            while req in self.running and req.launchable:
+                query = req.seq_len + req.in_flight - 1
+                want = query + 1 + (extra.get(req.rid, 0) if extra else 0)
                 need_pages = self.cache.pages_needed(want)
                 if len(req.pages) >= need_pages:
-                    if req.prefill_pos is None:
-                        evicted.extend(self._grow_window(
-                            req, req.seq_len - 1, want))
+                    evicted.extend(self._grow_window(req, query, want))
                     break
                 try:
                     req.pages.extend(
@@ -578,8 +625,8 @@ class ContinuousBatchingScheduler:
                     # newest admission left): then the loop's membership
                     # check ends its growth and it waits its turn
                     victim = self.preempt_one()
-                    assert victim is not None  # self.running non-empty
-                    evicted.append(victim)
+                    if victim is not None:
+                        evicted.append(victim)
         return evicted
 
     # -- deadlines -------------------------------------------------------
@@ -649,5 +696,16 @@ class ContinuousBatchingScheduler:
         return done
 
     @property
+    def slots_used(self) -> int:
+        """Batch slots taken: the running requests, less those that
+        only wait for their last token to land (:attr:`Request.spent`).
+        Such a row rides no further launch, so an admission takes its
+        slot a step before it retires; its pages it keeps until then."""
+        return sum(1 for r in self.running if not r.spent)
+
+    @property
     def idle(self) -> bool:
-        return not self.waiting and not self.running
+        """Nothing queued, nothing running and no launch in flight: a
+        driver that sleeps while the engine is idle leaves no token on
+        the device."""
+        return not (self.waiting or self.running or self.in_flight)

@@ -232,6 +232,11 @@ EVENT_FIELDS: Dict[str, Dict[str, FieldSpec]] = {
         # out of PHASE_RING, so phase_ms sums to no more than step_ms
         "step_ms": opt(*NUMBER),
         "phase_ms": opt(dict),
+        # ISSUE 34: 1 when this step's launch was dispatched before the
+        # tokens of the one before it were fetched (the launch ran
+        # ahead of the host), else 0; batch is what was launched,
+        # new_tokens what LANDED in the step
+        "in_flight": opt(int),
         # speculative verify boundaries (ISSUE 12): present only when
         # the step ran the draft–verify executable.  spec_verify is a
         # REAL bool; spec_drafted/spec_accepted count draft tokens

@@ -803,3 +803,281 @@ class TestServingEngine:
             eng.submit([1], 0)
         with pytest.raises(ValueError):
             eng.submit([], 4)
+
+
+# ---------------------------------------------------------------------------
+# One decode launch in flight (ISSUE 34): step n+1 is built and
+# dispatched while step n runs, and step n's tokens are fetched after
+# it.  Counts and order on the CPU, never times.
+# ---------------------------------------------------------------------------
+
+
+def _engine(params, **kw):
+    kw.setdefault("num_pages", 64)
+    kw.setdefault("max_batch", 4)
+    kw.setdefault("clock", SimClock())
+    return ServingEngine(CFG, params, page_size=8,
+                         prefill_budget=CFG.max_position, **kw)
+
+
+def _decode_records():
+    from apex_tpu.telemetry import PHASE_RING
+    return [r for r in PHASE_RING.snapshot() if r.name == "engine.decode"]
+
+
+def _step_until_in_flight(eng, req, generated):
+    """Step until ``req`` holds ``generated`` landed tokens and one more
+    in flight."""
+    while not (len(req.generated) == generated and req.in_flight):
+        assert len(req.generated) <= generated
+        eng.step()
+
+
+class TestLaunchInFlight:
+    @pytest.mark.parametrize("finish", ["length", "eos"])
+    def test_streams_are_bitwise_sequential_with_rows_finishing_mid_batch(
+            self, serving_params, finish):
+        # budgets (or an EOS) that end rows in the middle of a batch
+        # while the others run on
+        prompts = _prompts(4)
+        budgets = [3, 12, 7, 1]
+        free, _ = _run_engine(serving_params, prompts, max_new=12)
+        eos = free[1][5] if finish == "eos" else None
+
+        def run(ps, bs, max_batch):
+            eng = _engine(serving_params, max_batch=max_batch)
+            reqs = [eng.submit(p, b, eos_id=eos) for p, b in zip(ps, bs)]
+            eng.run()
+            return reqs, eng
+
+        reqs, eng = run(prompts, budgets, 4)
+        alone = [run([p], [b], 1)[0][0] for p, b in zip(prompts, budgets)]
+        assert [r.generated for r in reqs] == [r.generated for r in alone]
+        assert [r.finish_reason for r in reqs] == \
+            [r.finish_reason for r in alone]
+        for r, b in zip(reqs, budgets):
+            if r.finish_reason == "eos":
+                assert r.generated.index(eos) == len(r.generated) - 1
+            else:
+                assert len(r.generated) == b
+            assert r.in_flight == 0 and not r.pages
+        if finish == "eos":
+            assert reqs[1].finish_reason == "eos"
+            assert len(reqs[1].generated) < budgets[1]
+        assert eng.cache.pages_used == 0
+        assert eng._flight is None and not eng.sched.in_flight
+
+    def test_the_token_launched_after_an_unlanded_eos_is_dropped(
+            self, serving_params):
+        from apex_tpu.telemetry import PHASE_RING
+
+        free, _ = _run_engine(serving_params, _prompts(1), max_new=12)
+        eos = free[0][4]
+        n = free[0].index(eos) + 1           # tokens up to the EOS
+        PHASE_RING.clear()
+        eng = _engine(serving_params)
+        req = eng.submit(_prompts(1)[0], 12, eos_id=eos)
+        eng.run()
+        assert req.generated == free[0][:n] and req.finish_reason == "eos"
+        decodes = _decode_records()
+        # the prefill gave one token, n - 1 launches the rest, and one
+        # more went out before the EOS was on the host: its token never
+        # entered `generated`
+        assert sum(r.attrs["rows"] for r in decodes) == n
+        assert sum(len(r.attrs["rids"]) for r in decodes) == n - 1
+        assert eng.cache.pages_used == 0 and not req.pages
+
+    def test_in_flight_is_1_on_every_launch_but_the_first(
+            self, serving_params):
+        from apex_tpu import telemetry as tel
+        from apex_tpu.telemetry import PHASE_RING
+
+        PHASE_RING.clear()
+        mem = tel.MemorySink()
+        eng = _engine(serving_params,
+                      telemetry=tel.TelemetryBus(run_id="fl", sinks=[mem]))
+        for p in _prompts(4):
+            eng.submit(p, 9)
+        eng.run()
+        launches = [r.attrs for r in _decode_records() if r.attrs["rows"]]
+        assert len(launches) == eng.decode_steps == 8
+        assert all(a["rows"] == 4 for a in launches)
+        assert [a["in_flight"] for a in launches] == [0] + [1] * 7
+        events = [e for e in mem.events if e["type"] == "decode_step"]
+        for ev in events:
+            tel.validate_event(ev)
+        assert [e["in_flight"] for e in events if e["batch"]] == \
+            [0] + [1] * 7
+        # a forced landing empties the queue: the next launch is a
+        # first again
+        eng = _engine(serving_params)
+        req = eng.submit(_prompts(1)[0], 9)
+        PHASE_RING.clear()
+        _step_until_in_flight(eng, req, 3)
+        eng.snapshot()
+        assert req.in_flight == 0 and len(req.generated) == 4
+        eng.run()
+        flags = [r.attrs["in_flight"] for r in _decode_records()
+                 if r.attrs["rows"]]
+        assert flags == [0, 1, 1, 0, 1, 1, 1, 1]
+
+    def test_first_token_and_finish_are_stamped_at_landing(
+            self, serving_params):
+        eng = _engine(serving_params)
+        req = eng.submit(_prompts(1)[0], 3)
+        eng.step()                  # t=0: prefill, launch of token 2
+        assert req.first_token_t == 0.0 and req.stream_t == 0.0
+        assert len(req.generated) == 1 and req.in_flight == 1
+        eng.step()                  # t=1: launch of token 3, token 2 lands
+        assert len(req.generated) == 2 and req.in_flight == 1
+        assert req.spent and not req.done and req.finish_t is None
+        eng.step()                  # t=2: nothing to launch, token 3 lands
+        assert len(req.generated) == 3 and req.in_flight == 0
+        assert req.done and req.finish_t is None    # not yet retired
+        assert eng.sched.running == [req] and not eng.sched.idle
+        eng.step()                  # t=3: retired, on the host's clock
+        assert req.finish_t == 3.0 and req.finish_reason == "length"
+        assert eng.sched.idle
+
+    def test_idle_is_false_while_a_launch_is_in_flight(self, serving_params):
+        eng = _engine(serving_params)
+        # a deadline that dies with the request's second token in
+        # flight: nothing waits, nothing runs, and the launch is still
+        # to be landed
+        req = eng.submit(_prompts(1)[0], 8, deadline_s=0.5)
+        eng.step()
+        assert req.in_flight == 1
+        eng.step()
+        assert req.finish_reason == "timeout" and not req.pages
+        assert not eng.sched.running and not eng.sched.waiting
+        assert eng._flight is None and eng.sched.idle
+        assert len(req.generated) == 1      # the token in flight: dropped
+        eng = _engine(serving_params)
+        req = eng.submit(_prompts(1)[0], 8)
+        eng.step()
+        eng.sched.running.remove(req)       # as a timeout leaves it
+        assert eng.sched.in_flight and not eng.sched.idle
+
+    @pytest.mark.parametrize("driver", ["run", "run_cut", "serve"])
+    def test_the_drivers_return_with_nothing_in_flight(self, serving_params,
+                                                       driver):
+        eng = _engine(serving_params)
+        if driver == "serve":
+            trace = poisson_trace(3, 5, rate=2.0, prompt_len=(4, 9),
+                                  max_new=(2, 6), vocab_size=CFG.vocab_size)
+            fin = eng.serve(trace)
+            assert len(fin) == 5
+        else:
+            reqs = [eng.submit(p, 6) for p in _prompts(3)]
+            if driver == "run_cut":
+                # cut short with a launch in flight: it lands, nothing
+                # is left on the device
+                eng.run(max_steps=3, raise_on_stall=False)
+                assert all(len(r.generated) == 4 for r in reqs)
+            else:
+                eng.run()
+        assert eng._flight is None and not eng.sched.in_flight
+        assert all(r.in_flight == 0 for r in
+                   list(eng.sched.running) + eng.sched.finished)
+
+    @pytest.mark.parametrize("what", ["snapshot", "recover", "export",
+                                      "preempt", "adopt"])
+    def test_taken_with_a_launch_in_flight_the_stream_is_bitwise(
+            self, serving_params, what):
+        prompts = _prompts(3)
+        control, _ = _run_engine(serving_params, prompts, max_new=10)
+        kw = dict(kv_import=True) if what == "export" else {}
+        eng = _engine(serving_params, **kw)
+        reqs = [eng.submit(p, 10) for p in prompts]
+        _step_until_in_flight(eng, reqs[0], 4)
+        assert all(r.in_flight == 1 for r in reqs)
+        if what == "snapshot":
+            snap = eng.snapshot()           # lands the launch first
+            assert all(len(r["generated"]) == 5 for r in snap["requests"])
+            eng = _engine(serving_params)
+            reqs = eng.restore(snap)
+            eng.run()
+        elif what == "recover":
+            eng.recover(cause="device_loss")    # the launch is lost
+            assert all(len(r.generated) == 4 and r.in_flight == 0
+                       for r in reqs)
+            assert eng._flight is None and not eng.sched.in_flight
+            eng.run()
+        elif what == "export":
+            record, pages, kv_len = eng.export_request(reqs[1].rid)
+            assert len(record["generated"]) == 5 and kv_len == \
+                len(prompts[1]) + 4
+            dst = _engine(serving_params, kv_import=True)
+            dst.warmup()
+            reqs[1] = dst.adopt_prefilled(record, pages, kv_len)
+            dst.run()
+            eng.run()
+        elif what == "preempt":
+            victim = eng.sched.preempt_one()
+            assert victim is reqs[2] and victim.preemptions == 1
+            # its token landed before its pages went
+            assert len(victim.generated) == 5 and victim.in_flight == 0
+            assert all(len(r.generated) == 5 for r in reqs)
+            eng.run()
+        else:
+            other = _engine(serving_params)
+            moved = other.submit(prompts[0], 10)
+            other.step()
+            eng.adopt([r for r in other.snapshot()["requests"]
+                       if r["rid"] == moved.rid
+                       and not r.update(rid=7)])
+            assert all(len(r.generated) == 5 for r in reqs)
+            eng.run()
+            extra = next(r for r in eng.sched.finished if r.rid == 7)
+            assert extra.generated == control[0]
+        assert [r.generated for r in reqs] == control
+        assert eng.cache.pages_used == 0
+
+    def test_landing_for_a_preemption_retires_what_it_finishes(
+            self, serving_params):
+        # the victim's token in flight is its last: landing it retires
+        # the request, its pages come back, and nobody is evicted
+        eng = _engine(serving_params)
+        long_, short = (eng.submit(p, n) for p, n in
+                        zip(_prompts(2), (10, 3)))
+        _step_until_in_flight(eng, short, 2)
+        assert short.spent and eng.sched.running[-1] is short
+        assert eng.sched.preempt_one() is None
+        assert short.finish_reason == "length" and len(short.generated) == 3
+        assert short.preemptions == 0 and not short.pages
+        assert eng.sched.running == [long_] and long_.in_flight == 0
+        eng.run()
+        control, _ = _run_engine(serving_params, _prompts(2)[:1], max_new=10)
+        assert long_.generated == control[0]
+
+    def test_a_spent_row_gives_its_slot_to_the_next_admission(
+            self, serving_params):
+        eng = _engine(serving_params, max_batch=2)
+        a, b, c = (eng.submit(p, n) for p, n in
+                   zip(_prompts(3), (2, 8, 8)))
+        eng.step()                  # a and b prefilled, launched
+        assert a.spent and eng.sched.slots_used == 1
+        eng.step()                  # c takes a's slot; a's token lands
+        assert c.admit_t == 1.0 and a.done and a.finish_t is None
+        assert len(eng.sched.running) == 3
+        eng.step()
+        assert a.finish_t == 2.0 and len(eng.sched.running) == 2
+        eng.run()
+        control = [_run_engine(serving_params, [p], max_new=n)[0][0]
+                   for p, n in zip(_prompts(3), (2, 8, 8))]
+        assert [r.generated for r in (a, b, c)] == control
+
+    def test_no_recompile_from_empty_to_busy_to_empty_and_back(
+            self, serving_params):
+        from apex_tpu.analysis import hot_path_guard
+
+        eng = _engine(serving_params)
+        eng.warmup()
+        with hot_path_guard("empty -> busy -> empty, twice",
+                            transfers=None) as g:
+            for _ in range(2):
+                reqs = [eng.submit(p, 5) for p in _prompts(3)]
+                eng.run()
+                assert eng.sched.idle and eng._flight is None
+                assert all(len(r.generated) == 5 for r in reqs)
+        assert g.recompiles == 0 and g.syncs == []

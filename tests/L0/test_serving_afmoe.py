@@ -483,9 +483,15 @@ def test_the_ring_holds_the_page_and_expert_counters(params):
     assert sum(a["released_full"] for a in steps) == \
         eng.cache.pages_needed(50) + eng.cache.pages_needed(15)
     assert all("released_window" in a for a in by_name["engine.release"])
-    for a in by_name["engine.decode"]:
+    # the counters ride the deferred fetch with the tokens (ISSUE 34):
+    # a span carries those of the launch whose tokens LANDED in it
+    landings = [a for a in by_name["engine.decode"] if a["rids"]]
+    assert len(landings) == eng.decode_steps        # every launch lands
+    for a in landings:
         assert 0 < a["moe_load_max"] <= a["moe_pairs_held"] <= \
-            a["rows"] * 3 * 3
+            len(a["rids"]) * 3 * 3
+    assert all("moe_load_max" not in a for a in by_name["engine.decode"]
+               if not a["rids"])
     done = [a for a in by_name["engine.prefill"] if "moe_pairs_held" in a]
     assert len(done) == 2               # the row, and the last chunk
     assert len(by_name["engine.prefill"]) == 1 + 3

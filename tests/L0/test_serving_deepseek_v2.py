@@ -491,8 +491,11 @@ def test_the_ring_and_the_executables_name_what_the_model_adds(params):
     eng.submit(prompts(13, (12,))[0], 3)
     eng.run()
     decodes = [r for r in PHASE_RING.snapshot() if r.name == "engine.decode"]
+    # ... on the span in which the launch's tokens landed (ISSUE 34)
     assert decodes and all(
-        {"moe_pairs_held", "moe_load_max"} <= set(r.attrs) for r in decodes)
+        {"moe_pairs_held", "moe_load_max"} <= set(r.attrs)
+        for r in decodes if r.attrs["rids"])
+    assert sum(bool(r.attrs["rids"]) for r in decodes) == eng.decode_steps
     lowered = eng.analysis_executables()
     text = lowered["decode"].as_text(debug_info=True)
     for scope in ("mla_q", "mla_kv_down", "mla_absorb", "attn_latent",

@@ -393,7 +393,11 @@ class TestRollbackAndFallback:
         steps = [e for e in mem.events if e["type"] == "decode_step"]
         assert steps and all("spec_verify" not in e for e in steps), (
             "empty drafts must take the plain q_len=1 decode executable")
-        assert all(e["new_tokens"] == e["batch"] for e in steps)
+        # a speculative boundary lands the launch before it proposes
+        # (ISSUE 34): no launch runs ahead, and every one lands
+        assert all(e["in_flight"] == 0 for e in steps)
+        assert sum(e["new_tokens"] for e in steps) == \
+            sum(e["batch"] for e in steps)
 
     def test_draft_clamped_by_remaining_budget(self, serving_params):
         # a request one token from its budget must not overshoot
@@ -653,9 +657,12 @@ class TestSnapshotRestore:
         r2 = eng2.submit([5, 6, 5, 6, 5], 3)
         # run to completion but capture BEFORE retirement, then finish
         # through the restore path
-        while not r2.done:
+        while r2.launchable:
             eng2.step()
+        # its last token is in flight: the snapshot lands it
+        assert r2.in_flight == 1 and not r2.done
         snap = eng2.snapshot()
+        assert r2.in_flight == 0 and r2.done
         dst = _engine(serving_params, spec=SpecConfig(k=2))
         dst.proposer.propose(r2.rid, [1, 2, 1, 2], 2)  # seed rid state
         dst.restore(snap)                     # done request: finished

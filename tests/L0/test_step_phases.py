@@ -176,26 +176,43 @@ def test_decode_record_carries_the_rows_of_the_bus_event_and_the_scheduler(
     decodes = [r for r in PHASE_RING.snapshot()
                if r.name == "engine.decode"]
     events = [e for e in mem.events if e["type"] == "decode_step"]
-    assert len(decodes) == len(events) == eng.decode_steps > 0
+    assert len(decodes) == len(events) > 0
+    # a launch a decode step; the last span only lands what is left
+    assert sum(r.attrs["rows"] > 0 for r in decodes) == eng.decode_steps
+    assert decodes[-1].attrs["rows"] == 0 and decodes[-1].attrs["rids"]
     for rec, ev in zip(decodes, events):
-        assert rec.attrs["rows"] == ev["batch"] == len(rec.attrs["rids"])
+        # rows: what was launched; rids: whose token landed (ISSUE 34)
+        assert rec.attrs["rows"] == ev["batch"]
+        assert len(rec.attrs["rids"]) == ev["new_tokens"]
+        assert rec.attrs["in_flight"] == ev["in_flight"]
         assert rec.step == ev["step"]
+    # every launch lands, one span later
+    assert [r.attrs["rows"] for r in decodes[:-1]] == \
+        [len(r.attrs["rids"]) for r in decodes[1:]]
+    assert [r.attrs["in_flight"] for r in decodes] == \
+        [0] + [1] * (len(decodes) - 2) + [0]
 
 
-def test_decode_rids_are_the_schedulers_running_rows(params):
+def test_decode_rids_are_the_rows_whose_token_landed(params):
     PHASE_RING.clear()
     eng = _engine(params)
-    for p in _prompts(4):
-        eng.submit(p, 8)
+    reqs = [eng.submit(p, 8) for p in _prompts(4)]
+    landed = {r.rid: 1 for r in reqs}       # the first token: the prefill's
     while not eng.sched.idle:
         before = len(PHASE_RING)
+        launched = tuple(r.rid for r in eng.sched.running if r.in_flight)
         eng.step()
         new = PHASE_RING.snapshot()[before:]
         decodes = [r for r in new if r.name == "engine.decode"]
         if decodes:
-            # rows that finished this step are retired by the next one
-            assert decodes[0].attrs["rids"] == tuple(
-                r.rid for r in eng.sched.running)
+            # what landed is what the launch before carried; rows that
+            # finished on it are retired by the next step
+            assert decodes[0].attrs["rids"] == launched
+            assert set(launched) <= {r.rid for r in eng.sched.running}
+            for rid in launched:
+                landed[rid] += 1
+        assert all(len(r.generated) == landed[r.rid] for r in reqs
+                   if r.admit_t is not None)
 
 
 @pytest.mark.parametrize("mode", ["plain", "spec", "chunked", "preempt"])
@@ -274,7 +291,13 @@ def test_phase_ms_passes_the_schema_and_sums_to_no_more_than_step_ms(params):
     assert events
     for ev, rec in zip(events, decodes):
         tel.validate_event(ev)
-        assert set(ev["phase_ms"]) == LEAVES["engine.decode"]
+        # a launch with one before it in flight has all four; the
+        # first has nothing to fetch, the last nothing to launch
+        want = LEAVES["engine.decode"]
+        if not ev["in_flight"]:
+            want = ({"decode.build", "decode.dispatch"} if ev["batch"]
+                    else {"decode.fetch", "decode.commit"})
+        assert set(ev["phase_ms"]) == want
         assert all(v >= 0 for v in ev["phase_ms"].values())
         assert sum(ev["phase_ms"].values()) <= ev["step_ms"] * (1 + 1e-9)
         # one measurement: the bus event is the ring's record
