@@ -42,6 +42,12 @@ from apex_tpu.ops.fused_softmax import (  # noqa: F401
     scaled_upper_triang_masked_softmax,
 )
 from apex_tpu.ops.mlp import MLP, mlp  # noqa: F401
+from apex_tpu.ops.ssm import (  # noqa: F401
+    causal_conv,
+    ssd_chunk_scan,
+    ssm_decode_route,
+    ssm_decode_update,
+)
 from apex_tpu.ops.fused_linear_xent import (  # noqa: F401
     fused_linear_cross_entropy,
 )

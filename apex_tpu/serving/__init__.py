@@ -52,6 +52,7 @@ from apex_tpu.serving.kv_cache import (  # noqa: F401
     PagePoolCorruption,
     PagePoolExhausted,
     PrefixIndex,
+    StatePool,
     WindowPages,
     WindowPool,
     quantize_tokens,
@@ -59,6 +60,7 @@ from apex_tpu.serving.kv_cache import (  # noqa: F401
 from apex_tpu.serving.model import (  # noqa: F401
     AfmoeConfig,
     DeepseekV2Config,
+    GraniteHybridConfig,
     PagedDecoder,
     ServingModelConfig,
     init_params,
@@ -90,11 +92,13 @@ __all__ = [
     "PagePoolCorruption",
     "PagePoolExhausted",
     "PrefixIndex",
+    "StatePool",
     "WindowPages",
     "WindowPool",
     "quantize_tokens",
     "AfmoeConfig",
     "DeepseekV2Config",
+    "GraniteHybridConfig",
     "PagedDecoder",
     "ServingModelConfig",
     "init_params",

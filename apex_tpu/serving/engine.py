@@ -91,7 +91,12 @@ model whose sliding-window layers live in a second pool
 (``cache.window_pool``) runs through the same five executables, the
 same scheduler and the same phases, with ``engine.release`` after
 every decode step and chunk for the pages that slid out
-(docs/serving.md "The block seam", "Two page lifetimes").
+(docs/serving.md "The block seam", "Two page lifetimes").  A model with
+state-space layers (ISSUE 35) adds the recurrent-state pool to the same
+carries (``cache.state_pool``) and each row's slot to the tables: a
+request owns a slot beside its pages, a decode launch advances the
+slots of its rows in place, and a prefill chunk starts from what the
+chunk before left in the slot (docs/serving.md "Slots beside pages").
 
 Step phases (ISSUE 27): bus or no bus, every step marks what the host
 does with :func:`apex_tpu.telemetry.phase` — ``engine.step`` around
@@ -141,9 +146,11 @@ import numpy as np
 from apex_tpu.ops import flash_attention_route
 from apex_tpu.serving.kv_cache import (PagedKVCache, PagePoolCorruption,
                                        PagePoolExhausted, PrefixIndex,
-                                       WindowPool, verify_page_payload)
+                                       StatePool, WindowPool,
+                                       verify_page_payload)
 from apex_tpu.serving.model import (PagedDecoder, ServingModelConfig,
-                                    WindowKV, init_params, shard_params_tp)
+                                    StateIO, WindowKV, init_params,
+                                    shard_params_tp)
 from apex_tpu.serving.scheduler import (FINISHED, RUNNING, WAITING,
                                         ContinuousBatchingScheduler,
                                         QueueFullError, Request)
@@ -310,7 +317,10 @@ class ServingEngine:
     ``num_pages``/``page_size`` size the shared pool, and
     ``window_pages`` the second pool of a model whose sliding-window
     layers keep only a window of tokens (docs/serving.md "Two page
-    lifetimes"; as many as ``num_pages`` where not given);
+    lifetimes"; as many as ``num_pages`` where not given), and
+    ``state_slots`` the slots of recurrent state of a model with
+    state-space layers, the scratch slot among them (docs/serving.md
+    "Slots beside pages"; ``max_batch + 2`` where not given);
     ``prefill_budget`` is the widest packed prefill row (defaults to
     ``cfg.max_position``; a request's row is the narrowest rung of
     :attr:`prefill_widths` that holds its context) and the scheduler's
@@ -362,7 +372,8 @@ class ServingEngine:
                  prefix_entries: int = 8,
                  prefill_only: bool = False,
                  kv_import: bool = False,
-                 window_pages: Optional[int] = None):
+                 window_pages: Optional[int] = None,
+                 state_slots: Optional[int] = None):
         self.cfg = cfg
         self.decoder = PagedDecoder(cfg)
         # what this architecture's block cannot serve yet is refused
@@ -376,8 +387,7 @@ class ServingEngine:
                 raise ValueError(
                     f"{cfg.name}: the engine option {option!r} is not "
                     "supported for this model (docs/serving.md, "
-                    "\"What afmoe refuses\", \"What DeepSeek-V2 "
-                    "refuses\")")
+                    f"\"{self.decoder.block.refuses_doc}\")")
         self.params = params if params is not None else init_params(cfg, seed)
         if prefill_budget is None and cfg.max_position is None:
             raise ValueError(f"{cfg.name}: no position table to take the "
@@ -442,6 +452,7 @@ class ServingEngine:
             max_pages_per_request = min(-(-cap_tokens // page_size),
                                         max(1, num_pages - 1))
         self._window_pages = window_pages
+        self._state_slots = state_slots or max_batch + 2
         self.cache = self._new_cache(num_pages, page_size,
                                      max_pages_per_request, validate_pages)
         self.prefix_index = (
@@ -494,9 +505,11 @@ class ServingEngine:
 
         # the pool loop carries, in executable order: (k, v), then the
         # quantized pool's scale planes (r17), then the window pool's
-        # k and v (ISSUE 29); a window pool's two tables follow the
-        # other operands.  The step bodies take them as they come and
-        # hand the decoder what the cache is made of.
+        # k and v (ISSUE 29), then the state pool's states and tails
+        # (ISSUE 35); a window pool's two tables follow the other
+        # operands, and a state pool's (slots, fresh) those.  The step
+        # bodies take them as they come and hand the decoder what the
+        # cache is made of.
         n_pool = len(self._pool_state())
         n_stat = 1 if decoder.stat_names else 0
         latent = decoder.latent
@@ -509,6 +522,10 @@ class ServingEngine:
             kw = {}
             if quant:
                 kw.update(k_scale=pools[2], v_scale=pools[3])
+            if self.cache.state_pool is not None:
+                kw["state"] = StateIO(pools[-2], pools[-1],
+                                      rest[-2], rest[-1])
+                pools, rest = pools[:-2], rest[:-2]
             if self.cache.window_pool is not None:
                 kw["window"] = WindowKV(pools[-2], pools[-1],
                                         rest[-2], rest[-1])
@@ -673,14 +690,17 @@ class ServingEngine:
         """The pool of the layers that keep every token and, where the
         model has layers with a window, the :class:`WindowPool` of
         those beside it (``cache.window_pool``; ``window_pages`` of
-        them, as many as the full pool where not given).  ``__init__``
-        and :meth:`recover` build the same."""
+        them, as many as the full pool where not given); where it has
+        state-space layers, the :class:`StatePool` of their recurrent
+        state (``cache.state_pool``, ``state_slots`` slots): one
+        manager for what a request owns.  ``__init__`` and
+        :meth:`recover` build the same."""
         cfg, dec = self.cfg, self.decoder
         if not dec.full_layers:
             raise ValueError(f"{cfg.name}: a model needs at least one "
                              "layer that keeps every token")
         geometry = dict(page_size=page_size, num_heads=cfg.kv_heads,
-                        head_dim=cfg.head_dim, dtype=cfg.dtype,
+                        head_dim=dec.page_head_dim, dtype=cfg.dtype,
                         crc_pages=crc_pages, latent_dim=cfg.latent_dim)
         cache = PagedKVCache(
             num_layers=dec.full_layers, num_pages=num_pages,
@@ -695,6 +715,11 @@ class ServingEngine:
                     window, self.chunk_size or self.prefill_budget,
                     page_size)),
                 **geometry)
+        if dec.n_state_layers:
+            cache.state_pool = StatePool(
+                num_layers=dec.n_state_layers, num_slots=self._state_slots,
+                state_shape=cfg.state_shape, tail_shape=cfg.tail_shape,
+                dtype=cfg.dtype, state_dtype=cfg.state_dtype)
         return cache
 
     def _new_scheduler(self, max_queue: Optional[int],
@@ -730,12 +755,14 @@ class ServingEngine:
         """The pool loop-carry operands in executable order —
         ``(k, v)`` (a latent pool: ``k`` alone), then, quantized,
         ``(k_scale, v_scale)``, then the window pool's ``(k, v)`` where
-        there is one."""
+        there is one, then the state pool's ``(ssm, conv)``."""
         cache = self.cache
         pools = tuple(getattr(cache, name) for name in cache.operands)
-        wpool = cache.window_pool
+        wpool, spool = cache.window_pool, cache.state_pool
         if wpool is not None:
             pools += (wpool.k, wpool.v)
+        if spool is not None:
+            pools += (spool.ssm, spool.conv)
         return pools
 
     def _bind_pools(self, out: Tuple) -> Tuple:
@@ -744,22 +771,32 @@ class ServingEngine:
         block's counters."""
         n = len(self._pool_state())
         pools, rest = out[:n], out[n:]
-        wpool = self.cache.window_pool
+        wpool, spool = self.cache.window_pool, self.cache.state_pool
+        if spool is not None:
+            spool.ssm, spool.conv = pools[-2:]
+            pools = pools[:-2]
         if wpool is not None:
             wpool.k, wpool.v = pools[-2:]
         for name, pool in zip(self.cache.operands, pools):
             setattr(self.cache, name, pool)
         return rest
 
-    def _tables(self, reqs: Sequence[Request], rows: int) -> Tuple:
+    def _tables(self, reqs: Sequence[Request], rows: int,
+                fresh: bool = False) -> Tuple:
         """The page tables of ``reqs`` as the executables take them:
         the full pool's, and after the other operands the window
-        pool's compact table and its first positions."""
+        pool's compact table and its first positions, then the state
+        pool's slots and whether each row starts from zero (``fresh``:
+        a request's first chunk) instead of from its slot."""
         table = self.cache.page_table([r.pages for r in reqs], rows=rows)
-        wpool = self.cache.window_pool
-        if wpool is None:
-            return table, ()
-        return table, wpool.tables([r.window for r in reqs], rows=rows)
+        wpool, spool = self.cache.window_pool, self.cache.state_pool
+        extra = ()
+        if wpool is not None:
+            extra += wpool.tables([r.window for r in reqs], rows=rows)
+        if spool is not None:
+            extra += (spool.table([r.slot for r in reqs], rows=rows),
+                      jnp.asarray(np.full((rows,), int(fresh), np.int32)))
+        return table, extra
 
     def _stats(self, stats: Tuple) -> Dict[str, int]:
         """A launch's counters by name (one small fetch; the caller is
@@ -793,6 +830,8 @@ class ServingEngine:
             if wpool is not None:
                 t += (sds((rows, wpool.max_pages_per_request), i32),
                       sds((rows,), i32))
+            if self.cache.state_pool is not None:
+                t += (sds((rows,), i32),) * 2
             return t
 
         row = sds((1, S), i32)
@@ -907,8 +946,9 @@ class ServingEngine:
         the draft–verify subsystem is on (ISSUE 12) — the verify step
         at ``q_len = spec.k + 1`` and the ``[1, chunk_size]`` chunked-
         prefill step.  Every warmup launch writes only into scratch
-        page 0, which no reader ever sees; the zero-compiles-after-
-        warmup pin runs a speculative + chunked trace too."""
+        page 0 (and, of a state pool, scratch slot 0), which no reader
+        ever sees; the zero-compiles-after-warmup pin runs a
+        speculative + chunked trace too."""
         t0 = time.perf_counter()
         wpool = self.cache.window_pool
         for S in self.prefill_widths:
@@ -922,10 +962,13 @@ class ServingEngine:
         p_max = self.cache.max_pages_per_request
 
         def tables(rows):
-            if wpool is None:
-                return ()
-            return (jnp.zeros((rows, wpool.max_pages_per_request),
-                              jnp.int32), jnp.zeros((rows,), jnp.int32))
+            t = ()
+            if wpool is not None:
+                t += (jnp.zeros((rows, wpool.max_pages_per_request),
+                                jnp.int32), jnp.zeros((rows,), jnp.int32))
+            if self.cache.state_pool is not None:
+                t += (jnp.zeros((rows,), jnp.int32),) * 2
+            return t
 
         # twice: the second launch takes the first's tokens as its
         # token input, as every launch of the serving loop takes the
@@ -974,15 +1017,19 @@ class ServingEngine:
         jax.block_until_ready(self.cache.k)
         return time.perf_counter() - t0
 
-    def _scatter_row(self, kv, pages, offsets, wpages) -> None:
+    def _scatter_row(self, kv, pages, offsets, wpages, slot=0) -> None:
         """A prefill row's stacks into the pool(s) they are for: K and V
         (a latent model: its one stack), then the window layers' into
-        the window pool at ``wpages``."""
+        the window pool at ``wpages``, then the state-space layers'
+        final states and tails into ``slot``."""
         self.cache.write_tokens(
             kv[0], None if self.decoder.latent else kv[1], pages, offsets)
         if self.cache.window_pool is not None:
             self.cache.window_pool.write_tokens(kv[2], kv[3], wpages,
                                                 offsets)
+        if self.cache.state_pool is not None:
+            n = self.decoder.kv_stacks
+            self.cache.state_pool.write(slot, kv[n - 2], kv[n - 1])
 
     def prefill_width(self, context_len: int) -> int:
         """The row a context of ``context_len`` tokens is prefilled in:
@@ -1045,8 +1092,11 @@ class ServingEngine:
                     # window's tail; what lies before it is never read
                     wpages = np.zeros((S,), np.int32)
                     wpages[:C], _ = wpool.write_targets(req.window, idx)
-                self._scatter_row(kv, pages, offsets, wpages)
+                self._scatter_row(kv, pages, offsets, wpages, req.slot)
             req.kv_len = C
+            if req.slot is not None:
+                # a whole row starts from zero and overwrites the slot
+                span.attrs["state_in"] = 0
             self._register_prefix(ctx, req.pages)
             if self.prefix_index is not None:
                 # a whole row computes every token of its context
@@ -1322,7 +1372,12 @@ class ServingEngine:
                 wpages[0, pad:] = pg
                 woffs[0, pad:] = pos % ps
                 self._check_private(pg, "chunk scatter")
-                page_table, wtables = self._tables([req], 1)
+                # a request's first chunk starts from zero, every later
+                # one from what the chunk before left in the slot
+                page_table, wtables = self._tables([req], 1,
+                                                   fresh=start == 0)
+                if req.slot is not None:
+                    span.attrs["state_in"] = int(start != 0)
             with phase("prefill.dispatch"):
                 out = self._chunk_fn(
                     self.params, *self._pool_state(),
@@ -1608,6 +1663,10 @@ class ServingEngine:
         # a request whose budget was a single token is done at prefill
         progress = bool(self._retire(now)) or progress
         self._held_pages(counters)
+        spool = self.cache.state_pool
+        if spool is not None:
+            counters["state_slots_held"] = spool.slots_used
+            counters["state_slots"] = spool.num_slots
         if self.prefix_index is not None:
             counters["prefix_entries"] = len(self.prefix_index)
             counters["prefix_pages_shared"] = self.cache.pages_shared
@@ -1638,6 +1697,9 @@ class ServingEngine:
         if rows or self._flight is not None or self._landed:
             spec_fields = {}
             with phase("engine.decode", rows=len(rows)) as span:
+                if spool is not None:
+                    # the rows whose slot is not the scratch slot
+                    span.attrs["state_rows"] = len(rows)
                 # rids: the requests whose token LANDED in this span;
                 # committed: how many each, where that is not one
                 committed: Optional[Tuple[int, ...]] = None
@@ -1859,12 +1921,16 @@ class ServingEngine:
     # -- disaggregated prefill/decode (r18) --------------------------------
 
     def _no_window_pages(self, what: str) -> None:
-        """Shipping pages is for one page lifetime: a window pool's
-        compact page lists have no wire format yet."""
-        if self.cache.window_pool is not None:
-            raise ValueError(
-                f"{self.cfg.name}: {what} cannot ship the pages of a "
-                "window pool (docs/serving.md, \"What afmoe refuses\")")
+        """Shipping pages is for one page lifetime and for pages alone:
+        a window pool's compact page lists and a state pool's slots
+        have no wire format yet."""
+        for pool, held in ((self.cache.window_pool, "pages of a window"),
+                           (self.cache.state_pool, "slots of a state")):
+            if pool is not None:
+                raise ValueError(
+                    f"{self.cfg.name}: {what} cannot ship the {held} pool "
+                    f"(docs/serving.md, "
+                    f"\"{self.decoder.block.refuses_doc}\")")
 
     def export_request(self, rid: int):
         """Detach a freshly prefilled request for shipping (the
@@ -2067,6 +2133,7 @@ class ServingEngine:
         for req in running:
             req.pages = []
             req.window = None
+            req.slot = None
             req.kv_len = 0
             # a mid-chunk request restarts its chunked prefill after
             # the rebuild — chunk progress is as rebuildable as KV

@@ -49,6 +49,15 @@ of fixed-size pages:
   page ids and on whichever operands there are (:attr:`PagedKVCache.
   operands`).
 
+* A model with STATE-SPACE layers (ISSUE 35: Mamba-2 mixers beside a
+  few attention layers) keeps, a request and a layer, a recurrent state
+  of FIXED size instead of K/V that grow: the :class:`StatePool` beside
+  the page pool (``cache.state_pool``) holds one SLOT a request, taken
+  at admission and given back at retirement and preemption; slot 0 is
+  the scratch slot as page 0 is the scratch page.  The page pool then
+  holds only the attention layers' K/V (docs/serving.md, "Slots beside
+  pages").
+
 The device arrays are functionally updated (``.at[].set``); the cache
 object re-binds them, so callers treat ``cache.k``/``cache.v`` (and,
 quantized, ``cache.k_scale``/``cache.v_scale``) as the current pool
@@ -278,6 +287,29 @@ class PagedKVCache:
         #: the pool of the layers that keep only a window of tokens,
         #: where the model has such layers (the engine sets it)
         self.window_pool: Optional["WindowPool"] = None
+        #: the slots of the state-space layers' recurrent state, where
+        #: the model has such layers (the engine sets it)
+        self.state_pool: Optional["StatePool"] = None
+
+    # -- slots beside pages (ISSUE 35) -------------------------------------
+
+    @property
+    def slots_free(self) -> Optional[int]:
+        """State slots a new request could take; None where requests
+        own no slot (no state-space layer)."""
+        return (None if self.state_pool is None
+                else self.state_pool.slots_free)
+
+    def allocate_slot(self, owner: int) -> Optional[int]:
+        """The slot an admitted request owns beside its pages (None
+        where requests own none); raises :class:`PagePoolExhausted`
+        when every slot is held."""
+        return (None if self.state_pool is None
+                else self.state_pool.allocate(owner))
+
+    def free_slot(self, slot: Optional[int]) -> None:
+        if slot is not None:
+            self.state_pool.free(slot)
 
     @property
     def operands(self) -> Tuple[str, ...]:
@@ -796,6 +828,93 @@ class WindowPool(PagedKVCache):
         pages = np.asarray(held.pages + [0], np.int32)[
             np.where(slot >= 0, slot, len(held.pages))]
         return pages, (positions % self.page_size).astype(np.int32)
+
+
+def _write_slot(ssm, conv, ssm_new, conv_new, slot):
+    """pool[:, slot] = new for both arrays, the slot traced: every
+    admission reuses one compiled executable."""
+    return (jax.lax.dynamic_update_slice_in_dim(
+                ssm, ssm_new[:, None].astype(ssm.dtype), slot, axis=1),
+            jax.lax.dynamic_update_slice_in_dim(
+                conv, conv_new[:, None].astype(conv.dtype), slot, axis=1))
+
+
+class StatePool:
+    """The recurrent state of a model's state-space layers: one SLOT a
+    request, of fixed size, where attention layers have pages that grow.
+
+    ``ssm`` ``[num_layers, num_slots, N, H * P]`` (float32 as served:
+    the state is updated every token, and rounding it to bfloat16 each
+    step drifts) and ``conv`` ``[num_layers, num_slots, taps - 1, ch]``
+    (the convolution's tail, in the activations' type: a copy, nothing
+    accumulates), allocated once.  Slot 0 is the SCRATCH slot, never
+    handed out: idle decode rows and warm-up launches name it.
+    Allocation is lowest-first, like pages.  A slot is NOT zeroed when
+    it changes hands: a whole-row prefill overwrites it, and a chunked
+    prefill's first chunk is told to start from zero
+    (``PagedDecoder.extend``'s ``fresh``), so nothing of the last owner
+    is ever read."""
+
+    def __init__(self, *, num_layers: int, num_slots: int,
+                 state_shape: Tuple[int, int], tail_shape: Tuple[int, int],
+                 dtype=jnp.float32, state_dtype=jnp.float32):
+        if num_slots < 2:
+            raise ValueError("num_slots must be >= 2 (slot 0 is the "
+                             "reserved scratch slot)")
+        self.num_layers = num_layers
+        self.num_slots = num_slots
+        self.ssm = jnp.zeros((num_layers, num_slots) + tuple(state_shape),
+                             state_dtype)
+        self.conv = jnp.zeros((num_layers, num_slots) + tuple(tail_shape),
+                              dtype)
+        self._write = jax.jit(
+            _write_slot, donate_argnums=(0, 1)
+            if jax.default_backend() == "tpu" else ())
+        self._free: List[int] = list(range(1, num_slots))
+        self._owner: Dict[int, int] = {}
+
+    @property
+    def slots_free(self) -> int:
+        return len(self._free)
+
+    @property
+    def slots_used(self) -> int:
+        return (self.num_slots - 1) - len(self._free)
+
+    def allocate(self, owner: int) -> int:
+        if not self._free:
+            raise PagePoolExhausted(
+                f"no state slot free ({self.slots_used}/"
+                f"{self.num_slots - 1} in use)")
+        slot = self._free.pop(0)
+        self._owner[slot] = owner
+        return slot
+
+    def free(self, slot: int) -> None:
+        if slot == 0 or slot not in self._owner:
+            raise ValueError(f"double free / scratch free: slot {slot}")
+        del self._owner[slot]
+        bisect.insort(self._free, slot)
+
+    def owner_of(self, slot: int) -> Optional[int]:
+        return self._owner.get(slot)
+
+    def table(self, slots: Sequence[Optional[int]],
+              rows: Optional[int] = None) -> jnp.ndarray:
+        """``[rows]`` int32: each request's slot, the scratch slot for
+        the rows past them."""
+        rows = len(slots) if rows is None else rows
+        t = np.zeros((rows,), np.int32)
+        t[:len(slots)] = slots
+        return jnp.asarray(t)
+
+    def write(self, slot: int, ssm_new: jnp.ndarray,
+              conv_new: jnp.ndarray) -> None:
+        """Put a whole-row prefill's final state ``[num_layers, N, H *
+        P]`` and tail ``[num_layers, taps - 1, ch]`` into ``slot`` (in
+        place on the TPU: the pools are donated)."""
+        self.ssm, self.conv = self._write(
+            self.ssm, self.conv, ssm_new, conv_new, np.int32(slot))
 
 
 class PrefixIndex:

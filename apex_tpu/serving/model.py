@@ -13,8 +13,12 @@ one definition; :class:`AfmoeBlock` (RoPE on sliding-window layers and
 none on full ones, RMSNorm sandwiches, QK-norm, a sigmoid output gate,
 grouped-query heads, SwiGLU, a dropless top-k expert layer that holds
 a share of the experts, muP-scaled embeddings, an untied head) the
-second.  The engine asks a configuration for its block and never
-asks which it got.
+second, :class:`DeepseekV2Block` (latent attention over one vector a
+token) the third, :class:`GraniteHybridBlock` the fourth: most of its
+layers are Mamba-2 STATE-SPACE mixers, which are handed no ``attend``
+but a ``scan`` that takes the request's recurrent state (a slot of the
+cache's state pool, ISSUE 35) to its next.  The engine asks a
+configuration for its block and never asks which it got.
 
 Two entry points mirror the two phases of continuous batching:
 
@@ -99,6 +103,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from apex_tpu.ops import flash_attention, flash_decode, flash_decode_latent
+from apex_tpu.ops.ssm import causal_conv, ssd_chunk_scan, ssm_decode_update
 from apex_tpu.serving.experts import expert_layer, swiglu
 from apex_tpu.serving.kv_cache import (latent_width, pad_latent,
                                        quantize_tokens)
@@ -239,6 +244,8 @@ class GPTBlock:
     refuses: Tuple[str, ...] = ()
     #: per-launch counters its executables return after the pools
     stat_names: Tuple[str, ...] = ()
+    #: the section of docs/serving.md that says why each is refused
+    refuses_doc = "The block seam"
 
     def __init__(self, cfg: ServingModelConfig):
         self.cfg = cfg
@@ -402,6 +409,8 @@ class AfmoeBlock:
     refuses = ("tp", "kv_quant", "prefix_sharing", "speculation",
                "prefill_only", "kv_import")
     stat_names = ("moe_pairs_held", "moe_load_max")
+    #: where the engine's refusal sends the reader
+    refuses_doc = "What afmoe refuses"
 
     def __init__(self, cfg: AfmoeConfig):
         self.cfg = cfg
@@ -630,6 +639,7 @@ class DeepseekV2Block:
     refuses = ("tp", "kv_quant", "speculation", "prefill_only",
                "kv_import")
     stat_names = ("moe_pairs_held", "moe_load_max")
+    refuses_doc = "What DeepSeek-V2 refuses"
 
     def __init__(self, cfg: DeepseekV2Config):
         self.cfg = cfg
@@ -688,6 +698,245 @@ class DeepseekV2Block:
         return x @ params["head"]
 
 
+# -- granite-hybrid: the fourth block, state-space layers ---------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class GraniteHybridConfig:
+    """Geometry of a ``granitemoehybrid`` decoder without experts
+    (ibm-granite Granite 4.0-H): ``layer_types`` names each layer's
+    mixer, ``"mamba"`` (a Mamba-2 state-space mixer) or ``"attention"``
+    (grouped-query attention with NO positional signal), each followed
+    by a SwiGLU MLP; the four Granite multipliers scale the embedding,
+    both residual branches, the attention scores and the logits; the
+    head is the embedding.  No position enters anywhere, so no table
+    bounds a request: its pages do.
+
+    A request keeps, a state-space layer, a state of ``mamba_d_state x
+    (mamba_n_heads * mamba_d_head)`` numbers and the last ``mamba_d_conv
+    - 1`` rows of the convolution's input (:attr:`state_shape`,
+    :attr:`tail_shape`: a SLOT of the cache's ``state_pool``), and an
+    attention layer, K and V of ``num_kv_heads`` heads a token."""
+
+    vocab_size: int
+    hidden_size: int
+    num_heads: int
+    num_kv_heads: int
+    layer_types: Tuple[str, ...]       # "mamba" | "attention"
+    intermediate_size: int
+    mamba_n_heads: int
+    mamba_d_head: int
+    mamba_d_state: int
+    attention_multiplier: float
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    rms_norm_eps: float = 1e-5
+    dtype: object = jnp.float32
+    #: the recurrent state's type in the pool
+    state_dtype: object = jnp.float32
+
+    name = "granite_hybrid"
+    max_position = None
+    latent_dim = None
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def head_dim(self) -> int:
+        if self.hidden_size % self.num_heads:
+            raise ValueError("hidden_size must divide by num_heads")
+        return self.hidden_size // self.num_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads
+
+    @property
+    def layer_windows(self) -> Tuple[Optional[int], ...]:
+        return (None,) * self.num_layers
+
+    @property
+    def state_layers(self) -> Tuple[int, ...]:
+        """The layers that keep a recurrent state and no K/V."""
+        return tuple(i for i, t in enumerate(self.layer_types)
+                     if t == "mamba")
+
+    @property
+    def page_head_dim(self) -> int:
+        """The width a K/V head is stored at in the page pool: whole
+        lane tiles, the padding zero.  The device lays a head of 64 out
+        in a 128-lane tile anyway, and the paged kernels want whole
+        tiles (:meth:`PagedDecoder._stored`)."""
+        return latent_width(self.head_dim)
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels of the convolution: ``x`` and one group's B and C."""
+        return self.d_inner + 2 * self.mamba_d_state
+
+    @property
+    def state_shape(self) -> Tuple[int, int]:
+        """A slot's state of one layer (``apex_tpu.ops.ssm``'s layout)."""
+        return (self.mamba_d_state, self.d_inner)
+
+    @property
+    def tail_shape(self) -> Tuple[int, int]:
+        """A slot's tail of one layer as the pool holds it: the last
+        ``mamba_d_conv - 1`` rows of ``xBC`` end to end, one vector."""
+        return (1, (self.mamba_d_conv - 1) * self.conv_dim)
+
+    def block(self) -> "GraniteHybridBlock":
+        return GraniteHybridBlock(self)
+
+    def init_params(self, seed: int = 0):
+        """Seeded parameters in the block's layout (the reference's):
+        matrices normal with std 1/sqrt(fan_in), gains 1 + 0.02 normal,
+        the convolution's taps normal with std 1/sqrt(taps).  The
+        recurrence's scalars as Mamba-2 initialises them, so that heads
+        remember over a few tokens and over thousands: ``A`` spread over
+        [1, 16], the step ``softplus(dt_bias)`` log-spread over [0.001,
+        0.1], ``D`` 1."""
+        d, dt = self.hidden_size, self.dtype
+        hq, hk, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        nh, di, ch = self.mamba_n_heads, self.d_inner, self.conv_dim
+        f = self.intermediate_size
+        keys = iter(jax.random.split(jax.random.PRNGKey(seed), 4096))
+
+        def mat(*shape):
+            return (jax.random.normal(next(keys), shape, jnp.float32)
+                    / math.sqrt(shape[-2])).astype(dt)
+
+        def gain(n):
+            return (1.0 + 0.02 * jax.random.normal(
+                next(keys), (n,), jnp.float32)).astype(dt)
+
+        step = jnp.exp(jnp.linspace(math.log(1e-3), math.log(1e-1), nh))
+        layers = []
+        for kind in self.layer_types:
+            layer = {"g1": gain(d), "g2": gain(d),
+                     "mlp": {"wg": mat(d, f), "wu": mat(d, f),
+                             "wd": mat(f, d)}}
+            if kind == "mamba":
+                layer.update(
+                    win=mat(d, di + ch + nh), conv_w=mat(self.mamba_d_conv,
+                                                         ch),
+                    conv_b=(0.02 * jax.random.normal(
+                        next(keys), (ch,), jnp.float32)).astype(dt),
+                    dt_bias=jnp.log(jnp.expm1(step)).astype(dt),
+                    a_log=jnp.log(jnp.linspace(1.0, 16.0, nh)).astype(dt),
+                    d_skip=gain(nh), gn=gain(di), wout=mat(di, d))
+            else:
+                layer.update(wq=mat(d, hq * hd), wk=mat(d, hk * hd),
+                             wv=mat(d, hk * hd), wo=mat(hq * hd, d))
+            layers.append(layer)
+        # a row of norm 1 AFTER the multiplier: the head is the same
+        # matrix, and rows the multiplier made 12 times the usual would
+        # make every token predict itself
+        embed = (jax.random.normal(next(keys), (self.vocab_size, d),
+                                   jnp.float32)
+                 / (self.embedding_multiplier * math.sqrt(d))).astype(dt)
+        return {"embed": embed, "norm_f": gain(d), "layers": layers}
+
+
+class GraniteHybridBlock:
+    """The Granite 4.0-H block (docs/serving.md, "Slots beside pages"):
+    ``h = x + r Mixer(RMS(x))``, ``x' = h + r MLP(RMS(h))`` with ``r``
+    the residual multiplier; embeddings times ``embedding_multiplier``,
+    logits over ``logits_scaling`` through the embedding transposed.
+
+    The mixer is the layer's own, and so is what the decoder hands it:
+    an ATTENTION layer (a few of the layers) gets ``attend(q, k, v,
+    scale=)`` as every attention block does, with 32 query heads over 8
+    K/V heads of 64, scores times ``attention_multiplier``, no rotary
+    and no bias; a STATE-SPACE layer gets ``scan(xbc, dt, layer)``, the
+    Mamba-2 recurrence from the request's state and tail to its new
+    ones (the chunked form over a prefill row or chunk, the in-place
+    update at decode: ``apex_tpu.ops.ssm``), between the block's input
+    projection and its gated RMSNorm and output projection."""
+
+    #: a shared prefix needs a snapshot of the state at the page it
+    #: ends on, a rejected draft a rolled-back state, shipped pages a
+    #: shipped slot: none exists yet
+    refuses = ("tp", "kv_quant", "prefix_sharing", "speculation",
+               "prefill_only", "kv_import")
+    stat_names: Tuple[str, ...] = ()
+    #: where the engine's refusal sends the reader
+    refuses_doc = "What granite-hybrid refuses"
+
+    def __init__(self, cfg: GraniteHybridConfig):
+        self.cfg = cfg
+
+    def embed(self, params, tokens, positions):
+        x = params["embed"][tokens]
+        return x * jnp.asarray(self.cfg.embedding_multiplier, x.dtype)
+
+    def _residual(self, x, branch):
+        r = self.cfg.residual_multiplier
+        return (x.astype(jnp.float32)
+                + r * branch.astype(jnp.float32)).astype(x.dtype)
+
+    def layer(self, layer, li, x, positions, mixer, *, tp_axis=None,
+              valid=None, stats=None):
+        cfg = self.cfg
+        eps, hd = cfg.rms_norm_eps, cfg.head_dim
+        lead = x.shape[:-1]
+        u = _rms(x, layer["g1"], eps)
+        if "win" in layer:
+            di, ch = cfg.d_inner, cfg.conv_dim
+            with jax.named_scope("ssm_in_proj"):
+                zxbcdt = u @ layer["win"]
+                z = zxbcdt[..., :di]
+                xbc = zxbcdt[..., di:di + ch]
+                dt = zxbcdt[..., di + ch:]
+            y = mixer(xbc, dt, layer)           # float32
+            with jax.named_scope("ssm_gate_norm"):
+                g = y * jax.nn.silu(z.astype(jnp.float32))
+                g = _rms(g, layer["gn"], eps).astype(x.dtype)
+            with jax.named_scope("ssm_out_proj"):
+                m = g @ layer["wout"]
+        else:
+            q = (u @ layer["wq"]).reshape(*lead, cfg.num_heads, hd)
+            k = (u @ layer["wk"]).reshape(*lead, cfg.num_kv_heads, hd)
+            v = (u @ layer["wv"]).reshape(*lead, cfg.num_kv_heads, hd)
+            with jax.named_scope("attn_nope"):
+                ctx = mixer(q, k, v, scale=cfg.attention_multiplier)
+            m = ctx @ layer["wo"]
+        h = self._residual(x, m)
+        with jax.named_scope("mlp"):
+            m = swiglu(_rms(h, layer["g2"], eps), layer["mlp"])
+        return self._residual(h, m)
+
+    def final_norm(self, params, x):
+        return _rms(x, params["norm_f"], self.cfg.rms_norm_eps)
+
+    def logits(self, params, x):
+        return (x @ params["embed"].T) / jnp.asarray(
+            self.cfg.logits_scaling, x.dtype)
+
+
+class StateIO(NamedTuple):
+    """The recurrent-state half of a hybrid cache as a paged step sees
+    it: the pools of the state-space layers (``ssm`` ``[L_s, n_slots,
+    N, H * P]``, ``conv`` ``[L_s, n_slots, taps - 1, ch]``), each row's
+    slot (``slots [b]``; 0, the scratch slot, for an idle row) and, for
+    a multi-token step, whether the row starts from zero instead of
+    from what its slot holds (``fresh [b]``: a request's first chunk)."""
+
+    ssm: jnp.ndarray
+    conv: jnp.ndarray
+    slots: jnp.ndarray
+    fresh: jnp.ndarray
+
+
 class WindowKV(NamedTuple):
     """The window-lifetime half of a two-lifetime cache as a paged step
     sees it: the pool of the sliding layers (``k``/``v`` ``[L_w,
@@ -715,20 +964,65 @@ class PagedDecoder:
         self.cfg = cfg
         self.block = cfg.block()
         self.windows = tuple(cfg.layer_windows)
-        counts = {False: 0, True: 0}
+        #: the layers that keep a recurrent state in a slot and no K/V
+        self.state_layers = frozenset(getattr(cfg, "state_layers", ()))
+        counts = {"full": 0, "window": 0, "state": 0}
         index = []
-        for w in self.windows:
-            index.append(counts[w is not None])
-            counts[w is not None] += 1
+        for li, w in enumerate(self.windows):
+            family = ("state" if li in self.state_layers
+                      else "full" if w is None else "window")
+            index.append(counts[family])
+            counts[family] += 1
         self.pool_index = tuple(index)
-        self.full_layers, self.window_layers = counts[False], counts[True]
+        self.full_layers, self.window_layers = (counts["full"],
+                                                counts["window"])
+        self.n_state_layers = counts["state"]
         self.stat_names = self.block.stat_names
         #: a latent model: one vector a token, one pool operand
         self.latent = cfg.latent_dim is not None
-        #: the K/V stacks a prefill returns after the logits, as many
-        #: as the cache's pools have unquantized operands
-        self.kv_stacks = (1 if self.latent
-                          else 4 if self.window_layers else 2)
+        #: the width a K/V head has in the pool (wider than the head
+        #: where the configuration stores it in whole lane tiles)
+        self.page_head_dim = getattr(cfg, "page_head_dim", cfg.head_dim)
+        #: the stacks a prefill returns after the logits: K/V, as many
+        #: as the cache's pools have unquantized operands, then the
+        #: state-space layers' final states and tails
+        self.kv_stacks = ((1 if self.latent
+                           else 4 if self.window_layers else 2)
+                          + (2 if self.n_state_layers else 0))
+
+    # -- heads under a lane tile ------------------------------------------
+
+    def _stored(self, *heads):
+        """``[..., h, head_dim]`` -> the pool's ``[..., h,
+        page_head_dim]``, zeros after: a query so padded scores a stored
+        key as the head itself scores it, and what comes back has the
+        head's own numbers first."""
+        pad = self.page_head_dim - self.cfg.head_dim
+        if not pad:
+            return heads
+        return tuple(jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)])
+                     for a in heads)
+
+    def _ssm_row(self, p, xbc, dt, valid, state_in, tail_in):
+        """One row of a prefill or a chunk through a state-space
+        layer's convolution and chunked scan: ``xbc`` ``[S, ch]``,
+        ``dt`` ``[S, H]``, from (state, tail) to the new ones.  Returns
+        (y ``[S, H * P]`` float32, state, tail); the tails as the pool
+        holds them (:attr:`GraniteHybridConfig.tail_shape`)."""
+        cfg = self.cfg
+        di, n = cfg.d_inner, cfg.mamba_d_state
+        with jax.named_scope("ssm_conv"):
+            conv, tail = causal_conv(
+                xbc, tail_in.reshape(-1, cfg.conv_dim), valid, p["conv_w"],
+                p["conv_b"])
+            conv = conv.astype(xbc.dtype)
+            tail = tail.reshape(cfg.tail_shape)
+        y, state = ssd_chunk_scan(
+            conv[:, :di].reshape(-1, cfg.mamba_n_heads, cfg.mamba_d_head),
+            dt, p["a_log"], conv[:, di:di + n], conv[:, di + n:],
+            p["d_skip"], state_in, valid, dt_bias=p["dt_bias"],
+            chunk=cfg.mamba_chunk_size)
+        return y, state, tail
 
     def _expanded(self, attention, seg, kept):
         """A latent layer's ``attend`` for a whole row, no cache yet:
@@ -778,7 +1072,10 @@ class PagedDecoder:
         packed position, for the engine to scatter into pages.  A
         model with window layers returns two more, ``(logits, k, v,
         wk, wv)``: the full layers' K/V and then the window layers',
-        each for its own pool; a block with counters appends them last.
+        each for its own pool; a model with state-space layers the
+        row's final states ``[L_s, 1, N, H * P]`` and tails ``[L_s, 1,
+        taps - 1, ch]`` for its slot (the K/V then of its attention
+        layers alone); a block with counters appends them last.
 
         ``last_index`` (traced int scalar, so the compiled shape never
         changes): compute logits ``[1, 1, vocab]`` for that single
@@ -815,15 +1112,33 @@ class PagedDecoder:
                 v.transpose(0, 2, 1, 3), causal=True,
                 segment_ids=seg, window=window, scale=scale)
 
+        states, tails = [], []
+
+        def scan(xbc, dt, p):
+            """A state-space layer's mixer over a whole row: from a
+            zero state and tail; the final ones are kept for the slot."""
+            cfg = self.cfg
+            zero = (jnp.zeros(cfg.state_shape, jnp.float32),
+                    jnp.zeros(cfg.tail_shape, xbc.dtype))
+            y, state, tail = jax.vmap(
+                lambda xbc, dt, valid: self._ssm_row(
+                    p, xbc, dt, valid, *zero))(xbc, dt, seg != 0)
+            states.append(state)
+            tails.append(tail)
+            return y
+
         def attend_in(li):
+            if li in self.state_layers:
+                return scan
             if self.latent:
                 return self._expanded(attention, seg, kept[False][0])
             window = self.windows[li]
             ks, vs = kept[window is not None]
 
-            def attend(q, k, v):
+            def attend(q, k, v, scale=None):
                 b, s = q.shape[:2]
-                ctx = attention(q, k, v, seg, window=window)
+                ctx = attention(q, k, v, seg, window=window, scale=scale)
+                k, v = self._stored(k, v)
                 ks.append(k)
                 vs.append(v)
                 return ctx.transpose(0, 2, 1, 3).reshape(b, s, -1)
@@ -846,6 +1161,9 @@ class PagedDecoder:
             out += (jnp.stack(kept[False][1]),)
         if self.window_layers:
             out += (jnp.stack(kept[True][0]), jnp.stack(kept[True][1]))
+        if states:
+            out += (jnp.stack(states).astype(self.cfg.state_dtype),
+                    jnp.stack(tails))
         if stats:
             out += (self._stats(stats),)
         return out
@@ -854,7 +1172,8 @@ class PagedDecoder:
 
     def _paged(self, params, k_pool, v_pool, tokens, positions,
                write_pages, write_offsets, page_table, kv_len, *,
-               k_scale, v_scale, tp_axis, window: Optional[WindowKV]):
+               k_scale, v_scale, tp_axis, window: Optional[WindowKV],
+               state: Optional[StateIO] = None):
         """What :meth:`decode` (lead shape ``[b]``) and :meth:`extend`
         (``[b, q]``) share: every layer appends its tokens' K/V at
         ``(write_pages, write_offsets)`` of its pool and attends over
@@ -932,15 +1251,58 @@ class PagedDecoder:
 
             return attend
 
+        slot_pools = [state.ssm, state.conv] if state is not None else None
+
+        def scan_in(pi):
+            cfg = self.cfg
+
+            def step(xbc, dt, p):
+                # one token a row: the slot advanced where it lies
+                y, slot_pools[0], slot_pools[1] = ssm_decode_update(
+                    slot_pools[0], slot_pools[1], state.slots, xbc, dt,
+                    layer=pi, conv_w=p["conv_w"], conv_b=p["conv_b"],
+                    dt_bias=p["dt_bias"], a_log=p["a_log"],
+                    d_skip=p["d_skip"], heads=cfg.mamba_n_heads)
+                return y
+
+            def scan(xbc, dt, p):
+                # a chunk a row, from what the slot holds (or zero) to
+                # what it holds next: a slice in and a slice out, row
+                # by row (a scatter into a pool of gigabytes is not
+                # reliably in place)
+                ys = []
+                for i in range(b):
+                    slot, keep = state.slots[i], state.fresh[i] == 0
+                    at = lambda pool: jax.lax.dynamic_index_in_dim(
+                        pool[pi], slot, keepdims=False)
+                    y, s_out, t_out = self._ssm_row(
+                        p, xbc[i], dt[i], real[i],
+                        jnp.where(keep, at(slot_pools[0]), 0).astype(
+                            jnp.float32),
+                        jnp.where(keep, at(slot_pools[1]), 0))
+                    for j, new in enumerate((s_out, t_out)):
+                        slot_pools[j] = jax.lax.dynamic_update_slice(
+                            slot_pools[j],
+                            new[None, None].astype(slot_pools[j].dtype),
+                            (pi, slot, 0, 0))
+                    ys.append(y)
+                return jnp.stack(ys)
+
+            return step if tokens.ndim == 1 else scan
+
         def attend_in(li):
+            pi = self.pool_index[li]
+            if li in self.state_layers:
+                return scan_in(pi)
             w = self.windows[li]
             pool = pools[w is not None]
             pages, offsets = targets[w is not None]
-            pi = self.pool_index[li]
             if self.latent:
                 return absorbed(pi)
 
-            def attend(q, k, v):
+            def attend(q, k, v, scale=None):
+                own = q.shape[-1]
+                q, k, v = self._stored(q, k, v)
                 nh, hd = q.shape[-2:]
                 k_new, v_new = k, v
                 if quantized:
@@ -956,8 +1318,10 @@ class PagedDecoder:
                     kw["window"] = w
                 ctx = flash_decode(
                     q4, pool[0], pool[1], kw.pop("page_table"), kv_len,
-                    layer=pi, k_scale=pool[2], v_scale=pool[3], **kw)
-                return ctx.transpose(0, 2, 1, 3).reshape(*lead, -1)
+                    scale=scale, layer=pi, k_scale=pool[2],
+                    v_scale=pool[3], **kw)
+                return ctx.transpose(0, 2, 1, 3)[..., :own].reshape(
+                    *lead, -1)
 
             return attend
 
@@ -971,6 +1335,8 @@ class PagedDecoder:
                                  else 1 if self.latent else 2])
         if window is not None:
             out += tuple(pools[True][:2])
+        if state is not None:
+            out += tuple(slot_pools)
         if stats:
             out += (self._stats(stats),)
         return x, out
@@ -983,7 +1349,8 @@ class PagedDecoder:
                k_scale: Optional[jnp.ndarray] = None,
                v_scale: Optional[jnp.ndarray] = None,
                tp_axis: Optional[str] = None,
-               window: Optional[WindowKV] = None):
+               window: Optional[WindowKV] = None,
+               state: Optional[StateIO] = None):
         """One decode step for a fixed-width batch.
 
         ``tokens``/``positions`` ``[b]``: each row's newest token and
@@ -998,7 +1365,9 @@ class PagedDecoder:
         5-tuple appending the updated scale planes: the append
         quantizes on write and ``flash_decode`` dequantizes on read.
         With ``window`` (:class:`WindowKV`) the window pool's k and v
-        follow the full pool's, and a block's counters come last.
+        follow the full pool's, with ``state`` (:class:`StateIO`) the
+        state pool's two arrays after those, and a block's counters
+        come last.
         ``tp_axis``: per-shard body under ``shard_map`` (local head
         slice of pool and scales, one ``psum`` per block).
 
@@ -1012,7 +1381,7 @@ class PagedDecoder:
         x, out = self._paged(
             params, k_pool, v_pool, tokens, positions, page_idx, offset,
             page_table, kv_len, k_scale=k_scale, v_scale=v_scale,
-            tp_axis=tp_axis, window=window)
+            tp_axis=tp_axis, window=window, state=state)
         with jax.named_scope("head"):
             logits = self.block.logits(
                 params, self.block.final_norm(params, x))
@@ -1027,7 +1396,8 @@ class PagedDecoder:
                k_scale: Optional[jnp.ndarray] = None,
                v_scale: Optional[jnp.ndarray] = None,
                tp_axis: Optional[str] = None,
-               window: Optional[WindowKV] = None):
+               window: Optional[WindowKV] = None,
+               state: Optional[StateIO] = None):
         """Append ``q`` tokens per row to the paged cache and score
         them in one :func:`~apex_tpu.ops.flash_decode` launch.
 
@@ -1050,7 +1420,7 @@ class PagedDecoder:
         LM head — the chunked-prefill shape, where one next-token
         distribution is wanted and front-padding pins the chunk's last
         valid token to row ``q - 1``.  ``k_scale``/``v_scale``,
-        ``window`` and ``tp_axis``: as in :meth:`decode`
+        ``window``, ``state`` and ``tp_axis``: as in :meth:`decode`
         (quantize-on-write appends / the window pool / per-shard
         ``shard_map`` body).  Returns (logits ``[b, q, vocab]`` or
         ``[b, 1, vocab]``, k_pool', v_pool'[, k_scale', v_scale']
@@ -1059,7 +1429,7 @@ class PagedDecoder:
         x, out = self._paged(
             params, k_pool, v_pool, tokens, positions, write_pages,
             write_offsets, page_table, kv_len, k_scale=k_scale,
-            v_scale=v_scale, tp_axis=tp_axis, window=window)
+            v_scale=v_scale, tp_axis=tp_axis, window=window, state=state)
         with jax.named_scope("head"):
             x = self.block.final_norm(params, x)
             if last_only:

@@ -55,6 +55,14 @@ so a seeded arrival trace replays bit-identically):
   long prompt never holds more than a window and a chunk there.  A
   chunk or a decode step that finds the window pool dry preempts from
   the back, like growth in the full pool.
+* **slots beside pages** (ISSUE 35): where the cache has a
+  ``state_pool`` (a model with state-space layers) a request owns one
+  SLOT of recurrent state beside its pages.  Admission asks the cache
+  for both: a request with pages to be had and no slot free waits (at
+  short contexts the slot is what runs out first).  A slot never
+  grows; retirement, a deadline and preemption give it back with the
+  pages, and the preempted request re-prefills into whatever slot its
+  re-admission takes.
 
 Resilience policy (ISSUE 10 — docs/serving.md "Failure semantics"):
 
@@ -124,6 +132,9 @@ class Request:
     # layers that keep only a window of tokens (ISSUE 29); None until
     # such a pool admits it
     window: Optional[WindowPages] = None
+    # the slot of recurrent state it owns, where the model has
+    # state-space layers (ISSUE 35); None until admission takes one
+    slot: Optional[int] = None
     kv_len: int = 0               # tokens whose K/V sit in the pool
     # tokens launched and not yet on the host (ISSUE 34): 1 while the
     # decode launch that holds this row is in flight, else 0.  The
@@ -404,6 +415,13 @@ class ContinuousBatchingScheduler:
                 need = self.chunk_size if chunked else ctx
             if need > budget:
                 break
+            if self.cache.slots_free == 0:
+                # pages or not, a request needs a slot for its
+                # recurrent state: it waits for a retirement
+                if not self.running and not admitted:
+                    raise PagePoolExhausted(
+                        "no state slot free and nothing running")
+                break
             if shared:
                 # pin the shared pages FIRST: index eviction inside
                 # the allocation retry below may otherwise free them
@@ -452,6 +470,7 @@ class ContinuousBatchingScheduler:
                     break
             self.waiting.popleft()
             req.pages = pages
+            req.slot = self.cache.allocate_slot(req.rid)
             req.state = RUNNING
             req.prefix_hit = bool(m)
             req.prefix_tokens = m
@@ -545,9 +564,12 @@ class ContinuousBatchingScheduler:
         return victim
 
     def _release(self, req: Request) -> None:
-        """Give back every page ``req`` holds, of both lifetimes."""
+        """Give back every page ``req`` holds, of both lifetimes, and
+        its slot of recurrent state."""
         self.cache.free(req.pages)
         req.pages = []
+        self.cache.free_slot(req.slot)
+        req.slot = None
         if req.window is not None:
             self.wpool.release(req.window)
             req.window = None

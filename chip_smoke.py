@@ -18,6 +18,10 @@ work, and it has no CPU mode.  With random weights made from a seed it
   the rung and at the widest row: the same first token, the same K/V
   to bf16 rounding, every rung on the widest row's kernel route;
 * warms the verify, chunk and int8 executables at two layers;
+* runs one period of a state-space hybrid (nine Mamba-2 layers, one
+  attention layer, published widths): the in-place decode update
+  against the chunked scan on the same tokens, a slot's bytes across
+  another row's step, a reused slot starting from zero;
 * with four or more devices, repeats train on a ``(2, 2, 1)`` mesh and
   serve at ``tp=4`` and checks that state is spread over the mesh.
 
@@ -48,9 +52,11 @@ from apex_tpu.analysis import hot_path_guard
 from apex_tpu.ops import (flash_attention_qkv, flash_attention_qkv_route,
                           flash_attention_route, flash_decode,
                           flash_decode_latent_route, flash_decode_route,
-                          routing_override)
-from apex_tpu.serving import (DeepseekV2Config, PagedDecoder, ServingEngine,
+                          routing_override, ssm_decode_route)
+from apex_tpu.serving import (DeepseekV2Config, GraniteHybridConfig,
+                              PagedDecoder, ServingEngine,
                               ServingModelConfig, SpecConfig, poisson_trace)
+from apex_tpu.serving.model import StateIO
 from apex_tpu.serving.engine import prefill_route
 from apex_tpu.transformer import parallel_state
 from apex_tpu.transformer.testing import (build_flagship_train_step,
@@ -71,6 +77,11 @@ GRAD_TOL = 3e-2
 # they round at different places.  Measured (PR 33): 3.7e-2 between
 # them, where each lies 4.1e-2 and 4.3e-2 from the float32 reference.
 LATENT_FORMS_TOL = 6e-2
+# The recurrence's two forms, bf16 activations, a float32 state: the
+# chunked scan's matrix products round where the token-by-token update
+# does not.  Measured (PR 35), one period at the published widths:
+# 2.3e-2 on the logits and on the state, 1.4e-2 on the tail.
+SSM_FORMS_TOL = 6e-2
 # Chips holding equal shards of one program's state.
 BALANCE_FACTOR = 1.5
 
@@ -466,6 +477,98 @@ def leg_latent(cfg, *, seed, page_size, row, doc_pages, max_new) -> dict:
             "memory": memory_by_device()}
 
 
+def leg_state_space(cfg, *, seed, page_size, row, max_new) -> dict:
+    """The recurrence's two forms on the same tokens, and the slots.
+
+    A row of ``row`` tokens goes through the decoder whole (the chunked
+    scan from zero); then its first half again as a row, written into a
+    slot, and its second half token by token through the in-place
+    decode update: one mathematics, so the final state and the logits
+    agree to rounding.  The step that advances that slot leaves another
+    slot's bytes as they were.  Then an engine with ONE slot to hand
+    out serves two requests one after the other: the second takes the
+    slot the first gave back and serves what it serves alone."""
+    dec = PagedDecoder(cfg)
+    params = cfg.init_params(seed)
+    rng = np.random.RandomState(seed)
+    seq = rng.randint(0, cfg.vocab_size, row)
+    half = row // 2
+    one = lambda a: jnp.asarray(np.asarray(a, np.int32)[None])
+    prefill = jax.jit(dec.prefill)
+    logits, _, _, state, tail = prefill(
+        params, one(seq), one(np.ones(row)), one(np.arange(row)))
+    eng = ServingEngine(cfg, params, num_pages=row // page_size + 8,
+                        page_size=page_size, max_batch=2,
+                        prefill_budget=half, state_slots=4)
+    cache, spool = eng.cache, eng.cache.state_pool
+    route = ssm_decode_route(spool.ssm)
+    tokens = np.zeros((row,), np.int32)
+    tokens[:half] = seq[:half]
+    seg = (np.arange(row) < half).astype(np.int32)
+    _, k, v, s_half, t_half = prefill(
+        params, one(tokens), one(seg), one(np.arange(row) * seg))
+    pages = cache.allocate(cache.pages_needed(row), 0)
+    idx = np.arange(row)
+    cache.write_tokens(
+        k[:, 0], v[:, 0], np.where(seg, np.asarray(pages)[idx // page_size],
+                                   0), np.where(seg, idx % page_size, 0))
+    spool.write(2, s_half[:, 0], t_half[:, 0])
+    spool.write(3, state[:, 0], tail[:, 0])         # a bystander
+    bystander = np.asarray(spool.ssm[:, 3])
+    decode = jax.jit(dec.decode, donate_argnums=(1, 2))
+    table = cache.page_table([pages])
+    steps = []
+    for p in range(half, row):
+        out = decode(params, cache.k, cache.v, one(seq[p]), one(p), table,
+                     one(p + 1),
+                     state=StateIO(spool.ssm, spool.conv, one(2), one(0)))
+        cache.k, cache.v, spool.ssm, spool.conv = out[1:]
+        steps.append(out[0][0])
+    forms = {"logits": rel_l2(jnp.stack(steps), logits[0, half:]),
+             "state": rel_l2(spool.ssm[:, 2], state[:, 0]),
+             "tail": rel_l2(spool.conv[:, 2], tail[:, 0])}
+    require(max(forms.values()) < SSM_FORMS_TOL,
+            f"state space: decode form against chunked form {forms}")
+    require(np.array_equal(bystander, np.asarray(spool.ssm[:, 3])),
+            "state space: a slot changed under another row's steps")
+    cache.free(pages)
+
+    def serve(engine, prompts):
+        engine.warmup()
+        reqs = []
+        for prompt in prompts:
+            reqs.append(engine.submit(prompt, max_new))
+            with hot_path_guard("state-space serving after warm-up",
+                                transfers=None, tripwire=False):
+                engine.run()
+        return [r.generated for r in reqs]
+
+    prompts = [[int(t) for t in rng.randint(0, cfg.vocab_size, n)]
+               for n in (half // 2, half + page_size)]
+    kw = dict(num_pages=row // page_size + 8, page_size=page_size,
+              max_batch=2, prefill_budget=half)
+    shared = serve(ServingEngine(cfg, params, state_slots=2, **kw), prompts)
+    alone = serve(ServingEngine(cfg, params, **kw), prompts[1:])
+    require(shared[1] == alone[0],
+            "state space: a reused slot did not start from zero")
+    return {"layers": cfg.num_layers, "route": route, "rel_l2": forms,
+            "page_head_dim": cfg.page_head_dim,
+            "memory": memory_by_device()}
+
+
+def _hybrid_config(periods: int) -> GraniteHybridConfig:
+    """Granite 4.0-H Micro's published widths, ``periods`` periods of
+    ten layers deep, an eighth of the vocabulary."""
+    return GraniteHybridConfig(
+        vocab_size=12544, hidden_size=2048, num_heads=32, num_kv_heads=8,
+        layer_types=tuple("attention" if i % 10 == 5 else "mamba"
+                          for i in range(10 * periods)),
+        intermediate_size=8192, mamba_n_heads=64, mamba_d_head=64,
+        mamba_d_state=128, attention_multiplier=0.015625,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        logits_scaling=8.0, dtype=jnp.bfloat16)
+
+
 def _latent_config(layers: int) -> DeepseekV2Config:
     """DeepSeek-V2's published widths, ``layers`` deep (one dense), an
     eighth of the experts and of the vocabulary."""
@@ -552,6 +655,11 @@ def main() -> int:
         doc_pages=32, max_new=8))
     require(latent["route"] == ROUTES_ON_TPU["decode"],
             f"latent: the paged route is {latent['route']}")
+
+    hybrid = _report("state_space", leg_state_space(
+        _hybrid_config(1), seed=4, page_size=64, row=1024, max_new=8))
+    require(hybrid["route"] == ROUTES_ON_TPU["decode"],
+            f"state space: the update's route is {hybrid['route']}")
 
     if len(devices) >= 4:
         _report("train_mesh", leg_train(

@@ -104,6 +104,24 @@ def test_latent_leg():
     json.dumps(out)
 
 
+def test_state_space_leg():
+    from apex_tpu.serving import GraniteHybridConfig
+
+    cfg = GraniteHybridConfig(
+        vocab_size=96, hidden_size=64, num_heads=8, num_kv_heads=2,
+        layer_types=tuple("attention" if i == 5 else "mamba"
+                          for i in range(10)),
+        intermediate_size=128, mamba_n_heads=8, mamba_d_head=16,
+        mamba_d_state=16, attention_multiplier=0.125, mamba_chunk_size=8,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        logits_scaling=8.0)
+    out = chip_smoke.leg_state_space(cfg, seed=4, page_size=8, row=32,
+                                     max_new=3)
+    assert out["route"] == "xla" and out["page_head_dim"] == 128
+    assert max(out["rel_l2"].values()) < 1e-4
+    json.dumps(out)
+
+
 def test_warm_leg():
     out = chip_smoke.leg_warm(TOY_SERVE, spec_k=2, chunk_size=16, seed=1,
                               **TOY_TRAFFIC)

@@ -24,7 +24,8 @@ from apex_tpu.ops import (flash_attention, flash_attention_qkv,
                           flash_attention_qkv_route, flash_attention_route,
                           flash_decode, flash_decode_latent,
                           flash_decode_latent_route, flash_decode_route,
-                          layer_norm, routing_override)
+                          layer_norm, routing_override, ssm_decode_route,
+                          ssm_decode_update)
 
 SDS = jax.ShapeDtypeStruct
 BF16 = jnp.bfloat16
@@ -116,6 +117,10 @@ CELL_GEOMETRIES = {
     "trinity_decode_window": (64, 48, 1, (4, 3072, 64, 8, 128), 97, 4096),
     "trinity_chunk_full": (1, 48, 2048, (1, 6144, 64, 8, 128), 264, None),
     "trinity_chunk_window": (1, 48, 2048, (4, 3072, 64, 8, 128), 97, 4096),
+    # granite-4.0-h-micro-serve.json: 32 query heads over 8 K/V heads
+    # of 64, stored 128 wide
+    "granite_decode": (64, 32, 1, (4, 1600, 64, 8, 128), 32, None),
+    "granite_chunk": (1, 32, 1024, (4, 1600, 64, 8, 128), 32, None),
 }
 
 
@@ -165,6 +170,50 @@ def test_latent_decode_lowers_at_the_cells_geometry(b, q_len):
     assert 'kernel_name = "flash_decode_latent"' in calls[0]
     # ONE operand is the pool, whole: key and value at once
     assert calls[0].count("tensor<6x4608x64x640x") == 1, calls[0][-600:]
+
+
+# the state-space cell (benchmark/configs/granite-4.0-h-micro-serve.json):
+# 64 rows, each advancing its slot of the 36-layer pool in place
+@pytest.mark.parametrize("layer", [0, 35])
+def test_state_update_lowers_at_the_cells_geometry(layer):
+    heads, lanes, n, ch = 64, 4096, 128, 4096 + 256
+    ssm = SDS((36, 66, n, lanes), jnp.float32)
+    conv = SDS((36, 66, 1, 3 * ch), BF16)
+    assert ssm_decode_route(ssm) == "decode"
+    # half a lane tile of state, or lanes that are not whole tiles: XLA
+    assert ssm_decode_route(SDS((36, 66, 64, lanes), jnp.float32)) == "xla"
+    assert ssm_decode_route(SDS((36, 66, n, 4000), jnp.float32)) == "xla"
+
+    def fn(ssm, conv, slots, xbc, dt, conv_w, conv_b, dt_bias, a_log, d):
+        return ssm_decode_update(
+            ssm, conv, slots, xbc, dt, layer=layer, conv_w=conv_w,
+            conv_b=conv_b, dt_bias=dt_bias, a_log=a_log, d_skip=d,
+            heads=heads)
+
+    head = SDS((heads,), BF16)
+    calls = [line for line in _tpu_text(
+        fn, ssm, conv, SDS((64,), jnp.int32), SDS((64, ch), BF16),
+        SDS((64, heads), BF16), SDS((4, ch), BF16), SDS((ch,), BF16),
+        head, head, head).splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1
+    assert 'kernel_name = "ssm_decode_update"' in calls[0]
+    # both pools are operands, whole, and come back in place
+    assert calls[0].count("tensor<36x66x128x4096xf32>") == 2, calls[0][-600:]
+    assert calls[0].count("tensor<36x66x1x13056xbf16>") == 2
+    assert "output_operand_aliases" in calls[0]
+
+
+def test_a_prefill_row_of_heads_of_64_lowers_on_the_varlen_route():
+    # 32 query heads over 8 K/V heads of 64, a row of 1,024 and its half
+    for width in (512, 1024):
+        q, k = SDS((32, width, 64), BF16), SDS((8, width, 64), BF16)
+        assert flash_attention_route(q, k, segment_ids=True)["fwd"] \
+            == "varlen"
+        fn = lambda q, k, v, seg: flash_attention(
+            q, k, v, causal=True, segment_ids=seg, scale=1 / 64)
+        assert _mosaic_calls(
+            fn, SDS((1, 32, width, 64), BF16), SDS((1, 8, width, 64), BF16),
+            SDS((1, 8, width, 64), BF16), SDS((1, width), jnp.int32)) == 1
 
 
 # -- generic flash attention: block-skip routes with more than one block ----
