@@ -17,6 +17,7 @@ from apex_tpu.ops.attention import (  # noqa: F401
     flash_decode_latent,
     flash_decode_latent_route,
     flash_decode_route,
+    latent_walk_tiles,
     ring_attention,
     routing_override,
 )

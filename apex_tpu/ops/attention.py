@@ -61,7 +61,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import math
-from typing import Optional, Tuple, Union
+from typing import NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -2917,6 +2917,17 @@ def flash_decode(
 # every contraction is ``[rows, width] x [width, columns]`` on the MXU.
 # The page walk, the two slots and the hand-over of the first block to
 # the step before are ``_make_decode_kernel``'s.
+#
+# A grid step takes a TILE (ISSUE 36): at one query a row, up to
+# ``_LATENT_GROUP`` rows of the batch whose page tables start with the
+# same blocks.  The blocks they share are fetched once and scored by
+# one product over all the tile's query rows (the shared walk); then
+# each row walks what is left of its own table with its own rows of the
+# same running max, sum and accumulator (the tail walk).  A row that
+# shares with nobody is a tile of one and a shared walk of no block: the
+# tail walk alone, which is also how a chunk (``q_len > 1``) runs, one
+# tile of ``_latent_q_tile`` positions a step.  What decides a tile is
+# the page tables (``latent_walk_tiles``); nothing is configured.
 # ---------------------------------------------------------------------------
 
 # Query rows (position, head) a grid step of the latent kernel takes at
@@ -2924,9 +2935,19 @@ def flash_decode(
 # rows at 17k of context: 3.6 / 3.0 / 3.1 ms a call at 512 / 1,024 /
 # 2,048 columns; a 512-token chunk 19.1 / 16.1 / 16.9 ms at 256 / 512 /
 # 1,024 rows; skipping the mask on blocks wholly under the causal edge
-# made both slower; PERF.md, PR 33).
+# made both slower; PERF.md, PR 33).  Why a tile (PERF.md, PR 36; 128
+# heads, 64 rows over 8 documents of 16,384 tokens): the same 0.30 TFLOP
+# took 2.83 ms as 64 steps of 128 query rows and 2.12 ms as 16 steps of
+# 512, each against one fetched block; walked in tiles a call takes
+# 2.17 ms (documents of 8 rows), 2.27 (of 3), 2.43 (of 2), 2.92 where
+# no page is shared (2.83 before the tiles: a turn's cost not found),
+# a chunk 16.5 (16.3).  A shared product as wide as the tile has rows:
+# tiles of 2 and 3 scored 512 rows wide took 4.11 and 2.83 ms.
 _LATENT_ROWS = 512
 _LATENT_BLOCK_COLS = 1024
+# Rows of the batch a tile takes at most (and no more than fit
+# ``_LATENT_ROWS``).
+_LATENT_GROUP = 4
 
 
 def _latent_q_tile(q_len, heads):
@@ -2938,40 +2959,166 @@ def _latent_q_tile(q_len, heads):
     return tq
 
 
-def _make_latent_decode_kernel(*, scale, page_size, q_len, heads, width,
-                               v_dim, pages, p_max, tq):
-    """grid (b, q_len // tq); scalar prefetch (page_table [b, p_max],
-    kv_len [b], layer [1], q_start [b]).  The q block is ``[tq * heads,
-    width]``, position major (row ``r`` is query position ``r //
-    heads``); the pool stays in HBM and a step walks its own live
-    pages, ``pages`` a turn, each one DMA ``[page_size, width]`` into
-    one of two slots.  A step whose query positions all lie before
-    ``q_start`` (the front padding of a chunk) walks nothing."""
-    rows_n = heads * tq
-    P = pages
-    n_cols = P * page_size
+def _latent_geometry(q_len, heads, page_size, p_max):
+    """(tq, group, pages): query positions a unit of work takes, units a
+    tile takes at most, pages a block."""
+    tq = _latent_q_tile(q_len, heads)
+    group = (max(1, min(_LATENT_GROUP, _LATENT_ROWS // heads))
+             if q_len == 1 else 1)
+    pages = max(1, min(_LATENT_BLOCK_COLS // page_size, p_max))
+    return tq, group, pages
 
-    def kernel(pt_ref, kl_ref, layer_ref, qs_ref, q_ref, kv_hbm, o_ref,
-               kv_buf, sem, flight_ref, m_ref, l_ref, acc_ref):
-        b_idx, t_idx = pl.program_id(0), pl.program_id(1)
-        n_b, n_t = pl.num_programs(0), pl.num_programs(1)
+
+class LatentTiles(NamedTuple):
+    """What a call of the latent kernel walks, tile by tile.  A UNIT is
+    a row of the batch at ``tq`` of its query positions (``n_units = b *
+    q_len // tq``, row major).  ``members [n_units * group]``: tile
+    ``t``'s units at ``[t * group, t * group + count[t])`` (a slot
+    after them: some unit); ``count [n_units]``: 0 from the first
+    tile that holds nothing; ``shared [n_units]``: the leading blocks
+    the tile's units walk together.  ``walked`` / ``fetched``: the
+    blocks the units must see, summed, and the block DMAs this walk
+    issues for them."""
+    members: jnp.ndarray
+    count: jnp.ndarray
+    shared: jnp.ndarray
+    walked: jnp.ndarray
+    fetched: jnp.ndarray
+
+
+def latent_walk_tiles(page_table, kv_len, *, q_len: int, heads: int,
+                      page_size: int, q_start=None) -> LatentTiles:
+    """The tiles of one :func:`flash_decode_latent` call, from what the
+    call receives.  At one query a row: rows whose tables start with
+    the same page become neighbours (rows with nothing to see last),
+    cut into tiles of at most ``group`` that never hold two first
+    pages; a tile's shared run is the leading blocks on which every row
+    holds its first row's page ids and which lie wholly under every
+    row's ``kv_len``.  Otherwise every unit is its own tile."""
+    page_table = jnp.asarray(page_table, jnp.int32)
+    kv_len = jnp.asarray(kv_len, jnp.int32)
+    b, p_max = page_table.shape
+    tq, group, pages = _latent_geometry(q_len, heads, page_size, p_max)
+    n_cols = pages * page_size
+    n_t = q_len // tq
+    # the blocks a unit must see: ``span`` of the kernel
+    ti = jnp.arange(n_t, dtype=jnp.int32)[None]
+    last = jnp.minimum(kv_len[:, None] - q_len + (ti + 1) * tq - 1,
+                       p_max * page_size - 1)
+    live = last >= 0
+    if q_start is not None:
+        live &= (ti + 1) * tq > jnp.asarray(q_start, jnp.int32)[:, None]
+    blocks = jnp.where(live, jnp.maximum(last, 0) // n_cols + 1,
+                       0).reshape(-1)
+    n_units = b * n_t
+    unit = jnp.arange(n_units, dtype=jnp.int32)
+    walked = jnp.sum(blocks)
+    if group == 1:
+        return LatentTiles(unit, jnp.ones_like(unit), jnp.zeros_like(unit),
+                           walked, walked)
+    # compared all against all (``b`` is a batch's rows: no sort, no
+    # scatter): ``[i, j]`` is about row ``i`` and row ``j``
+    key = jnp.where(kv_len > 0, page_table[:, 0],
+                    jnp.iinfo(jnp.int32).max)
+    alike = key[:, None] == key[None, :]
+    earlier = alike & (unit[None, :] < unit[:, None])
+    place = jnp.sum(earlier, axis=1) % group    # its slot in its tile
+    opens = place == 0
+    before = (key[None, :] < key[:, None]) | earlier
+    tile_of = jnp.sum(before & opens[None, :], axis=1) + opens - 1
+    inside = tile_of[None, :] == unit[:, None]          # [tile, row]
+    count = jnp.sum(inside, axis=1, dtype=jnp.int32)
+    slot = jnp.arange(group, dtype=jnp.int32)
+    holds = inside[:, None, :] & (place[None, None, :] == slot[None, :, None])
+    members = jnp.sum(jnp.where(holds, unit[None, None, :], 0), axis=2)
+    # a slot past a tile's count keeps the unit of the tile before: a
+    # query block whose index does not move is not copied again
+    held = jnp.max(jnp.where(
+        (slot[None, None, :] < count[None, :, None])
+        & (unit[None, :, None] <= unit[:, None, None]),
+        unit[None, :, None], 0), axis=1)
+    members = jnp.take_along_axis(members, held, axis=0)
+    whole = p_max // pages
+    first = page_table[members[tile_of, 0]]
+    same = (page_table[:, :whole * pages]
+            == first[:, :whole * pages]).reshape(b, whole, pages).all(-1)
+    run = jnp.minimum(
+        jnp.min(jnp.where(same, whole, jnp.arange(whole)[None]), axis=1),
+        kv_len // n_cols)
+    shared = jnp.min(jnp.where(inside, run[None, :], whole), axis=1)
+    shared = jnp.where(count > 1, shared, 0)
+    fetched = walked - jnp.sum(shared * jnp.maximum(count - 1, 0))
+    return LatentTiles(members.reshape(-1), count, shared, walked, fetched)
+
+
+def _make_latent_decode_kernel(*, scale, page_size, q_len, heads, width,
+                               v_dim, pages, p_max, tq, group):
+    """grid (n_units,): a step is one tile of :class:`LatentTiles`.
+    Scalar prefetch (page_table [b, p_max], kv_len [b], layer [1],
+    q_start [b], members, count, shared).  ``q`` comes as ``group``
+    blocks ``[rows, width]``, one a member, ``rows = tq * heads``
+    position major (row ``r`` is query position ``r // heads``); the
+    pool and the output stay in HBM.  A step walks its shared blocks
+    once for all its members, then each member's own, ``pages`` pages a
+    turn, each one DMA ``[page_size, width]`` into one of two slots;
+    the turns of a step are one sequence of fetches, and its last
+    sends the next step's first.  A unit whose query positions all lie
+    before ``q_start`` (the front padding of a chunk) walks nothing."""
+    R = heads * tq
+    G, P = group, pages
+    n_cols = P * page_size
+    n_t = q_len // tq
+
+    def kernel(pt_ref, kl_ref, layer_ref, qs_ref, mem_ref, cnt_ref, sh_ref,
+               *refs):
+        q_refs, (kv_hbm, o_hbm, kv_buf, sem, flight_ref, q_all, m_ref,
+                 l_ref, acc_ref, o_buf) = refs[:G], refs[G:]
+        t_idx, n_tiles = pl.program_id(0), pl.num_programs(0)
         layer = layer_ref[0]
 
-        def first_row(bi, ti):
-            return kl_ref[bi] - q_len + ti * tq
+        def pick(g, xs):
+            """``xs[g]`` of a short list of scalars."""
+            out = xs[0]
+            for j in range(1, len(xs)):
+                out = jnp.where(g == j, xs[j], out)
+            return out
 
-        def span(bi, ti):
-            """The step's last live page and how many blocks lead to it
-            (0: nothing to see)."""
-            last = jax.lax.min(first_row(bi, ti) + tq - 1,
-                               p_max * page_size - 1)
-            hi = jax.lax.div(jax.lax.max(last, 0), page_size)
-            live = (last >= 0) & ((ti + 1) * tq > qs_ref[bi])
-            return hi, jnp.where(live, jax.lax.div(hi, P) + 1, 0)
+        def plan(t):
+            """Tile ``t``'s walk: the shared run, where in the step's
+            sequence of fetches each member's own blocks start (the
+            last entry: how many fetches in all), and each member's
+            row of the batch, first query row and last live page."""
+            shared, count = sh_ref[t], cnt_ref[t]
+            at, bis, row0s, his = [shared], [], [], []
+            for g in range(G):
+                u = mem_ref[t * G + g]
+                bi, ti = (u, 0) if n_t == 1 else (jax.lax.div(u, n_t),
+                                                  jax.lax.rem(u, n_t))
+                row0 = kl_ref[bi] - q_len + ti * tq
+                last = jax.lax.min(row0 + tq - 1, p_max * page_size - 1)
+                hi = jax.lax.div(jax.lax.max(last, 0), page_size)
+                live = ((g < count) & (last >= 0)
+                        & ((ti + 1) * tq > qs_ref[bi]))
+                own = jnp.where(
+                    live, jax.lax.max(jax.lax.div(hi, P) + 1 - shared, 0), 0)
+                at.append(at[-1] + own)
+                bis.append(bi)
+                row0s.append(row0)
+                his.append(hi)
+            return at, bis, row0s, his
 
-        def block_dma(bi, hi, i, slot, wait=False):
-            first = i * P
-            live = jax.lax.min(P, hi - first + 1)
+        def fetch(walk, k):
+            """The ``k``-th fetch of a step: (row of the batch, block,
+            live pages of it)."""
+            at, bis, _, his = walk
+            g = sum(((k >= at[j]).astype(jnp.int32) for j in range(1, G)),
+                    jnp.int32(0))
+            block = at[0] + k - pick(g, at[:G])
+            return (pick(g, bis), block,
+                    jax.lax.min(P, pick(g, his) - block * P + 1))
+
+        def block_dma(bi, block, live, slot, wait=False):
+            first = block * P
 
             def page_dma(j, _):
                 page = 0 if wait else pt_ref[bi, first + j]
@@ -2983,13 +3130,24 @@ def _make_latent_decode_kernel(*, scale, page_size, q_len, heads, width,
 
             jax.lax.fori_loop(0, live, page_dma, 0)
 
-        hi, n_blocks = span(b_idx, t_idx)
-        row0 = first_row(b_idx, t_idx)
+        def out_dma(g, wait=False):
+            home = 0 if wait else mem_ref[t_idx * G + g]
+            copy = pltpu.make_async_copy(o_buf.at[g], o_hbm.at[home],
+                                         sem.at[2])
+            copy.wait() if wait else copy.start()
+            return 0
 
-        @pl.when((b_idx == 0) & (t_idx == 0))
+        def land():
+            """Wait for the rows the step before sent home."""
+            jax.lax.fori_loop(0, flight_ref[2],
+                              lambda g, _: out_dma(g, wait=True), 0)
+            flight_ref[2] = 0
+
+        @pl.when(t_idx == 0)
         def _():
             flight_ref[0] = 0
             flight_ref[1] = 0
+            flight_ref[2] = 0
 
             # a page of a live block that is never fetched is masked,
             # but 0 x NaN is NaN: the slots start finite
@@ -3001,122 +3159,223 @@ def _make_latent_decode_kernel(*, scale, page_size, q_len, heads, width,
 
             jax.lax.fori_loop(0, P, zero, 0)
 
-        m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
-
-        slot0 = flight_ref[0]
-
-        @pl.when((n_blocks > 0) & (flight_ref[1] == 0))
+        @pl.when(cnt_ref[t_idx] > 0)
         def _():
-            block_dma(b_idx, hi, 0, slot0)
+            walk = plan(t_idx)
+            shared, total = walk[0][0], walk[0][G]
+            count = cnt_ref[t_idx]
 
-        pos = jax.lax.div(jax.lax.broadcasted_iota(
-            jnp.int32, (rows_n, n_cols), 0), heads)
-        col = jax.lax.broadcasted_iota(jnp.int32, (rows_n, n_cols), 1)
+            # what a step does once a member it does for the members it
+            # has: the rows of the others hold what they held, and a
+            # product's output row depends on its own query row only
+            def members(do, where=True):
+                for g in range(G):
+                    pl.when((g < count) & where)(functools.partial(do, g))
 
-        def turn(i, _):
-            slot = jax.lax.rem(slot0 + i, 2)
+            def start(g):
+                q_all[g] = q_refs[g][0]
+                m_ref[g] = jnp.full((R, 1), _NEG_INF, jnp.float32)
+                l_ref[g] = jnp.zeros((R, 1), jnp.float32)
+                acc_ref[g] = jnp.zeros((R, v_dim), jnp.float32)
 
-            @pl.when(i + 1 < n_blocks)
+            # a tile with nothing to see (a chunk's front padding, rows
+            # without a token) only sends zeros home
+            members(start, total > 0)
+
+            slot0 = flight_ref[0]
+
+            @pl.when((total > 0) & (flight_ref[1] == 0))
             def _():
-                block_dma(b_idx, hi, i + 1, 1 - slot)
+                block_dma(*fetch(walk, 0), slot0)
 
-            @pl.when(i + 1 == n_blocks)
-            def _():
-                wrap = t_idx + 1 == n_t
-                nb_ = jnp.where(wrap, b_idx + 1, b_idx)
-                nt_ = jnp.where(wrap, 0, t_idx + 1)
-                there = nb_ < n_b
-                nb_ = jax.lax.min(nb_, n_b - 1)
-                nhi, nn = span(nb_, nt_)
-                sent = there & (nn > 0)
+            # the step after this one: its first block is sent for by
+            # this step's last turn
+            after = plan(jax.lax.min(t_idx + 1, n_tiles - 1))
+            sent = (t_idx + 1 < n_tiles) & (after[0][G] > 0)
+            ahead = fetch(after, 0)
 
-                @pl.when(sent)
-                def _():
-                    block_dma(nb_, nhi, 0, 1 - slot)
+            pos = jax.lax.div(jax.lax.broadcasted_iota(
+                jnp.int32, (R, n_cols), 0), heads)
+            col = jax.lax.broadcasted_iota(jnp.int32, (R, n_cols), 1)
 
-                flight_ref[0] = 1 - slot
-                flight_ref[1] = sent.astype(jnp.int32)
+            def run(k0, n, bi, hi, block0, who, row0=None):
+                """``n`` turns from the step's ``k0``-th fetch on: the
+                blocks from ``block0`` of row ``bi``'s table, scored by
+                the query rows of ``who``: one member, or a slice of
+                them, as one product, unmasked (no first query row is
+                given: a shared block lies wholly under every member's
+                limit)."""
+                then = fetch(walk, k0 + n)
 
-            block_dma(b_idx, hi, i, slot, wait=True)
-            # the block serves as K, whole, and its first v_dim lanes as V
-            kv = kv_buf[slot].reshape(n_cols, width)
-            s = jax.lax.dot_general(
-                q_ref[0], kv, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            # the causal limit of the q_len tail is also the kv_len
-            # cutoff: a column past it, fetched or not, never scores
-            s = jnp.where(i * n_cols + col <= row0 + pos, s, _NEG_INF)
-            m_prev = m_ref[...]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            pexp = _masked_exp(s, m_new)
-            alpha = jnp.where(m_prev <= _NEG_INF / 2, 0.0,
-                              jnp.exp(m_prev - m_new))
-            l_ref[...] = alpha * l_ref[...] + jnp.sum(
-                pexp, axis=-1, keepdims=True)
-            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-                pexp.astype(kv.dtype), kv[:, :v_dim],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[...] = m_new
-            return 0
+                def get(ref):
+                    x = ref[who]
+                    return x.reshape(-1, x.shape[-1])
 
-        jax.lax.fori_loop(0, n_blocks, turn, 0)
+                def put(ref, x):
+                    ref[who] = (x.reshape(-1, R, x.shape[-1])
+                                if isinstance(who, slice) else x)
 
-        l = l_ref[...]
-        o_ref[0] = (acc_ref[...] / jnp.where(l == 0, 1.0, l)).astype(
-            o_ref.dtype)
+                def turn(i, _):
+                    k, block = k0 + i, block0 + i
+                    slot = jax.lax.rem(slot0 + k, 2)
+
+                    @pl.when(i + 1 < n)
+                    def _():
+                        block_dma(bi, block + 1, jax.lax.min(
+                            P, hi - (block + 1) * P + 1), 1 - slot)
+
+                    @pl.when(i + 1 == n)
+                    def _():
+                        @pl.when(k + 1 < total)
+                        def _():
+                            block_dma(*then, 1 - slot)
+
+                        @pl.when(k + 1 == total)
+                        def _():
+                            @pl.when(sent)
+                            def _():
+                                block_dma(*ahead, 1 - slot)
+
+                            flight_ref[0] = 1 - slot
+                            flight_ref[1] = sent.astype(jnp.int32)
+
+                    block_dma(0, 0, jax.lax.min(P, hi - block * P + 1),
+                              slot, wait=True)
+                    # the block serves as K, whole, and its first v_dim
+                    # lanes as V
+                    kv = kv_buf[slot].reshape(n_cols, width)
+                    s = jax.lax.dot_general(
+                        get(q_all), kv, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    if row0 is not None:
+                        # the causal limit of the q_len tail is also the
+                        # kv_len cutoff: a column past it, fetched or
+                        # not, never scores
+                        s = jnp.where(block * n_cols + col <= row0 + pos,
+                                      s, _NEG_INF)
+                    # the running max is of the scores before ``scale``:
+                    # a difference times the scale is the same
+                    # arithmetic with the mask between or without it (a
+                    # product and a difference may be fused into one
+                    # rounding, or not)
+                    m_prev = get(m_ref)
+                    m_new = jnp.maximum(
+                        m_prev, jnp.max(s, axis=-1, keepdims=True))
+                    pexp = jnp.where(m_new <= _NEG_INF / 2, 0.0,
+                                     jnp.exp((s - m_new) * scale))
+                    alpha = jnp.where(m_prev <= _NEG_INF / 2, 0.0,
+                                      jnp.exp((m_prev - m_new) * scale))
+                    put(l_ref, alpha * get(l_ref) + jnp.sum(
+                        pexp, axis=-1, keepdims=True))
+                    put(acc_ref, get(acc_ref) * alpha + jax.lax.dot_general(
+                        pexp.astype(kv.dtype), kv[:, :v_dim],
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32))
+                    put(m_ref, m_new)
+                    return 0
+
+                jax.lax.fori_loop(0, n, turn, 0)
+
+            at, bis, row0s, his = walk
+
+            def tail(g, _):
+                k0 = pick(g, at[:G])
+                run(k0, pick(g, at[1:]) - k0, pick(g, bis), pick(g, his),
+                    shared, g, pick(g, row0s))
+                return 0
+
+            if G == 1:
+                tail(0, 0)
+            else:
+                # the shared product is as many rows as the tile has
+                for c in range(2, G + 1):
+                    pl.when(count == c)(functools.partial(
+                        run, 0, shared, bis[0], his[0], 0, slice(0, c)))
+                jax.lax.fori_loop(0, count, tail, 0)
+
+            land()
+
+            def finish(g):
+                l = l_ref[g]
+                o_buf[g] = (acc_ref[g] / jnp.where(l == 0, 1.0, l)).astype(
+                    o_buf.dtype)
+                out_dma(g)
+
+            def blank(g):
+                o_buf[g] = jnp.zeros((R, v_dim), o_buf.dtype)
+                out_dma(g)
+
+            members(finish, total > 0)
+            members(blank, total == 0)
+            flight_ref[2] = count
+
+        @pl.when(t_idx + 1 == n_tiles)
+        def _():
+            land()
 
     return kernel
 
 
-def _flash_decode_latent_pallas(q, kv_pages, page_table, kv_len, scale,
-                                layer, v_dim, q_start):
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "v_dim", "tq", "group", "pages", "interpret"))
+def _flash_decode_latent_pallas(q, kv_pages, page_table, kv_len, layer,
+                                q_start, tiles, *, scale, v_dim, tq, group,
+                                pages, interpret):
+    """A jit of its own, ``layer [1]`` an operand: the calls of all of
+    a model's layers share one tracing and one lowering of the kernel
+    (six of them cost a decode executable 9.9 s of tracing on the
+    chip's host where this costs it one; XLA inlines the calls).  What
+    a trace depends on besides its operands' shapes is static."""
     b, q_len, heads, width = q.shape
     page_size = kv_pages.shape[2]
     p_max = page_table.shape[1]
-    tq = _latent_q_tile(q_len, heads)
-    pages = max(1, min(_LATENT_BLOCK_COLS // page_size, p_max))
-    rows_n = heads * tq
-    q_spec = pl.BlockSpec((1, rows_n, width), lambda bi, t, *_: (bi, t, 0))
-    o_spec = pl.BlockSpec((1, rows_n, v_dim), lambda bi, t, *_: (bi, t, 0))
+    rows = heads * tq
+    n_units = b * q_len // tq
+    q_specs = [pl.BlockSpec(
+        (1, rows, width),
+        lambda t, pt, kl, la, qs, members, *_, g=g: (
+            members[t * group + g], 0, 0)) for g in range(group)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(b, q_len // tq),
-        in_specs=[q_spec, pl.BlockSpec(memory_space=pltpu.HBM)],
-        out_specs=o_spec,
+        num_scalar_prefetch=7,
+        grid=(n_units,),
+        in_specs=q_specs + [pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=pl.BlockSpec(memory_space=pltpu.HBM),
         scratch_shapes=[
             pltpu.VMEM((2, pages, page_size, width), kv_pages.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SMEM((2,), jnp.int32),
-            pltpu.VMEM((rows_n, 1), jnp.float32),
-            pltpu.VMEM((rows_n, 1), jnp.float32),
-            pltpu.VMEM((rows_n, v_dim), jnp.float32),
+            pltpu.SemaphoreType.DMA((3,)),
+            pltpu.SMEM((3,), jnp.int32),
+            pltpu.VMEM((group, rows, width), q.dtype),
+            pltpu.VMEM((group, rows, 1), jnp.float32),
+            pltpu.VMEM((group, rows, 1), jnp.float32),
+            pltpu.VMEM((group, rows, v_dim), jnp.float32),
+            pltpu.VMEM((group, rows, v_dim), q.dtype),
         ],
     )
+    q = q.reshape(n_units, rows, width)
     o = pl.pallas_call(
         _make_latent_decode_kernel(
             scale=scale, page_size=page_size, q_len=q_len, heads=heads,
-            width=width, v_dim=v_dim, pages=pages, p_max=p_max, tq=tq),
+            width=width, v_dim=v_dim, pages=pages, p_max=p_max, tq=tq,
+            group=group),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, q_len * heads, v_dim), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_units, rows, v_dim), q.dtype),
         name="flash_decode_latent",
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_DECODE_VMEM_LIMIT),
-        interpret=use_interpret(),
-    )(page_table, kv_len, jnp.full((1,), layer, jnp.int32), q_start,
-      q.reshape(b, q_len * heads, width), kv_pages)
+        interpret=interpret,
+    )(page_table, kv_len, layer, q_start, tiles.members, tiles.count,
+      tiles.shared, *[q] * group, kv_pages)
     return o.reshape(b, q_len, heads, v_dim)
 
 
 def _paged_latent_attention_xla(q, kv_pages, page_table, kv_len, scale,
-                                layer, v_dim, q_start):
+                                layer, v_dim):
     """The generic baseline: the row's pages gathered into one
     ``[b, p_max * page_size, width]`` view, plain masked attention in
-    float32, the same mathematics as the kernel (``q_start`` saves it
-    nothing: it computes the padding's rows like the others)."""
+    float32, the same mathematics as the kernel (it computes a chunk's
+    front padding like the other rows, and every row against its own
+    view, shared pages or not)."""
     b, q_len, heads, width = q.shape
     kc = kv_pages[layer][page_table].reshape(b, -1, width).astype(
         jnp.float32)
@@ -3150,7 +3409,8 @@ def flash_decode_latent_route(q, kv_pages) -> str:
 
 
 def flash_decode_latent(q, kv_pages, page_table, kv_len, *, v_dim: int,
-                        scale: float, layer: int = 0, q_start=None):
+                        scale: float, layer: int = 0, q_start=None,
+                        tiles: Optional[LatentTiles] = None):
     """Decode-mode attention against a paged LATENT cache: every query
     head scores the same key, and the key's first ``v_dim`` numbers are
     the value.
@@ -3165,9 +3425,11 @@ def flash_decode_latent(q, kv_pages, page_table, kv_len, *, v_dim: int,
     to see returns zeros.  ``q_start`` ``[b]`` (0 where not given): the
     query positions before it are a chunk's front padding, and what is
     returned for them is unspecified (the kernel skips the steps that
-    hold nothing else and returns zeros there).  Returns ``[b, q_len,
-    heads, v_dim]``: the probabilities' sum over the values, not yet
-    projected up."""
+    hold nothing else and returns zeros there).  ``tiles``: what
+    :func:`latent_walk_tiles` makes of these tables, for a caller that
+    makes it once for the calls of all its layers; a row's output does
+    not depend on its tile.  Returns ``[b, q_len, heads, v_dim]``: the
+    probabilities' sum over the values, not yet projected up."""
     if not 0 <= layer < kv_pages.shape[0]:
         raise ValueError(f"layer {layer} is not one of the pool's "
                          f"{kv_pages.shape[0]}")
@@ -3179,11 +3441,21 @@ def flash_decode_latent(q, kv_pages, page_table, kv_len, *, v_dim: int,
     page_table = jnp.asarray(page_table, jnp.int32)
     q_start = (jnp.zeros_like(kv_len) if q_start is None
                else jnp.asarray(q_start, jnp.int32))
-    attend = (_flash_decode_latent_pallas
-              if flash_decode_latent_route(q, kv_pages) == "decode"
-              else _paged_latent_attention_xla)
-    return attend(q, kv_pages, page_table, kv_len, float(scale), layer,
-                  v_dim, q_start)
+    if flash_decode_latent_route(q, kv_pages) != "decode":
+        return _paged_latent_attention_xla(
+            q, kv_pages, page_table, kv_len, float(scale), layer, v_dim)
+    q_len, heads = q.shape[1:3]
+    page_size = kv_pages.shape[2]
+    if tiles is None:
+        tiles = latent_walk_tiles(page_table, kv_len, q_len=q_len,
+                                  heads=heads, page_size=page_size,
+                                  q_start=q_start)
+    tq, group, pages = _latent_geometry(q_len, heads, page_size,
+                                        page_table.shape[1])
+    return _flash_decode_latent_pallas(
+        q, kv_pages, page_table, kv_len, jnp.full((1,), layer, jnp.int32),
+        q_start, tiles, scale=float(scale), v_dim=v_dim, tq=tq, group=group,
+        pages=pages, interpret=use_interpret())
 
 
 # ---------------------------------------------------------------------------

@@ -102,7 +102,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from apex_tpu.ops import flash_attention, flash_decode, flash_decode_latent
+from apex_tpu.ops import (flash_attention, flash_decode, flash_decode_latent,
+                          latent_walk_tiles)
 from apex_tpu.ops.ssm import causal_conv, ssd_chunk_scan, ssm_decode_update
 from apex_tpu.serving.experts import expert_layer, swiglu
 from apex_tpu.serving.kv_cache import (latent_width, pad_latent,
@@ -638,7 +639,8 @@ class DeepseekV2Block:
 
     refuses = ("tp", "kv_quant", "speculation", "prefill_only",
                "kv_import")
-    stat_names = ("moe_pairs_held", "moe_load_max")
+    stat_names = ("moe_pairs_held", "moe_load_max", "latent_blocks_walked",
+                  "latent_blocks_fetched")
     refuses_doc = "What DeepSeek-V2 refuses"
 
     def __init__(self, cfg: DeepseekV2Config):
@@ -1053,12 +1055,17 @@ class PagedDecoder:
 
         return attend
 
-    def _stats(self, stats):
+    def _stats(self, stats, walk=()):
         """The block's per-launch counters as one int32 vector, in
         ``stat_names`` order (afmoe: the (token, expert) pairs its held
-        experts took, summed over layers; the most any one took)."""
+        experts took, summed over layers; the most any one took;
+        deepseek_v2 besides: ``walk``, the blocks of latent pages a
+        layer's call had to see and those it fetched, 0 where no call
+        walked pages)."""
         load = jnp.stack(stats)
-        return jnp.stack([jnp.sum(load), jnp.max(load)]).astype(jnp.int32)
+        walk = walk or (0,) * (len(self.stat_names) - 2)
+        return jnp.stack([jnp.sum(load), jnp.max(load),
+                          *walk]).astype(jnp.int32)
 
     # -- admission: packed varlen prefill --------------------------------
 
@@ -1214,6 +1221,18 @@ class PagedDecoder:
             tables[True] = dict(page_table=window.pages,
                                 kv_start=window.start)
         stats = []
+        if self.latent:
+            # a chunk's rows before its first real one are padding (they
+            # write the scratch page): the kernel walks nothing for them
+            q_start = (None if real.ndim == 1 else
+                       real.shape[1] - jnp.sum(real, axis=1, dtype=jnp.int32))
+            # which rows walk which pages together: the same for every
+            # layer, so made once a step (docs/serving.md, "The latent
+            # page")
+            tiles = latent_walk_tiles(
+                page_table, kv_len, q_len=1 if len(lead) == 1 else lead[1],
+                heads=self.cfg.num_heads, page_size=k_pool.shape[2],
+                q_start=q_start)
 
         def absorbed(pi):
             """A latent layer's ``attend`` over the latent pages: the
@@ -1224,10 +1243,6 @@ class PagedDecoder:
             cfg = self.cfg
             pool = pools[False]
             rank, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-            # a chunk's rows before its first real one are padding (they
-            # write the scratch page): the kernel walks nothing for them
-            q_start = (None if real.ndim == 1 else
-                       real.shape[1] - jnp.sum(real, axis=1, dtype=jnp.int32))
 
             def attend(q_nope, q_pe, latent, wuk, wuv, *, scale):
                 nh = q_nope.shape[-2]
@@ -1242,7 +1257,7 @@ class PagedDecoder:
                 o = flash_decode_latent(
                     q.reshape(b, -1, nh, width), pool[0], page_table,
                     kv_len, v_dim=rank, scale=scale, layer=pi,
-                    q_start=q_start)
+                    q_start=q_start, tiles=tiles)
                 with jax.named_scope("mla_absorb"):
                     ctx = jnp.einsum(
                         "...hc,chd->...hd", o.reshape(*lead, nh, rank),
@@ -1338,7 +1353,8 @@ class PagedDecoder:
         if state is not None:
             out += tuple(slot_pools)
         if stats:
-            out += (self._stats(stats),)
+            out += (self._stats(stats, (tiles.walked, tiles.fetched)
+                                if self.latent else ()),)
         return x, out
 
     # -- steady state: paged decode --------------------------------------

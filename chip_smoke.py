@@ -51,7 +51,8 @@ from apex_tpu import _native
 from apex_tpu.analysis import hot_path_guard
 from apex_tpu.ops import (flash_attention_qkv, flash_attention_qkv_route,
                           flash_attention_route, flash_decode,
-                          flash_decode_latent_route, flash_decode_route,
+                          flash_decode_latent, flash_decode_latent_route,
+                          flash_decode_route, latent_walk_tiles,
                           routing_override, ssm_decode_route)
 from apex_tpu.serving import (DeepseekV2Config, GraniteHybridConfig,
                               PagedDecoder, ServingEngine,
@@ -408,9 +409,73 @@ SERVE_TRAFFIC = dict(rate=8.0, prompt_len=(64, 256), max_new=(16, 64),
                      page_size=64, max_batch=8)
 
 
-def leg_latent(cfg, *, seed, page_size, row, doc_pages, max_new) -> dict:
-    """Latent attention's two forms on the same tokens, and a shared
-    page under a second reader.
+def grouped_walk(cfg, width, *, seed, page_size, rows, documents, doc_pages,
+                 own_pages) -> dict:
+    """Decode rows that read the same latent pages (ISSUE 36): ``rows``
+    rows over ``documents`` documents of ``doc_pages`` pages and up to
+    ``own_pages`` of their own.  What a row returns in the batch is what
+    it returns called alone; on the chip, how long a layer's call takes
+    with the documents shared, with no page shared, and as a quarter of
+    the rows at four query positions each (the tile the shared walk
+    scores with, against one walk: ISSUE 36's step 0)."""
+    rng = np.random.RandomState(seed)
+    heads, rank = cfg.num_heads, cfg.kv_lora_rank
+    p_max = doc_pages + own_pages
+    n_pages = 1 + documents * doc_pages + rows * own_pages
+    key = jax.random.PRNGKey(seed)
+    pool = jax.random.normal(key, (1, n_pages, page_size, width), cfg.dtype)
+    q = jax.random.normal(key, (rows, 1, heads, width), cfg.dtype)
+    table = np.zeros((rows, p_max), np.int32)
+    kv_len = np.zeros((rows,), np.int32)
+    free = 1 + documents * doc_pages
+    for r in range(rows):
+        own = rng.randint(1, own_pages * page_size)
+        held = -(-own // page_size)
+        table[r, :doc_pages] = 1 + (r % documents) * doc_pages + np.arange(
+            doc_pages)
+        table[r, doc_pages:doc_pages + held] = free + np.arange(held)
+        free += held
+        kv_len[r] = doc_pages * page_size + own
+    # the same lengths over pages no two rows hold in the same place
+    apart = table.copy()
+    apart[:, :doc_pages] = rng.randint(1, n_pages, (rows, doc_pages))
+    scale = cfg.softmax_scale
+    call = jax.jit(lambda q, table, kv_len: flash_decode_latent(
+        q, pool, table, kv_len, v_dim=rank, scale=scale))
+    together = np.asarray(call(q, table, kv_len), np.float32)
+    gap = max(float(np.abs(together[r] - np.asarray(call(
+        q[r:r + 1], table[r:r + 1], kv_len[r:r + 1]), np.float32)[0]).max())
+        for r in range(0, rows, max(1, rows // 8)))
+    tiles = latent_walk_tiles(table, kv_len, q_len=1, heads=heads,
+                              page_size=page_size)
+    out = {"alone_max_abs_gap": gap, "blocks_walked": int(tiles.walked),
+           "blocks_fetched": int(tiles.fetched), "call_ms": "not measured"}
+    if jax.default_backend() == "tpu":
+        def ms(*args, n=20):
+            jax.block_until_ready(call(*args))
+            best = float("inf")
+            for _ in range(3):
+                t0 = time.perf_counter()
+                for _ in range(n):
+                    o = call(*args)
+                jax.block_until_ready(o)
+                best = min(best, (time.perf_counter() - t0) / n * 1e3)
+            return best
+
+        few = rows // 4
+        out["call_ms"] = {
+            "documents_shared": ms(q, table, kv_len),
+            "no_page_shared": ms(q, apart, kv_len),
+            "quarter_of_the_rows_at_4_positions": ms(
+                q.reshape(few, 4, heads, width), table[:few], kv_len[:few])}
+    return out
+
+
+def leg_latent(cfg, *, seed, page_size, row, doc_pages, max_new,
+               walk) -> dict:
+    """Latent attention's two forms on the same tokens, a shared page
+    under a second reader, and rows that walk shared pages together
+    (``walk``: :func:`grouped_walk`'s sizes).
 
     A row of ``row`` tokens goes through the decoder whole (expanded,
     no cache), then its second half again over the latent pages its
@@ -470,10 +535,21 @@ def leg_latent(cfg, *, seed, page_size, row, doc_pages, max_new) -> dict:
     for r in (first, second):
         require(len(r.generated) == max_new,
                 f"latent: request {r.rid} made {len(r.generated)} tokens")
+    width = int(cache.k.shape[-1])
+    del eng, cache, before
+    free_device_memory()
+    grouped = grouped_walk(cfg, width, seed=seed, page_size=page_size,
+                           **walk)
+    # the MXU gives a query row the same numbers whatever rows share its
+    # product; the XLA route batches its einsum otherwise
+    require(grouped["alone_max_abs_gap"] <= (0 if route == "decode"
+                                             else 1e-5),
+            f"latent: a row alone differs by "
+            f"{grouped['alone_max_abs_gap']:.2e} from the row in its batch")
     return {"layers": cfg.num_layers, "route": route,
             "rel_l2": {"absorbed_vs_expanded": forms},
             "shared_tokens": second.prefix_tokens,
-            "pool_width": int(cache.k.shape[-1]),
+            "pool_width": width, "grouped_walk": grouped,
             "memory": memory_by_device()}
 
 
@@ -652,7 +728,9 @@ def main() -> int:
 
     latent = _report("latent", leg_latent(
         _latent_config(WARM_LAYERS), seed=3, page_size=64, row=1024,
-        doc_pages=32, max_new=8))
+        doc_pages=32, max_new=8,
+        # the cell's decode step: 64 rows on 8 documents of 16,384 tokens
+        walk=dict(rows=64, documents=8, doc_pages=256, own_pages=24)))
     require(latent["route"] == ROUTES_ON_TPU["decode"],
             f"latent: the paged route is {latent['route']}")
 

@@ -5,13 +5,15 @@ interpret mode, the XLA baseline) against a plain softmax over EXPANDED
 heads: the absorbed query and output are what a latent layer hands the
 kernel, per-head keys and values are what they stand for."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from apex_tpu.ops import (flash_decode_latent, flash_decode_latent_route,
-                          routing_override)
+                          latent_walk_tiles, routing_override)
 
 HEADS, RANK, ROPE, NOPE, VDIM, PS = 8, 64, 8, 16, 16, 8
 WIDTH = 128        # RANK + ROPE, padded to a lane tile
@@ -148,3 +150,137 @@ def test_routes_and_refusals():
         flash_decode_latent(zeros((1, 1, HEADS, 64)), zeros(pool.shape),
                             zeros((1, 2), jnp.int32), zeros((1,), jnp.int32),
                             v_dim=RANK, scale=1.0)
+
+
+# ---------------------------------------------------------------------------
+# Rows that read the same pages share the fetch and the product (ISSUE
+# 36).  Pages of 64 as in the cell: a block is 16 pages, 1,024 columns.
+# ---------------------------------------------------------------------------
+
+BIG, BLOCK = 64, 16
+
+
+def shared_batch(seed, rows, p_max, dtype=jnp.float32):
+    """``rows``: (document or None, pages of it the row holds, kv_len,
+    {slot: a page of its own in the document's place}).  A document's
+    pages are the same page ids in every row that holds it; what a row
+    needs beyond them is its own."""
+    rng = np.random.RandomState(seed)
+    table = np.zeros((len(rows), p_max), np.int32)
+    docs, free = {}, 1
+    for i, (doc, lead, kv_len, own) in enumerate(rows):
+        if doc is not None and doc not in docs:
+            docs[doc] = free + np.arange(p_max)
+            free += p_max
+        need = -(-kv_len // BIG)
+        for slot in range(need):
+            if doc is not None and slot < lead and slot not in own:
+                table[i, slot] = docs[doc][slot]
+            else:
+                table[i, slot] = free
+                free += 1
+    pool = np.zeros((1, free, BIG, WIDTH), np.float32)
+    pool[..., :RANK + ROPE] = rng.randn(1, free, BIG, RANK + ROPE)
+    q = np.zeros((len(rows), 1, HEADS, WIDTH), np.float32)
+    q[..., :RANK + ROPE] = rng.randn(len(rows), 1, HEADS, RANK + ROPE)
+    return (jnp.asarray(q, dtype), jnp.asarray(pool, dtype), table,
+            np.asarray([r[2] for r in rows], np.int32))
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted(route):
+    # a trace keeps the route it was made under
+    return jax.jit(lambda *args: flash_decode_latent(
+        *args, v_dim=RANK, scale=SCALE))
+
+
+def latent(route, q, pool, table, kv_lens):
+    with routing_override(decode=route):
+        return np.asarray(_jitted(route)(q, pool, table, kv_lens),
+                          np.float32)
+
+
+def tiles_of(table, kv_lens):
+    t = latent_walk_tiles(table, kv_lens, q_len=1, heads=HEADS,
+                          page_size=BIG)
+    n = int(np.sum(np.asarray(t.count) > 0))
+    return (np.asarray(t.count)[:n].tolist(),
+            np.asarray(t.shared)[:n].tolist(),
+            int(t.walked), int(t.fetched))
+
+
+DOC = 2 * BLOCK     # a document of two whole blocks
+SHARED = {
+    "eight rows on one document, tails of one to three blocks": dict(
+        rows=[(0, DOC, 2048 + own, {}) for own in
+              (5, 700, 1024, 1030, 2000, 2048, 2500, 3072)],
+        p_max=80, count=[4, 4], shared=[2, 2],
+        walked=8 * 2 + 15, fetched=2 * 2 + 15),
+    "documents of one, two, three and five rows": dict(
+        rows=[(2, DOC, 2300, {}), (0, DOC, 2100, {}), (3, DOC, 2050, {}),
+              (2, DOC, 2049, {}), (3, DOC, 3000, {}), (3, DOC, 2500, {}),
+              (1, DOC, 2200, {}), (2, DOC, 2700, {}), (3, DOC, 2048, {}),
+              (1, DOC, 2600, {}), (3, DOC, 2101, {})],
+        # in the order of the documents' first pages: 2, 0, 3, 3, 1
+        p_max=48, count=[3, 1, 4, 1, 2], shared=[2, 0, 2, 0, 2],
+        walked=11 * 3 - 1, fetched=(2 + 3) + 3 + (2 + 3) + 3 + (2 + 2)),
+    "two tables that part inside a block": dict(
+        rows=[(0, DOC, 2200, {}), (0, DOC, 2300, {20: "own"})],
+        p_max=48, count=[2], shared=[1], walked=6, fetched=1 + 4),
+    "a row that ends inside what the others share": dict(
+        rows=[(0, DOC, 2200, {}), (0, DOC, 1500, {}), (0, DOC, 2300, {}),
+              (0, DOC, 2100, {})],
+        p_max=48, count=[4], shared=[1], walked=3 + 2 + 3 + 3,
+        fetched=1 + 2 + 1 + 2 + 2),
+    "rows with nothing to see between rows that share": dict(
+        rows=[(0, DOC, 2100, {}), (None, 0, 0, {}), (0, DOC, 2200, {}),
+              (None, 0, 0, {}), (0, DOC, 2300, {})],
+        p_max=48, count=[3, 2], shared=[2, 0], walked=9, fetched=2 + 3),
+    "no sharing at all": dict(
+        rows=[(None, 0, 70, {}), (None, 0, 1024, {}), (None, 0, 2300, {}),
+              (None, 0, 1025, {}), (None, 0, 3000, {})],
+        p_max=48, count=[1] * 5, shared=[0] * 5, walked=10, fetched=10),
+}
+
+
+@pytest.mark.parametrize("name", list(SHARED))
+def test_the_tiles_follow_from_the_page_tables(name):
+    spec = SHARED[name]
+    _, _, table, kv_lens = shared_batch(1, spec["rows"], spec["p_max"])
+    assert tiles_of(table, kv_lens) == (
+        spec["count"], spec["shared"], spec["walked"], spec["fetched"])
+
+
+@pytest.mark.parametrize("name", list(SHARED))
+def test_a_row_returns_bitwise_what_it_returns_alone(name):
+    spec = SHARED[name]
+    q, pool, table, kv_lens = shared_batch(
+        sum(map(ord, name)), spec["rows"], spec["p_max"])
+    got = latent("decode", q, pool, table, kv_lens)
+    for i in range(len(kv_lens)):
+        alone = latent("decode", q[i:i + 1], pool, table[i:i + 1],
+                       kv_lens[i:i + 1])
+        np.testing.assert_array_equal(got[i], alone[0], err_msg=f"row {i}")
+    np.testing.assert_allclose(
+        got, latent("xla", q, pool, table, kv_lens), atol=2e-5)
+
+
+@pytest.mark.parametrize("name", list(SHARED))
+def test_a_row_does_not_depend_on_the_batch_around_it(name):
+    """Another order, and half of the rows taken away: other tiles,
+    the same numbers."""
+    spec = SHARED[name]
+    q, pool, table, kv_lens = shared_batch(
+        sum(map(ord, name)), spec["rows"], spec["p_max"])
+    got = latent("decode", q, pool, table, kv_lens)
+    order = np.random.RandomState(3).permutation(len(kv_lens))
+    for keep in (order, order[::2]):
+        part = latent("decode", q[keep], pool, table[keep], kv_lens[keep])
+        np.testing.assert_array_equal(part, got[keep])
+
+
+def test_the_grouped_walk_and_the_baseline_agree_in_bfloat16():
+    spec = SHARED["documents of one, two, three and five rows"]
+    args = shared_batch(5, spec["rows"], spec["p_max"], dtype=jnp.bfloat16)
+    np.testing.assert_allclose(latent("decode", *args),
+                               latent("xla", *args), atol=2e-2)

@@ -96,10 +96,14 @@ def test_latent_leg():
         intermediate_size=64, moe_intermediate_size=24, n_routed_experts=16,
         experts_held=(0, 2), top_k=3, n_group=8, topk_group=3,
         routed_scaling_factor=16.0, n_shared_experts=2, rope_original_max=32)
-    out = chip_smoke.leg_latent(cfg, seed=3, page_size=8, row=32,
-                                doc_pages=4, max_new=3)
+    out = chip_smoke.leg_latent(
+        cfg, seed=3, page_size=8, row=32, doc_pages=4, max_new=3,
+        walk=dict(rows=8, documents=2, doc_pages=4, own_pages=2))
     assert out["route"] == "xla" and out["shared_tokens"] == 32
     assert out["pool_width"] == 128
+    walk = out["grouped_walk"]
+    assert walk["blocks_walked"] == walk["blocks_fetched"] == 8
+    assert walk["call_ms"] == "not measured"      # no chip here
     assert out["rel_l2"]["absorbed_vs_expanded"] < 1e-5
     json.dumps(out)
 

@@ -22,6 +22,7 @@ if BENCH not in sys.path:
 
 from reference import deepseek_v2_serve as ref  # noqa: E402
 
+from apex_tpu.ops import attention  # noqa: E402
 from apex_tpu.ops.attention import routing_override  # noqa: E402
 from apex_tpu.serving import (DeepseekV2Config, ServingEngine,  # noqa: E402
                               SimClock, SpecConfig)
@@ -474,6 +475,40 @@ def test_sharing_pages_changes_no_token(params):
     assert got[0] == got[1]
 
 
+def test_rows_on_one_document_walk_it_together_and_serve_the_same(
+        params, monkeypatch):
+    """ISSUE 36, through the engine and the interpreted kernel: with
+    sharing on, three questions on one document decode as one tile
+    whose shared blocks are fetched once; the streams are those of an
+    engine in which every request holds pages of its own, where every
+    block walked is a block fetched."""
+    # a block of two pages, so that the toy document is two whole blocks
+    monkeypatch.setattr(attention, "_LATENT_BLOCK_COLS", 2 * PS)
+    doc = prompts(10, (32,))[0]
+    asks = [doc + q for q in prompts(11, (3, 9, 5))]
+    got, walks = [], []
+    with routing_override(decode="decode"):
+        for sharing in (True, False):
+            eng = engine(params, prefix_sharing=sharing)
+            eng.submit(doc, 1)
+            eng.run()
+            PHASE_RING.clear()
+            reqs = [eng.submit(a, 8) for a in asks]
+            eng.run()
+            got.append([r.generated for r in reqs])
+            walks.append([
+                (r.attrs["latent_blocks_walked"],
+                 r.attrs["latent_blocks_fetched"])
+                for r in PHASE_RING.snapshot()
+                if r.name == "engine.decode" and r.attrs["rids"]])
+    assert got[0] == got[1]
+    together, apart = walks
+    assert together and all(w >= f > 0 for w, f in together)
+    # three rows, two shared blocks: four fetches fewer a step
+    assert max(w - f for w, f in together) == 4
+    assert apart and all(w == f > 0 for w, f in apart)
+
+
 def test_recover_and_defrag_carry_the_one_operand(params):
     eng = engine(params)
     a = eng.submit(prompts(12, (20,))[0], 3)
@@ -493,7 +528,8 @@ def test_the_ring_and_the_executables_name_what_the_model_adds(params):
     decodes = [r for r in PHASE_RING.snapshot() if r.name == "engine.decode"]
     # ... on the span in which the launch's tokens landed (ISSUE 34)
     assert decodes and all(
-        {"moe_pairs_held", "moe_load_max"} <= set(r.attrs)
+        {"moe_pairs_held", "moe_load_max", "latent_blocks_walked",
+         "latent_blocks_fetched"} <= set(r.attrs)
         for r in decodes if r.attrs["rids"])
     assert sum(bool(r.attrs["rids"]) for r in decodes) == eng.decode_steps
     lowered = eng.analysis_executables()

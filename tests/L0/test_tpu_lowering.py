@@ -24,8 +24,8 @@ from apex_tpu.ops import (flash_attention, flash_attention_qkv,
                           flash_attention_qkv_route, flash_attention_route,
                           flash_decode, flash_decode_latent,
                           flash_decode_latent_route, flash_decode_route,
-                          layer_norm, routing_override, ssm_decode_route,
-                          ssm_decode_update)
+                          latent_walk_tiles, layer_norm, routing_override,
+                          ssm_decode_route, ssm_decode_update)
 
 SDS = jax.ShapeDtypeStruct
 BF16 = jnp.bfloat16
@@ -151,17 +151,23 @@ def test_paged_decode_lowers_at_the_cells_geometries(name):
 
 
 # the latent cell (benchmark/configs/deepseek-v2-serve-ep8-l6.json): 128
-# heads over one vector of 576 numbers, stored 640 wide; a decode step,
-# the chunk, the smoke's chunk
-@pytest.mark.parametrize("b, q_len", [(64, 1), (1, 512), (2, 8)])
-def test_latent_decode_lowers_at_the_cells_geometry(b, q_len):
+# heads over one vector of 576 numbers, stored 640 wide; a decode step
+# (the grouped walk of ISSUE 36, its tiles made by the call or handed in
+# as the decoder hands them, once for all layers), the chunk, the
+# smoke's chunk
+@pytest.mark.parametrize("b, q_len, tiles", [
+    (64, 1, False), (64, 1, True), (1, 512, False), (2, 8, False)])
+def test_latent_decode_lowers_at_the_cells_geometry(b, q_len, tiles):
     q = SDS((b, q_len, 128, 640), BF16)
     pool = SDS((6, 4608, 64, 640), BF16)
     assert flash_decode_latent_route(q, pool) == "decode"
 
     def fn(q, pool, pt, kl, start):
+        made = latent_walk_tiles(pt, kl, q_len=q_len, heads=128,
+                                 page_size=64) if tiles else None
         return flash_decode_latent(q, pool, pt, kl, v_dim=512,
-                                   scale=0.114721, layer=5, q_start=start)
+                                   scale=0.114721, layer=5, q_start=start,
+                                   tiles=made)
 
     calls = [line for line in _tpu_text(
         fn, q, pool, SDS((b, 280), jnp.int32), SDS((b,), jnp.int32),
