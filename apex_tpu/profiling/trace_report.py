@@ -32,6 +32,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 import jax
 import numpy as np
 
+from apex_tpu.telemetry import scopes
+
 __all__ = ["OpTime", "parse_trace_dir", "top_ops_report",
            "format_top_ops", "device_time_ms", "hlo_fusion_flops",
            "join_roofline", "PHASES", "classify_op", "PhaseReport",
@@ -429,12 +431,6 @@ def device_time_ms(fn: Callable, *args, steps: int = 4,
     return tot
 
 
-_CALLER_RE = re.compile(
-    r"%([\w.-]+) = [^\n]*?(?:calls|to_apply|body)=%([\w.-]+)", re.M)
-_COMP_DEF_RE = re.compile(
-    r"^(?:ENTRY )?%?([\w.-]+) \([^)]*\) -> .+ \{", re.M)
-
-
 def _body_flops(body: str) -> float:
     """Matmul/conv flops inside one HLO computation body.
 
@@ -503,11 +499,9 @@ def hlo_fusion_flops(hlo_text: str) -> Dict[str, tuple]:
     fusions.  A ``while`` body's flops are counted once (the static
     trip count is not recoverable from HLO text) — an undercount for
     loops, stated here rather than hidden."""
-    names = [m for m in _COMP_DEF_RE.finditer(hlo_text)]
-    bodies: Dict[str, str] = {}
-    for i, m in enumerate(names):
-        end = names[i + 1].start() if i + 1 < len(names) else len(hlo_text)
-        bodies[m.group(1)] = hlo_text[m.start():end]
+    # the computation splitter and the caller / op_name patterns are
+    # telemetry.scopes's: the tree's one parser of optimized-HLO text
+    bodies: Dict[str, str] = scopes.computations(hlo_text)
 
     _ITEM = {"f64": 8, "s64": 8, "u64": 8, "f32": 4, "s32": 4, "u32": 4,
              "bf16": 2, "f16": 2, "s16": 2, "u16": 2,
@@ -540,16 +534,16 @@ def hlo_fusion_flops(hlo_text: str) -> Dict[str, tuple]:
         if body is None:
             return 0.0
         total = _body_flops(body)
-        for m in _CALLER_RE.finditer(body):
+        for m in scopes.CALLER_RE.finditer(body):
             total += comp_flops(m.group(2), stack + (comp,))
         memo[comp] = total
         return total
 
     out: Dict[str, tuple] = {}
-    for m in _CALLER_RE.finditer(hlo_text):
+    for m in scopes.CALLER_RE.finditer(hlo_text):
         inst, comp = m.group(1), m.group(2)
         line = hlo_text[m.start():hlo_text.find("\n", m.start())]
-        nm = re.search(r'op_name="([^"]*)"', line)
+        nm = scopes.OP_NAME_RE.search(line)
         out.setdefault(inst, (comp_flops(comp), comp_bytes(comp),
                               nm.group(1) if nm else ""))
     for comp in bodies:  # trace rows sometimes carry the COMPUTATION name
@@ -558,9 +552,7 @@ def hlo_fusion_flops(hlo_text: str) -> Dict[str, tuple]:
     # calls (Pallas kernels) are opaque to flops parsing (est 0, like
     # XLA's own cost analysis) but their source identity matters most:
     # they ARE the handwritten kernels being judged
-    for m in re.finditer(
-            r"^\s*(?:ROOT )?%([\w.-]+) = [^\n]*?"
-            r'op_name="([^"]*)"', hlo_text, re.M):
+    for m in scopes.NAMED_INSTRUCTION_RE.finditer(hlo_text):
         out.setdefault(m.group(1), (0.0, 0.0, m.group(2)))
     return out
 

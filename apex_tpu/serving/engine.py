@@ -156,6 +156,7 @@ from apex_tpu.serving.scheduler import (FINISHED, RUNNING, WAITING,
                                         QueueFullError, Request)
 from apex_tpu.serving.spec import (NgramProposer, SpecConfig,
                                    commit_tokens)
+from apex_tpu.telemetry import scopes
 from apex_tpu.telemetry.phases import phase
 
 #: The compiled-shapes contract as code, in docs/serving.md table
@@ -510,9 +511,14 @@ class ServingEngine:
         # operands, and a state pool's (slots, fresh) those.  The step
         # bodies take them as they come and hand the decoder what the
         # cache is made of.
+        # The step bodies close over plain values only, never over the
+        # engine: telemetry.scopes keeps the jitted functions beyond the
+        # engine's life, and they must keep no pool or parameter alive.
         n_pool = len(self._pool_state())
         n_stat = 1 if decoder.stat_names else 0
         latent = decoder.latent
+        has_state = self.cache.state_pool is not None
+        has_window = self.cache.window_pool is not None
 
         def carries(args):
             """(pools, the other operands, decoder keywords)."""
@@ -522,11 +528,11 @@ class ServingEngine:
             kw = {}
             if quant:
                 kw.update(k_scale=pools[2], v_scale=pools[3])
-            if self.cache.state_pool is not None:
+            if has_state:
                 kw["state"] = StateIO(pools[-2], pools[-1],
                                       rest[-2], rest[-1])
                 pools, rest = pools[:-2], rest[:-2]
-            if self.cache.window_pool is not None:
+            if has_window:
                 kw["window"] = WindowKV(pools[-2], pools[-1],
                                         rest[-2], rest[-1])
                 rest = rest[:-2]
@@ -808,19 +814,23 @@ class ServingEngine:
 
     # -- compiled-artifact exposure (ISSUE 13) -----------------------------
 
-    def _executable_arg_structs(self) -> Dict[str, Tuple]:
+    def _executable_arg_structs(self, prefill_width: Optional[int] = None
+                                ) -> Dict[str, Tuple]:
         """``jax.ShapeDtypeStruct`` argument tuples per enabled
         executable of the compiled-shapes contract (minus the
         admission scatter, which :class:`PagedKVCache` owns) — the
         same shapes :meth:`warmup` launches, pinned against it by the
         no-drift regression so the analyzed artifacts are the served
-        artifacts."""
+        artifacts.  The prefill row is ``prefill_width`` wide (a rung
+        of :attr:`prefill_widths`; the widest where not given).  One
+        source of shapes for the HLO contracts, :meth:`warmup`'s rows
+        and the scope maps (:meth:`_register_scopes`)."""
         sds = jax.ShapeDtypeStruct
         i32 = jnp.int32
         params = jax.tree_util.tree_map(
             lambda a: sds(jnp.shape(a), a.dtype), self.params)
         pools = tuple(sds(a.shape, a.dtype) for a in self._pool_state())
-        S, b = self.prefill_budget, self.max_batch
+        S, b = prefill_width or self.prefill_budget, self.max_batch
         p_max = self.cache.max_pages_per_request
         wpool = self.cache.window_pool
 
@@ -950,6 +960,7 @@ class ServingEngine:
         ever sees; the zero-compiles-after-warmup pin runs a
         speculative + chunked trace too."""
         t0 = time.perf_counter()
+        self._register_scopes()
         wpool = self.cache.window_pool
         for S in self.prefill_widths:
             z = jnp.zeros((1, S), jnp.int32)
@@ -1016,6 +1027,26 @@ class ServingEngine:
             self.cache.warm_export()
         jax.block_until_ready(self.cache.k)
         return time.perf_counter() - t0
+
+    def _register_scopes(self) -> None:
+        """Hand every executable this engine launches to
+        :mod:`apex_tpu.telemetry.scopes`, at the shapes and placements
+        it is launched with: a dict entry each, nothing lowered or
+        compiled (``scope_maps()`` does that, on request)."""
+        state = (self.params,) + self._pool_state()
+        for S in self.prefill_widths:
+            structs = self._executable_arg_structs(S)
+            scopes.register(scopes.executable_name(self._prefill_fn),
+                            self._prefill_fn,
+                            (self.params,) + structs["prefill"][1:],
+                            variant=S)
+        # only the prefill row depends on S: any width's structs serve
+        for name, fn in (("decode", self._decode_fn),
+                         ("verify", self._verify_fn),
+                         ("chunk", self._chunk_fn)):
+            if fn is not None:
+                scopes.register(scopes.executable_name(fn), fn,
+                                state + structs[name][len(state):])
 
     def _scatter_row(self, kv, pages, offsets, wpages, slot=0) -> None:
         """A prefill row's stacks into the pool(s) they are for: K and V

@@ -680,10 +680,12 @@ class DeepseekV2Block:
             ctx = attend(q_nope, q_pe.astype(q.dtype), latent,
                          layer["wuk"], layer["wuv"],
                          scale=cfg.softmax_scale)
-        h = x + ctx @ layer["wo"]
+        with jax.named_scope("mla_out"):
+            h = x + ctx @ layer["wo"]
         u = _rms(h, layer["g2"], eps)
         if "mlp" in layer:
-            m = swiglu(u, layer["mlp"])
+            with jax.named_scope("mlp"):
+                m = swiglu(u, layer["mlp"])
         else:
             m, load = expert_layer(
                 u, layer["moe"], held=cfg.experts_held, top_k=cfg.top_k,

@@ -19,6 +19,12 @@ capture, per-op attribution) is :mod:`apex_tpu.profiling`:
   one also a :class:`PhaseRecord` in :data:`PHASE_RING`, the one
   bounded in-memory ring (the serving engine's step phases; the
   benchmark's per-layer readers cut their window out of it);
+- **scopes** — :mod:`apex_tpu.telemetry.scopes` (ISSUE 37): the device
+  half of the phases.  :func:`register` takes every executable the hot
+  path compiles at warm-up (a dict entry); :func:`scope_maps`
+  turns each, on request, into a map from optimized-HLO instruction to
+  the ``named_scope`` path it came from, and :func:`by_scope` sums a
+  profile's device events by it (``python -m apex_tpu.telemetry scopes``);
 - **schema** — :func:`validate_event` / :func:`validate_jsonl`, the
   CI-side contract every producer is tested against;
 - **sampler** — :class:`ProfileSampler` (ISSUE 9): periodic in-run
@@ -59,6 +65,12 @@ from apex_tpu.telemetry.phases import (  # noqa: F401
     phase,
 )
 from apex_tpu.telemetry.recorder import FlightRecorder  # noqa: F401
+from apex_tpu.telemetry.scopes import (  # noqa: F401
+    ScopeMap,
+    by_scope,
+    register,
+    scope_maps,
+)
 from apex_tpu.telemetry.sampler import (  # noqa: F401
     JaxProfilerTracer,
     ProfileSampler,
@@ -98,6 +110,10 @@ __all__ = [
     "PHASE_RING",
     "PhaseRecord",
     "phase",
+    "ScopeMap",
+    "by_scope",
+    "register",
+    "scope_maps",
     "SPAN_KINDS",
     "Span",
     "TTFT_SUM_TOLERANCE_MS",

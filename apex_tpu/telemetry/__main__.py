@@ -17,6 +17,12 @@ Subcommands:
   as a record.  Exit 1 when any tree is structurally broken (orphan
   spans, dangling parents) or a decomposition fails to sum to the
   measured TTFT within tolerance.
+- ``scopes --maps SCOPES.json PROFILE.xplane.pb`` — device time of each
+  mapped executable's runs in a profile, by the program's own
+  ``named_scope``s (ISSUE 37).  ``SCOPES.json`` is what
+  ``apex_tpu.telemetry.scopes.dump()`` wrote in the process that was
+  profiled; ``--executable jit__decode`` restricts to one, ``--depth
+  N`` cuts the scope paths.  Exit 1 when no mapped executable ran.
 """
 
 from __future__ import annotations
@@ -59,7 +65,24 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     p_tr.add_argument("--json", action="store_true",
                       help="emit trees + decompositions as JSON")
 
+    p_sc = sub.add_parser(
+        "scopes", help="device time of a profile's executables by the "
+                       "program's named scopes")
+    p_sc.add_argument("xplane", help="a profile's .xplane.pb")
+    p_sc.add_argument("--maps", required=True,
+                      help="the scope maps telemetry.scopes.dump() wrote")
+    p_sc.add_argument("--executable", default=None,
+                      help="one executable's name, e.g. jit__decode")
+    p_sc.add_argument("--depth", type=int, default=None,
+                      help="keep this many leading elements of a scope")
+
     args = parser.parse_args(argv)
+
+    if args.cmd == "scopes":
+        from apex_tpu.telemetry.scopes import run_scopes_cli
+
+        return run_scopes_cli(args.xplane, args.maps,
+                              executable=args.executable, depth=args.depth)
 
     if args.cmd == "trace":
         from apex_tpu.telemetry.tracing import run_trace_cli

@@ -55,6 +55,7 @@ fp32 FusedAdam is asserted on the emulated mesh in
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, NamedTuple, Optional, Sequence
 
@@ -65,6 +66,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from apex_tpu.contrib.optimizers import DistributedFusedAdam
 from apex_tpu.multi_tensor.buckets import DEFAULT_BUCKET_BYTES, plan_buckets
+from apex_tpu.telemetry import scopes
 from apex_tpu.transformer import parallel_state
 from apex_tpu.transformer.testing.standalone_gpt import GPTConfig, GPTModel
 
@@ -192,6 +194,28 @@ class FlagshipSetup(NamedTuple):
     # the ISSUE 15 bucketed-overlap plan the 3-D step compiled with
     # (None on the single-axis path and the legacy serialized control)
     bucket_plan: Any = None
+
+
+def _jit_step(fn, mesh, in_specs, donate: bool):
+    """``jax.jit`` of a train step with the donation it ships with,
+    handed to :mod:`apex_tpu.telemetry.scopes` whenever it is TRACED:
+    only then are the batch's shapes known, and a trace happens at
+    warm-up, never in a step.  The entry holds shapes with the
+    placements ``in_specs`` name, nothing of the state itself."""
+    shardings = tuple(NamedSharding(mesh, spec) for spec in in_specs)
+
+    @functools.wraps(fn)
+    def traced(*args):
+        scopes.register(
+            scopes.executable_name(step), step,
+            tuple(jax.tree_util.tree_map(
+                lambda a, sh=sh: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype, sharding=sh), arg)
+                for arg, sh in zip(args, shardings)))
+        return fn(*args)
+
+    step = jax.jit(traced, donate_argnums=(0, 1) if donate else ())
+    return step
 
 
 def _place_state(mesh, params, opt, schema, lead_shape, opt_spec):
@@ -335,8 +359,8 @@ def build_flagship_train_step(
         in_specs=(P(), P("data"), P("data"), P("data")),
         out_specs=(P(), P("data"), P()),
         check_rep=False)
-    step = jax.jit(sharded,
-                   donate_argnums=(0, 1) if donate else ())
+    step = _jit_step(sharded, mesh, (P(), P("data"), P("data"), P("data")),
+                     donate)
     return FlagshipSetup(step, params, opt_state, mesh, schema, opt,
                          model, plan, shardings=(P(), P("data")))
 
@@ -469,7 +493,8 @@ def _build_flagship_train_step_3d(cfg, *, plan, lr, weight_decay, devs,
             in_specs=(P(), spec3, P("data"), P("data")),
             out_specs=(P(), spec3, P()),
             check_rep=False)
-        step = jax.jit(sharded, donate_argnums=(0, 1) if donate else ())
+        step = _jit_step(sharded, mesh,
+                         (P(), spec3, P("data"), P("data")), donate)
         return FlagshipSetup(
             step, master, opt_state, mesh, schema, opt, model, plan,
             shardings=(P(), spec3), mesh_axes=mesh_axes,
@@ -523,7 +548,8 @@ def _build_flagship_train_step_3d(cfg, *, plan, lr, weight_decay, devs,
         new_p, new_state = opt_sharded(grads, state, mp)
         return new_p, new_state, loss
 
-    step = jax.jit(train_step, donate_argnums=(0, 1) if donate else ())
+    step = _jit_step(train_step, mesh,
+                     (P(), spec3, P("data"), P("data")), donate)
     return FlagshipSetup(
         step, master, opt_state, mesh, schema, opt, model, plan,
         shardings=(P(), spec3), mesh_axes=mesh_axes)
