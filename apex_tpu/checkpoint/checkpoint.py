@@ -429,7 +429,10 @@ def save_checkpoint(
                         raise ValueError(
                             f"leaf {key} dim {i} has size {val.shape[i]} "
                             f"but its spec shards it over {ax!r} "
-                            f"(size {shard_axes[ax]})")
+                            f"(size {shard_axes[ax]}); a live ZeRO state "
+                            "is saved through resilience."
+                            "save_zero_checkpoint, which takes its "
+                            "stacked_zero_state")
                 entry["shard_axes"] = lead
                 entry["replicated_shards"] = _flat.is_replicated_stack(
                     val, len(lead))
@@ -455,7 +458,9 @@ def save_checkpoint(
                 raise ValueError(
                     f"inconsistent shard counts in one save: leaf {key} "
                     f"has leading axis {val.shape[0]}, earlier sharded "
-                    f"leaves have {n_shards}")
+                    f"leaves have {n_shards}; a live ZeRO state is saved "
+                    "through resilience.save_zero_checkpoint, which takes "
+                    "its stacked_zero_state")
             entry["shard_axis"] = shard_axis
             # a per-rank REPLICATED stack must re-broadcast on reshard,
             # not concat.  Only 1-D [n_shards] stacks (per-rank scalars

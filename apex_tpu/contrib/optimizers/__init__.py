@@ -6,5 +6,7 @@ from apex_tpu.contrib.optimizers.distributed_fused import (  # noqa: F401
     DistributedFusedLAMB,
     DistributedShardedOptimizer,
     ShardedOptState,
+    live_zero_state,
     reshard_zero_state,
+    stacked_zero_state,
 )

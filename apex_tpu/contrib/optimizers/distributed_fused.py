@@ -91,9 +91,77 @@ def _unpack(flat, schema: FlatSchema):
 
 
 class ShardedOptState(NamedTuple):
+    """One rank's state as :meth:`DistributedShardedOptimizer.step`
+    takes and returns it.  The same tuple holds a whole mesh's state
+    outside the step, in one of two forms: LIVE (what the flagship
+    train step carries: ``step`` a ``[*lead]`` stack, each moment 1-D
+    ``[world * shard]``) and STACKED (the interchange form of sharded
+    checkpoints and reshards: each moment ``[*lead, shard]``);
+    :func:`stacked_zero_state` / :func:`live_zero_state` go between."""
+
     step: jnp.ndarray  # i32 scalar
-    exp_avg: jnp.ndarray  # [shard] f32 (momentum)
+    exp_avg: jnp.ndarray  # [shard] exp_avg_dtype (momentum)
     exp_avg_sq: jnp.ndarray  # [shard] f32 (2nd moment)
+
+
+def _map_zero_states(fn, tree):
+    """``fn`` over every :class:`ShardedOptState` in ``tree``, which
+    may be one itself; other leaves pass through."""
+    is_state = lambda x: isinstance(x, ShardedOptState)  # noqa: E731
+    return jax.tree_util.tree_map(
+        lambda x: fn(x) if is_state(x) else x, tree, is_leaf=is_state)
+
+
+def stacked_zero_state(tree):
+    """The INTERCHANGE view of every live :class:`ShardedOptState` in
+    ``tree`` (a state itself, or a ``(params, opt_state)`` pair; other
+    leaves pass through): host NumPy arrays, each moment as the
+    ``[*lead, shard]`` stack of per-rank partitions, ``lead`` being the
+    ``step`` counter's shape (``[n_shards]``, or ``[dp, pp, tp]`` on a
+    three-axis mesh).
+
+    The train step carries a moment as ONE 1-D array, the ranks' shards
+    laid end to end in linearized rank order, so that a device's piece
+    is ``[shard]``, the shape the update runs on (on the TPU a
+    ``[1, shard]`` piece is tiled differently, and squeezing it cost a
+    pass over the state a step: PERF.md, PR 38).  That array is byte
+    for byte the C-order flattening of the stack, so this view is a
+    host-side ``reshape``, made once a save or a mesh rebuild and never
+    in a step.  Sharded checkpoints (``save_checkpoint(shard_axis= /
+    shard_axes=)``), :func:`reshard_zero_state` and
+    :func:`apex_tpu.multi_tensor.flat.reshard_stack` define a leaf by
+    its leading stack axes: they take this view.
+    :func:`live_zero_state` takes it back."""
+    def view(state):
+        step = np.asarray(jax.device_get(state.step))
+        return ShardedOptState(step, *(
+            np.asarray(jax.device_get(a)).reshape(*step.shape, -1)
+            for a in (state.exp_avg, state.exp_avg_sq)))
+
+    return _map_zero_states(view, tree)
+
+
+def live_zero_state(tree, like=None):
+    """Inverse of :func:`stacked_zero_state`: every stacked
+    :class:`ShardedOptState` in ``tree`` back in the form the train
+    step carries (moments 1-D, the counter as it was).  ``like`` — a
+    live tree of the same structure (``fs.opt_state``, or the
+    ``(params, opt_state)`` pair): each array is placed with the
+    sharding of its counterpart there, every device receiving only its
+    own slice; without it the arrays stay uncommitted and the step
+    places them on its first call."""
+    def live(state):
+        return ShardedOptState(
+            np.asarray(jax.device_get(state.step)),
+            *(np.asarray(jax.device_get(a)).reshape(-1)
+              for a in (state.exp_avg, state.exp_avg_sq)))
+
+    out = _map_zero_states(live, tree)
+    if like is None:
+        return jax.tree_util.tree_map(jnp.asarray, out)
+    # straight from the host to each device's own slice
+    return jax.tree_util.tree_map(
+        lambda a, ref: jax.device_put(a, ref.sharding), out, like)
 
 
 def reshard_zero_state(opt_state: ShardedOptState, *,
@@ -101,10 +169,13 @@ def reshard_zero_state(opt_state: ShardedOptState, *,
                        schema: FlatSchema,
                        lead_shape=None) -> ShardedOptState:
     """Re-partition a STACKED per-rank :class:`ShardedOptState` (leading
-    stack axes on every leaf, the layout the flagship train step
-    carries) onto a new topology — the in-memory half of the elastic
+    stack axes on every leaf: the interchange form,
+    :func:`stacked_zero_state` of what the flagship train step carries)
+    onto a new topology — the in-memory half of the elastic
     cross-topology story (the on-disk half lives in
-    ``checkpoint.restore_checkpoint``'s sharded-manifest reshard).
+    ``checkpoint.restore_checkpoint``'s sharded-manifest reshard).  The
+    result is stacked too; :func:`live_zero_state` hands it to the new
+    topology's step.
 
     ``n_shards`` — single-axis form: the leading ``[old_n]`` stack
     re-partitions to ``[n_shards, total/n_shards]``.  ``lead_shape`` —

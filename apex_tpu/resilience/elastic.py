@@ -409,23 +409,39 @@ class _ArmedStep:
 
 
 def save_zero_checkpoint(ckpt_dir: str, state: Any, *, step: int,
-                         shardings: Any, shard_axis: str = "data",
+                         shardings: Any, shard_axis: Optional[str] = "data",
                          **kw) -> str:
     """Sharded save of a ZeRO train state: leaves whose spec leads with
     ``shard_axis`` (the per-rank optimizer partitions, leading
     ``[n_shards]`` axis) go to per-shard files with per-shard CRC32
-    digests; replicated leaves are stored once.  Thin veneer over
-    :func:`apex_tpu.checkpoint.save_checkpoint` — all its knobs
+    digests; replicated leaves are stored once.  This is the ONE save
+    entry that owns the interchange view: a live ``ShardedOptState`` in
+    ``state`` (the flagship step's: moments 1-D) is written through
+    :func:`~apex_tpu.contrib.optimizers.stacked_zero_state`, which is
+    what ``shardings`` describes (``FlagshipSetup.stacked_shardings``)
+    and what the manifest records; a state already stacked passes
+    through.  The view is a host transfer, so it is taken where
+    :func:`apex_tpu.checkpoint.save_checkpoint` takes its own snapshot:
+    on the writing process only, and after the fence on an earlier
+    async write, so two host copies of the state never coexist.
+    Otherwise a thin veneer over ``save_checkpoint`` — all its knobs
     (``blocking``, ``retry``, ``keep``, and the format-4 multi-axis
     ``shard_axes=`` mapping, which supersedes ``shard_axis``) pass
     through."""
+    import jax
+
     from apex_tpu import checkpoint as ckpt
+    from apex_tpu.contrib.optimizers import stacked_zero_state
+    from apex_tpu.resilience.async_checkpoint import wait_for_save
 
     if kw.get("shard_axes") is not None:
         shard_axis = None  # multi-axis form supersedes the default axis
-    return ckpt.save_checkpoint(ckpt_dir, state, step=step,
-                                shardings=shardings, shard_axis=shard_axis,
-                                **kw)
+    if jax.process_index() != 0:
+        return ckpt.step_dir(ckpt_dir, step)
+    wait_for_save()
+    return ckpt.save_checkpoint(ckpt_dir, stacked_zero_state(state),
+                                step=step, shardings=shardings,
+                                shard_axis=shard_axis, **kw)
 
 
 def restore_zero_checkpoint(ckpt_dir: str, target: Any, *, mesh=None,
@@ -433,9 +449,12 @@ def restore_zero_checkpoint(ckpt_dir: str, target: Any, *, mesh=None,
                             max_fallbacks: Optional[int] = None):
     """Cross-topology resilient restore: the newest *intact* sharded
     checkpoint under ``ckpt_dir``, re-partitioned to ``target``'s
-    topology (whatever shard count its leading axes carry — build the
-    target with the CURRENT mesh's ``build_flagship_train_step`` and an
-    8-device save restores onto 4 devices, or 1).  Walks corrupt
+    topology (whatever shard count it carries — build the target with
+    the CURRENT mesh's ``build_flagship_train_step`` and an 8-device
+    save restores onto 4 devices, or 1).  ``target`` may hold the ZeRO
+    state live (moments 1-D: a saved leaf's logical value is the
+    C-order flattening of its stack, which IS the live moment) or
+    stacked; the result has the target's form.  Walks corrupt
     candidates newest-first exactly like
     :func:`~apex_tpu.resilience.restore_resilient` (it IS that
     function; this alias exists so call sites read as topology-aware)."""
@@ -544,10 +563,12 @@ def run_elastic_training(
     ``build(devices) -> (step_fn, state, shardings)`` constructs the
     train step for a given device set — for the flagship this wraps
     :func:`~apex_tpu.transformer.testing.build_flagship_train_step`
-    (whose ZeRO state carries a leading ``[n_shards]`` axis and whose
-    ``shardings`` lead with ``shard_axis`` for the per-rank partition
-    leaves).  The returned ``state`` doubles as the restore *target*:
-    its topology defines the M of any N→M reshard.
+    (whose ZeRO state is live, moments 1-D over ``n_shards`` shards,
+    and whose ``shardings`` describe its stacked view and lead with
+    ``shard_axis`` for the per-rank partition leaves: the loop's
+    sharded saves take that view, :func:`save_zero_checkpoint`).  The
+    returned ``state`` doubles as the restore *target*: its topology
+    defines the M of any N→M reshard.
 
     The inner loop is
     :func:`~apex_tpu.transformer.testing.run_resilient_training` with
@@ -671,7 +692,7 @@ def run_elastic_training(
     def _shard_axes(shape):
         dp, tp, pp = shape
         # the parallel_state mesh order — and the stacking order of the
-        # flagship opt leaves ([dp, pp, tp, shard])
+        # flagship opt leaves' stacked view ([dp, pp, tp, shard])
         return {"data": dp, "pipeline": pp, "tensor": tp}
 
     def _build(devs, shape):

@@ -56,7 +56,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import math
 from typing import Any, NamedTuple, Optional, Sequence
 
 import jax
@@ -64,7 +63,7 @@ import jax.numpy as jnp
 from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from apex_tpu.contrib.optimizers import DistributedFusedAdam
+from apex_tpu.contrib.optimizers import DistributedFusedAdam, ShardedOptState
 from apex_tpu.multi_tensor.buckets import DEFAULT_BUCKET_BYTES, plan_buckets
 from apex_tpu.telemetry import scopes
 from apex_tpu.transformer import parallel_state
@@ -176,20 +175,30 @@ class FlagshipSetup(NamedTuple):
 
     step: Any          # jitted (params, opt_state, tokens, labels) -> …
     params: Any        # pytree in plan.param_dtype
-    opt_state: Any     # per-rank ZeRO state, leading [n_shards] axis
+    # the ZeRO state in its LIVE form, committed to the mesh: each
+    # moment ONE 1-D array [world * shard], the ranks' shards laid end
+    # to end in linearized rank order and sharded so that a device
+    # holds exactly its [shard] (the shape the update runs on: nothing
+    # is squeezed or re-laid out in the step); the step counter a
+    # [n_shards] (3-D mesh: [dp, pp, tp]) stack of int32.  Sharded
+    # saves and reshards take its stacked view, [*lead, shard]:
+    # contrib.optimizers.stacked_zero_state / live_zero_state.
+    opt_state: Any
     mesh: Any
     schema: Any
     opt: DistributedFusedAdam
     model: GPTModel
     plan: ZeroFitPlan
-    # structure-prefix PartitionSpecs for the (params, opt_state) state
-    # tuple: params replicated, every opt_state leaf led by the "data"
-    # axis — exactly what save_checkpoint(shard_axis="data") needs to
-    # write per-rank partition files (resilience/elastic.py).  On a 3-D
-    # mesh the opt_state spec leads with all three axes and mesh_axes
-    # carries the {"data": dp, "pipeline": pp, "tensor": tp} mapping a
-    # format-4 save (shard_axes=) wants.
-    shardings: Any = None
+    # structure-prefix PartitionSpecs of the (params, STACKED view of
+    # opt_state) tuple, NOT of the live arrays (those carry their own
+    # placement, ``opt_state.exp_avg.sharding``: one dim over the
+    # linearized axes): params replicated, every stacked opt_state leaf
+    # led by the "data" axis — what a sharded save records and
+    # partitions by (resilience.save_zero_checkpoint takes the view and
+    # these specs).  On a 3-D mesh the spec leads with all three axes
+    # and mesh_axes carries the {"data": dp, "pipeline": pp, "tensor":
+    # tp} mapping a format-4 save (shard_axes=) wants.
+    stacked_shardings: Any = None
     mesh_axes: Any = None
     # the ISSUE 15 bucketed-overlap plan the 3-D step compiled with
     # (None on the single-axis path and the legacy serialized control)
@@ -202,40 +211,66 @@ def _jit_step(fn, mesh, in_specs, donate: bool):
     only then are the batch's shapes known, and a trace happens at
     warm-up, never in a step.  The entry holds shapes with the
     placements ``in_specs`` name, nothing of the state itself."""
-    shardings = tuple(NamedSharding(mesh, spec) for spec in in_specs)
-
     @functools.wraps(fn)
     def traced(*args):
         scopes.register(
             scopes.executable_name(step), step,
-            tuple(jax.tree_util.tree_map(
-                lambda a, sh=sh: jax.ShapeDtypeStruct(
-                    a.shape, a.dtype, sharding=sh), arg)
-                for arg, sh in zip(args, shardings)))
+            jax.tree_util.tree_map(
+                lambda spec, arg: jax.tree_util.tree_map(
+                    lambda a: jax.ShapeDtypeStruct(
+                        a.shape, a.dtype,
+                        sharding=NamedSharding(mesh, spec)), arg),
+                tuple(in_specs), args,
+                is_leaf=lambda x: isinstance(x, P)))
         return fn(*args)
 
     step = jax.jit(traced, donate_argnums=(0, 1) if donate else ())
     return step
 
 
-def _place_state(mesh, params, opt, schema, lead_shape, opt_spec):
+def _live_specs(axes) -> ShardedOptState:
+    """PartitionSpecs of the LIVE ZeRO state over the mesh ``axes`` it
+    is sharded on: each moment is one 1-D array, the ranks' shards
+    laid end to end in linearized rank order, so a device's piece is
+    ``[shard]``, the shape the update runs on; the ``step`` counter
+    (four bytes a rank) keeps one stack axis a mesh axis, and its shape
+    is what :func:`~apex_tpu.contrib.optimizers.stacked_zero_state`
+    takes the lead shape from."""
+    flat = P(tuple(axes))
+    return ShardedOptState(step=P(*axes), exp_avg=flat, exp_avg_sq=flat)
+
+
+def _enter(state: ShardedOptState) -> ShardedOptState:
+    """This rank's state inside the ``shard_map`` body: the moments
+    arrive as ``[shard]`` and pass through, the counter sheds its stack
+    axes (:func:`_leave` puts them back)."""
+    return state._replace(step=state.step[(0,) * state.step.ndim])
+
+
+def _leave(state: ShardedOptState, n_lead: int) -> ShardedOptState:
+    return state._replace(step=state.step[(None,) * n_lead])
+
+
+def _place_state(mesh, params, opt, schema, lead_shape, specs):
     """(params, opt_state) committed to ``mesh`` with the shardings the
     step runs under: params replicated, the zero optimizer state built
-    already sharded, each device materializing only its own slice.
+    already in its live form (:func:`_live_specs`) and already sharded,
+    each device materializing only its own slice.
 
     Left uncommitted, both would sit whole on the first device (at
-    world=4 the stacked state alone is the size of an unsharded one)
-    and the step would copy them onto the mesh on every call, so its
-    donation could not alias."""
+    world=4 the state alone is the size of an unsharded one) and the
+    step would copy them onto the mesh on every call, so its donation
+    could not alias."""
     params = jax.device_put(params, NamedSharding(mesh, P()))
 
     def zeros():
-        return jax.tree_util.tree_map(
-            lambda a: jnp.broadcast_to(a, (*lead_shape, *a.shape)),
-            opt.init(params, schema, math.prod(lead_shape)))
+        # the whole superblock's moments: every rank's shard, end to end
+        whole = opt.init(params, schema, 1)
+        return whole._replace(
+            step=jnp.broadcast_to(whole.step, lead_shape))
 
-    opt_state = jax.jit(
-        zeros, out_shardings=NamedSharding(mesh, opt_spec))()
+    opt_state = jax.jit(zeros, out_shardings=jax.tree_util.tree_map(
+        lambda spec: NamedSharding(mesh, spec), specs))()
     return params, opt_state
 
 
@@ -269,13 +304,17 @@ def build_flagship_train_step(
     replicated master params, taken with a traced ``dynamic_slice``
     inside the step), and ZeRO shards the optimizer state over the
     **linearized world** — every (d, p, t) coordinate owns one
-    contiguous shard of the master flat buffer, so the opt_state leaves
-    are ``[dp, pp, tp, shard]`` stacks with spec
-    ``P("data", "pipeline", "tensor")``.  ``pp`` must be 1 for the
+    contiguous shard of the master flat buffer, so a moment is one
+    ``[dp * pp * tp * shard]`` array with spec
+    ``P(("data", "pipeline", "tensor"))`` (its stacked view
+    ``[dp, pp, tp, shard]``, spec ``P("data", "pipeline", "tensor")``:
+    :class:`FlagshipSetup`).  ``pp`` must be 1 for the
     *train step* (the pipeline schedules are separate:
     ``transformer.pipeline_parallel``; the checkpoint / reshard
-    machinery handles pp > 1 states).  ``mesh_shape=None`` keeps the
-    historical single-axis layout byte-for-byte.
+    machinery handles pp > 1 states).  ``mesh_shape=None`` shards over
+    "data" alone; both paths carry the state in the same form, and
+    byte for byte where the single-axis ``[n_shards, shard]`` stack
+    had it.
 
     ``bucket_bytes`` (3-D path only, ISSUE 15) selects the gradient
     data path:
@@ -317,7 +356,7 @@ def build_flagship_train_step(
     if bucket_bytes != "auto":
         raise ValueError(
             "bucket_bytes applies to the mesh_shape=(dp, tp, pp) step; "
-            "the single-axis path keeps the historical layout")
+            "the single-axis path has one whole-buffer collective pair")
     parallel_state.destroy_model_parallel()
     mesh = parallel_state.initialize_model_parallel(1, 1, devices=devs)
     n_shards = len(devs)
@@ -334,12 +373,12 @@ def build_flagship_train_step(
         gather_dtype=plan.gather_dtype,
         exp_avg_dtype=plan.exp_avg_dtype)
     schema = opt.make_schema(params, n_shards)
-    # per-rank state with an explicit leading shard axis
+    specs = _live_specs((parallel_state.DATA_AXIS,))
     params, opt_state = _place_state(mesh, params, opt, schema,
-                                     (n_shards,), P("data"))
+                                     (n_shards,), specs)
 
     def inner(p, state, tokens, labels):
-        state = jax.tree_util.tree_map(lambda a: a[0], state)
+        state = _enter(state)
 
         def lossf(p):
             return jnp.mean(model.apply(p, tokens, labels=labels))
@@ -350,19 +389,15 @@ def build_flagship_train_step(
             loss, grads = jax.value_and_grad(lossf)(p)
         new_p, new_state = opt.step(grads, state, p, schema)
         loss = jax.lax.pmean(loss, opt.axis_name)
-        return (new_p,
-                jax.tree_util.tree_map(lambda a: a[None], new_state),
-                loss)
+        return new_p, _leave(new_state, 1), loss
 
+    in_specs = (P(), specs, P("data"), P("data"))
     sharded = shard_map(
-        inner, mesh=mesh,
-        in_specs=(P(), P("data"), P("data"), P("data")),
-        out_specs=(P(), P("data"), P()),
-        check_rep=False)
-    step = _jit_step(sharded, mesh, (P(), P("data"), P("data"), P("data")),
-                     donate)
+        inner, mesh=mesh, in_specs=in_specs,
+        out_specs=(P(), specs, P()), check_rep=False)
+    step = _jit_step(sharded, mesh, in_specs, donate)
     return FlagshipSetup(step, params, opt_state, mesh, schema, opt,
-                         model, plan, shardings=(P(), P("data")))
+                         model, plan, stacked_shardings=(P(), P("data")))
 
 
 def _tp_slice_tables(master, local0):
@@ -440,8 +475,9 @@ def _build_flagship_train_step_3d(cfg, *, plan, lr, weight_decay, devs,
         axis_name=tuple(parallel_state.MESH_AXES))
     schema = opt.make_schema(master, world)
     spec3 = P(*parallel_state.MESH_AXES)
+    specs = _live_specs(parallel_state.MESH_AXES)
     master, opt_state = _place_state(mesh, master, opt, schema,
-                                     (dp, pp, tp), spec3)
+                                     (dp, pp, tp), specs)
     mesh_axes = {parallel_state.DATA_AXIS: dp,
                  parallel_state.PIPELINE_AXIS: pp,
                  parallel_state.TENSOR_AXIS: tp}
@@ -471,7 +507,7 @@ def _build_flagship_train_step_3d(cfg, *, plan, lr, weight_decay, devs,
             itemsize=jnp.dtype(plan.scatter_dtype or jnp.float32).itemsize)
 
         def _bucketed_zero_inner(mp, state, tokens, labels):
-            state = jax.tree_util.tree_map(lambda a: a[0, 0, 0], state)
+            state = _enter(state)
             t_idx = jax.lax.axis_index(parallel_state.TENSOR_AXIS)
 
             def local_loss(mp):
@@ -483,21 +519,16 @@ def _build_flagship_train_step_3d(cfg, *, plan, lr, weight_decay, devs,
             loss = jax.lax.pmean(loss, parallel_state.DATA_AXIS)
             new_p, new_state = opt.step_buckets(grads, state, mp, schema,
                                                 bplan)
-            return (new_p,
-                    jax.tree_util.tree_map(
-                        lambda a: a[None, None, None], new_state),
-                    loss)
+            return new_p, _leave(new_state, 3), loss
 
+        in_specs = (P(), specs, P("data"), P("data"))
         sharded = shard_map(
-            _bucketed_zero_inner, mesh=mesh,
-            in_specs=(P(), spec3, P("data"), P("data")),
-            out_specs=(P(), spec3, P()),
-            check_rep=False)
-        step = _jit_step(sharded, mesh,
-                         (P(), spec3, P("data"), P("data")), donate)
+            _bucketed_zero_inner, mesh=mesh, in_specs=in_specs,
+            out_specs=(P(), specs, P()), check_rep=False)
+        step = _jit_step(sharded, mesh, in_specs, donate)
         return FlagshipSetup(
             step, master, opt_state, mesh, schema, opt, model, plan,
-            shardings=(P(), spec3), mesh_axes=mesh_axes,
+            stacked_shardings=(P(), spec3), mesh_axes=mesh_axes,
             bucket_plan=bplan)
 
     # -- the legacy serialized control (bucket_bytes=None) -------------
@@ -532,14 +563,12 @@ def _build_flagship_train_step_3d(cfg, *, plan, lr, weight_decay, devs,
         check_rep=False)
 
     def inner_opt(grads, state, mp):
-        state = jax.tree_util.tree_map(lambda a: a[0, 0, 0], state)
-        new_p, new_state = opt.step(grads, state, mp, schema)
-        return new_p, jax.tree_util.tree_map(
-            lambda a: a[None, None, None], new_state)
+        new_p, new_state = opt.step(grads, _enter(state), mp, schema)
+        return new_p, _leave(new_state, 3)
 
     opt_sharded = shard_map(
         inner_opt, mesh=mesh,
-        in_specs=(P(), spec3, P()), out_specs=(P(), spec3),
+        in_specs=(P(), specs, P()), out_specs=(P(), specs),
         check_rep=False)
 
     def train_step(mp, state, tokens, labels):
@@ -549,10 +578,10 @@ def _build_flagship_train_step_3d(cfg, *, plan, lr, weight_decay, devs,
         return new_p, new_state, loss
 
     step = _jit_step(train_step, mesh,
-                     (P(), spec3, P("data"), P("data")), donate)
+                     (P(), specs, P("data"), P("data")), donate)
     return FlagshipSetup(
         step, master, opt_state, mesh, schema, opt, model, plan,
-        shardings=(P(), spec3), mesh_axes=mesh_axes)
+        stacked_shardings=(P(), spec3), mesh_axes=mesh_axes)
 
 
 def flagship_elastic_build(cfg: GPTConfig, *, plan: str | ZeroFitPlan
@@ -563,17 +592,22 @@ def flagship_elastic_build(cfg: GPTConfig, *, plan: str | ZeroFitPlan
     :func:`apex_tpu.resilience.run_elastic_training`: each call stands up
     the ZeRO flagship step on exactly ``devices`` (a fresh mesh whose
     "data" axis spans them) and adapts it to the resilient-loop contract
-    — ``state`` is the ``(params, opt_state)`` tuple (leading
-    ``[len(devices)]`` shard axis on every opt leaf, so it doubles as
-    the cross-topology restore target) and ``step_fn(state, (tokens,
-    labels))`` returns ``(state, None)``.  ``on_loss(step_loss)`` taps
-    the per-step loss for trajectory assertions.
+    — ``state`` is the ``(params, opt_state)`` tuple with the ZeRO
+    state LIVE (:class:`FlagshipSetup`: moments 1-D over
+    ``len(devices)`` shards; it doubles as the cross-topology restore
+    target, a saved stack restoring into it by its C-order
+    flattening), ``shardings`` is ``FlagshipSetup.stacked_shardings``
+    (the specs of its stacked view, which the loop's sharded saves
+    take: ``save_zero_checkpoint``), and
+    ``step_fn(state, (tokens, labels))`` returns ``(state, None)``.
+    ``on_loss(step_loss)`` taps the per-step loss for trajectory
+    assertions.
 
     ``build(devices, mesh_shape=(dp, tp, pp))`` — the multi-axis form
     the 3-D elastic harness calls: the step builds over the full
-    dp×tp×pp ``parallel_state`` mesh and the opt leaves carry
-    ``[dp, pp, tp, shard]`` stacks (see
-    :func:`build_flagship_train_step`'s ``mesh_shape`` notes)."""
+    dp×tp×pp ``parallel_state`` mesh and the moments span
+    ``dp * pp * tp`` shards, stacked ``[dp, pp, tp, shard]`` in a save
+    (see :func:`build_flagship_train_step`'s ``mesh_shape`` notes)."""
 
     def build(devices, mesh_shape=None):
         fs = build_flagship_train_step(
@@ -590,6 +624,6 @@ def flagship_elastic_build(cfg: GPTConfig, *, plan: str | ZeroFitPlan
                 on_loss(float(loss))
             return (p, s), None
 
-        return step_fn, (fs.params, fs.opt_state), fs.shardings
+        return step_fn, (fs.params, fs.opt_state), fs.stacked_shardings
 
     return build

@@ -28,7 +28,7 @@ import time
 from typing import Any, Callable, Dict, Iterable, Optional
 
 from apex_tpu import checkpoint as ckpt
-from apex_tpu.resilience import wait_for_save
+from apex_tpu.resilience import save_zero_checkpoint, wait_for_save
 from apex_tpu.resilience.guards import StepGuard
 from apex_tpu.resilience.preemption import GracePeriodHandler
 
@@ -209,11 +209,14 @@ def run_resilient_training(
         data_state = (data_iter.state_dict()
                       if data_iter is not None
                       and hasattr(data_iter, "state_dict") else None)
-        ckpt.save_checkpoint(ckpt_dir, state, step=step, keep=keep,
-                             shardings=shardings, shard_axis=shard_axis,
-                             shard_axes=shard_axes,
-                             data_state=data_state,
-                             blocking=blocking or not async_saves)
+        # a sharded save goes through the one entry that takes the
+        # interchange view of a live ZeRO state
+        sharded = shard_axis is not None or shard_axes is not None
+        save = save_zero_checkpoint if sharded else ckpt.save_checkpoint
+        save(ckpt_dir, state, step=step, keep=keep,
+             shardings=shardings, shard_axis=shard_axis,
+             shard_axes=shard_axes, data_state=data_state,
+             blocking=blocking or not async_saves)
         dt = time.monotonic() - t0
         last_saved = step
         if telemetry is not None:

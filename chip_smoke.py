@@ -10,7 +10,8 @@ work, and it has no CPU mode.  With random weights made from a seed it
 * checks one forward+backward of packed-QKV attention and five
   ``flash_decode`` calls against the XLA routes at the flagship shapes;
 * trains the full-width GPT-1.3B flagship step (``bf16_fit`` ZeRO plan)
-  for a few steps on a fixed batch: finite, falling loss;
+  for a few steps on a fixed batch: finite, falling loss, the first
+  step's loss the forward's at the same parameters;
 * serves a seeded Poisson trace on the 1.3B-geometry ``ServingEngine``,
   then the same prompts again: every request completes, nothing
   compiles after warm-up, the streams repeat;
@@ -46,6 +47,8 @@ import warnings
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.shard_map import shard_map
+from jax.sharding import PartitionSpec as P
 
 from apex_tpu import _native
 from apex_tpu.analysis import hot_path_guard
@@ -231,10 +234,27 @@ def leg_train(cfg, *, batch_per_chip, seq, steps, mesh_shape=None,
     labels = jnp.roll(tokens, -1, axis=-1)
     params, opt_state, step, mesh = fs.params, fs.opt_state, fs.step, fs.mesh
 
+    forward_loss = None
+    if mesh_shape is None:
+        # the loss a user logs is the loss of the step: up to PR 37 the
+        # donating step returned 1.1% less than the forward at the same
+        # parameters from 10 layers on (PERF.md section 7, row 0)
+        forward = jax.jit(shard_map(
+            lambda p, t, l: jax.lax.pmean(
+                jnp.mean(fs.model.apply(p, t, labels=l)),
+                parallel_state.DATA_AXIS),
+            mesh=mesh, in_specs=(P(), P("data"), P("data")), out_specs=P(),
+            check_rep=False))
+        forward_loss = float(forward(params, tokens, labels))
+
     t0 = time.perf_counter()
     params, opt_state, loss = step(params, opt_state, tokens, labels)
     losses = [float(loss)]
     first_step_s = time.perf_counter() - t0
+    if forward_loss is not None:
+        require(abs(losses[0] - forward_loss) <= 1e-3 * abs(forward_loss),
+                f"train: the step's loss {losses[0]} is not the forward's "
+                f"{forward_loss} at the same parameters")
     step_ms = []
     for _ in range(steps):
         t0 = time.perf_counter()
@@ -250,7 +270,7 @@ def leg_train(cfg, *, batch_per_chip, seq, steps, mesh_shape=None,
     parallel_state.destroy_model_parallel()
     return {"layers": cfg.num_layers, "batch": int(tokens.shape[0]),
             "seq": seq, "mesh": dict(mesh.shape), "losses": losses,
-            "first_step_s": round(first_step_s, 1), "step_ms": step_ms,
+            "forward_loss": forward_loss, "first_step_s": round(first_step_s, 1), "step_ms": step_ms,
             "memory": memory}
 
 

@@ -39,6 +39,7 @@ from apex_tpu.multi_tensor import (
     make_schema,
     plan_buckets,
 )
+from apex_tpu.resilience import save_zero_checkpoint
 from apex_tpu.transformer.testing import (
     build_flagship_train_step,
     gpt1p3b_config,
@@ -299,9 +300,10 @@ def test_format4_round_trip_is_bucket_plan_invariant(tmp_path):
         losses.append(float(loss))
         if len(losses) == 2:
             p2, s2 = p, s
-            ckpt.save_checkpoint(
+            # a format-4 save partitions the live state's stacked view
+            save_zero_checkpoint(
                 str(tmp_path / "c"), (p, s), step=2,
-                shardings=fs_src.shardings,
+                shardings=fs_src.stacked_shardings,
                 shard_axes=fs_src.mesh_axes)
 
     fs_dst = build_flagship_train_step(

@@ -362,3 +362,140 @@ def test_bracket_quote_keys_round_trip(tmp_path):
     out, _ = ckpt.restore_checkpoint(str(tmp_path))
     np.testing.assert_array_equal(np.asarray(out["x"]["y"]), 3 * np.ones(2))
     np.testing.assert_array_equal(np.asarray(out["x']['y"]), 7 * np.ones(2))
+
+
+# --------------------------- the ZeRO state's interchange form (ISSUE 38)
+
+
+def _stacked_zero_state(lead, shard=256, seed=3):
+    """A ZeRO state as the tree before ISSUE 38 carried and saved it:
+    every leaf a stack over ``lead``, ``bf16_fit`` dtypes."""
+    from apex_tpu.contrib.optimizers import ShardedOptState
+
+    rng = np.random.RandomState(seed)
+    return ShardedOptState(
+        step=jnp.full(lead, 5, jnp.int32),
+        exp_avg=jnp.asarray(rng.randn(*lead, shard), jnp.bfloat16),
+        exp_avg_sq=jnp.asarray(rng.rand(*lead, shard), jnp.float32))
+
+
+@pytest.mark.parametrize("lead,kw,spec", [
+    ((8,), dict(shard_axis="data"), P("data")),
+    ((4, 1, 2), dict(shard_axes={"data": 4, "pipeline": 1, "tensor": 2}),
+     P("data", "pipeline", "tensor")),
+], ids=["format3", "format4"])
+def test_stacked_checkpoint_restores_bitwise_into_the_live_state(
+        tmp_path, lead, kw, spec):
+    """A sharded checkpoint written from the stacked form (the call the
+    tree made before ISSUE 38, its manifest and shard files) restores
+    bit for bit into the live state, whose moments are 1-D; the live
+    state saved through ``save_zero_checkpoint`` writes the SAME
+    manifest and shard digests; and that restores into a stacked
+    target as before."""
+    import json
+    import os
+
+    from apex_tpu.contrib.optimizers import (
+        live_zero_state, stacked_zero_state)
+    from apex_tpu.resilience import (
+        restore_zero_checkpoint, save_zero_checkpoint)
+
+    params = {"w": jnp.arange(12, dtype=jnp.bfloat16)}
+    stacked = _stacked_zero_state(lead)
+    shardings = (P(), spec)
+    old, new = str(tmp_path / "old"), str(tmp_path / "new")
+    ckpt.save_checkpoint(old, (params, stacked), step=2,
+                         shardings=shardings, **kw)
+
+    live_target = live_zero_state(
+        jax.tree_util.tree_map(jnp.zeros_like, stacked))
+    assert live_target.exp_avg.shape == (int(np.prod(lead)) * 256,)
+    (rp, live), step = restore_zero_checkpoint(old, (params, live_target))
+    assert step == 2
+    np.testing.assert_array_equal(np.asarray(rp["w"], np.float32),
+                                  np.asarray(params["w"], np.float32))
+    for got, tgt, want in zip(live, live_target, stacked):
+        assert got.shape == tgt.shape and got.dtype == want.dtype
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(want).reshape(tgt.shape))
+
+    # the live state back out, through the view: the same checkpoint
+    save_zero_checkpoint(new, (rp, live), step=2, shardings=shardings,
+                         **({} if "shard_axis" in kw else kw))
+
+    def manifest(d):
+        with open(os.path.join(ckpt.step_dir(d, 2), "manifest.json")) as f:
+            return json.load(f)
+
+    a, b = manifest(old), manifest(new)
+    assert a["leaves"] == b["leaves"] and a["format"] == b["format"]
+    assert a["topology"] == b["topology"]
+    assert sorted(os.listdir(ckpt.step_dir(old, 2))) == sorted(
+        os.listdir(ckpt.step_dir(new, 2)))
+    (_, back), _ = ckpt.restore_checkpoint(
+        new, (params, jax.tree_util.tree_map(jnp.zeros_like, stacked)),
+        verify=True)
+    for got, want in zip(back, stacked):
+        assert got.shape == want.shape
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    for got, want in zip(stacked_zero_state(live), stacked):
+        np.testing.assert_array_equal(got, np.asarray(want))
+
+
+def test_stacked_checkpoint_reshards_into_a_smaller_live_state(tmp_path):
+    """8 ranks' stacked save into a 4-rank live state: the stack's
+    C-order flattening IS the live moment, whatever the shard count."""
+    from apex_tpu.contrib.optimizers import live_zero_state
+    from apex_tpu.resilience import restore_zero_checkpoint
+
+    stacked = _stacked_zero_state((8,))
+    ckpt.save_checkpoint(str(tmp_path), ({}, stacked), step=1,
+                         shardings=(P(), P("data")), shard_axis="data")
+    target = live_zero_state(jax.tree_util.tree_map(
+        jnp.zeros_like, _stacked_zero_state((4,), shard=512)))
+    (_, live), _ = restore_zero_checkpoint(str(tmp_path), ({}, target))
+    assert live.step.shape == (4,) and np.all(np.asarray(live.step) == 5)
+    for got, want in zip(live[1:], stacked[1:]):
+        assert got.shape == (2048,)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(want).reshape(-1))
+
+
+def test_a_live_state_handed_to_a_sharded_save_is_refused(tmp_path):
+    """Forgetting the view must not write a shard file a scalar: the
+    save names the function that was skipped."""
+    from apex_tpu.contrib.optimizers import live_zero_state
+
+    live = live_zero_state(_stacked_zero_state((8,)))
+    with pytest.raises(ValueError, match="stacked_zero_state"):
+        ckpt.save_checkpoint(str(tmp_path), ({}, live), step=1,
+                             shardings=(P(), P("data")), shard_axis="data")
+
+
+@pytest.mark.parametrize("writer", [True, False])
+def test_save_zero_checkpoint_takes_the_view_where_the_snapshot_is(
+        tmp_path, monkeypatch, writer):
+    """The view is a host transfer of the whole state: a process that
+    does not write takes none, and the writer takes it only after the
+    fence on an earlier async write (never two host copies at once)."""
+    from apex_tpu.contrib import optimizers
+    from apex_tpu.contrib.optimizers import live_zero_state
+    from apex_tpu.resilience import async_checkpoint, save_zero_checkpoint
+
+    calls = []
+    view, fence = optimizers.stacked_zero_state, async_checkpoint.wait_for_save
+    monkeypatch.setattr(optimizers, "stacked_zero_state",
+                        lambda t: calls.append("view") or view(t))
+    monkeypatch.setattr(async_checkpoint, "wait_for_save",
+                        lambda *a: calls.append("fence") or fence(*a))
+    monkeypatch.setattr(jax, "process_index", lambda: 0 if writer else 1)
+
+    live = live_zero_state(_stacked_zero_state((8,)))
+    out = save_zero_checkpoint(str(tmp_path), ({}, live), step=3,
+                               shardings=(P(), P("data")))
+    assert out == ckpt.step_dir(str(tmp_path), 3)
+    if writer:
+        assert calls[:2] == ["fence", "view"], calls
+        assert ckpt.latest_step(str(tmp_path)) == 3
+    else:
+        assert calls == [] and ckpt.latest_step(str(tmp_path)) is None

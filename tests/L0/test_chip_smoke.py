@@ -59,6 +59,11 @@ def test_train_leg(mesh_shape):
     assert out["losses"][-1] < out["losses"][0]
     assert len(out["step_ms"]) == 5
     assert out["batch"] == 2 * out["mesh"]["data"]
+    # the single-axis leg holds the step's first loss to the forward's
+    assert (out["forward_loss"] is None) == (mesh_shape is not None)
+    if mesh_shape is None:
+        assert out["forward_loss"] == pytest.approx(out["losses"][0],
+                                                    rel=1e-3)
     json.dumps(out)
 
 

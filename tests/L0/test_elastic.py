@@ -217,6 +217,97 @@ def test_reshard_zero_state_in_memory():
         _assert_flat_parity(a, b, bitwise=True)
 
 
+@pytest.mark.parametrize("lead,new_lead", [((8,), (4,)),
+                                           ((4, 1, 2), (2, 2, 1))])
+def test_live_state_goes_through_the_interchange_form_and_back(lead,
+                                                                new_lead):
+    """ISSUE 38: the train step carries a moment as ONE 1-D array (the
+    stack's C-order flattening); ``stacked_zero_state`` /
+    ``live_zero_state`` are the two functions between that and the
+    stacked interchange form.  A ``reshard_zero_state`` input in the
+    stacked form (what the tree before ISSUE 38 carried) lands bitwise
+    in the new state, on the new topology, and comes back."""
+    from apex_tpu.contrib.optimizers import (
+        DistributedFusedAdam, ShardedOptState, live_zero_state,
+        reshard_zero_state, stacked_zero_state)
+
+    params = {"w": jnp.asarray(np.random.RandomState(1).randn(300),
+                               jnp.float32)}
+    opt = DistributedFusedAdam(exp_avg_dtype=jnp.bfloat16)
+    world, new_world = int(np.prod(lead)), int(np.prod(new_lead))
+    sch, new_sch = (opt.make_schema(params, w) for w in (world, new_world))
+    rng = np.random.RandomState(2)
+    raw = sum(sch.sizes)
+
+    def _moment(dtype):
+        a = rng.randn(sch.total).astype(np.float32)
+        a[raw:] = 0          # live state never has non-zero padding
+        return jnp.asarray(a.reshape(*lead, -1), dtype)
+
+    stacked = ShardedOptState(
+        step=jnp.broadcast_to(jnp.asarray(3, jnp.int32), lead),
+        exp_avg=_moment(jnp.bfloat16), exp_avg_sq=_moment(jnp.float32))
+
+    live = live_zero_state(stacked)
+    assert live.exp_avg.shape == live.exp_avg_sq.shape == (sch.total,)
+    assert live.step.shape == lead
+    back = stacked_zero_state(live)
+    for got, want in zip(back, stacked):
+        assert isinstance(got, np.ndarray)
+        assert got.shape == want.shape and got.dtype == want.dtype
+        np.testing.assert_array_equal(got, np.asarray(want))
+    # a pair (params, state) goes through whole; other leaves untouched
+    pair = stacked_zero_state((params, live))
+    assert pair[0]["w"] is params["w"]
+    assert pair[1].exp_avg.shape == (*lead, sch.total // world)
+    # an already stacked state passes through
+    assert stacked_zero_state(back).exp_avg.shape == back.exp_avg.shape
+
+    # the old form in, the new topology's live state out, and back
+    out = reshard_zero_state(
+        stacked, schema=new_sch,
+        **(dict(n_shards=new_lead[0]) if len(new_lead) == 1
+           else dict(lead_shape=new_lead)))
+    new_live = live_zero_state(out)
+    assert new_live.exp_avg.shape == (new_sch.total,)
+    assert new_live.step.shape == new_lead
+    for got, want in ((new_live.exp_avg, live.exp_avg),
+                      (new_live.exp_avg_sq, live.exp_avg_sq)):
+        assert got.dtype == want.dtype
+        _assert_flat_parity(got, want, bitwise=True)
+    again = reshard_zero_state(
+        stacked_zero_state(new_live), schema=sch,
+        **(dict(n_shards=lead[0]) if len(lead) == 1
+           else dict(lead_shape=lead)))
+    for got, want in zip(again, stacked):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_live_zero_state_places_a_shard_a_device():
+    """``like=``: every array lands with its live counterpart's
+    sharding, a device holding exactly its ``[shard]``."""
+    from jax.sharding import Mesh, NamedSharding
+    from apex_tpu.contrib.optimizers import (
+        ShardedOptState, live_zero_state)
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+    put = lambda a: jax.device_put(a, NamedSharding(mesh, P("data")))
+    like = ShardedOptState(put(jnp.zeros((4,), jnp.int32)),
+                           put(jnp.zeros((4 * 128,), jnp.bfloat16)),
+                           put(jnp.zeros((4 * 128,), jnp.float32)))
+    stacked = ShardedOptState(
+        np.full((4,), 7, np.int32),
+        np.arange(512, dtype=np.float32).reshape(4, 128).astype(
+            jnp.bfloat16),
+        np.arange(512, dtype=np.float32).reshape(4, 128))
+    live = live_zero_state(stacked, like=like)
+    for got, ref, want in zip(live, like, stacked):
+        assert got.sharding == ref.sharding
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(want).reshape(ref.shape))
+    assert live.exp_avg.sharding.shard_shape(live.exp_avg.shape) == (128,)
+
+
 def test_largest_divisor_submesh():
     """Losing 2 of 8 devices must rebuild on 4 (6 does not divide the
     global batch of 8), the select_devices policy the verify demo and a
@@ -389,23 +480,24 @@ def test_flagship_sharded_reshard_parity(tmp_path, plan, bitwise):
         state8, _ = step_fn(state8, b)
     d_sharded = str(tmp_path / "sharded")
     d_plain = str(tmp_path / "plain")
-    ckpt.save_checkpoint(d_sharded, state8, step=2, shardings=shardings,
-                         shard_axis="data")
-    ckpt.save_checkpoint(d_plain, state8, step=2, shardings=shardings)
+    # the state is live (moments 1-D): a sharded save takes its stacked
+    # view (save_zero_checkpoint does), an unsharded one the arrays
+    res.save_zero_checkpoint(d_sharded, state8, step=2,
+                             shardings=shardings)
+    ckpt.save_checkpoint(d_plain, state8, step=2)
 
     # 8 -> 4
     _, state4_t, _ = build(jax.devices()[:4])
     state4, s = res.restore_zero_checkpoint(d_sharded, state4_t)
     assert s == 2
-    for leaf_r, leaf_s in zip(jax.tree_util.tree_leaves(state4[1]),
-                              jax.tree_util.tree_leaves(state8[1])):
-        if leaf_r.ndim >= 2:  # flat-buffer stacks
-            _assert_flat_parity(leaf_r, leaf_s, bitwise=bitwise)
+    assert state4[1].exp_avg.shape == state4_t[1].exp_avg.shape
+    assert state4[1].exp_avg.ndim == 1
+    for leaf_r, leaf_s in zip(state4[1][1:], state8[1][1:]):  # moments
+        _assert_flat_parity(leaf_r, leaf_s, bitwise=bitwise)
 
     # 4 -> 8, against the unsharded restore of the same state
     d_mid = str(tmp_path / "mid")
-    ckpt.save_checkpoint(d_mid, state4, step=2,
-                         shardings=shardings, shard_axis="data")
+    res.save_zero_checkpoint(d_mid, state4, step=2, shardings=shardings)
     _, state8_t, _ = build(jax.devices()[:8])
     state8_rt, _ = res.restore_zero_checkpoint(d_mid, state8_t)
     state8_direct, _ = ckpt.restore_checkpoint(d_plain, target=state8_t,
@@ -422,10 +514,8 @@ def test_flagship_sharded_reshard_parity(tmp_path, plan, bitwise):
     # 8 -> 1: the single-chip debug restore
     _, state1_t, _ = build(jax.devices()[:1])
     state1, _ = res.restore_zero_checkpoint(d_sharded, state1_t)
-    for leaf_r, leaf_s in zip(jax.tree_util.tree_leaves(state1[1]),
-                              jax.tree_util.tree_leaves(state8[1])):
-        if leaf_r.ndim >= 2:
-            _assert_flat_parity(leaf_r, leaf_s, bitwise=bitwise)
+    for leaf_r, leaf_s in zip(state1[1][1:], state8[1][1:]):
+        _assert_flat_parity(leaf_r, leaf_s, bitwise=bitwise)
 
 
 @pytest.mark.slow  # two flagship jit constructions + 7 train steps

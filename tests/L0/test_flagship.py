@@ -192,3 +192,220 @@ def test_flagship_shape_engages_packed_attention(monkeypatch):
     # one-pass kernel
     q = jax.ShapeDtypeStruct((4 * 16, 2048, 128), jnp.bfloat16)
     assert attn_mod._pallas_bwd_ok(q, q, None, 512, 512)
+
+
+# ---------------------------------------------- the ZeRO state's live form
+#
+# ISSUE 38: the moments cross the jitted step's boundary in the shape the
+# update uses.  A device's piece is ``[shard]``, never ``[1, shard]``:
+# on the TPU the two are tiled differently, and the ``a[0]`` / ``a[None]``
+# around the old leading shard axis cost 27 ms of a 258.5 ms step.
+
+# the flagship paths that carry the state, and the devices each spans
+# (few devices: a shard is then larger than anything fwd_bwd reshapes,
+# so the size rule below sees the optimizer phase alone)
+STATE_PATHS = {
+    "single_axis": dict(n_dev=2),
+    "3d_bucketed": dict(n_dev=4, mesh_shape=(2, 2, 1)),
+    "3d_serialized": dict(n_dev=2, mesh_shape=(2, 1, 1),
+                          bucket_bytes=None),
+}
+
+# primitives that only re-shape or re-lay out their operand
+_RELAYOUT = {"squeeze", "reshape", "expand_dims", "broadcast_in_dim"}
+# ... and those that move it without arithmetic: what "the same array"
+# means when the state is followed through the body
+_MOVES = _RELAYOUT | {"slice", "dynamic_slice", "concatenate", "copy",
+                      "convert_element_type", "transpose"}
+
+
+def _build_path(path):
+    spec = dict(STATE_PATHS[path])
+    n_dev = spec.pop("n_dev")
+    cfg = gpt1p3b_config(**TOY_KW)
+    return cfg, build_flagship_train_step(
+        cfg, plan="bf16_fit", lr=1e-3, devices=jax.devices()[:n_dev],
+        donate=False, **spec)
+
+
+def _walk(jaxpr):
+    """Every equation of ``jaxpr`` and of what it calls."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk(sub)
+
+
+def _size(v):
+    return int(np.prod(v.aval.shape)) if hasattr(v.aval, "shape") else 0
+
+
+def _state_relayouts(body, moments_in, moments_out):
+    """Re-layouts of the state in a shard_map body: ``_RELAYOUT``
+    equations reached from the moments' inputs, or reaching their
+    outputs, through data movement alone."""
+    fwd, hits = set(moments_in), []
+    for eqn in body.eqns:
+        if eqn.primitive.name in _MOVES and fwd & {
+                v for v in eqn.invars if hasattr(v, "count")}:
+            fwd |= set(eqn.outvars)
+            if eqn.primitive.name in _RELAYOUT:
+                hits.append(eqn)
+    bwd = set(moments_out)
+    for eqn in reversed(body.eqns):
+        if eqn.primitive.name in _MOVES and bwd & set(eqn.outvars):
+            bwd |= {v for v in eqn.invars if hasattr(v, "count")}
+            if eqn.primitive.name in _RELAYOUT:
+                hits.append(eqn)
+    return hits
+
+
+def _stacked_form_step(fs):
+    """The step as it stood before ISSUE 38, from the optimizer's own
+    ``step``: the state a ``[n_shards, shard]`` stack, ``a[0]`` on the
+    way in and ``a[None]`` on the way out."""
+    opt, schema, model = fs.opt, fs.schema, fs.model
+
+    def inner(p, state, tokens, labels):
+        state = jax.tree_util.tree_map(lambda a: a[0], state)
+        loss, grads = jax.value_and_grad(
+            lambda p: jnp.mean(model.apply(p, tokens, labels=labels)))(p)
+        new_p, new_state = opt.step(grads, state, p, schema)
+        return (new_p,
+                jax.tree_util.tree_map(lambda a: a[None], new_state),
+                jax.lax.pmean(loss, opt.axis_name))
+
+    return jax.jit(shard_map(
+        inner, mesh=fs.mesh,
+        in_specs=(P(), P("data"), P("data"), P("data")),
+        out_specs=(P(), P("data"), P()), check_rep=False))
+
+
+def _bodies_that_take(jaxpr, moment_shape):
+    """``(body, the moments' variables in it)`` of every ``shard_map``
+    under ``jaxpr`` that is handed the moments: the only operands of
+    ``moment_shape``, which no parameter has."""
+    out = []
+    for eqn in _walk(jaxpr):
+        if eqn.primitive.name != "shard_map":
+            continue
+        at = [i for i, v in enumerate(eqn.invars)
+              if v.aval.shape == moment_shape]
+        if at:
+            assert len(at) == 2
+            body = eqn.params["jaxpr"]
+            body = getattr(body, "jaxpr", body)
+            out.append((body, [body.invars[i] for i in at]))
+    return out
+
+
+def test_the_state_rule_sees_the_stacked_forms_relayouts():
+    """The rule of the next test is not vacuous: on the step as it
+    stood, it finds the squeeze behind ``a[0]`` and the re-shape behind
+    ``a[None]`` of both moments."""
+    from apex_tpu.contrib.optimizers import stacked_zero_state
+
+    cfg, fs = _build_path("single_axis")
+    tokens, labels = _batch(cfg, b=4)
+    stacked = stacked_zero_state(fs.opt_state)
+    n, shard = stacked.exp_avg.shape
+    jaxpr = jax.make_jaxpr(_stacked_form_step(fs))(
+        fs.params, stacked, tokens, labels).jaxpr
+    (body, moments_in), = _bodies_that_take(jaxpr, (n, shard))
+    assert [v.aval.shape for v in moments_in] == [(1, shard)] * 2
+    moments_out = [v for v in body.outvars if v.aval.shape == (1, shard)]
+    hits = _state_relayouts(body, moments_in, moments_out)
+    assert len(hits) >= 4, [str(e) for e in hits]
+
+
+@pytest.mark.parametrize("path", sorted(STATE_PATHS))
+def test_state_reaches_the_update_as_shard(path):
+    """In the step's jaxpr the moments reach the ``shard_map`` body as
+    ``[shard]`` and leave it so, nothing re-shapes them on the way, and
+    (where the whole-buffer ``opt.step`` runs) no re-layout of ``shard``
+    elements or more exists outside ``zero_pack`` / ``zero_unpack``."""
+    cfg, fs = _build_path(path)
+    tokens, labels = _batch(cfg, b=4)
+    world = fs.mesh.size
+    shard = fs.schema.total // world
+    state = fs.opt_state
+    assert state.exp_avg.shape == state.exp_avg_sq.shape == (
+        world * shard,)
+    assert state.step.shape == tuple(
+        fs.mesh.shape[a] for a in fs.stacked_shardings[1])
+    assert state.exp_avg.sharding.shard_shape(
+        state.exp_avg.shape) == (shard,)
+
+    jaxpr = jax.make_jaxpr(fs.step)(fs.params, state, tokens, labels).jaxpr
+    bodies = _bodies_that_take(jaxpr, (world * shard,))
+    assert len(bodies) == 1
+    (body, moments_in), = bodies
+    assert [v.aval.shape for v in moments_in] == [(shard,)] * 2
+    moments_out = [v for v in body.outvars if v.aval.shape == (shard,)]
+    assert len(moments_out) == 2, body.outvars
+    hits = _state_relayouts(body, moments_in, moments_out)
+    assert not hits, [str(e) for e in hits]
+
+    if path == "3d_bucketed":
+        # step_buckets views the flat gradient and parameter buffers
+        # as [world, shard] column blocks: whole-buffer reshapes of its
+        # own, outside this rule (PERF.md section 7 row 7)
+        return
+    for eqn in _walk(jaxpr):
+        if eqn.primitive.name not in _RELAYOUT:
+            continue
+        scope = str(eqn.source_info.name_stack)
+        if "zero_pack" in scope or "zero_unpack" in scope:
+            continue
+        big = [v.aval.shape for v in (*eqn.invars, *eqn.outvars)
+               if _size(v) >= shard]
+        assert not big, (eqn.primitive.name, scope, big)
+
+
+@pytest.mark.parametrize("plan", ["bf16_fit", "bf16_fp32m"])
+def test_three_steps_are_the_stacked_forms_bit_for_bit(plan):
+    """Three steps of the flagship step against the stacked form driven
+    through ``DistributedFusedAdam.step`` directly, each step of both
+    from the SAME inputs: ``exp_avg`` and ``exp_avg_sq`` come out equal
+    bit for bit (the live moment IS the stack's C-order flattening), and
+    so do the parameters under ``bf16_fp32m``.  Under ``bf16_fit`` a
+    few parameters in a million land one rounding of bfloat16 apart (6
+    of 1.66M after step 2).  The instruction (disassembled, PERF.md
+    section 6, PR 38): the parameter update reads ``b1 * m +
+    (1 - b1) * g`` in float32 before ``m`` is rounded to bfloat16 for
+    the store, and XLA:CPU's LLVM contracts that sum into ONE fused
+    multiply-add, ``fma(m, b1, round((1 - b1) * g))`` in the live
+    program's update fusion and ``fma(g, 1 - b1, round(b1 * m))`` in
+    the stacked program's: which product stays unrounded is the code
+    generator's choice a program.  Two programs are two compilations:
+    on the TPU too the old and the new executable part in the last
+    bits (there by the backward GEMMs' tiling), which is why each step
+    here starts both from the same inputs."""
+    from apex_tpu.contrib.optimizers import stacked_zero_state
+
+    cfg = gpt1p3b_config(**TOY_KW)
+    fs = build_flagship_train_step(
+        cfg, plan=plan, lr=1e-3, devices=jax.devices()[:2], donate=False)
+    tokens, labels = _batch(cfg)
+    old = _stacked_form_step(fs)
+    p, s = fs.params, fs.opt_state
+    for i in range(3):
+        want_p, want_s, want_loss = old(
+            p, jax.tree_util.tree_map(jnp.asarray, stacked_zero_state(s)),
+            tokens, labels)
+        p, s, loss = fs.step(p, s, tokens, labels)
+        assert float(loss) == float(want_loss)
+        assert np.asarray(want_s.exp_avg).any()
+        for got, want in zip(stacked_zero_state(s), want_s):
+            want = np.asarray(want)
+            assert got.dtype == want.dtype and got.shape == want.shape
+            np.testing.assert_array_equal(got, want)
+        off = total = 0
+        for a, b in zip(jax.tree_util.tree_leaves(p),
+                        jax.tree_util.tree_leaves(want_p)):
+            a, b = np.asarray(a), np.asarray(b)
+            ulps = np.abs(a.view(np.int16).astype(np.int32)
+                          - b.view(np.int16).astype(np.int32))
+            assert ulps.max() <= 1, (i, ulps.max())
+            off, total = off + int((ulps > 0).sum()), total + a.size
+        assert off <= (1e-4 * total if plan == "bf16_fit" else 0), (i, off)
