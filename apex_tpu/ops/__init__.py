@@ -45,6 +45,7 @@ from apex_tpu.ops.fused_softmax import (  # noqa: F401
 from apex_tpu.ops.mlp import MLP, mlp  # noqa: F401
 from apex_tpu.ops.ssm import (  # noqa: F401
     causal_conv,
+    short_conv_update,
     ssd_chunk_scan,
     ssm_decode_route,
     ssm_decode_update,

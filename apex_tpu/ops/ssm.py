@@ -47,7 +47,11 @@ on every one of a row's 512 vector registers.)
   under named scopes (``PERF.md``, PR 35, has the traced share that says
   they are enough).
 * :func:`causal_conv` — the convolution of a (padded) row that starts
-  from a tail and leaves the new one.
+  from a tail and leaves the new one; Mamba-2's bias and SiLU, or
+  neither (a short convolution).
+* :func:`short_conv_update` — the decode form of LFM2's gated SHORT
+  convolution (ISSUE 39): no state but the tail, so no recurrence, one
+  token a row against its slot's tail, the tail advanced in place.
 
 No VJP: the serving path never differentiates (training and the scan's
 backward are ROADMAP.md, Reach 5).
@@ -198,16 +202,16 @@ def _state_update_xla(state_pool, conv_pool, slots, layer, decay, delta,
             conv_pool.at[layer, slots].set(tail))
 
 
-def _conv_taps(seq, w, bias, length: int):
-    """``silu(sum_j w[j] * seq[i + j] + bias)`` for ``i < length``:
+def _conv_taps(seq, w, bias, length: int, act):
+    """``act(sum_j w[j] * seq[i + j] + bias)`` for ``i < length``:
     ``seq`` ``[..., length + taps - 1, ch]``, ``w`` ``[taps, ch]``,
-    float32 accumulation."""
+    float32 accumulation; ``bias`` and ``act`` may be None."""
     wf = w.astype(jnp.float32)
-    acc = bias.astype(jnp.float32)
+    acc = 0.0 if bias is None else bias.astype(jnp.float32)
     for j in range(w.shape[0]):
         acc = acc + wf[j] * jax.lax.slice_in_dim(
             seq, j, j + length, axis=-2).astype(jnp.float32)
-    return _silu(acc)
+    return acc if act is None else act(acc)
 
 
 def ssm_decode_update(state_pool, conv_pool, slots, xbc, dt, *, layer: int,
@@ -250,7 +254,7 @@ def ssm_decode_update(state_pool, conv_pool, slots, xbc, dt, *, layer: int,
             per_head(d_skip.astype(f32))[None], xbc.dtype)
 
 
-def causal_conv(xbc, tail, valid, conv_w, conv_b):
+def causal_conv(xbc, tail, valid, conv_w, conv_b=None, act=_silu):
     """The depthwise causal convolution of one padded row that starts
     from a tail.
 
@@ -259,8 +263,10 @@ def causal_conv(xbc, tail, valid, conv_w, conv_b):
     row); ``tail`` ``[taps - 1, ch]``, the ``xbc`` rows before the
     run's first (zeros for a request's first token).  The tail is laid
     directly before the run, so no padding enters a real position's
-    window.  Returns (``silu(conv)`` ``[S, ch]`` float32, unspecified
-    at padding; the new tail: the last ``taps - 1`` rows of tail and
+    window.  ``conv_b`` and ``act`` are Mamba-2's bias and SiLU; a
+    short convolution (LFM2) has neither (None).  Returns
+    (``act(conv + conv_b)`` ``[S, ch]`` float32, unspecified at
+    padding; the new tail: the last ``taps - 1`` rows of tail and
     run)."""
     taps = conv_w.shape[0]
     s = xbc.shape[0]
@@ -272,7 +278,34 @@ def causal_conv(xbc, tail, valid, conv_w, conv_b):
     seq = jax.lax.dynamic_update_slice_in_dim(
         seq, tail.astype(xbc.dtype), first, axis=0)
     new_tail = jax.lax.dynamic_slice_in_dim(seq, first + count, taps - 1, 0)
-    return _conv_taps(seq, conv_w, conv_b, s), new_tail
+    return _conv_taps(seq, conv_w, conv_b, s, act), new_tail
+
+
+def short_conv_update(conv_pool, slots, v, *, layer: int, conv_w):
+    """One token a row through a SHORT convolution (LFM2's gated
+    short-convolution mixer), its tail advanced in the pool.
+
+    ``conv_pool`` ``[L, n_slots, taps - 1, ch]`` (the activations'
+    type), ``slots`` ``[rows]`` int32 as :func:`ssm_decode_update`
+    takes them, ``v`` ``[rows, ch]`` the rows' gated inputs ``B * x``,
+    ``conv_w`` ``[taps, ch]``, ``layer`` (static) the pool's layer.  No
+    bias, no activation and no recurrence: ``c = sum_j w[j] * [tail,
+    v][j]`` in float32, and the tail moves up one row and takes ``v``.
+    Plain XLA: a row's tail is ``(taps - 1) * ch`` numbers (8 KB at the
+    published sizes), a gather and a scatter of them a layer.
+
+    Returns (``c`` ``[rows, ch]`` float32, ``conv_pool'``).  Pass the
+    WHOLE pool (and donate it): only the rows' slots are written."""
+    if not 0 <= layer < conv_pool.shape[0]:
+        raise ValueError(f"layer {layer} is not one of the pool's "
+                         f"{conv_pool.shape[0]}")
+    slots = jnp.asarray(slots, jnp.int32)
+    tail = conv_pool[layer, slots]                     # [rows, taps - 1, ch]
+    seq = jnp.concatenate([tail, v.astype(conv_pool.dtype)[:, None]], 1)
+    c = _conv_taps(seq, conv_w, None, 1, None)[:, 0]
+    # rows that name one slot, the idle ones, write it in an unspecified
+    # order: nobody reads the scratch slot
+    return c, conv_pool.at[layer, slots].set(seq[:, 1:])
 
 
 def ssd_chunk_scan(x, dt, a_log, b, c, d_skip, state_in, valid, *,

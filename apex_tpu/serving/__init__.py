@@ -35,6 +35,12 @@ Three composable layers, bottom-up:
   (multi-head latent attention over a one-operand latent page,
   expanded at prefill and absorbed at decode; a group-limited softmax
   router over the same expert layer), which carries ``prefix_sharing``.
+* ISSUE 35: a fourth — ``GraniteHybridConfig`` (Mamba-2 state-space
+  layers whose requests own a slot of :class:`StatePool`).
+* ISSUE 39: a fifth — :class:`Lfm2MoeConfig` / :class:`Lfm2MoeBlock`
+  (gated short-convolution layers, whose slot holds a convolution tail
+  alone, beside QK-normed grouped-query layers; a top-4 sigmoid expert
+  layer without a shared expert).
 
 See docs/serving.md for the page-table layout, the admission policy,
 decode routing, speculative decoding, prefix sharing, the quantized
@@ -61,6 +67,8 @@ from apex_tpu.serving.model import (  # noqa: F401
     AfmoeConfig,
     DeepseekV2Config,
     GraniteHybridConfig,
+    Lfm2MoeBlock,
+    Lfm2MoeConfig,
     PagedDecoder,
     ServingModelConfig,
     init_params,
@@ -99,6 +107,8 @@ __all__ = [
     "AfmoeConfig",
     "DeepseekV2Config",
     "GraniteHybridConfig",
+    "Lfm2MoeBlock",
+    "Lfm2MoeConfig",
     "PagedDecoder",
     "ServingModelConfig",
     "init_params",

@@ -518,6 +518,8 @@ class ServingEngine:
         n_stat = 1 if decoder.stat_names else 0
         latent = decoder.latent
         has_state = self.cache.state_pool is not None
+        # a slot's planes: a Mamba-2 state and a tail, or a tail alone
+        planes = self.cache.state_pool.planes if has_state else ()
         has_window = self.cache.window_pool is not None
 
         def carries(args):
@@ -529,9 +531,10 @@ class ServingEngine:
             if quant:
                 kw.update(k_scale=pools[2], v_scale=pools[3])
             if has_state:
-                kw["state"] = StateIO(pools[-2], pools[-1],
+                held = dict(zip(planes, pools[len(pools) - len(planes):]))
+                kw["state"] = StateIO(held.get("ssm"), held["conv"],
                                       rest[-2], rest[-1])
-                pools, rest = pools[:-2], rest[:-2]
+                pools, rest = pools[:-len(planes)], rest[:-2]
             if has_window:
                 kw["window"] = WindowKV(pools[-2], pools[-1],
                                         rest[-2], rest[-1])
@@ -545,8 +548,10 @@ class ServingEngine:
                                           positions, last_index,
                                           tp_axis=ax)
             n_kv = len(kv) - n_stat      # K/V stacks, then the counters
-            return (jnp.argmax(logits[0, 0], axis=-1),
-                    *(a[:, 0] for a in kv[:n_kv]), *kv[n_kv:])
+            with jax.named_scope("head"):
+                first = jnp.argmax(logits[0, 0], axis=-1)
+            with jax.named_scope("kv_out"):
+                return (first, *(a[:, 0] for a in kv[:n_kv]), *kv[n_kv:])
 
         def _decode(params, *args):
             pools, rest, kw = carries(args)
@@ -555,11 +560,14 @@ class ServingEngine:
             # host has not fetched yet, or the host's token where
             # src[i] < 0 (a row fresh from prefill)
             tokens, prev, src, *rest = rest
-            tokens = jnp.where(src >= 0, prev[jnp.maximum(src, 0)], tokens)
+            with jax.named_scope("tokens"):
+                tokens = jnp.where(src >= 0, prev[jnp.maximum(src, 0)],
+                                   tokens)
             logits, *out = decoder.decode(
                 params, pools[0], pools[1], tokens, *rest, tp_axis=ax,
                 **kw)
-            return (jnp.argmax(logits, axis=-1), *out)
+            with jax.named_scope("head"):
+                return (jnp.argmax(logits, axis=-1), *out)
 
         def _verify(params, *args):
             # all k+1 positions scored in ONE flash_decode launch;
@@ -567,7 +575,8 @@ class ServingEngine:
             pools, rest, kw = carries(args)
             logits, *out = decoder.extend(
                 params, pools[0], pools[1], *rest, tp_axis=ax, **kw)
-            return (jnp.argmax(logits, axis=-1), *out)
+            with jax.named_scope("head"):
+                return (jnp.argmax(logits, axis=-1), *out)
 
         def _chunk(params, *args):
             # one chunk of a long context; front-padding pins the
@@ -578,7 +587,8 @@ class ServingEngine:
             logits, *out = decoder.extend(
                 params, pools[0], pools[1], *rest, last_only=True,
                 tp_axis=ax, **kw)
-            return (jnp.argmax(logits[:, 0], axis=-1), *out)
+            with jax.named_scope("head"):
+                return (jnp.argmax(logits[:, 0], axis=-1), *out)
 
         pool_donate = tuple(range(1, 1 + n_pool))
 
@@ -725,7 +735,8 @@ class ServingEngine:
             cache.state_pool = StatePool(
                 num_layers=dec.n_state_layers, num_slots=self._state_slots,
                 state_shape=cfg.state_shape, tail_shape=cfg.tail_shape,
-                dtype=cfg.dtype, state_dtype=cfg.state_dtype)
+                dtype=cfg.dtype,
+                state_dtype=getattr(cfg, "state_dtype", jnp.float32))
         return cache
 
     def _new_scheduler(self, max_queue: Optional[int],
@@ -761,14 +772,15 @@ class ServingEngine:
         """The pool loop-carry operands in executable order —
         ``(k, v)`` (a latent pool: ``k`` alone), then, quantized,
         ``(k_scale, v_scale)``, then the window pool's ``(k, v)`` where
-        there is one, then the state pool's ``(ssm, conv)``."""
+        there is one, then the state pool's planes (``ssm, conv``, or
+        ``conv`` alone: :attr:`StatePool.planes`)."""
         cache = self.cache
         pools = tuple(getattr(cache, name) for name in cache.operands)
         wpool, spool = cache.window_pool, cache.state_pool
         if wpool is not None:
             pools += (wpool.k, wpool.v)
         if spool is not None:
-            pools += (spool.ssm, spool.conv)
+            pools += spool.arrays
         return pools
 
     def _bind_pools(self, out: Tuple) -> Tuple:
@@ -779,8 +791,9 @@ class ServingEngine:
         pools, rest = out[:n], out[n:]
         wpool, spool = self.cache.window_pool, self.cache.state_pool
         if spool is not None:
-            spool.ssm, spool.conv = pools[-2:]
-            pools = pools[:-2]
+            n = len(spool.planes)
+            spool.bind(pools[len(pools) - n:])
+            pools = pools[:-n]
         if wpool is not None:
             wpool.k, wpool.v = pools[-2:]
         for name, pool in zip(self.cache.operands, pools):
@@ -1058,9 +1071,10 @@ class ServingEngine:
         if self.cache.window_pool is not None:
             self.cache.window_pool.write_tokens(kv[2], kv[3], wpages,
                                                 offsets)
-        if self.cache.state_pool is not None:
+        spool = self.cache.state_pool
+        if spool is not None:
             n = self.decoder.kv_stacks
-            self.cache.state_pool.write(slot, kv[n - 2], kv[n - 1])
+            spool.write(slot, *kv[n - len(spool.planes):n])
 
     def prefill_width(self, context_len: int) -> int:
         """The row a context of ``context_len`` tokens is prefilled in:

@@ -38,16 +38,18 @@ import jax
 import jax.numpy as jnp
 
 
-def route(u, router, bias, *, top_k: int, route_scale: float):
+def route(u, router, bias, *, top_k: int, route_scale: float,
+          eps: float = 1e-20):
     """u ``[T, d]`` -> (experts ``[T, k]`` int32, weights ``[T, k]``
     float32): sigmoid scores over all experts, top-k on ``score +
-    bias``, the chosen scores renormalised and scaled."""
+    bias``, the chosen scores renormalised (``w / (sum w + eps)``: the
+    published epsilon, 1e-20 in afmoe, 1e-6 in lfm2_moe) and scaled."""
     scores = jax.nn.sigmoid(jnp.dot(
         u.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
     w = jnp.take_along_axis(scores, experts, axis=-1)
-    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * route_scale
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps) * route_scale
     return experts.astype(jnp.int32), w
 
 
@@ -114,25 +116,30 @@ def held_experts(u, experts, weights, p, held: Tuple[int, int],
 
 
 def expert_layer(u, p, *, held: Tuple[int, int], top_k: int,
-                 route_scale: float, valid=None, groups=None):
+                 route_scale: float, valid=None, groups=None,
+                 route_eps: float = 1e-20):
     """``sum_i w_i Expert_i(u)`` over the held experts among the chosen,
-    plus the shared expert.  ``u`` ``[..., d]``; ``p`` has ``router``
-    ``[d, E]``, ``experts`` and ``shared``, and for the sigmoid router
-    ``expert_bias`` ``[E]``; ``groups`` ``(n_group, topk_group)``
-    chooses :func:`route_grouped` instead; ``valid`` (bool, ``u``'s
-    lead shape) marks the tokens that are not padding.  Returns (y like
-    ``u``, load ``[n_held]``)."""
+    plus the shared expert where ``p`` has one.  ``u`` ``[..., d]``;
+    ``p`` has ``router`` ``[d, E]``, ``experts``, and ``shared`` where
+    the model has a shared expert (afmoe, deepseek_v2; lfm2_moe has
+    none), and for the sigmoid router ``expert_bias`` ``[E]`` and its
+    renormalisation's ``route_eps``; ``groups`` ``(n_group,
+    topk_group)`` chooses :func:`route_grouped` instead; ``valid``
+    (bool, ``u``'s lead shape) marks the tokens that are not padding.
+    Returns (y like ``u``, load ``[n_held]``)."""
     lead = u.shape[:-1]
     u2 = u.reshape(-1, u.shape[-1])
     with jax.named_scope("moe_router"):
         if groups is None:
             experts, weights = route(u2, p["router"], p["expert_bias"],
-                                     top_k=top_k, route_scale=route_scale)
+                                     top_k=top_k, route_scale=route_scale,
+                                     eps=route_eps)
         else:
             experts, weights = route_grouped(
                 u2, p["router"], top_k=top_k, route_scale=route_scale,
                 n_group=groups[0], topk_group=groups[1])
     y, load = held_experts(u2, experts, weights, p["experts"], held, valid)
-    with jax.named_scope("moe_shared"):
-        y = y + swiglu(u2, p["shared"])
+    if "shared" in p:
+        with jax.named_scope("moe_shared"):
+            y = y + swiglu(u2, p["shared"])
     return y.reshape(*lead, -1), load

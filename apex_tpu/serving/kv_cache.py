@@ -56,7 +56,9 @@ of fixed-size pages:
   at admission and given back at retirement and preemption; slot 0 is
   the scratch slot as page 0 is the scratch page.  The page pool then
   holds only the attention layers' K/V (docs/serving.md, "Slots beside
-  pages").
+  pages").  A slot holds the planes its block declares: a Mamba-2
+  state and a convolution tail, or (ISSUE 39: LFM2's short-convolution
+  layers) a tail alone.
 
 The device arrays are functionally updated (``.at[].set``); the cache
 object re-binds them, so callers treat ``cache.k``/``cache.v`` (and,
@@ -830,25 +832,28 @@ class WindowPool(PagedKVCache):
         return pages, (positions % self.page_size).astype(np.int32)
 
 
-def _write_slot(ssm, conv, ssm_new, conv_new, slot):
-    """pool[:, slot] = new for both arrays, the slot traced: every
+def _write_slot(pools, new, slot):
+    """pool[:, slot] = new for each plane, the slot traced: every
     admission reuses one compiled executable."""
-    return (jax.lax.dynamic_update_slice_in_dim(
-                ssm, ssm_new[:, None].astype(ssm.dtype), slot, axis=1),
-            jax.lax.dynamic_update_slice_in_dim(
-                conv, conv_new[:, None].astype(conv.dtype), slot, axis=1))
+    return tuple(jax.lax.dynamic_update_slice_in_dim(
+        pool, x[:, None].astype(pool.dtype), slot, axis=1)
+        for pool, x in zip(pools, new))
 
 
 class StatePool:
-    """The recurrent state of a model's state-space layers: one SLOT a
+    """What a model's state layers keep of a request: one SLOT a
     request, of fixed size, where attention layers have pages that grow.
 
-    ``ssm`` ``[num_layers, num_slots, N, H * P]`` (float32 as served:
-    the state is updated every token, and rounding it to bfloat16 each
-    step drifts) and ``conv`` ``[num_layers, num_slots, taps - 1, ch]``
-    (the convolution's tail, in the activations' type: a copy, nothing
-    accumulates), allocated once.  Slot 0 is the SCRATCH slot, never
-    handed out: idle decode rows and warm-up launches name it.
+    The pool holds the PLANES its block declares, in this order:
+    ``ssm`` ``[num_layers, num_slots, N, H * P]``, a Mamba-2 layer's
+    recurrent state (float32 as served: the state is updated every
+    token, and rounding it to bfloat16 each step drifts), where
+    ``state_shape`` is given, and ``conv`` ``[num_layers, num_slots,
+    *tail_shape]``, the convolution's tail (in the activations' type: a
+    copy, nothing accumulates).  Granite 4.0-H's layers keep both; an
+    LFM2 short-convolution layer keeps the tail alone (``state_shape``
+    None, ``ssm`` None).  Allocated once.  Slot 0 is the SCRATCH slot,
+    never handed out: idle decode rows and warm-up launches name it.
     Allocation is lowest-first, like pages.  A slot is NOT zeroed when
     it changes hands: a whole-row prefill overwrites it, and a chunked
     prefill's first chunk is told to start from zero
@@ -856,22 +861,36 @@ class StatePool:
     is ever read."""
 
     def __init__(self, *, num_layers: int, num_slots: int,
-                 state_shape: Tuple[int, int], tail_shape: Tuple[int, int],
+                 tail_shape: Tuple[int, ...],
+                 state_shape: Optional[Tuple[int, int]] = None,
                  dtype=jnp.float32, state_dtype=jnp.float32):
         if num_slots < 2:
             raise ValueError("num_slots must be >= 2 (slot 0 is the "
                              "reserved scratch slot)")
         self.num_layers = num_layers
         self.num_slots = num_slots
-        self.ssm = jnp.zeros((num_layers, num_slots) + tuple(state_shape),
-                             state_dtype)
+        self.ssm = (None if state_shape is None else jnp.zeros(
+            (num_layers, num_slots) + tuple(state_shape), state_dtype))
         self.conv = jnp.zeros((num_layers, num_slots) + tuple(tail_shape),
                               dtype)
+        #: the names of the planes held, in executable order
+        self.planes: Tuple[str, ...] = (
+            ("conv",) if self.ssm is None else ("ssm", "conv"))
         self._write = jax.jit(
-            _write_slot, donate_argnums=(0, 1)
+            _write_slot, donate_argnums=(0,)
             if jax.default_backend() == "tpu" else ())
         self._free: List[int] = list(range(1, num_slots))
         self._owner: Dict[int, int] = {}
+
+    @property
+    def arrays(self) -> Tuple[jnp.ndarray, ...]:
+        """The planes' device arrays, in :attr:`planes` order."""
+        return tuple(getattr(self, name) for name in self.planes)
+
+    def bind(self, arrays: Sequence[jnp.ndarray]) -> None:
+        """Rebind the planes to a step's returned arrays."""
+        for name, a in zip(self.planes, arrays):
+            setattr(self, name, a)
 
     @property
     def slots_free(self) -> int:
@@ -908,13 +927,12 @@ class StatePool:
         t[:len(slots)] = slots
         return jnp.asarray(t)
 
-    def write(self, slot: int, ssm_new: jnp.ndarray,
-              conv_new: jnp.ndarray) -> None:
-        """Put a whole-row prefill's final state ``[num_layers, N, H *
-        P]`` and tail ``[num_layers, taps - 1, ch]`` into ``slot`` (in
-        place on the TPU: the pools are donated)."""
-        self.ssm, self.conv = self._write(
-            self.ssm, self.conv, ssm_new, conv_new, np.int32(slot))
+    def write(self, slot: int, *new: jnp.ndarray) -> None:
+        """Put a whole-row prefill's final planes into ``slot``, one
+        array a plane in :attr:`planes` order (a state ``[num_layers, N,
+        H * P]``, a tail ``[num_layers, *tail_shape]``; in place on the
+        TPU: the pools are donated)."""
+        self.bind(self._write(self.arrays, new, np.int32(slot)))
 
 
 class PrefixIndex:

@@ -17,7 +17,10 @@ second, :class:`DeepseekV2Block` (latent attention over one vector a
 token) the third, :class:`GraniteHybridBlock` the fourth: most of its
 layers are Mamba-2 STATE-SPACE mixers, which are handed no ``attend``
 but a ``scan`` that takes the request's recurrent state (a slot of the
-cache's state pool, ISSUE 35) to its next.  The engine asks a
+cache's state pool, ISSUE 35) to its next; :class:`Lfm2MoeBlock` the
+fifth: gated SHORT-convolution layers, whose slot holds a convolution
+tail alone, beside QK-normed grouped-query layers, and a top-4 sigmoid
+expert layer without a shared expert (ISSUE 39).  The engine asks a
 configuration for its block and never asks which it got.
 
 Two entry points mirror the two phases of continuous batching:
@@ -104,7 +107,8 @@ import numpy as np
 
 from apex_tpu.ops import (flash_attention, flash_decode, flash_decode_latent,
                           latent_walk_tiles)
-from apex_tpu.ops.ssm import causal_conv, ssd_chunk_scan, ssm_decode_update
+from apex_tpu.ops.ssm import (causal_conv, short_conv_update, ssd_chunk_scan,
+                              ssm_decode_update)
 from apex_tpu.serving.experts import expert_layer, swiglu
 from apex_tpu.serving.kv_cache import (latent_width, pad_latent,
                                        quantize_tokens)
@@ -927,13 +931,227 @@ class GraniteHybridBlock:
             self.cfg.logits_scaling, x.dtype)
 
 
+# -- lfm2_moe (LFM2): the fifth block, short convolutions --------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class Lfm2MoeConfig:
+    """Geometry of an ``lfm2_moe`` decoder (LiquidAI LFM2-MoE):
+    ``layer_types`` names each layer's mixer, ``"conv"`` (a gated short
+    convolution) or ``"full_attention"`` (grouped-query attention with
+    RMS-normed heads and RoPE); the first ``num_dense_layers`` layers
+    have a SwiGLU MLP and the rest a top-k sigmoid expert layer of
+    ``num_experts`` experts, of which ``experts_held`` ``[lo, hi)`` are
+    here (all of them where one chip holds a layer), and no shared
+    expert.  The head is the embedding.  No position table: a request is
+    bounded by its pages.
+
+    A request keeps, a convolution layer, the last ``conv_L_cache - 1``
+    gated inputs of the convolution (:attr:`tail_shape`: a SLOT of the
+    cache's ``state_pool``, which holds no recurrent state for this
+    model, :attr:`state_shape` None), and an attention layer, K and V of
+    ``num_kv_heads`` heads a token."""
+
+    vocab_size: int
+    hidden_size: int
+    num_heads: int
+    num_kv_heads: int
+    layer_types: Tuple[str, ...]       # "conv" | "full_attention"
+    num_dense_layers: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    num_experts: int
+    experts_held: Tuple[int, int]
+    top_k: int
+    route_scale: float = 1.0
+    conv_L_cache: int = 3
+    rope_theta: float = 1000000.0
+    norm_eps: float = 1e-5
+    dtype: object = jnp.float32
+
+    name = "lfm2_moe"
+    max_position = None
+    latent_dim = None
+    #: a slot holds a tail and no recurrent state
+    state_shape = None
+    #: the renormalisation's epsilon in the published router
+    route_eps = 1e-6
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def head_dim(self) -> int:
+        if self.hidden_size % self.num_heads:
+            raise ValueError("hidden_size must divide by num_heads")
+        return self.hidden_size // self.num_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads
+
+    @property
+    def layer_windows(self) -> Tuple[Optional[int], ...]:
+        return (None,) * self.num_layers
+
+    @property
+    def state_layers(self) -> Tuple[int, ...]:
+        """The layers that keep a tail in a slot and no K/V."""
+        return tuple(i for i, t in enumerate(self.layer_types)
+                     if t == "conv")
+
+    @property
+    def page_head_dim(self) -> int:
+        """A K/V head's width in the page pool: whole lane tiles, as
+        :attr:`GraniteHybridConfig.page_head_dim`."""
+        return latent_width(self.head_dim)
+
+    @property
+    def tail_shape(self) -> Tuple[int, int]:
+        """A slot's tail of one layer: the gated inputs ``B * x`` of the
+        ``conv_L_cache - 1`` tokens before the next."""
+        return (self.conv_L_cache - 1, self.hidden_size)
+
+    def block(self) -> "Lfm2MoeBlock":
+        return Lfm2MoeBlock(self)
+
+    def init_params(self, seed: int = 0):
+        """Seeded parameters in the block's layout (the reference's):
+        matrices normal with std 1/sqrt(fan_in) (the convolution's taps
+        1/sqrt(taps)), gains 1 + 0.02 normal, the router's selection
+        bias 0.02 normal (so that tests exercise it)."""
+        d, dt = self.hidden_size, self.dtype
+        hq, hk, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        n_held = self.experts_held[1] - self.experts_held[0]
+        keys = iter(jax.random.split(jax.random.PRNGKey(seed), 4096))
+
+        def mat(*shape):
+            return (jax.random.normal(next(keys), shape, jnp.float32)
+                    / math.sqrt(shape[-2])).astype(dt)
+
+        def gain(n):
+            return (1.0 + 0.02 * jax.random.normal(
+                next(keys), (n,), jnp.float32)).astype(dt)
+
+        def mlp(*lead, f):
+            return {"wg": mat(*lead, d, f), "wu": mat(*lead, d, f),
+                    "wd": mat(*lead, f, d)}
+
+        layers = []
+        for i, kind in enumerate(self.layer_types):
+            layer = {"g_op": gain(d), "g_ffn": gain(d)}
+            if kind == "conv":
+                layer.update(w_in=mat(d, 3 * d),
+                             conv_w=mat(self.conv_L_cache, d),
+                             w_out=mat(d, d))
+            else:
+                layer.update(wq=mat(d, hq * hd), wk=mat(d, hk * hd),
+                             wv=mat(d, hk * hd), wo=mat(hq * hd, d),
+                             gq=gain(hd), gk=gain(hd))
+            if i < self.num_dense_layers:
+                layer["mlp"] = mlp(f=self.intermediate_size)
+            else:
+                layer["moe"] = {
+                    "router": mat(d, self.num_experts),
+                    "expert_bias": (0.02 * jax.random.normal(
+                        next(keys), (self.num_experts,),
+                        jnp.float32)).astype(dt),
+                    "experts": mlp(n_held, f=self.moe_intermediate_size)}
+            layers.append(layer)
+        embed = (jax.random.normal(next(keys), (self.vocab_size, d),
+                                   jnp.float32) / math.sqrt(d)).astype(dt)
+        return {"embed": embed, "norm_f": gain(d), "layers": layers}
+
+
+class Lfm2MoeBlock:
+    """The LFM2-MoE block (docs/serving.md, "The fifth block"): ``h = x
+    + Mixer(RMS(x; g_op))``, ``x' = h + FFN(RMS(h; g_ffn))``; the head
+    is the embedding transposed, after a final RMSNorm.
+
+    A CONVOLUTION layer's mixer: ``[B | C | x] = u w_in``, ``v = B * x``,
+    a depthwise causal convolution of ``conv_L_cache`` taps over ``v``
+    with no bias and no activation, gated by ``C``, then ``w_out``; the
+    decoder hands it ``mixer(v, layer)``, the convolution from the
+    request's tail (a slot: the whole row's from zero, a chunk's from
+    the slot, a decode token's against the slot in place:
+    :func:`~apex_tpu.ops.ssm.short_conv_update`).  An ATTENTION layer:
+    grouped-query heads, each of q and k RMS-normed over its head
+    before RoPE (rotate-half over the whole head), scores over
+    ``sqrt(head_dim)``, no bias; it gets ``attend(q, k, v, scale=)``.
+    The FFN is SwiGLU in the first ``num_dense_layers`` layers and the
+    dropless sigmoid expert layer after them, with no shared expert and
+    the published renormalisation ``w / (sum w + 1e-6)``."""
+
+    #: as Granite's: a shared prefix needs a snapshot of the tail at
+    #: the page it ends on, a rejected draft a rolled-back tail, shipped
+    #: pages a shipped slot: none exists yet
+    refuses = ("tp", "kv_quant", "prefix_sharing", "speculation",
+               "prefill_only", "kv_import")
+    stat_names = ("moe_pairs_held", "moe_load_max", "moe_experts_touched")
+    #: where the engine's refusal sends the reader
+    refuses_doc = "What LFM2 refuses"
+
+    def __init__(self, cfg: Lfm2MoeConfig):
+        self.cfg = cfg
+
+    def embed(self, params, tokens, positions):
+        return params["embed"][tokens]
+
+    def layer(self, layer, li, x, positions, mixer, *, tp_axis=None,
+              valid=None, stats=None):
+        cfg = self.cfg
+        eps, hd = cfg.norm_eps, cfg.head_dim
+        lead = x.shape[:-1]
+        u = _rms(x, layer["g_op"], eps)
+        if "w_in" in layer:
+            with jax.named_scope("conv_in_proj"):
+                b, c, xt = jnp.split(u @ layer["w_in"], 3, axis=-1)
+                v = b * xt
+            with jax.named_scope("short_conv"):
+                conv = mixer(v, layer)            # float32
+            with jax.named_scope("conv_out_proj"):
+                m = ((c.astype(jnp.float32) * conv).astype(x.dtype)
+                     @ layer["w_out"])
+        else:
+            with jax.named_scope("attn_full"):
+                q = (u @ layer["wq"]).reshape(*lead, cfg.num_heads, hd)
+                k = (u @ layer["wk"]).reshape(*lead, cfg.num_kv_heads, hd)
+                v = (u @ layer["wv"]).reshape(*lead, cfg.num_kv_heads, hd)
+                q = _rope(_rms(q, layer["gq"], eps), positions,
+                          cfg.rope_theta)
+                k = _rope(_rms(k, layer["gk"], eps), positions,
+                          cfg.rope_theta)
+                m = mixer(q, k, v, scale=hd ** -0.5) @ layer["wo"]
+        h = x + m
+        u = _rms(h, layer["g_ffn"], eps)
+        if "mlp" in layer:
+            with jax.named_scope("mlp"):
+                m = swiglu(u, layer["mlp"])
+        else:
+            m, load = expert_layer(
+                u, layer["moe"], held=cfg.experts_held, top_k=cfg.top_k,
+                route_scale=cfg.route_scale, valid=valid,
+                route_eps=cfg.route_eps)
+            if stats is not None:
+                stats.append(load)
+        return h + m
+
+    def final_norm(self, params, x):
+        return _rms(x, params["norm_f"], self.cfg.norm_eps)
+
+    def logits(self, params, x):
+        return x @ params["embed"].T
+
+
 class StateIO(NamedTuple):
-    """The recurrent-state half of a hybrid cache as a paged step sees
-    it: the pools of the state-space layers (``ssm`` ``[L_s, n_slots,
-    N, H * P]``, ``conv`` ``[L_s, n_slots, taps - 1, ch]``), each row's
-    slot (``slots [b]``; 0, the scratch slot, for an idle row) and, for
-    a multi-token step, whether the row starts from zero instead of
-    from what its slot holds (``fresh [b]``: a request's first chunk)."""
+    """The slot half of a hybrid cache as a paged step sees it: the
+    pools of the state layers (``ssm`` ``[L_s, n_slots, N, H * P]``, None
+    where a slot holds a tail alone; ``conv`` ``[L_s, n_slots, taps -
+    1, ...]``), each row's slot (``slots [b]``; 0, the scratch slot, for
+    an idle row) and, for a multi-token step, whether the row starts
+    from zero instead of from what its slot holds (``fresh [b]``: a
+    request's first chunk)."""
 
     ssm: jnp.ndarray
     conv: jnp.ndarray
@@ -981,6 +1199,11 @@ class PagedDecoder:
         self.full_layers, self.window_layers = (counts["full"],
                                                 counts["window"])
         self.n_state_layers = counts["state"]
+        #: what a slot holds of a state layer: a Mamba-2 state and a
+        #: tail, or (a short convolution) a tail alone
+        self.state_planes = (
+            () if not self.n_state_layers
+            else ("conv",) if cfg.state_shape is None else ("ssm", "conv"))
         self.stat_names = self.block.stat_names
         #: a latent model: one vector a token, one pool operand
         self.latent = cfg.latent_dim is not None
@@ -989,10 +1212,10 @@ class PagedDecoder:
         self.page_head_dim = getattr(cfg, "page_head_dim", cfg.head_dim)
         #: the stacks a prefill returns after the logits: K/V, as many
         #: as the cache's pools have unquantized operands, then the
-        #: state-space layers' final states and tails
+        #: state layers' final planes (states and tails, or tails)
         self.kv_stacks = ((1 if self.latent
                            else 4 if self.window_layers else 2)
-                          + (2 if self.n_state_layers else 0))
+                          + len(self.state_planes))
 
     # -- heads under a lane tile ------------------------------------------
 
@@ -1057,17 +1280,22 @@ class PagedDecoder:
 
         return attend
 
-    def _stats(self, stats, walk=()):
+    def _stats(self, stats, walk=(0, 0)):
         """The block's per-launch counters as one int32 vector, in
-        ``stat_names`` order (afmoe: the (token, expert) pairs its held
-        experts took, summed over layers; the most any one took;
-        deepseek_v2 besides: ``walk``, the blocks of latent pages a
-        layer's call had to see and those it fetched, 0 where no call
-        walked pages)."""
-        load = jnp.stack(stats)
-        walk = walk or (0,) * (len(self.stat_names) - 2)
-        return jnp.stack([jnp.sum(load), jnp.max(load),
-                          *walk]).astype(jnp.int32)
+        ``stat_names`` order: the (token, expert) pairs its held experts
+        took, summed over layers; the most any one took; lfm2_moe: the
+        held experts that took any, summed over layers; deepseek_v2:
+        ``walk``, the blocks of latent pages a layer's call had to see
+        and those it fetched, 0 where no call walked pages."""
+        with jax.named_scope("counters"):
+            load = jnp.stack(stats)
+            named = {"moe_pairs_held": jnp.sum(load),
+                     "moe_load_max": jnp.max(load),
+                     "moe_experts_touched": jnp.sum(load > 0),
+                     "latent_blocks_walked": walk[0],
+                     "latent_blocks_fetched": walk[1]}
+            return jnp.stack([named[n] for n in self.stat_names]).astype(
+                jnp.int32)
 
     # -- admission: packed varlen prefill --------------------------------
 
@@ -1136,9 +1364,18 @@ class PagedDecoder:
             tails.append(tail)
             return y
 
+        def short_conv(v, p):
+            """A short-convolution layer's mixer over a whole row: from
+            a zero tail; the final one is kept for the slot."""
+            zero = jnp.zeros(self.cfg.tail_shape, v.dtype)
+            c, tail = jax.vmap(lambda v, valid: causal_conv(
+                v, zero, valid, p["conv_w"], None, None))(v, seg != 0)
+            tails.append(tail)
+            return c
+
         def attend_in(li):
             if li in self.state_layers:
-                return scan
+                return scan if "ssm" in self.state_planes else short_conv
             if self.latent:
                 return self._expanded(attention, seg, kept[False][0])
             window = self.windows[li]
@@ -1171,8 +1408,9 @@ class PagedDecoder:
         if self.window_layers:
             out += (jnp.stack(kept[True][0]), jnp.stack(kept[True][1]))
         if states:
-            out += (jnp.stack(states).astype(self.cfg.state_dtype),
-                    jnp.stack(tails))
+            out += (jnp.stack(states).astype(self.cfg.state_dtype),)
+        if tails:
+            out += (jnp.stack(tails),)
         if stats:
             out += (self._stats(stats),)
         return out
@@ -1307,10 +1545,39 @@ class PagedDecoder:
 
             return step if tokens.ndim == 1 else scan
 
+        def conv_in(pi):
+            def step(v, p):
+                # one token a row against its slot's tail, in place
+                c, slot_pools[1] = short_conv_update(
+                    slot_pools[1], state.slots, v, layer=pi,
+                    conv_w=p["conv_w"])
+                return c
+
+            def chunk(v, p):
+                # a chunk a row from its slot's tail (or zero), the new
+                # tail back into the slot, as ``scan`` does
+                cs = []
+                for i in range(b):
+                    slot, keep = state.slots[i], state.fresh[i] == 0
+                    held = jax.lax.dynamic_index_in_dim(
+                        slot_pools[1][pi], slot, keepdims=False)
+                    c, tail = causal_conv(
+                        v[i], jnp.where(keep, held, 0), real[i],
+                        p["conv_w"], None, None)
+                    slot_pools[1] = jax.lax.dynamic_update_slice(
+                        slot_pools[1],
+                        tail[None, None].astype(slot_pools[1].dtype),
+                        (pi, slot, 0, 0))
+                    cs.append(c)
+                return jnp.stack(cs)
+
+            return step if tokens.ndim == 1 else chunk
+
         def attend_in(li):
             pi = self.pool_index[li]
             if li in self.state_layers:
-                return scan_in(pi)
+                return (scan_in(pi) if "ssm" in self.state_planes
+                        else conv_in(pi))
             w = self.windows[li]
             pool = pools[w is not None]
             pages, offsets = targets[w is not None]
@@ -1353,10 +1620,10 @@ class PagedDecoder:
         if window is not None:
             out += tuple(pools[True][:2])
         if state is not None:
-            out += tuple(slot_pools)
+            out += tuple(a for a in slot_pools if a is not None)
         if stats:
             out += (self._stats(stats, (tiles.walked, tiles.fetched)
-                                if self.latent else ()),)
+                                if self.latent else (0, 0)),)
         return x, out
 
     # -- steady state: paged decode --------------------------------------
@@ -1384,18 +1651,19 @@ class PagedDecoder:
         quantizes on write and ``flash_decode`` dequantizes on read.
         With ``window`` (:class:`WindowKV`) the window pool's k and v
         follow the full pool's, with ``state`` (:class:`StateIO`) the
-        state pool's two arrays after those, and a block's counters
-        come last.
+        state pool's planes after those (states and tails, or tails),
+        and a block's counters come last.
         ``tp_axis``: per-shard body under ``shard_map`` (local head
         slice of pool and scales, one ``psum`` per block).
 
         The append and the attention over the pages are
         :meth:`_paged`'s; :meth:`extend` does the same."""
         page_size = k_pool.shape[2]     # a latent pool's too
-        page_slot = positions // page_size
-        page_idx = jnp.take_along_axis(
-            page_table, page_slot[:, None], axis=1)[:, 0]
-        offset = positions % page_size
+        with jax.named_scope("pages"):
+            page_slot = positions // page_size
+            page_idx = jnp.take_along_axis(
+                page_table, page_slot[:, None], axis=1)[:, 0]
+            offset = positions % page_size
         x, out = self._paged(
             params, k_pool, v_pool, tokens, positions, page_idx, offset,
             page_table, kv_len, k_scale=k_scale, v_scale=v_scale,

@@ -58,7 +58,7 @@ from apex_tpu.ops import (flash_attention_qkv, flash_attention_qkv_route,
                           flash_decode_route, latent_walk_tiles,
                           routing_override, ssm_decode_route)
 from apex_tpu.serving import (DeepseekV2Config, GraniteHybridConfig,
-                              PagedDecoder, ServingEngine,
+                              Lfm2MoeConfig, PagedDecoder, ServingEngine,
                               ServingModelConfig, SpecConfig, poisson_trace)
 from apex_tpu.serving.model import StateIO
 from apex_tpu.serving.engine import prefill_route
@@ -86,6 +86,10 @@ LATENT_FORMS_TOL = 6e-2
 # does not.  Measured (PR 35), one period at the published widths:
 # 2.3e-2 on the logits and on the state, 1.4e-2 on the tail.
 SSM_FORMS_TOL = 6e-2
+# The short convolution's two forms (LFM2), bf16 activations: a row's
+# convolution and projections against a token's, the same arithmetic in
+# other fusions; taken as the recurrence's until measured on the chip.
+SHORT_CONV_FORMS_TOL = 6e-2
 # Chips holding equal shards of one program's state.
 BALANCE_FACTOR = 1.5
 
@@ -652,6 +656,101 @@ def leg_state_space(cfg, *, seed, page_size, row, max_new) -> dict:
             "memory": memory_by_device()}
 
 
+def leg_short_conv(cfg, *, seed, page_size, row, max_new) -> dict:
+    """LFM2's gated short convolution in its two forms on the same
+    tokens, and its tail-only slots (ISSUE 39).
+
+    A row of ``row`` tokens goes through the decoder whole; then its
+    first half again as a row, whose tails are written into a slot, and
+    its second half token by token, each convolution layer reading and
+    advancing the slot's tail in place: the logits and the final tails
+    agree to rounding, and a bystander slot keeps its bytes.  Then an
+    engine with ONE slot to hand out serves two requests one after the
+    other: the second takes the slot the first gave back and serves
+    what it serves alone."""
+    dec = PagedDecoder(cfg)
+    params = cfg.init_params(seed)
+    rng = np.random.RandomState(seed)
+    seq = rng.randint(0, cfg.vocab_size, row)
+    half = row // 2
+    one = lambda a: jnp.asarray(np.asarray(a, np.int32)[None])
+    prefill = jax.jit(dec.prefill)
+    logits, _, _, tail, _ = prefill(
+        params, one(seq), one(np.ones(row)), one(np.arange(row)))
+    eng = ServingEngine(cfg, params, num_pages=row // page_size + 8,
+                        page_size=page_size, max_batch=2,
+                        prefill_budget=half, state_slots=4)
+    cache, spool = eng.cache, eng.cache.state_pool
+    tokens = np.zeros((row,), np.int32)
+    tokens[:half] = seq[:half]
+    seg = (np.arange(row) < half).astype(np.int32)
+    _, k, v, t_half, _ = prefill(
+        params, one(tokens), one(seg), one(np.arange(row) * seg))
+    pages = cache.allocate(cache.pages_needed(row), 0)
+    idx = np.arange(row)
+    cache.write_tokens(
+        k[:, 0], v[:, 0], np.where(seg, np.asarray(pages)[idx // page_size],
+                                   0), np.where(seg, idx % page_size, 0))
+    spool.write(2, t_half[:, 0])
+    spool.write(3, tail[:, 0])                  # a bystander
+    bystander = np.asarray(spool.conv[:, 3])
+    decode = jax.jit(dec.decode, donate_argnums=(1, 2))
+    table = cache.page_table([pages])
+    steps = []
+    for p in range(half, row):
+        out = decode(params, cache.k, cache.v, one(seq[p]), one(p), table,
+                     one(p + 1),
+                     state=StateIO(None, spool.conv, one(2), one(0)))
+        cache.k, cache.v, spool.conv = out[1:4]
+        steps.append(out[0][0])
+    forms = {"logits": rel_l2(jnp.stack(steps), logits[0, half:]),
+             "tail": rel_l2(spool.conv[:, 2], tail[:, 0])}
+    require(max(forms.values()) < SHORT_CONV_FORMS_TOL,
+            f"short conv: decode form against row form {forms}")
+    require(np.array_equal(bystander, np.asarray(spool.conv[:, 3])),
+            "short conv: a slot changed under another row's steps")
+    cache.free(pages)
+
+    def serve(engine, prompts):
+        engine.warmup()
+        reqs = []
+        for prompt in prompts:
+            reqs.append(engine.submit(prompt, max_new))
+            with hot_path_guard("short-conv serving after warm-up",
+                                transfers=None, tripwire=False):
+                engine.run()
+        return [r.generated for r in reqs]
+
+    prompts = [[int(t) for t in rng.randint(0, cfg.vocab_size, n)]
+               for n in (half // 2, half + page_size)]
+    kw = dict(num_pages=row // page_size + 8, page_size=page_size,
+              max_batch=2, prefill_budget=half)
+    shared = serve(ServingEngine(cfg, params, state_slots=2, **kw), prompts)
+    alone = serve(ServingEngine(cfg, params, **kw), prompts[1:])
+    require(shared[1] == alone[0],
+            "short conv: a reused slot did not start from zero")
+    return {"layers": cfg.num_layers, "rel_l2": forms,
+            "route": flash_decode_route(
+                jax.ShapeDtypeStruct((2, cfg.num_heads, 1,
+                                      dec.page_head_dim), cfg.dtype),
+                jax.ShapeDtypeStruct(cache.k.shape[1:], cache.k.dtype)),
+            "tail_pool": list(spool.conv.shape),
+            "memory": memory_by_device()}
+
+
+def _lfm2_config() -> Lfm2MoeConfig:
+    """LFM2-24B-A2B's published widths: the two dense layers and one
+    period (attention, conv, conv, conv), an eighth of the experts and
+    of the vocabulary."""
+    return Lfm2MoeConfig(
+        vocab_size=8192, hidden_size=2048, num_heads=32, num_kv_heads=8,
+        layer_types=("conv", "conv", "full_attention", "conv", "conv",
+                     "conv"),
+        num_dense_layers=2, intermediate_size=11776,
+        moe_intermediate_size=1536, num_experts=64, experts_held=(0, 8),
+        top_k=4, dtype=jnp.bfloat16)
+
+
 def _hybrid_config(periods: int) -> GraniteHybridConfig:
     """Granite 4.0-H Micro's published widths, ``periods`` periods of
     ten layers deep, an eighth of the vocabulary."""
@@ -758,6 +857,11 @@ def main() -> int:
         _hybrid_config(1), seed=4, page_size=64, row=1024, max_new=8))
     require(hybrid["route"] == ROUTES_ON_TPU["decode"],
             f"state space: the update's route is {hybrid['route']}")
+
+    short = _report("short_conv", leg_short_conv(
+        _lfm2_config(), seed=5, page_size=64, row=1024, max_new=8))
+    require(short["route"] == ROUTES_ON_TPU["decode"],
+            f"short conv: the paged route is {short['route']}")
 
     if len(devices) >= 4:
         _report("train_mesh", leg_train(

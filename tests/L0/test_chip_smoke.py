@@ -131,6 +131,21 @@ def test_state_space_leg():
     json.dumps(out)
 
 
+def test_short_conv_leg():
+    from apex_tpu.serving import Lfm2MoeConfig
+
+    cfg = Lfm2MoeConfig(
+        vocab_size=96, hidden_size=64, num_heads=4, num_kv_heads=2,
+        layer_types=("conv", "conv", "full_attention", "conv"),
+        num_dense_layers=2, intermediate_size=128, moe_intermediate_size=32,
+        num_experts=8, experts_held=(0, 8), top_k=2)
+    out = chip_smoke.leg_short_conv(cfg, seed=5, page_size=8, row=32,
+                                    max_new=3)
+    assert out["route"] == "xla" and out["tail_pool"] == [3, 4, 2, 64]
+    assert max(out["rel_l2"].values()) < 1e-4
+    json.dumps(out)
+
+
 def test_warm_leg():
     out = chip_smoke.leg_warm(TOY_SERVE, spec_k=2, chunk_size=16, seed=1,
                               **TOY_TRAFFIC)

@@ -9,6 +9,7 @@ import gc
 import inspect
 import json
 import os
+import re
 import weakref
 
 import jax
@@ -17,8 +18,8 @@ import pytest
 
 from apex_tpu.analysis import hot_path_guard
 from apex_tpu.serving import (AfmoeConfig, DeepseekV2Config,
-                              GraniteHybridConfig, ServingEngine,
-                              ServingModelConfig, SimClock)
+                              GraniteHybridConfig, Lfm2MoeConfig,
+                              ServingEngine, ServingModelConfig, SimClock)
 from apex_tpu.telemetry import scopes
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -348,6 +349,14 @@ def _granite_hybrid():
         logits_scaling=8.0, rms_norm_eps=1e-5)
 
 
+def _lfm2_moe():
+    return Lfm2MoeConfig(
+        vocab_size=96, hidden_size=64, num_heads=4, num_kv_heads=2,
+        layer_types=("conv", "full_attention", "conv"), num_dense_layers=1,
+        intermediate_size=128, moe_intermediate_size=32, num_experts=8,
+        experts_held=(0, 8), top_k=2)
+
+
 BLOCKS = {
     "gpt": (lambda: ServingModelConfig(
         vocab_size=64, hidden_size=32, num_heads=4, num_layers=2,
@@ -366,6 +375,10 @@ BLOCKS = {
                                          "prefill_budget": 16},
                        {"ssm_in_proj", "ssm_update", "ssm_gate_norm",
                         "ssm_out_proj", "attn_nope", "mlp", "layer", "head"}),
+    "lfm2_moe": (_lfm2_moe, {"max_pages_per_request": 16,
+                             "prefill_budget": 16},
+                 {"conv_in_proj", "short_conv", "conv_out_proj", "attn_full",
+                  "mlp", "moe_router", "moe_experts", "layer", "head"}),
 }
 #: of a tiny engine's decode instructions, at most this share is a fusion
 #: of parts from more than one scope (the CPU's fusions; measured 0.02-0.07)
@@ -392,6 +405,39 @@ def test_a_tiny_engine_resolves_a_decode_map_with_its_blocks_scopes(block):
     # only what was asked for was resolved
     assert scopes._REGISTRY[("jit__prefill", str(eng.prefill_widths[0]))
                             ].resolved is None
+
+
+def test_the_lfm2_blocks_three_executables_leave_nothing_under_no_scope():
+    """ISSUE 39: every instruction of the decode step, of each prefill
+    row and of the chunk comes from some named scope, so that the time
+    of a run adds up by scope with nothing left over."""
+    make, kw, _ = BLOCKS["lfm2_moe"]
+    cfg = make()
+    eng = ServingEngine(cfg, cfg.init_params(3), num_pages=40, page_size=8,
+                        max_batch=3, clock=SimClock(), **kw)
+    eng._register_scopes()
+    names = ["jit__decode", "jit__prefill", "jit__chunk"]
+    maps = scopes.scope_maps(names)
+    assert sorted(maps) == sorted(names)
+    structs = eng._executable_arg_structs()
+    for name, key in zip(names, ("decode", "prefill", "chunk")):
+        # what runs: the entry and the bodies of its loops (a reducer's
+        # or a comparator's instructions are no events of their own);
+        # parameters, constants and tuples are no work
+        text = jax.jit(eng._exec_defs[key][0]).trace(
+            *structs[key]).lower().compile().as_text()
+        comps = scopes.computations(text)
+        run = ({re.search(r"^ENTRY %?(\S+)", text, re.M).group(1)}
+               | set(re.findall(r"(?:body|condition)=%?([\w.\-]+)", text)))
+        heads = {head for comp in run
+                 for head, _, opcode, _ in scopes._instructions(comps[comp])
+                 if opcode not in {"parameter", "constant", "tuple",
+                                   "get-tuple-element", "bitcast"}}
+        (m,) = [m for m in maps[name]
+                if m.variant in (None, str(eng.prefill_widths[-1]))]
+        assert heads and heads <= set(m.instructions), name
+        bare = sorted(h for h in heads if not m.instructions[h][0])
+        assert not bare, (name, bare[:10])
 
 
 def test_a_prefill_row_is_registered_at_every_width():
