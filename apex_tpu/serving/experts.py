@@ -17,17 +17,16 @@ models, as one chip of an expert-parallel deployment runs it.
 * A second router, :func:`route_grouped` (DeepSeek-V2): a softmax, the
   experts in groups of which only the best few may be chosen from, no
   renormalisation.  What follows the choice is the same code.
-* NO TOKEN IS DROPPED and every shape is static: the ``T * top_k``
-  pairs are sorted so that those of held experts come first, expert by
-  expert; three :func:`jax.lax.ragged_dot` calls (gate, up, down) run
-  the SwiGLU experts over the sorted rows, group by group; the rows are
-  put back in pair order and summed with their weights.  On TPU
-  ``ragged_dot`` is a grouped matrix product that visits only the row
-  tiles its groups cover, so the work follows the pairs that really
-  landed here (an eighth of them when 32 of 256 experts are held) and
-  each touched expert's weights are read once; the rows after the last
-  group are never computed and are masked.  One path serves a decode
-  step of 64 rows and a prefill chunk of 2,048 (PERF.md, PR 29).
+* NO TOKEN IS DROPPED and every shape is static: the pairs of held
+  experts are laid out expert by expert, each expert's group starting
+  on a whole row tile (:func:`apex_tpu.ops.grouped.group_rows`: the
+  pairs of absent experts and of padding tokens are left out), two
+  Pallas kernels run the SwiGLU experts tile by tile
+  (:func:`apex_tpu.ops.grouped.grouped_swiglu`: no tile holds two
+  experts' rows, each touched expert's weights are read once, the tile
+  is the mean pairs an expert takes), and the rows are put back in pair
+  order and summed with their weights.  One path serves a decode step
+  of 64 rows and a prefill chunk of 2,048.
 """
 
 from __future__ import annotations
@@ -36,6 +35,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+
+from apex_tpu.ops.grouped import group_rows, grouped_swiglu, row_tile
 
 
 def route(u, router, bias, *, top_k: int, route_scale: float,
@@ -81,38 +82,37 @@ def swiglu(u, p):
 
 
 def held_experts(u, experts, weights, p, held: Tuple[int, int],
-                 valid=None):
+                 valid=None, *, tm: int):
     """The routed part of the layer that the experts ``[lo, hi)`` give.
 
     ``u`` ``[T, d]``; ``experts``/``weights`` ``[T, k]`` from
     :func:`route`; ``p`` the held experts' stacked weights (``wg``,
     ``wu`` ``[n, d, f]``, ``wd`` ``[n, f, d]``); ``valid`` ``[T]`` bool,
-    where given: a token that is padding takes no expert.  Returns (y
+    where given: a token that is padding takes no expert; ``tm`` the
+    row tile (:func:`apex_tpu.ops.grouped.row_tile`).  Returns (y
     ``[T, d]``, load ``[n]`` int32: the pairs each held expert took)."""
     lo, hi = held
     n = hi - lo
     T, k = experts.shape
+    if n == 0:
+        return jnp.zeros_like(u), jnp.zeros((0,), jnp.int32)
     local = experts.reshape(-1) - lo
     here = (local >= 0) & (local < n)
     if valid is not None:
         here &= jnp.repeat(valid.reshape(-1), k)
-    # absent experts sort last, as group n
-    key = jnp.where(here, local, n)
-    order = jnp.argsort(key, stable=True)
-    load = jnp.bincount(key, length=n + 1)[:n].astype(jnp.int32)
-    rows = jnp.take(u, order // k, axis=0)
+    # absent experts are group n: they take no row of the layout
+    layout = group_rows(jnp.where(here, local, n), n, tm)
+    rows = jnp.take(u, layout.src // k, axis=0, mode="fill", fill_value=0)
     with jax.named_scope("moe_experts"):
-        h = (jax.nn.silu(jax.lax.ragged_dot(rows, p["wg"], load))
-             * jax.lax.ragged_dot(rows, p["wu"], load))
-        y = jax.lax.ragged_dot(h, p["wd"], load)
-    # rows past the last group were not computed
-    y = jnp.where((jnp.arange(T * k) < jnp.sum(load))[:, None], y, 0)
-    # back to pair order, weighted, summed over a token's k pairs
-    back = jnp.argsort(order)
-    y = jnp.take(y, back, axis=0).reshape(T, k, -1)
+        y = grouped_swiglu(rows, layout.tile_expert, layout.active,
+                           p["wg"], p["wu"], p["wd"], tm=tm)
+    # back to pair order (a pair of no held expert reads 0), weighted,
+    # summed over a token's k pairs
+    y = jnp.take(y, layout.dest, axis=0, mode="fill",
+                 fill_value=0).reshape(T, k, -1)
     w = jnp.where(here.reshape(T, k), weights, 0.0)
     y = jnp.einsum("tkd,tk->td", y.astype(jnp.float32), w)
-    return y.astype(u.dtype), load
+    return y.astype(u.dtype), layout.load
 
 
 def expert_layer(u, p, *, held: Tuple[int, int], top_k: int,
@@ -126,7 +126,8 @@ def expert_layer(u, p, *, held: Tuple[int, int], top_k: int,
     renormalisation's ``route_eps``; ``groups`` ``(n_group,
     topk_group)`` chooses :func:`route_grouped` instead; ``valid``
     (bool, ``u``'s lead shape) marks the tokens that are not padding.
-    Returns (y like ``u``, load ``[n_held]``)."""
+    The row tile is :func:`expert_tile`'s.  Returns (y like ``u``, load
+    ``[n_held]``)."""
     lead = u.shape[:-1]
     u2 = u.reshape(-1, u.shape[-1])
     with jax.named_scope("moe_router"):
@@ -138,8 +139,16 @@ def expert_layer(u, p, *, held: Tuple[int, int], top_k: int,
             experts, weights = route_grouped(
                 u2, p["router"], top_k=top_k, route_scale=route_scale,
                 n_group=groups[0], topk_group=groups[1])
-    y, load = held_experts(u2, experts, weights, p["experts"], held, valid)
+    y, load = held_experts(u2, experts, weights, p["experts"], held, valid,
+                           tm=expert_tile(u, p, top_k))
     if "shared" in p:
         with jax.named_scope("moe_shared"):
             y = y + swiglu(u2, p["shared"])
     return y.reshape(*lead, -1), load
+
+
+def expert_tile(u, p, top_k: int) -> int:
+    """The row tile of :func:`expert_layer` over ``u`` ``[..., d]``: the
+    mean pairs an expert takes, from the static shape (``T * top_k``
+    over the router's ``E``)."""
+    return row_tile(u.size // u.shape[-1] * top_k, p["router"].shape[1])

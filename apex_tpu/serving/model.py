@@ -107,9 +107,10 @@ import numpy as np
 
 from apex_tpu.ops import (flash_attention, flash_decode, flash_decode_latent,
                           latent_walk_tiles)
+from apex_tpu.ops.grouped import rows_tiled
 from apex_tpu.ops.ssm import (causal_conv, short_conv_update, ssd_chunk_scan,
                               ssm_decode_update)
-from apex_tpu.serving.experts import expert_layer, swiglu
+from apex_tpu.serving.experts import expert_layer, expert_tile, swiglu
 from apex_tpu.serving.kv_cache import (latent_width, pad_latent,
                                        quantize_tokens)
 
@@ -413,7 +414,7 @@ class AfmoeBlock:
 
     refuses = ("tp", "kv_quant", "prefix_sharing", "speculation",
                "prefill_only", "kv_import")
-    stat_names = ("moe_pairs_held", "moe_load_max")
+    stat_names = ("moe_pairs_held", "moe_load_max", "moe_rows_tiled")
     #: where the engine's refusal sends the reader
     refuses_doc = "What afmoe refuses"
 
@@ -453,7 +454,8 @@ class AfmoeBlock:
                 u, layer["moe"], held=cfg.experts_held, top_k=cfg.top_k,
                 route_scale=cfg.route_scale, valid=valid)
             if stats is not None:
-                stats.append(load)
+                stats.append(
+                    (load, expert_tile(u, layer["moe"], cfg.top_k)))
         return h + _rms(m, layer["g4"], eps)
 
     def final_norm(self, params, x):
@@ -643,8 +645,8 @@ class DeepseekV2Block:
 
     refuses = ("tp", "kv_quant", "speculation", "prefill_only",
                "kv_import")
-    stat_names = ("moe_pairs_held", "moe_load_max", "latent_blocks_walked",
-                  "latent_blocks_fetched")
+    stat_names = ("moe_pairs_held", "moe_load_max", "moe_rows_tiled",
+                  "latent_blocks_walked", "latent_blocks_fetched")
     refuses_doc = "What DeepSeek-V2 refuses"
 
     def __init__(self, cfg: DeepseekV2Config):
@@ -696,7 +698,8 @@ class DeepseekV2Block:
                 route_scale=cfg.routed_scaling_factor, valid=valid,
                 groups=(cfg.n_group, cfg.topk_group))
             if stats is not None:
-                stats.append(load)
+                stats.append(
+                    (load, expert_tile(u, layer["moe"], cfg.top_k)))
         return h + m
 
     def final_norm(self, params, x):
@@ -1088,7 +1091,8 @@ class Lfm2MoeBlock:
     #: pages a shipped slot: none exists yet
     refuses = ("tp", "kv_quant", "prefix_sharing", "speculation",
                "prefill_only", "kv_import")
-    stat_names = ("moe_pairs_held", "moe_load_max", "moe_experts_touched")
+    stat_names = ("moe_pairs_held", "moe_load_max", "moe_rows_tiled",
+                  "moe_experts_touched")
     #: where the engine's refusal sends the reader
     refuses_doc = "What LFM2 refuses"
 
@@ -1134,7 +1138,8 @@ class Lfm2MoeBlock:
                 route_scale=cfg.route_scale, valid=valid,
                 route_eps=cfg.route_eps)
             if stats is not None:
-                stats.append(load)
+                stats.append(
+                    (load, expert_tile(u, layer["moe"], cfg.top_k)))
         return h + m
 
     def final_norm(self, params, x):
@@ -1283,14 +1288,19 @@ class PagedDecoder:
     def _stats(self, stats, walk=(0, 0)):
         """The block's per-launch counters as one int32 vector, in
         ``stat_names`` order: the (token, expert) pairs its held experts
-        took, summed over layers; the most any one took; lfm2_moe: the
-        held experts that took any, summed over layers; deepseek_v2:
-        ``walk``, the blocks of latent pages a layer's call had to see
-        and those it fetched, 0 where no call walked pages."""
+        took, summed over layers; the most any one took; the rows the
+        grouped product multiplied (each layer's active row tiles times
+        its tile), summed over layers; lfm2_moe: the held experts that
+        took any, summed over layers; deepseek_v2: ``walk``, the blocks
+        of latent pages a layer's call had to see and those it fetched,
+        0 where no call walked pages.  ``stats``: a ``(load, tm)`` a
+        layer."""
         with jax.named_scope("counters"):
-            load = jnp.stack(stats)
+            load = jnp.stack([pairs for pairs, _ in stats])
             named = {"moe_pairs_held": jnp.sum(load),
                      "moe_load_max": jnp.max(load),
+                     "moe_rows_tiled": sum(rows_tiled(pairs, tm)
+                                           for pairs, tm in stats),
                      "moe_experts_touched": jnp.sum(load > 0),
                      "latent_blocks_walked": walk[0],
                      "latent_blocks_fetched": walk[1]}

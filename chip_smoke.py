@@ -23,6 +23,9 @@ work, and it has no CPU mode.  With random weights made from a seed it
   attention layer, published widths): the in-place decode update
   against the chunked scan on the same tokens, a slot's bytes across
   another row's step, a reused slot starting from zero;
+* runs the expert layer's grouped product at the three expert cells'
+  decode and chunk shapes (published widths) on integer-valued operands,
+  bitwise against the three ``ragged_dot`` calls it replaced;
 * with four or more devices, repeats train on a ``(2, 2, 1)`` mesh and
   serve at ``tp=4`` and checks that state is spread over the mesh.
 
@@ -60,6 +63,8 @@ from apex_tpu.ops import (flash_attention_qkv, flash_attention_qkv_route,
 from apex_tpu.serving import (DeepseekV2Config, GraniteHybridConfig,
                               Lfm2MoeConfig, PagedDecoder, ServingEngine,
                               ServingModelConfig, SpecConfig, poisson_trace)
+from apex_tpu.ops.grouped import row_tile
+from apex_tpu.serving.experts import held_experts
 from apex_tpu.serving.model import StateIO
 from apex_tpu.serving.engine import prefill_route
 from apex_tpu.transformer import parallel_state
@@ -90,6 +95,17 @@ SSM_FORMS_TOL = 6e-2
 # convolution and projections against a token's, the same arithmetic in
 # other fusions; taken as the recurrence's until measured on the chip.
 SHORT_CONV_FORMS_TOL = 6e-2
+# The grouped expert product at the expert cells' shapes: name ->
+# (tokens, top_k, router width, experts held, d, f, documents whose rows
+# route alike (0: rows route independently), padding tokens first).
+GROUPED_SHAPES = {
+    "lfm2_decode": (64, 4, 64, (0, 64), 2048, 1536, 0, 0),
+    "lfm2_row": (1024, 4, 64, (0, 64), 2048, 1536, 0, 100),
+    "trinity_decode": (64, 4, 256, (0, 32), 3072, 3072, 0, 0),
+    "trinity_chunk": (2048, 4, 256, (0, 32), 3072, 3072, 0, 300),
+    "dsv2_decode": (64, 6, 160, (0, 20), 5120, 1536, 8, 0),
+    "dsv2_chunk": (512, 6, 160, (0, 20), 5120, 1536, 1, 100),
+}
 # Chips holding equal shards of one program's state.
 BALANCE_FACTOR = 1.5
 
@@ -738,6 +754,118 @@ def leg_short_conv(cfg, *, seed, page_size, row, max_new) -> dict:
             "memory": memory_by_device()}
 
 
+def ragged_held_experts(u, experts, weights, p, held, valid=None):
+    """The expert layer's routed part as three ``jax.lax.ragged_dot``
+    calls over the pairs sorted expert by expert (what
+    ``experts.held_experts`` ran before the grouped kernels)."""
+    lo, hi = held
+    n = hi - lo
+    T, k = experts.shape
+    local = experts.reshape(-1) - lo
+    here = (local >= 0) & (local < n)
+    if valid is not None:
+        here &= jnp.repeat(valid.reshape(-1), k)
+    key = jnp.where(here, local, n)
+    order = jnp.argsort(key, stable=True)
+    load = jnp.bincount(key, length=n + 1)[:n].astype(jnp.int32)
+    rows = jnp.take(u, order // k, axis=0)
+    h = (jax.nn.silu(jax.lax.ragged_dot(rows, p["wg"], load))
+         * jax.lax.ragged_dot(rows, p["wu"], load))
+    y = jax.lax.ragged_dot(h, p["wd"], load)
+    y = jnp.where((jnp.arange(T * k) < jnp.sum(load))[:, None], y, 0)
+    y = jnp.take(y, jnp.argsort(order), axis=0).reshape(T, k, -1)
+    w = jnp.where(here.reshape(T, k), weights, 0.0)
+    y = jnp.einsum("tkd,tk->td", y.astype(jnp.float32), w)
+    return y.astype(u.dtype), load
+
+
+def grouped_operands(*, seed, tokens, top_k, width, held, d, f,
+                     documents=0, padding=0, dtype=jnp.bfloat16):
+    """One expert layer call's operands, integer-valued so that every
+    product and sum of the layer is exact in float32 and a lost,
+    doubled or misrouted row is an exact mismatch: a token row has four
+    ones; gate weights are 0 or 32 (so a gate is 0 or at least 32, where
+    silu is the identity to float32 rounding and ``h`` rounds to the
+    same bf16 either way), up weights -2..2, down weights -1..1, the
+    routing weights 1..3.  The routing: each row's ``top_k`` of
+    ``width`` experts at random, or, with ``documents``, rows of one
+    document (consecutive rows) prefer the same few experts, as rows on
+    one shared document do.  Returns (u, experts, weights, p, valid)."""
+    rng = np.random.default_rng(seed)
+    x = np.zeros((tokens, d), np.float32)
+    for t in range(tokens):
+        x[t, rng.choice(d, 4, replace=False)] = 1.0
+    scores = rng.random((tokens, width))
+    if documents:
+        doc = np.arange(tokens) * documents // tokens
+        scores += 4.0 * rng.random((documents, width))[doc] ** 16
+    experts = np.argsort(-scores, axis=1)[:, :top_k].astype(np.int32)
+    weights = rng.integers(1, 4, (tokens, top_k)).astype(np.float32)
+    n = held[1] - held[0]
+    kg, ku, kd = jax.random.split(jax.random.PRNGKey(seed), 3)
+    make = jax.jit(lambda kg, ku, kd: dict(
+        wg=(32 * jax.random.bernoulli(kg, 0.5, (n, d, f))).astype(dtype),
+        wu=jax.random.randint(ku, (n, d, f), -2, 3).astype(dtype),
+        wd=jax.random.randint(kd, (n, f, d), -1, 2).astype(dtype)))
+    return (jnp.asarray(x, dtype), jnp.asarray(experts),
+            jnp.asarray(weights), make(kg, ku, kd),
+            jnp.asarray(np.arange(tokens) >= padding))
+
+
+def _call_ms(fn, args, reps: int) -> float:
+    """Wall ms a call, ``reps`` calls dispatched back to back."""
+    out = jax.block_until_ready(fn(*args))
+    t = time.perf_counter()
+    for _ in range(reps):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t) / reps * 1e3
+
+
+def leg_grouped(shapes, *, seed, reps=10) -> dict:
+    """The expert layer's routed part (``experts.held_experts``: the
+    padded layout and the two grouped kernels) against the three
+    ``ragged_dot`` calls it replaced, at each of ``shapes``
+    (``GROUPED_SHAPES``' form), on integer-valued operands: equal bit
+    for bit, output and load.  Times (wall ms a call, the layout and
+    the unsort included) and the touched experts' bytes a second are
+    information."""
+    out = {}
+    for name, (tokens, top_k, width, held, d, f, documents,
+               padding) in shapes.items():
+        u, experts, weights, p, valid = grouped_operands(
+            seed=seed, tokens=tokens, top_k=top_k, width=width, held=held,
+            d=d, f=f, documents=documents, padding=padding)
+        tm = row_tile(tokens * top_k, width)
+        paths = {"grouped": jax.jit(
+                     lambda u, e, w, p, v: held_experts(u, e, w, p, held, v,
+                                                        tm=tm)),
+                 "ragged_dot": jax.jit(
+                     lambda u, e, w, p, v: ragged_held_experts(u, e, w, p,
+                                                               held, v))}
+        args = (u, experts, weights, p, valid)
+        (y, load), (y_r, load_r) = (fn(*args) for fn in paths.values())
+        require(np.array_equal(np.asarray(load), np.asarray(load_r)),
+                f"grouped {name}: the loads differ")
+        bad = int(np.sum(np.asarray(y, np.float32)
+                         != np.asarray(y_r, np.float32)))
+        require(bad == 0, f"grouped {name}: {bad} numbers differ from "
+                "ragged_dot's")
+        load = np.asarray(load)
+        touched = int(np.sum(load > 0))
+        ms = {path: _call_ms(fn, args, reps) for path, fn in paths.items()}
+        weight_bytes = touched * 3 * d * f * p["wg"].dtype.itemsize
+        out[name] = {"tm": tm, "pairs_held": int(load.sum()),
+                     "touched": touched,
+                     "tiles": int(np.sum(-(-load // tm))),
+                     "ms": ms,
+                     "GBps": {path: weight_bytes / t / 1e6
+                              for path, t in ms.items()}}
+        del p, paths
+        free_device_memory()
+    return out
+
+
 def _lfm2_config() -> Lfm2MoeConfig:
     """LFM2-24B-A2B's published widths: the two dense layers and one
     period (attention, conv, conv, conv), an eighth of the experts and
@@ -862,6 +990,8 @@ def main() -> int:
         _lfm2_config(), seed=5, page_size=64, row=1024, max_new=8))
     require(short["route"] == ROUTES_ON_TPU["decode"],
             f"short conv: the paged route is {short['route']}")
+
+    _report("grouped", leg_grouped(GROUPED_SHAPES, seed=6))
 
     if len(devices) >= 4:
         _report("train_mesh", leg_train(

@@ -11,6 +11,7 @@ import os
 import subprocess
 import sys
 
+import jax.numpy as jnp
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -144,6 +145,44 @@ def test_short_conv_leg():
     assert out["route"] == "xla" and out["tail_pool"] == [3, 4, 2, 64]
     assert max(out["rel_l2"].values()) < 1e-4
     json.dumps(out)
+
+
+# the grouped leg's shapes at toy widths: decode (some experts held), a
+# chunk with padding tokens whose rows route alike in four documents
+TOY_GROUPED = {"decode": (16, 4, 16, (4, 12), 256, 128, 0, 0),
+               "chunk": (64, 3, 16, (0, 16), 128, 256, 4, 9)}
+
+
+def test_grouped_leg():
+    out = chip_smoke.leg_grouped(TOY_GROUPED, seed=6, reps=1)
+    assert set(out) == set(TOY_GROUPED)
+    for leg in out.values():
+        assert leg["tm"] == 16 and leg["pairs_held"] > 0
+        assert 0 < leg["touched"] <= leg["tiles"]
+        assert set(leg["ms"]) == set(leg["GBps"]) == {"grouped",
+                                                      "ragged_dot"}
+    # the chip's shapes are the cells' (published widths)
+    assert {name: shape[:4] for name, shape in
+            chip_smoke.GROUPED_SHAPES.items()} == {
+        "lfm2_decode": (64, 4, 64, (0, 64)),
+        "lfm2_row": (1024, 4, 64, (0, 64)),
+        "trinity_decode": (64, 4, 256, (0, 32)),
+        "trinity_chunk": (2048, 4, 256, (0, 32)),
+        "dsv2_decode": (64, 6, 160, (0, 20)),
+        "dsv2_chunk": (512, 6, 160, (0, 20))}
+    json.dumps(out)
+
+
+def test_grouped_leg_fails_on_one_misplaced_row(monkeypatch):
+    def swapped(*args, **kw):
+        y, load = held_experts(*args, **kw)
+        return y.at[jnp.array([0, 1])].set(y[jnp.array([1, 0])]), load
+
+    held_experts = chip_smoke.held_experts
+    monkeypatch.setattr(chip_smoke, "held_experts", swapped)
+    with pytest.raises(chip_smoke.SmokeFailure, match="differ"):
+        chip_smoke.leg_grouped({"decode": TOY_GROUPED["decode"]}, seed=6,
+                               reps=1)
 
 
 def test_warm_leg():

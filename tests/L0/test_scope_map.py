@@ -440,6 +440,44 @@ def test_the_lfm2_blocks_three_executables_leave_nothing_under_no_scope():
         assert not bare, (name, bare[:10])
 
 
+@pytest.mark.parametrize("block", ["afmoe", "deepseek_v2", "lfm2_moe"])
+def test_the_grouped_kernels_sit_under_moe_experts(block):
+    """In each executable of every expert block the grouped
+    product's two kernels are under ``moe_experts`` with their own names
+    last, and what is under no scope is an op the step's own code made
+    outside every layer (its ``op_name`` the bare jit's), never one the
+    compiler made without a name inside the expert layer."""
+    make, kw, _ = BLOCKS[block]
+    cfg = make()
+    eng = ServingEngine(cfg, cfg.init_params(3), num_pages=40, page_size=8,
+                        max_batch=3, clock=SimClock(), **kw)
+    eng._register_scopes()
+    names = ["jit__decode", "jit__prefill", "jit__chunk"]
+    maps = scopes.scope_maps(names)
+    structs = eng._executable_arg_structs()
+    for name, key in zip(names, ("decode", "prefill", "chunk")):
+        (m,) = [m for m in maps[name]
+                if m.variant in (None, str(eng.prefill_widths[-1]))]
+        kernels = {scope for scope, _ in m.instructions.values()
+                   if "grouped_swiglu" in scope}
+        assert kernels == {"layer/moe_experts/grouped_swiglu_gate_up",
+                           "layer/moe_experts/grouped_swiglu_down"}, name
+        text = jax.jit(eng._exec_defs[key][0]).trace(
+            *structs[key]).lower().compile().as_text()
+        # what runs: the entry and the bodies of its loops; parameters,
+        # constants and tuples are no work
+        comps = scopes.computations(text)
+        run = ({re.search(r"^ENTRY %?(\S+)", text, re.M).group(1)}
+               | set(re.findall(r"(?:body|condition)=%?([\w.\-]+)", text)))
+        bare = [head for comp in run
+                for head, _, opcode, line in scopes._instructions(comps[comp])
+                if opcode not in {"parameter", "constant", "tuple",
+                                  "get-tuple-element", "bitcast"}
+                and not m.instructions[head][0]
+                and not re.search(r'op_name="jit\(\w+\)/[\w.]+"', line)]
+        assert not bare, (name, bare[:5])
+
+
 def test_a_prefill_row_is_registered_at_every_width():
     cfg = ServingModelConfig(vocab_size=64, hidden_size=32, num_heads=4,
                              num_layers=1, max_position=256)

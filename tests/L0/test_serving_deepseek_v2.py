@@ -553,4 +553,9 @@ def test_the_ring_and_the_executables_name_what_the_model_adds(params):
         structs = fresh._executable_arg_structs()[name]
         with routing_override(decode="decode"):
             walk(jax.make_jaxpr(fresh._exec_defs[name][0])(*structs).jaxpr)
-    assert names == ["flash_decode_latent"] * 6     # three layers, twice
+    # the two expert layers' grouped products, gate and up in one call,
+    # twice
+    grouped = [n for n in names if n.startswith("grouped_swiglu")]
+    assert grouped == ["grouped_swiglu_gate_up", "grouped_swiglu_down"] * 4
+    assert [n for n in names if n not in grouped] == [
+        "flash_decode_latent"] * 6                  # three layers, twice

@@ -163,7 +163,7 @@ def test_prefill_then_decode_agrees_with_the_reference_everywhere(
             params, row(seq[:C]), row(np.ones(C)), row(np.arange(C)))
         assert k.shape == (2, 1, S, 2, 128)
         assert tail.shape == (4, 1) + CFG.tail_shape
-        assert stats.shape == (3,)
+        assert stats.shape == (4,)       # pairs, max, rows tiled, touched
         np.testing.assert_allclose(np.asarray(logits[0, :C]), want[:C],
                                    atol=TOL)
         pages = cache.allocate(cache.pages_needed(len(seq)), 0)

@@ -15,6 +15,7 @@ chip (``chip_smoke.py``).
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +27,8 @@ from apex_tpu.ops import (flash_attention, flash_attention_qkv,
                           flash_decode_latent_route, flash_decode_route,
                           latent_walk_tiles, layer_norm, routing_override,
                           ssm_decode_route, ssm_decode_update)
+from apex_tpu.ops.grouped import row_tile
+from apex_tpu.serving.experts import held_experts
 
 SDS = jax.ShapeDtypeStruct
 BF16 = jnp.bfloat16
@@ -207,6 +210,35 @@ def test_state_update_lowers_at_the_cells_geometry(layer):
     assert calls[0].count("tensor<36x66x128x4096xf32>") == 2, calls[0][-600:]
     assert calls[0].count("tensor<36x66x1x13056xbf16>") == 2
     assert "output_operand_aliases" in calls[0]
+
+
+# the three expert cells' grouped product (benchmark/configs): tokens,
+# top_k, router width, experts held, d, f, and the row tile it takes
+@pytest.mark.parametrize("cell", [
+    ("lfm2 decode", 64, 4, 64, 64, 2048, 1536, 16),
+    ("lfm2 row", 1024, 4, 64, 64, 2048, 1536, 64),
+    ("trinity decode", 64, 4, 256, 32, 3072, 3072, 16),
+    ("trinity chunk", 2048, 4, 256, 32, 3072, 3072, 32),
+    ("dsv2 decode", 64, 6, 160, 20, 5120, 1536, 16),
+    ("dsv2 chunk", 512, 6, 160, 20, 5120, 1536, 32)], ids=lambda c: c[0])
+def test_grouped_experts_lower_at_the_cells_geometries(cell):
+    _, tokens, k, width, n, d, f, tm = cell
+    assert row_tile(tokens * k, width) == tm
+
+    def fn(u, experts, weights, wg, wu, wd):
+        return held_experts(u, experts, weights,
+                            {"wg": wg, "wu": wu, "wd": wd}, (0, n), tm=tm)
+
+    calls = [line for line in _tpu_text(
+        fn, SDS((tokens, d), BF16), SDS((tokens, k), jnp.int32),
+        SDS((tokens, k), jnp.float32), SDS((n, d, f), BF16),
+        SDS((n, d, f), BF16), SDS((n, f, d), BF16)).splitlines()
+        if "tpu_custom_call" in line]
+    names = [re.search(r'kernel_name = "(\w+)"', c).group(1) for c in calls]
+    assert names == ["grouped_swiglu_gate_up", "grouped_swiglu_down"]
+    # the weights go in whole: a kernel fetches only the blocks it names
+    assert calls[0].count(f"tensor<{n}x{d}x{f}xbf16>") == 2
+    assert calls[1].count(f"tensor<{n}x{f}x{d}xbf16>") == 1
 
 
 def test_a_prefill_row_of_heads_of_64_lowers_on_the_varlen_route():
