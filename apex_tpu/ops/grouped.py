@@ -1,9 +1,10 @@
-"""The grouped SwiGLU product of a dropless expert layer.
+"""The grouped expert product of a dropless expert layer.
 
 For every (token, expert) pair that landed on a held expert ``e``, the
 row ``x`` of its token goes through that expert's SwiGLU,
-``(silu(x Wg_e) * (x Wu_e)) Wd_e``.  Two ``pallas_call``s do it over a
-PADDED PER-EXPERT LAYOUT of the pairs:
+``(silu(x Wg_e) * (x Wu_e)) Wd_e``, or, for experts that are not gated
+(Nemotron-H), its squared ReLU, ``relu(x Wu_e)^2 Wd_e``.  Two
+``pallas_call``s do it over a PADDED PER-EXPERT LAYOUT of the pairs:
 
 * **Row tiles fitted to the groups.**  The pairs are laid out expert by
   expert, and every expert's group starts on a multiple of the row tile
@@ -31,16 +32,18 @@ PADDED PER-EXPERT LAYOUT of the pairs:
   prefetched too) name the last active tile's blocks, so they cost no
   DMA, and ``pl.when`` skips their multiply.
 * **Numerics**: both products accumulate in float32; ``h = silu(gate) *
-  up`` is computed in float32 from the float32 accumulators and rounded
-  once to the activations' type before the down product, whose result
-  is rounded to it too.
+  up`` (or ``relu(up)^2``) is computed in float32 from the float32
+  accumulators and rounded once to the activations' type before the
+  down product, whose result is rounded to it too.
 
 :func:`group_rows` makes the layout (outside any kernel: a sort,
 scatters of indices, cumulative sums); :func:`grouped_swiglu` runs the two kernels,
 ``grouped_swiglu_gate_up`` (gate and up read the rows once, in one
-kernel) and ``grouped_swiglu_down``.  The weights keep the stacked
-layout of the model (``wg``, ``wu`` ``[n, d, f]``, ``wd`` ``[n, f,
-d]``).  Off a TPU the kernels run in Pallas' interpret mode.
+kernel) and ``grouped_swiglu_down``; :func:`grouped_relu2` runs
+``grouped_relu2_up`` and the same down kernel.  The weights keep the
+stacked layout of the model (``wg``, ``wu`` ``[n, d, f]``, ``wd`` ``[n,
+f, d]``; ``d`` the width the rows come in at, a latent one where the
+layer has one).  Off a TPU the kernels run in Pallas' interpret mode.
 """
 
 from __future__ import annotations
@@ -148,6 +151,16 @@ def _gate_up_kernel(tile_expert_ref, active_ref, x_ref, wg_ref, wu_ref,
         h_ref[...] = (gate / (1.0 + jnp.exp(-gate)) * up).astype(h_ref.dtype)
 
 
+def _relu2_up_kernel(tile_expert_ref, active_ref, x_ref, wu_ref, h_ref):
+    del tile_expert_ref
+
+    @pl.when(pl.program_id(1) < active_ref[0])
+    def _():
+        up = jnp.maximum(jnp.dot(x_ref[...], wu_ref[...],
+                                 preferred_element_type=jnp.float32), 0.0)
+        h_ref[...] = (up * up).astype(h_ref.dtype)
+
+
 def _down_kernel(tile_expert_ref, active_ref, h_ref, wd_ref, y_ref):
     del tile_expert_ref
 
@@ -208,5 +221,21 @@ def _grouped_swiglu(rows, tile_expert, active, wg, wu, wd, *, tm,
                     interpret):
     h = _grouped_call(_gate_up_kernel, "grouped_swiglu_gate_up", rows, wg,
                       tm, interpret)(tile_expert, active, rows, wg, wu)
+    return _grouped_call(_down_kernel, "grouped_swiglu_down", h, wd, tm,
+                         interpret)(tile_expert, active, h, wd)
+
+
+def grouped_relu2(rows, tile_expert, active, wu, wd, *, tm: int):
+    """As :func:`grouped_swiglu`, each active tile through its expert's
+    squared ReLU ``relu(x Wu)^2 Wd``: ``grouped_relu2_up``, then the
+    down kernel the SwiGLU experts use."""
+    return _grouped_relu2(rows, tile_expert, active, wu, wd, tm=tm,
+                          interpret=use_interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
+def _grouped_relu2(rows, tile_expert, active, wu, wd, *, tm, interpret):
+    h = _grouped_call(_relu2_up_kernel, "grouped_relu2_up", rows, wu, tm,
+                      interpret)(tile_expert, active, rows, wu)
     return _grouped_call(_down_kernel, "grouped_swiglu_down", h, wd, tm,
                          interpret)(tile_expert, active, h, wd)

@@ -8,11 +8,13 @@ instead of a cache that grows: per head ``h`` of ``P`` channels a matrix
     ``y_t^h = S_t^h C_t + D_h x_t^h``
 
 with ``delta_t = softplus(dt_t + dt_bias)`` a scalar a head, ``A_h =
--exp(A_log_h)``, and ``B_t``, ``C_t`` (``[N]``) shared by all heads (one
-group).  ``x``, ``B`` and ``C`` come out of a depthwise causal
-convolution of ``d_conv`` taps over the projection's ``xBC`` channels,
-so a request also keeps the last ``d_conv - 1`` rows of ``xBC``: its
-TAIL.
+-exp(A_log_h)``, and ``B_t``, ``C_t`` (``[N]``) those of the head's
+GROUP: the heads lie in ``G`` equal groups in order, and head ``h``
+reads group ``h // (H / G)`` (one group, Granite 4.0-H; eight,
+Nemotron-H).  ``x``, ``B`` and ``C`` come out of a depthwise causal
+convolution of ``d_conv`` taps over the projection's ``xBC`` channels
+(``x``, then every group's ``B``, then every group's ``C``), so a
+request also keeps the last ``d_conv - 1`` rows of ``xBC``: its TAIL.
 
 **The state's layout.**  A request's state of one layer is one
 ``[N, H * P]`` float32 matrix, state index major: the lanes are (head,
@@ -76,19 +78,20 @@ _UPDATE_STATE_BYTES = 4 * 2 ** 20
 _UPDATE_VMEM_LIMIT = 48 * 2 ** 20
 
 
-def ssm_decode_route(state_pool) -> str:
+def ssm_decode_route(state_pool, groups: int = 1) -> str:
     """``"decode"`` (the Pallas kernel) or ``"xla"`` for a state pool
-    ``[L, n_slots, N, H * P]``: as :func:`~apex_tpu.ops.
-    flash_decode_route`, auto-routing picks the kernel only on a TPU and
-    where ``N`` is a whole lane tile and the state's lanes are whole
-    tiles, and ``routing_override(decode=...)`` forces either (a forced
-    kernel runs in interpret mode off the TPU, at any ``N`` of whole
-    sublane tiles)."""
+    ``[L, n_slots, N, H * P]`` of ``groups`` B/C groups: as
+    :func:`~apex_tpu.ops.flash_decode_route`, auto-routing picks the
+    kernel only on a TPU and where ``N`` is a whole lane tile and a
+    group's lanes are whole tiles, and ``routing_override(decode=...)``
+    forces either (a forced kernel runs in interpret mode off the TPU,
+    at any ``N`` of whole sublane tiles)."""
     forced = _ROUTE_OVERRIDE["decode"]
     if forced == "xla":
         return "xla"
     n, lanes = state_pool.shape[-2:]
-    if n % 8 or lanes % LANE or 4 * n * lanes > _UPDATE_STATE_BYTES:
+    if (n % 8 or lanes % (groups * LANE)
+            or 4 * n * lanes // groups > _UPDATE_STATE_BYTES):
         return "xla"
     if forced is None and (jax.default_backend() != "tpu" or n % LANE):
         return "xla"
@@ -138,29 +141,42 @@ def _make_update_kernel(n: int, lanes: int, ch: int, taps: int, act):
 
 
 def _state_update_pallas(state_pool, conv_pool, slots, layer, decay, delta,
-                         xbc, conv_w, conv_b, d_rep, act):
+                         xbc, conv_w, conv_b, d_rep, act, groups):
     """decay, delta ``[rows, H * P]`` f32 (a head's scalar on each of
     its lanes), xbc ``[rows, ch]`` (the tail's type), conv_w ``[taps,
     ch]``, conv_b ``[1, ch]``, d_rep ``[1, H * P]`` f32 -> (y ``[rows,
     H * P]`` f32, both pools with each row's slot advanced).  The pools
-    are operands and results of one call."""
+    are operands and results of one call; channels and tail are group
+    major.  The grid is ``(rows,)`` at one group and ``(rows, groups)``
+    past it, a group one block of every operand."""
     rows, lanes = decay.shape
     n = state_pool.shape[2]
     taps, ch = conv_w.shape
-    row = lambda width: pl.BlockSpec((1, 1, width), lambda i, *_: (i, 0, 0))
-    whole = lambda a: pl.BlockSpec(a.shape, lambda i, *_: (0,) * a.ndim)
+    lg, cg = lanes // groups, ch // groups
+    grid = (rows,) if groups == 1 else (rows, groups)
+
+    def at(index):
+        """An index map from (row, group) and the prefetched tables."""
+        def index_map(i, *rest):
+            g = 0 if groups == 1 else rest[0]
+            return index(i, g, *rest[-2:])
+        return index_map
+
+    row = lambda width: pl.BlockSpec((1, 1, width),
+                                     at(lambda i, g, *_: (i, 0, g)))
+    group = lambda a: pl.BlockSpec((a.shape[0], a.shape[1] // groups),
+                                   at(lambda i, g, *_: (0, g)))
     at_slot = lambda a: pl.BlockSpec(
-        (1, 1) + a.shape[2:],
-        lambda i, slots, layer: (layer[0], slots[i], 0, 0))
+        (1, 1, a.shape[2], a.shape[3] // groups),
+        at(lambda i, g, slots, layer: (layer[0], slots[i], 0, g)))
     y, state_pool, conv_pool = pl.pallas_call(
-        _make_update_kernel(n, lanes, ch, taps, act),
+        _make_update_kernel(n, lg, cg, taps, act),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(rows,),
-            in_specs=[row(lanes), row(lanes), row(ch), whole(conv_w),
-                      whole(conv_b), whole(d_rep), at_slot(state_pool),
+            num_scalar_prefetch=2, grid=grid,
+            in_specs=[row(lg), row(lg), row(cg), group(conv_w),
+                      group(conv_b), group(d_rep), at_slot(state_pool),
                       at_slot(conv_pool)],
-            out_specs=[row(lanes), at_slot(state_pool),
-                       at_slot(conv_pool)]),
+            out_specs=[row(lg), at_slot(state_pool), at_slot(conv_pool)]),
         out_shape=[jax.ShapeDtypeStruct((rows, 1, lanes), jnp.float32),
                    jax.ShapeDtypeStruct(state_pool.shape, state_pool.dtype),
                    jax.ShapeDtypeStruct(conv_pool.shape, conv_pool.dtype)],
@@ -169,7 +185,7 @@ def _state_update_pallas(state_pool, conv_pool, slots, layer, decay, delta,
         input_output_aliases={8: 1, 9: 2},
         name="ssm_decode_update",
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
+            dimension_semantics=("arbitrary",) * len(grid),
             vmem_limit_bytes=_UPDATE_VMEM_LIMIT),
         interpret=use_interpret(),
     )(slots, jnp.full((1,), layer, jnp.int32), decay[:, None, :],
@@ -179,27 +195,78 @@ def _state_update_pallas(state_pool, conv_pool, slots, layer, decay, delta,
 
 
 def _state_update_xla(state_pool, conv_pool, slots, layer, decay, delta,
-                      xbc, conv_w, conv_b, d_rep, act):
+                      xbc, conv_w, conv_b, d_rep, act, groups):
     """The generic baseline, the same mathematics: the rows' states and
     tails gathered, advanced and scattered back (rows that name one
     slot, the idle ones, write it in an unspecified order: nobody reads
-    the scratch slot)."""
+    the scratch slot).  Channels and tail group major, as the kernel
+    takes them; each group is the single-group update of its lanes."""
     rows, lanes = decay.shape
     n = state_pool.shape[2]
     taps, ch = conv_w.shape
-    tail = conv_pool[layer, slots]                       # [rows, 1, W]
-    seq = jnp.concatenate([tail[:, 0], xbc], axis=-1).astype(jnp.float32)
-    acc = conv_b + sum(conv_w[j] * seq[:, j * ch:(j + 1) * ch]
-                       for j in range(taps))
-    conv = _silu(acc).astype(act).astype(jnp.float32)
-    x, b, c = (conv[:, :lanes], conv[:, lanes:lanes + n],
-               conv[:, lanes + n:])
-    s = state_pool[layer, slots].astype(jnp.float32)     # [rows, N, H * P]
-    s = decay[:, None, :] * s + b[:, :, None] * (delta * x)[:, None, :]
-    y = jnp.sum(s * c[:, :, None], axis=1) + d_rep * x
-    tail = jnp.concatenate([tail[..., ch:], xbc[:, None, :]], -1)
-    return (y, state_pool.at[layer, slots].set(s.astype(state_pool.dtype)),
-            conv_pool.at[layer, slots].set(tail))
+    lg, cg = lanes // groups, ch // groups
+    tails = conv_pool[layer, slots][:, 0]               # [rows, W]
+    states = state_pool[layer, slots].astype(jnp.float32)
+    ys, news, new_tails = [], [], []
+    for g in range(groups):
+        lane, chan = slice(g * lg, (g + 1) * lg), slice(g * cg, (g + 1) * cg)
+        tail = tails[:, (taps - 1) * cg * g:(taps - 1) * cg * (g + 1)]
+        seq = jnp.concatenate([tail, xbc[:, chan]], -1).astype(jnp.float32)
+        acc = conv_b[:, chan] + sum(
+            conv_w[j, chan] * seq[:, j * cg:(j + 1) * cg] for j in range(taps))
+        conv = _silu(acc).astype(act).astype(jnp.float32)
+        x, b, c = conv[:, :lg], conv[:, lg:lg + n], conv[:, lg + n:]
+        s = decay[:, None, lane] * states[..., lane] \
+            + b[:, :, None] * (delta[:, lane] * x)[:, None, :]
+        ys.append(jnp.sum(s * c[:, :, None], axis=1) + d_rep[:, lane] * x)
+        news.append(s)
+        new_tails.append(jnp.concatenate([tail[:, cg:], xbc[:, chan]], -1))
+    cat = lambda parts: parts[0] if groups == 1 else jnp.concatenate(parts,
+                                                                     -1)
+    return (cat(ys),
+            state_pool.at[layer, slots].set(
+                cat(news).astype(state_pool.dtype)),
+            conv_pool.at[layer, slots].set(cat(new_tails)[:, None]))
+
+
+def group_major(v, lanes: int, n: int, groups: int):
+    """``[..., H P + 2 G N]`` in the projection's order (``x``, every
+    group's ``B``, every group's ``C``) -> the same channels group by
+    group (``x_g``, ``B_g``, ``C_g`` for each ``g``): the order the
+    decode update and the tail in its pool take.  One group: ``v``."""
+    if groups == 1:
+        return v
+    lead = v.shape[:-1]
+    part = lambda a, w: a.reshape(*lead, groups, w)
+    bc = groups * n
+    return jnp.concatenate(
+        [part(v[..., :lanes], lanes // groups),
+         part(v[..., lanes:lanes + bc], n), part(v[..., lanes + bc:], n)],
+        -1).reshape(v.shape)
+
+
+def tail_to_pool(tail, lanes: int, n: int, groups: int):
+    """A tail's rows ``[taps - 1, ch]`` (the projection's order) -> the
+    one vector its pool holds, ``[1, (taps - 1) * ch]``: group by group,
+    each group's rows end to end (one group: the rows end to end)."""
+    if groups == 1:
+        return tail.reshape(1, -1)
+    rows = tail.shape[0]
+    t = group_major(tail, lanes, n, groups).reshape(rows, groups, -1)
+    return jnp.swapaxes(t, 0, 1).reshape(1, -1)
+
+
+def tail_from_pool(tail, lanes: int, n: int, groups: int, taps: int):
+    """The inverse of :func:`tail_to_pool`: ``[1, (taps - 1) * ch]`` ->
+    the rows ``[taps - 1, ch]`` in the projection's order."""
+    if groups == 1:
+        return tail.reshape(taps - 1, -1)
+    t = jnp.swapaxes(tail.reshape(groups, taps - 1, -1), 0, 1)
+    lg = lanes // groups
+    return jnp.concatenate(
+        [t[..., :lg].reshape(taps - 1, lanes),
+         t[..., lg:lg + n].reshape(taps - 1, -1),
+         t[..., lg + n:].reshape(taps - 1, -1)], -1)
 
 
 def _conv_taps(seq, w, bias, length: int, act):
@@ -215,13 +282,15 @@ def _conv_taps(seq, w, bias, length: int, act):
 
 
 def ssm_decode_update(state_pool, conv_pool, slots, xbc, dt, *, layer: int,
-                      conv_w, conv_b, dt_bias, a_log, d_skip, heads: int):
+                      conv_w, conv_b, dt_bias, a_log, d_skip, heads: int,
+                      groups: int = 1):
     """One token a row through a state-space layer's convolution and
     recurrence, on the pools, in place.
 
     ``state_pool`` ``[L, n_slots, N, H * P]`` (float32 as served),
     ``conv_pool`` ``[L, n_slots, 1, (taps - 1) * ch]`` with ``ch = H *
-    P + 2 N`` (a tail's rows end to end); ``slots`` ``[rows]`` int32,
+    P + 2 G N`` (a tail's rows end to end, group by group:
+    :func:`tail_to_pool`); ``slots`` ``[rows]`` int32,
     each row's slot (0, the scratch slot, for an idle row; two real
     rows never name one slot); ``xbc`` ``[rows, ch]`` and ``dt``
     ``[rows, H]``, the projection's outputs for the rows' tokens;
@@ -244,14 +313,16 @@ def ssm_decode_update(state_pool, conv_pool, slots, xbc, dt, *, layer: int,
         delta = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
         decay = jnp.exp(-jnp.exp(a_log.astype(f32)) * delta)  # [rows, H]
         per_head = lambda v: jnp.repeat(v, lanes // heads, axis=-1)
+        grouped = lambda v: group_major(v, lanes, state_pool.shape[2],
+                                        groups)
         update = (_state_update_pallas
-                  if ssm_decode_route(state_pool) == "decode"
+                  if ssm_decode_route(state_pool, groups) == "decode"
                   else _state_update_xla)
         return update(
             state_pool, conv_pool, slots, layer, per_head(decay),
-            per_head(delta), xbc.astype(conv_pool.dtype),
-            conv_w.astype(f32), conv_b.astype(f32)[None],
-            per_head(d_skip.astype(f32))[None], xbc.dtype)
+            per_head(delta), grouped(xbc.astype(conv_pool.dtype)),
+            grouped(conv_w.astype(f32)), grouped(conv_b.astype(f32)[None]),
+            per_head(d_skip.astype(f32))[None], xbc.dtype, groups)
 
 
 def causal_conv(xbc, tail, valid, conv_w, conv_b=None, act=_silu):
@@ -313,8 +384,9 @@ def ssd_chunk_scan(x, dt, a_log, b, c, d_skip, state_in, valid, *,
     """The chunked (SSD) scan of one row from an initial state.
 
     ``x`` ``[S, H, P]``, ``dt`` ``[S, H]`` (before bias and softplus),
-    ``b``, ``c`` ``[S, N]``, ``a_log``, ``d_skip``, ``dt_bias`` ``[H]``,
-    ``state_in`` ``[N, H * P]`` float32 (this module's layout),
+    ``b``, ``c`` ``[S, N]`` (one group) or ``[S, G, N]`` (head ``h``
+    reads group ``h // (H / G)``), ``a_log``, ``d_skip``, ``dt_bias``
+    ``[H]``, ``state_in`` ``[N, H * P]`` float32 (this module's layout),
     ``valid`` ``[S]`` bool: a padded position has ``delta = 0``.  ``S``
     must be whole blocks of ``chunk`` (the engine's rows are) or at most
     one block.
@@ -322,14 +394,20 @@ def ssd_chunk_scan(x, dt, a_log, b, c, d_skip, state_in, valid, *,
     Over a block with ``a_t = delta_t A`` and ``L[i, j] = exp(sum_{j < k
     <= i} a_k)`` (``j <= i``, else 0): ``Y = (L * (C B^T)) (delta X) +
     (exp(cumsum a) * C) S_in^T + D X`` and ``S_out = exp(sum a) S_in +
-    sum_j exp(sum_{k > j} a_k) delta_j x_j (outer) B_j``.  The decays
-    and the state are float32; the matrix products take the operands in
-    ``x``'s type and accumulate in float32.
+    sum_j exp(sum_{k > j} a_k) delta_j x_j (outer) B_j``, each head with
+    its group's ``B`` and ``C``.  The decays and the state are float32;
+    the matrix products take the operands in ``x``'s type and accumulate
+    in float32.
 
     Returns (``y`` ``[S, H * P]`` float32, ``state_out`` ``[N, H * P]``
     float32)."""
     s, h, p = x.shape
     n = b.shape[-1]
+    if b.ndim == 3 and b.shape[1] == 1:
+        # one group: the single-group program, bit for bit
+        b, c = b[:, 0], c[:, 0]
+    grouped = b.ndim == 3
+    g = b.shape[1] if grouped else 1
     q = min(chunk, s)
     if s % q:
         raise ValueError(f"a row of {s} positions is not whole blocks "
@@ -346,19 +424,27 @@ def ssd_chunk_scan(x, dt, a_log, b, c, d_skip, state_in, valid, *,
         acum = jnp.cumsum(a, axis=1)                       # [nc, q, h]
         xf = x.astype(f32)
         dx = (delta[..., None] * xf).reshape(nc, q, h, p)
-        bq, cq = b.reshape(nc, q, n), c.reshape(nc, q, n)
         # inside a block: the quadratic form
         tri = jnp.tril(jnp.ones((q, q), bool))
         seg = acum[:, :, None, :] - acum[:, None, :, :]    # [nc, i, j, h]
         decay = jnp.where(tri[None, :, :, None], jnp.exp(
             jnp.where(tri[None, :, :, None], seg, 0.0)), 0.0)
-        g = dot("cin,cjn->cij", cq, bq)                    # C B^T
-        m = (decay * g[..., None]).astype(op)              # [nc, i, j, h]
-        y = dot("cijh,cjhp->cihp", m, dx.astype(op))
         # each block's own contribution to the state at its end
         to_end = jnp.exp(acum[:, -1:, :] - acum)           # [nc, q, h]
-        own = dot("cjn,cjhp->cnhp", bq,
-                  (to_end[..., None] * dx).astype(op))     # [nc, n, h, p]
+        if grouped:
+            bq, cq = b.reshape(nc, q, g, n), c.reshape(nc, q, g, n)
+            cb = jnp.repeat(dot("cign,cjgn->cijg", cq, bq), h // g, axis=-1)
+            heads = lambda a: a.reshape(*a.shape[:-2], g, h // g, p)
+            own = dot("cjgn,cjgkp->cngkp", bq,
+                      heads((to_end[..., None] * dx).astype(op))
+                      ).reshape(nc, n, h, p)
+        else:
+            bq, cq = b.reshape(nc, q, n), c.reshape(nc, q, n)
+            cb = dot("cin,cjn->cij", cq, bq)[..., None]    # C B^T
+            own = dot("cjn,cjhp->cnhp", bq,
+                      (to_end[..., None] * dx).astype(op))  # [nc, n, h, p]
+        m = (decay * cb).astype(op)                        # [nc, i, j, h]
+        y = dot("cijh,cjhp->cihp", m, dx.astype(op))
         # between blocks: the state, block by block
         total = jnp.exp(acum[:, -1, :])                    # [nc, h]
         state = state_in.astype(f32).reshape(n, h, p)
@@ -367,7 +453,12 @@ def ssd_chunk_scan(x, dt, a_log, b, c, d_skip, state_in, valid, *,
             starts.append(state)
             state = total[ci][None, :, None] * state + own[ci]
         start = jnp.stack(starts)                          # [nc, n, h, p]
-        y = y + jnp.exp(acum)[..., None] * dot(
-            "cin,cnhp->cihp", cq, start.astype(op))
+        if grouped:
+            read = dot("cign,cngkp->cigkp", cq,
+                       start.reshape(nc, n, g, h // g, p).astype(op)
+                       ).reshape(nc, q, h, p)
+        else:
+            read = dot("cin,cnhp->cihp", cq, start.astype(op))
+        y = y + jnp.exp(acum)[..., None] * read
         y = y + d_skip.astype(f32)[:, None] * xf.reshape(nc, q, h, p)
     return y.reshape(s, h * p), state.reshape(n, h * p)

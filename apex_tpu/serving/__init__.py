@@ -41,6 +41,11 @@ Three composable layers, bottom-up:
   (gated short-convolution layers, whose slot holds a convolution tail
   alone, beside QK-normed grouped-query layers; a top-4 sigmoid expert
   layer without a shared expert).
+* a sixth — :class:`NemotronHConfig` / :class:`NemotronHBlock`
+  (one mixer a layer: Mamba-2 layers with several B/C groups, NoPE
+  grouped-query layers, and expert layers that keep nothing of a
+  request, their squared-ReLU experts run in a latent beside a shared
+  expert).
 
 See docs/serving.md for the page-table layout, the admission policy,
 decode routing, speculative decoding, prefix sharing, the quantized
@@ -69,6 +74,8 @@ from apex_tpu.serving.model import (  # noqa: F401
     GraniteHybridConfig,
     Lfm2MoeBlock,
     Lfm2MoeConfig,
+    NemotronHBlock,
+    NemotronHConfig,
     PagedDecoder,
     ServingModelConfig,
     init_params,
@@ -109,6 +116,8 @@ __all__ = [
     "GraniteHybridConfig",
     "Lfm2MoeBlock",
     "Lfm2MoeConfig",
+    "NemotronHBlock",
+    "NemotronHConfig",
     "PagedDecoder",
     "ServingModelConfig",
     "init_params",

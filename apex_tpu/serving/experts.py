@@ -27,6 +27,14 @@ models, as one chip of an expert-parallel deployment runs it.
   is the mean pairs an expert takes), and the rows are put back in pair
   order and summed with their weights.  One path serves a decode step
   of 64 rows and a prefill chunk of 2,048.
+* The experts are SwiGLU (afmoe, deepseek_v2, lfm2_moe) or squared
+  ReLU, ``relu(x Wu)^2 Wd`` (``act="relu2"``, Nemotron-H: not gated,
+  :func:`apex_tpu.ops.grouped.grouped_relu2`), and so is the shared
+  expert.  A LATENT layer (Nemotron-H's LatentMoE) routes on the
+  full-width input but runs its routed experts in a narrower latent:
+  ``latent_down`` before the grouped product, the weighted sum taken in
+  the latent, ``latent_up`` after it; the shared expert stays at full
+  width.
 """
 
 from __future__ import annotations
@@ -36,7 +44,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.ops.grouped import group_rows, grouped_swiglu, row_tile
+from apex_tpu.ops.grouped import (group_rows, grouped_relu2, grouped_swiglu,
+                                  row_tile)
 
 
 def route(u, router, bias, *, top_k: int, route_scale: float,
@@ -81,13 +90,26 @@ def swiglu(u, p):
     return (jax.nn.silu(u @ p["wg"]) * (u @ p["wu"])) @ p["wd"]
 
 
+def relu2(u, p):
+    """``relu(u Wu)^2 Wd``: Nemotron-H's shared expert, not gated."""
+    return jnp.square(jax.nn.relu(u @ p["wu"])) @ p["wd"]
+
+
+#: an expert's activation -> (its dense form, its grouped kernels)
+ACTS = {"swiglu": (swiglu, lambda rows, te, active, p, tm: grouped_swiglu(
+            rows, te, active, p["wg"], p["wu"], p["wd"], tm=tm)),
+        "relu2": (relu2, lambda rows, te, active, p, tm: grouped_relu2(
+            rows, te, active, p["wu"], p["wd"], tm=tm))}
+
+
 def held_experts(u, experts, weights, p, held: Tuple[int, int],
-                 valid=None, *, tm: int):
+                 valid=None, *, tm: int, act: str = "swiglu"):
     """The routed part of the layer that the experts ``[lo, hi)`` give.
 
     ``u`` ``[T, d]``; ``experts``/``weights`` ``[T, k]`` from
-    :func:`route`; ``p`` the held experts' stacked weights (``wg``,
-    ``wu`` ``[n, d, f]``, ``wd`` ``[n, f, d]``); ``valid`` ``[T]`` bool,
+    :func:`route`; ``p`` the held experts' stacked weights (``wg``
+    where ``act`` is ``"swiglu"``, ``wu`` ``[n, d, f]``, ``wd`` ``[n,
+    f, d]``); ``valid`` ``[T]`` bool,
     where given: a token that is padding takes no expert; ``tm`` the
     row tile (:func:`apex_tpu.ops.grouped.row_tile`).  Returns (y
     ``[T, d]``, load ``[n]`` int32: the pairs each held expert took)."""
@@ -104,8 +126,7 @@ def held_experts(u, experts, weights, p, held: Tuple[int, int],
     layout = group_rows(jnp.where(here, local, n), n, tm)
     rows = jnp.take(u, layout.src // k, axis=0, mode="fill", fill_value=0)
     with jax.named_scope("moe_experts"):
-        y = grouped_swiglu(rows, layout.tile_expert, layout.active,
-                           p["wg"], p["wu"], p["wd"], tm=tm)
+        y = ACTS[act][1](rows, layout.tile_expert, layout.active, p, tm)
     # back to pair order (a pair of no held expert reads 0), weighted,
     # summed over a token's k pairs
     y = jnp.take(y, layout.dest, axis=0, mode="fill",
@@ -117,15 +138,19 @@ def held_experts(u, experts, weights, p, held: Tuple[int, int],
 
 def expert_layer(u, p, *, held: Tuple[int, int], top_k: int,
                  route_scale: float, valid=None, groups=None,
-                 route_eps: float = 1e-20):
+                 route_eps: float = 1e-20, act: str = "swiglu"):
     """``sum_i w_i Expert_i(u)`` over the held experts among the chosen,
     plus the shared expert where ``p`` has one.  ``u`` ``[..., d]``;
     ``p`` has ``router`` ``[d, E]``, ``experts``, and ``shared`` where
-    the model has a shared expert (afmoe, deepseek_v2; lfm2_moe has
-    none), and for the sigmoid router ``expert_bias`` ``[E]`` and its
+    the model has a shared expert (afmoe, deepseek_v2, nemotron_h;
+    lfm2_moe has none), ``latent_down`` ``[d, l]`` and ``latent_up``
+    ``[l, d]`` where the routed experts work in a latent of width ``l``
+    (``sum_i w_i Expert_i(u latent_down)`` then ``latent_up``), and for
+    the sigmoid router ``expert_bias`` ``[E]`` and its
     renormalisation's ``route_eps``; ``groups`` ``(n_group,
     topk_group)`` chooses :func:`route_grouped` instead; ``valid``
-    (bool, ``u``'s lead shape) marks the tokens that are not padding.
+    (bool, ``u``'s lead shape) marks the tokens that are not padding;
+    ``act`` the experts' and the shared expert's activation (``ACTS``).
     The row tile is :func:`expert_tile`'s.  Returns (y like ``u``, load
     ``[n_held]``)."""
     lead = u.shape[:-1]
@@ -139,11 +164,19 @@ def expert_layer(u, p, *, held: Tuple[int, int], top_k: int,
             experts, weights = route_grouped(
                 u2, p["router"], top_k=top_k, route_scale=route_scale,
                 n_group=groups[0], topk_group=groups[1])
-    y, load = held_experts(u2, experts, weights, p["experts"], held, valid,
-                           tm=expert_tile(u, p, top_k))
+    latent = "latent_down" in p
+    rows = u2
+    if latent:
+        with jax.named_scope("moe_latent_down"):
+            rows = u2 @ p["latent_down"]
+    y, load = held_experts(rows, experts, weights, p["experts"], held, valid,
+                           tm=expert_tile(u, p, top_k), act=act)
+    if latent:
+        with jax.named_scope("moe_latent_up"):
+            y = y @ p["latent_up"]
     if "shared" in p:
         with jax.named_scope("moe_shared"):
-            y = y + swiglu(u2, p["shared"])
+            y = y + ACTS[act][0](u2, p["shared"])
     return y.reshape(*lead, -1), load
 
 

@@ -109,7 +109,7 @@ from apex_tpu.ops import (flash_attention, flash_decode, flash_decode_latent,
                           latent_walk_tiles)
 from apex_tpu.ops.grouped import rows_tiled
 from apex_tpu.ops.ssm import (causal_conv, short_conv_update, ssd_chunk_scan,
-                              ssm_decode_update)
+                              ssm_decode_update, tail_from_pool, tail_to_pool)
 from apex_tpu.serving.experts import expert_layer, expert_tile, swiglu
 from apex_tpu.serving.kv_cache import (latent_width, pad_latent,
                                        quantize_tokens)
@@ -752,6 +752,8 @@ class GraniteHybridConfig:
     name = "granite_hybrid"
     max_position = None
     latent_dim = None
+    #: one B/C group: every head reads the same ``B`` and ``C``
+    mamba_n_groups = 1
 
     @property
     def num_layers(self) -> int:
@@ -1149,6 +1151,309 @@ class Lfm2MoeBlock:
         return x @ params["embed"].T
 
 
+# -- nemotron_h (Nemotron-H): the sixth block, one mixer a layer --------------
+
+#: ``hybrid_override_pattern``'s letters -> the layer's one mixer
+NEMOTRON_H_MIXERS = {"M": "mamba", "E": "moe", "*": "attention"}
+
+
+@dataclasses.dataclass(frozen=True)
+class NemotronHConfig:
+    """Geometry of a ``nemotron_h`` decoder with a latent expert layer
+    (NVIDIA Nemotron 3 Super): ``layer_types`` names each layer's ONE
+    mixer, ``"mamba"`` (a Mamba-2 state-space mixer whose heads read
+    ``mamba_n_groups`` groups of B and C), ``"moe"`` (a top-k sigmoid
+    expert layer of squared-ReLU experts, run in a latent of
+    ``moe_latent_size``, beside a squared-ReLU shared expert) or
+    ``"attention"`` (grouped-query attention with NO positional signal);
+    every layer is ``x + Mixer(RMS(x))``, with no second branch.
+    ``num_experts`` is the router's width, of which ``experts_held``
+    ``[lo, hi)`` are here.  The head is untied.  No position table: a
+    request is bounded by its pages.
+
+    A request keeps, a state-space layer, a state and a tail in a SLOT
+    of the cache's ``state_pool`` (:attr:`state_shape`,
+    :attr:`tail_shape`), an attention layer K and V of ``num_kv_heads``
+    heads a token, and an expert layer nothing (:attr:`bare_layers`).
+    :meth:`from_published` reads the published ``config.json`` keys."""
+
+    vocab_size: int
+    hidden_size: int
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    layer_types: Tuple[str, ...]       # "mamba" | "moe" | "attention"
+    mamba_n_heads: int
+    mamba_d_head: int
+    mamba_n_groups: int
+    mamba_d_state: int
+    moe_intermediate_size: int
+    moe_shared_expert_intermediate_size: int
+    num_experts: int
+    experts_held: Tuple[int, int]
+    top_k: int
+    moe_latent_size: int
+    route_scale: float = 1.0
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 128
+    rms_norm_eps: float = 1e-5
+    dtype: object = jnp.float32
+    #: the recurrent state's type in the pool
+    state_dtype: object = jnp.float32
+
+    name = "nemotron_h"
+    max_position = None
+    latent_dim = None
+    #: the renormalisation's epsilon in the published router
+    route_eps = 1e-20
+
+    @classmethod
+    def from_published(cls, model: dict, **kw) -> "NemotronHConfig":
+        """The configuration from a ``nemotron_h`` ``config.json``'s
+        keys (``hybrid_override_pattern``, ``mamba_num_heads``,
+        ``mamba_head_dim``, ``n_groups``, ``ssm_state_size``,
+        ``conv_kernel``, ``chunk_size``, ``moe_latent_size``, the expert
+        and attention sizes), and where a configuration file gives them
+        beside those, ``router_width`` (the router's outputs, where
+        ``n_routed_experts`` counts the experts held) and
+        ``experts_held`` (all of them where not given); ``kw`` sets any
+        field (``dtype``, ``state_dtype``, ``experts_held``).  Refuses what
+        the block does not implement: dense MLP layers (``-``), an
+        activation other than ``relu2``, routing that is not
+        renormalised or is group limited."""
+        pattern = model["hybrid_override_pattern"]
+        if set(pattern) - set(NEMOTRON_H_MIXERS):
+            raise ValueError(f"layers {sorted(set(pattern))}: only M, E "
+                             "and * are implemented")
+        if model["mlp_hidden_act"] != "relu2" or \
+                not model["norm_topk_prob"] or \
+                int(model.get("n_group", 1)) != 1:
+            raise ValueError("relu2 experts under a renormalised router "
+                             "with no group limit are implemented")
+        experts = int(model.get("router_width", model["n_routed_experts"]))
+        held = kw.pop("experts_held", model.get("experts_held",
+                                                (0, experts)))
+        return cls(
+            vocab_size=int(model["vocab_size"]),
+            hidden_size=int(model["hidden_size"]),
+            num_heads=int(model["num_attention_heads"]),
+            num_kv_heads=int(model["num_key_value_heads"]),
+            head_dim=int(model["head_dim"]),
+            layer_types=tuple(NEMOTRON_H_MIXERS[c] for c in pattern),
+            mamba_n_heads=int(model["mamba_num_heads"]),
+            mamba_d_head=int(model["mamba_head_dim"]),
+            mamba_n_groups=int(model["n_groups"]),
+            mamba_d_state=int(model["ssm_state_size"]),
+            mamba_d_conv=int(model["conv_kernel"]),
+            mamba_chunk_size=int(model["chunk_size"]),
+            moe_latent_size=int(model["moe_latent_size"]),
+            moe_intermediate_size=int(model["moe_intermediate_size"]),
+            moe_shared_expert_intermediate_size=int(
+                model["moe_shared_expert_intermediate_size"]),
+            num_experts=experts,
+            experts_held=tuple(int(e) for e in held),
+            top_k=int(model["num_experts_per_tok"]),
+            route_scale=float(model["routed_scaling_factor"]),
+            rms_norm_eps=float(model["layer_norm_epsilon"]), **kw)
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads
+
+    @property
+    def layer_windows(self) -> Tuple[Optional[int], ...]:
+        return (None,) * self.num_layers
+
+    @property
+    def state_layers(self) -> Tuple[int, ...]:
+        """The layers that keep a recurrent state and no K/V."""
+        return tuple(i for i, t in enumerate(self.layer_types)
+                     if t == "mamba")
+
+    @property
+    def bare_layers(self) -> Tuple[int, ...]:
+        """The layers that keep nothing of a request: the expert
+        layers."""
+        return tuple(i for i, t in enumerate(self.layer_types) if t == "moe")
+
+    @property
+    def page_head_dim(self) -> int:
+        """A K/V head's width in the page pool: whole lane tiles."""
+        return latent_width(self.head_dim)
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels of the convolution: ``x`` and every group's B and
+        C."""
+        return self.d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def state_shape(self) -> Tuple[int, int]:
+        """A slot's state of one layer (``apex_tpu.ops.ssm``'s layout)."""
+        return (self.mamba_d_state, self.d_inner)
+
+    @property
+    def tail_shape(self) -> Tuple[int, int]:
+        """A slot's tail of one layer as the pool holds it: the last
+        ``mamba_d_conv - 1`` rows of ``xBC``, group by group, each
+        group's rows end to end (``apex_tpu.ops.ssm.tail_to_pool``)."""
+        return (1, (self.mamba_d_conv - 1) * self.conv_dim)
+
+    def block(self) -> "NemotronHBlock":
+        return NemotronHBlock(self)
+
+    def init_params(self, seed: int = 0):
+        """Seeded parameters in the block's layout (the reference's):
+        matrices normal with std 1/sqrt(fan_in), the mixers' output
+        projections (``wout``, ``wo``, ``latent_up``, the shared
+        expert's ``wd``) that over sqrt(num_layers) more, so that the
+        residual stream stays O(1) through the layers; gains 1 + 0.02
+        normal; the router's selection bias 0.001 normal; the
+        recurrence's scalars as :meth:`GraniteHybridConfig.init_params`
+        sets them."""
+        d, dt = self.hidden_size, self.dtype
+        hq, hk, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        nh, di, ch = self.mamba_n_heads, self.d_inner, self.conv_dim
+        n_held = self.experts_held[1] - self.experts_held[0]
+        lat = self.moe_latent_size
+        f, fs = (self.moe_intermediate_size,
+                 self.moe_shared_expert_intermediate_size)
+        keys = iter(jax.random.split(jax.random.PRNGKey(seed), 4096))
+        out = 1.0 / math.sqrt(self.num_layers)
+
+        def mat(*shape, scale=1.0):
+            return (jax.random.normal(next(keys), shape, jnp.float32)
+                    * scale / math.sqrt(shape[-2])).astype(dt)
+
+        def gain(n):
+            return (1.0 + 0.02 * jax.random.normal(
+                next(keys), (n,), jnp.float32)).astype(dt)
+
+        step = jnp.exp(jnp.linspace(math.log(1e-3), math.log(1e-1), nh))
+        layers = []
+        for kind in self.layer_types:
+            layer = {"norm": gain(d)}
+            if kind == "mamba":
+                layer.update(
+                    win=mat(d, di + ch + nh),
+                    conv_w=mat(self.mamba_d_conv, ch),
+                    conv_b=(0.02 * jax.random.normal(
+                        next(keys), (ch,), jnp.float32)).astype(dt),
+                    dt_bias=jnp.log(jnp.expm1(step)).astype(dt),
+                    a_log=jnp.log(jnp.linspace(1.0, 16.0, nh)).astype(dt),
+                    d_skip=gain(nh), gn=gain(di), wout=mat(di, d, scale=out))
+            elif kind == "attention":
+                layer.update(wq=mat(d, hq * hd), wk=mat(d, hk * hd),
+                             wv=mat(d, hk * hd),
+                             wo=mat(hq * hd, d, scale=out))
+            else:
+                layer["moe"] = {
+                    "router": mat(d, self.num_experts),
+                    "expert_bias": (0.001 * jax.random.normal(
+                        next(keys), (self.num_experts,),
+                        jnp.float32)).astype(dt),
+                    "latent_down": mat(d, lat),
+                    "latent_up": mat(lat, d, scale=out),
+                    "experts": {"wu": mat(n_held, lat, f),
+                                "wd": mat(n_held, f, lat)},
+                    "shared": {"wu": mat(d, fs), "wd": mat(fs, d, scale=out)}}
+            layers.append(layer)
+        embed = jax.random.normal(next(keys), (self.vocab_size, d),
+                                  jnp.float32).astype(dt)
+        return {"embed": embed, "head": mat(d, self.vocab_size),
+                "norm_f": gain(d), "layers": layers}
+
+
+class NemotronHBlock:
+    """The Nemotron-H block (docs/serving.md, "The sixth block"): every
+    layer is ``x' = x + Mixer(RMS(x; norm))``, ONE mixer a layer; a
+    final RMSNorm and an untied head.
+
+    A STATE-SPACE layer gets ``scan(xbc, dt, layer)``, Granite's Mamba-2
+    recurrence with ``mamba_n_groups`` groups of B and C (head ``h``
+    reads group ``h // (H / G)``), and its gated RMSNorm ``RMS(y *
+    silu(z))`` is taken within each group's ``d_inner / G`` channels.
+    An ATTENTION layer gets ``attend(q, k, v, scale=)``: grouped-query
+    heads of ``head_dim``, scores over ``sqrt(head_dim)``, no rotary,
+    no bias.  An EXPERT layer gets nothing (``None``: it keeps no pages
+    and no slot): the sigmoid router over all ``num_experts`` at full
+    width, top-k on ``score + bias``, renormalised and scaled; the held
+    experts' part in the latent (``latent_down``, squared-ReLU experts,
+    the weighted sum, ``latent_up``), plus the squared-ReLU shared
+    expert at full width (:func:`~apex_tpu.serving.experts.
+    expert_layer`, ``act="relu2"``)."""
+
+    #: as Granite's: a shared prefix needs a snapshot of the state at
+    #: the page it ends on, a rejected draft a rolled-back state (the
+    #: model's own draft module among them), shipped pages a shipped
+    #: slot: none exists yet
+    refuses = ("tp", "kv_quant", "prefix_sharing", "speculation",
+               "prefill_only", "kv_import")
+    stat_names = ("moe_pairs_held", "moe_load_max", "moe_rows_tiled",
+                  "moe_experts_touched")
+    #: where the engine's refusal sends the reader
+    refuses_doc = "What Nemotron-H refuses"
+
+    def __init__(self, cfg: NemotronHConfig):
+        self.cfg = cfg
+
+    def embed(self, params, tokens, positions):
+        return params["embed"][tokens]
+
+    def layer(self, layer, li, x, positions, mixer, *, tp_axis=None,
+              valid=None, stats=None):
+        cfg = self.cfg
+        eps, hd = cfg.rms_norm_eps, cfg.head_dim
+        lead = x.shape[:-1]
+        u = _rms(x, layer["norm"], eps)
+        kind = cfg.layer_types[li]
+        if kind == "mamba":
+            di, ch, g = cfg.d_inner, cfg.conv_dim, cfg.mamba_n_groups
+            with jax.named_scope("ssm_in_proj"):
+                zxbcdt = u @ layer["win"]
+                z = zxbcdt[..., :di]
+                xbc = zxbcdt[..., di:di + ch]
+                dt = zxbcdt[..., di + ch:]
+            y = mixer(xbc, dt, layer)           # float32
+            with jax.named_scope("ssm_gate_norm"):
+                gated = (y * jax.nn.silu(z.astype(jnp.float32))).reshape(
+                    *lead, g, di // g)
+                gated = _rms(gated, layer["gn"].reshape(g, di // g), eps)
+                gated = gated.reshape(*lead, di).astype(x.dtype)
+            with jax.named_scope("ssm_out_proj"):
+                m = gated @ layer["wout"]
+        elif kind == "attention":
+            q = (u @ layer["wq"]).reshape(*lead, cfg.num_heads, hd)
+            k = (u @ layer["wk"]).reshape(*lead, cfg.num_kv_heads, hd)
+            v = (u @ layer["wv"]).reshape(*lead, cfg.num_kv_heads, hd)
+            with jax.named_scope("attn_nope"):
+                ctx = mixer(q, k, v, scale=hd ** -0.5)
+            m = ctx @ layer["wo"]
+        else:
+            m, load = expert_layer(
+                u, layer["moe"], held=cfg.experts_held, top_k=cfg.top_k,
+                route_scale=cfg.route_scale, valid=valid,
+                route_eps=cfg.route_eps, act="relu2")
+            if stats is not None:
+                stats.append(
+                    (load, expert_tile(u, layer["moe"], cfg.top_k)))
+        return x + m
+
+    def final_norm(self, params, x):
+        return _rms(x, params["norm_f"], self.cfg.rms_norm_eps)
+
+    def logits(self, params, x):
+        return x @ params["head"]
+
+
 class StateIO(NamedTuple):
     """The slot half of a hybrid cache as a paged step sees it: the
     pools of the state layers (``ssm`` ``[L_s, n_slots, N, H * P]``, None
@@ -1193,10 +1498,14 @@ class PagedDecoder:
         self.windows = tuple(cfg.layer_windows)
         #: the layers that keep a recurrent state in a slot and no K/V
         self.state_layers = frozenset(getattr(cfg, "state_layers", ()))
-        counts = {"full": 0, "window": 0, "state": 0}
+        #: the layers that keep nothing of a request, neither pages nor
+        #: a slot (Nemotron-H's expert layers): their mixer is None
+        self.bare_layers = frozenset(getattr(cfg, "bare_layers", ()))
+        counts = {"full": 0, "window": 0, "state": 0, "bare": 0}
         index = []
         for li, w in enumerate(self.windows):
             family = ("state" if li in self.state_layers
+                      else "bare" if li in self.bare_layers
                       else "full" if w is None else "window")
             index.append(counts[family])
             counts[family] += 1
@@ -1242,18 +1551,20 @@ class PagedDecoder:
         (y ``[S, H * P]`` float32, state, tail); the tails as the pool
         holds them (:attr:`GraniteHybridConfig.tail_shape`)."""
         cfg = self.cfg
-        di, n = cfg.d_inner, cfg.mamba_d_state
+        di, n, g = cfg.d_inner, cfg.mamba_d_state, cfg.mamba_n_groups
         with jax.named_scope("ssm_conv"):
             conv, tail = causal_conv(
-                xbc, tail_in.reshape(-1, cfg.conv_dim), valid, p["conv_w"],
-                p["conv_b"])
+                xbc, tail_from_pool(tail_in, di, n, g, cfg.mamba_d_conv),
+                valid, p["conv_w"], p["conv_b"])
             conv = conv.astype(xbc.dtype)
-            tail = tail.reshape(cfg.tail_shape)
+            tail = tail_to_pool(tail, di, n, g)
+        b, c = conv[:, di:di + g * n], conv[:, di + g * n:]
+        if g > 1:
+            b, c = b.reshape(-1, g, n), c.reshape(-1, g, n)
         y, state = ssd_chunk_scan(
             conv[:, :di].reshape(-1, cfg.mamba_n_heads, cfg.mamba_d_head),
-            dt, p["a_log"], conv[:, di:di + n], conv[:, di + n:],
-            p["d_skip"], state_in, valid, dt_bias=p["dt_bias"],
-            chunk=cfg.mamba_chunk_size)
+            dt, p["a_log"], b, c, p["d_skip"], state_in, valid,
+            dt_bias=p["dt_bias"], chunk=cfg.mamba_chunk_size)
         return y, state, tail
 
     def _expanded(self, attention, seg, kept):
@@ -1384,6 +1695,8 @@ class PagedDecoder:
             return c
 
         def attend_in(li):
+            if li in self.bare_layers:
+                return None
             if li in self.state_layers:
                 return scan if "ssm" in self.state_planes else short_conv
             if self.latent:
@@ -1527,7 +1840,8 @@ class PagedDecoder:
                     slot_pools[0], slot_pools[1], state.slots, xbc, dt,
                     layer=pi, conv_w=p["conv_w"], conv_b=p["conv_b"],
                     dt_bias=p["dt_bias"], a_log=p["a_log"],
-                    d_skip=p["d_skip"], heads=cfg.mamba_n_heads)
+                    d_skip=p["d_skip"], heads=cfg.mamba_n_heads,
+                    groups=cfg.mamba_n_groups)
                 return y
 
             def scan(xbc, dt, p):
@@ -1585,6 +1899,8 @@ class PagedDecoder:
 
         def attend_in(li):
             pi = self.pool_index[li]
+            if li in self.bare_layers:
+                return None
             if li in self.state_layers:
                 return (scan_in(pi) if "ssm" in self.state_planes
                         else conv_in(pi))

@@ -59,7 +59,8 @@ from apex_tpu.ops import (flash_attention_qkv, flash_attention_qkv_route,
                           flash_attention_route, flash_decode,
                           flash_decode_latent, flash_decode_latent_route,
                           flash_decode_route, latent_walk_tiles,
-                          routing_override, ssm_decode_route)
+                          routing_override, ssm_decode_route,
+                          ssm_decode_update)
 from apex_tpu.serving import (DeepseekV2Config, GraniteHybridConfig,
                               Lfm2MoeConfig, PagedDecoder, ServingEngine,
                               ServingModelConfig, SpecConfig, poisson_trace)
@@ -106,6 +107,18 @@ GROUPED_SHAPES = {
     "dsv2_decode": (64, 6, 160, (0, 20), 5120, 1536, 8, 0),
     "dsv2_chunk": (512, 6, 160, (0, 20), 5120, 1536, 1, 100),
 }
+# The grouped squared-ReLU product at the Nemotron-H cell's decode and
+# row shapes, in the latent (d 1,024) where its experts work.
+GROUPED_RELU2_SHAPES = {
+    "nemotron_decode": (64, 22, 512, (0, 128), 1024, 2688, 0, 0),
+    "nemotron_row": (1024, 22, 512, (0, 128), 1024, 2688, 0, 100),
+}
+# The in-place state update with eight B/C groups at the Nemotron-H
+# cell's decode shape: rows, slots, heads, head dim, groups, state, taps.
+GROUPED_STATE_SHAPE = (64, 66, 128, 64, 8, 128, 4)
+# The kernel and the XLA route compute the same float32 arithmetic in
+# other fusions: relative L2 of y, the state and the tail.
+GROUPED_STATE_TOL = 1e-5
 # Chips holding equal shards of one program's state.
 BALANCE_FACTOR = 1.5
 
@@ -754,10 +767,12 @@ def leg_short_conv(cfg, *, seed, page_size, row, max_new) -> dict:
             "memory": memory_by_device()}
 
 
-def ragged_held_experts(u, experts, weights, p, held, valid=None):
+def ragged_held_experts(u, experts, weights, p, held, valid=None,
+                        act="swiglu"):
     """The expert layer's routed part as three ``jax.lax.ragged_dot``
     calls over the pairs sorted expert by expert (what
-    ``experts.held_experts`` ran before the grouped kernels)."""
+    ``experts.held_experts`` ran before the grouped kernels); two for
+    squared-ReLU experts (``act="relu2"``)."""
     lo, hi = held
     n = hi - lo
     T, k = experts.shape
@@ -769,8 +784,9 @@ def ragged_held_experts(u, experts, weights, p, held, valid=None):
     order = jnp.argsort(key, stable=True)
     load = jnp.bincount(key, length=n + 1)[:n].astype(jnp.int32)
     rows = jnp.take(u, order // k, axis=0)
-    h = (jax.nn.silu(jax.lax.ragged_dot(rows, p["wg"], load))
-         * jax.lax.ragged_dot(rows, p["wu"], load))
+    up = jax.lax.ragged_dot(rows, p["wu"], load)
+    h = (jnp.square(jax.nn.relu(up)) if act == "relu2" else
+         jax.nn.silu(jax.lax.ragged_dot(rows, p["wg"], load)) * up)
     y = jax.lax.ragged_dot(h, p["wd"], load)
     y = jnp.where((jnp.arange(T * k) < jnp.sum(load))[:, None], y, 0)
     y = jnp.take(y, jnp.argsort(order), axis=0).reshape(T, k, -1)
@@ -822,14 +838,15 @@ def _call_ms(fn, args, reps: int) -> float:
     return (time.perf_counter() - t) / reps * 1e3
 
 
-def leg_grouped(shapes, *, seed, reps=10) -> dict:
+def leg_grouped(shapes, *, seed, reps=10, act="swiglu") -> dict:
     """The expert layer's routed part (``experts.held_experts``: the
     padded layout and the two grouped kernels) against the three
-    ``ragged_dot`` calls it replaced, at each of ``shapes``
-    (``GROUPED_SHAPES``' form), on integer-valued operands: equal bit
-    for bit, output and load.  Times (wall ms a call, the layout and
-    the unsort included) and the touched experts' bytes a second are
-    information."""
+    ``ragged_dot`` calls it replaced (two for squared-ReLU experts,
+    ``act="relu2"``: ``grouped_relu2_up`` and the down kernel), at each
+    of ``shapes`` (``GROUPED_SHAPES``' form), on integer-valued
+    operands: equal bit for bit, output and load.  Times (wall ms a
+    call, the layout and the unsort included) and the touched experts'
+    bytes a second are information."""
     out = {}
     for name, (tokens, top_k, width, held, d, f, documents,
                padding) in shapes.items():
@@ -839,10 +856,10 @@ def leg_grouped(shapes, *, seed, reps=10) -> dict:
         tm = row_tile(tokens * top_k, width)
         paths = {"grouped": jax.jit(
                      lambda u, e, w, p, v: held_experts(u, e, w, p, held, v,
-                                                        tm=tm)),
+                                                        tm=tm, act=act)),
                  "ragged_dot": jax.jit(
-                     lambda u, e, w, p, v: ragged_held_experts(u, e, w, p,
-                                                               held, v))}
+                     lambda u, e, w, p, v: ragged_held_experts(
+                         u, e, w, p, held, v, act))}
         args = (u, experts, weights, p, valid)
         (y, load), (y_r, load_r) = (fn(*args) for fn in paths.values())
         require(np.array_equal(np.asarray(load), np.asarray(load_r)),
@@ -854,7 +871,8 @@ def leg_grouped(shapes, *, seed, reps=10) -> dict:
         load = np.asarray(load)
         touched = int(np.sum(load > 0))
         ms = {path: _call_ms(fn, args, reps) for path, fn in paths.items()}
-        weight_bytes = touched * 3 * d * f * p["wg"].dtype.itemsize
+        mats = 2 if act == "relu2" else 3
+        weight_bytes = touched * mats * d * f * p["wg"].dtype.itemsize
         out[name] = {"tm": tm, "pairs_held": int(load.sum()),
                      "touched": touched,
                      "tiles": int(np.sum(-(-load // tm))),
@@ -864,6 +882,51 @@ def leg_grouped(shapes, *, seed, reps=10) -> dict:
         del p, paths
         free_device_memory()
     return out
+
+
+def leg_grouped_state(shape, *, seed, reps=10) -> dict:
+    """The in-place state update with several B/C groups
+    (``ops/ssm.ssm_decode_update``: a grid axis of groups, a group one
+    block of state, channels and tail) against its XLA route on the same
+    pools, at ``shape`` (``GROUPED_STATE_SHAPE``' form): one layer of
+    random float32 states and bf16 tails, half the rows real, the rest
+    on the scratch slot.  ``y``, the state and the tail of every real
+    row agree within ``GROUPED_STATE_TOL``; wall ms a call and the
+    states' bytes a second are information."""
+    rows, slots, heads, head_dim, groups, n, taps = shape
+    lanes = heads * head_dim
+    ch = lanes + 2 * groups * n
+    ks = jax.random.split(jax.random.PRNGKey(seed), 9)
+    bf = jnp.bfloat16
+    state = jax.random.normal(ks[0], (1, slots, n, lanes), jnp.float32)
+    tail = jax.random.normal(ks[1], (1, slots, 1, (taps - 1) * ch), bf)
+    real = rows // 2
+    table = jnp.concatenate([jnp.arange(1, real + 1, dtype=jnp.int32),
+                             jnp.zeros((rows - real,), jnp.int32)])
+    xbc = jax.random.normal(ks[2], (rows, ch), bf)
+    dt = jax.random.normal(ks[3], (rows, heads), bf)
+    w = dict(conv_w=(jax.random.normal(ks[4], (taps, ch)) / 2).astype(bf),
+             conv_b=(jax.random.normal(ks[5], (ch,)) / 10).astype(bf),
+             dt_bias=(jax.random.normal(ks[6], (heads,)) - 3).astype(bf),
+             a_log=(jax.random.normal(ks[7], (heads,)) / 2).astype(bf),
+             d_skip=(1 + jax.random.normal(ks[8], (heads,)) / 10).astype(bf))
+    outs, ms = {}, {}
+    for route in ("decode", "xla"):
+        with routing_override(decode=route):
+            fn = jax.jit(lambda s, t, tb, x, d: ssm_decode_update(
+                s, t, tb, x, d, layer=0, heads=heads, groups=groups, **w))
+            args = (state, tail, table, xbc, dt)
+            y, s1, t1 = fn(*args)
+            outs[route] = (y[:real], s1[0, 1:real + 1],
+                           t1[0, 1:real + 1].astype(jnp.float32))
+            ms[route] = _call_ms(fn, args, reps)
+    gaps = {name: rel_l2(a, b) for name, a, b in zip(
+        ("y", "state", "tail"), outs["decode"], outs["xla"])}
+    require(max(gaps.values()) < GROUPED_STATE_TOL,
+            f"grouped state update: kernel and XLA route differ {gaps}")
+    moved = 2 * real * n * lanes * 4
+    return {"rel_l2": gaps, "ms": ms,
+            "GBps": {k: moved / t / 1e6 for k, t in ms.items()}}
 
 
 def _lfm2_config() -> Lfm2MoeConfig:
@@ -992,6 +1055,9 @@ def main() -> int:
             f"short conv: the paged route is {short['route']}")
 
     _report("grouped", leg_grouped(GROUPED_SHAPES, seed=6))
+    _report("grouped_relu2", leg_grouped(GROUPED_RELU2_SHAPES, seed=6,
+                                         act="relu2"))
+    _report("grouped_state", leg_grouped_state(GROUPED_STATE_SHAPE, seed=7))
 
     if len(devices) >= 4:
         _report("train_mesh", leg_train(

@@ -173,6 +173,33 @@ def test_grouped_leg():
     json.dumps(out)
 
 
+def test_grouped_relu2_leg():
+    """Squared-ReLU experts (``grouped_relu2_up`` and the down kernel)
+    against their two ``ragged_dot`` calls, bit for bit, at toy widths;
+    the chip's shapes are the Nemotron-H cell's decode and row, in the
+    1,024-wide latent."""
+    out = chip_smoke.leg_grouped(TOY_GROUPED, seed=6, reps=1, act="relu2")
+    assert set(out) == set(TOY_GROUPED)
+    assert all(leg["pairs_held"] > 0 for leg in out.values())
+    assert {name: shape[:6] for name, shape in
+            chip_smoke.GROUPED_RELU2_SHAPES.items()} == {
+        "nemotron_decode": (64, 22, 512, (0, 128), 1024, 2688),
+        "nemotron_row": (1024, 22, 512, (0, 128), 1024, 2688)}
+    json.dumps(out)
+
+
+def test_grouped_state_leg():
+    """The state update with two B/C groups, its kernel (interpret mode
+    here) against its XLA route; the chip's shape is the cell's: 64
+    rows, 128 heads of 64 in 8 groups, a state of 128."""
+    out = chip_smoke.leg_grouped_state((4, 6, 16, 16, 2, 16, 4), seed=7,
+                                       reps=1)
+    assert max(out["rel_l2"].values()) < chip_smoke.GROUPED_STATE_TOL
+    assert set(out["ms"]) == {"decode", "xla"}
+    assert chip_smoke.GROUPED_STATE_SHAPE == (64, 66, 128, 64, 8, 128, 4)
+    json.dumps(out)
+
+
 def test_grouped_leg_fails_on_one_misplaced_row(monkeypatch):
     def swapped(*args, **kw):
         y, load = held_experts(*args, **kw)

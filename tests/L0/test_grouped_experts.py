@@ -10,9 +10,10 @@ pair, groups of ``tm - 1``, ``tm``, ``tm + 1`` and ``3 tm + 5`` rows,
 7/8 of the pairs on absent experts, no expert held, every pair absent,
 a pair count that is no multiple of ``tm``, padding tokens) the layer
 equals, bit for bit, a plain loop over the experts and the three
-``ragged_dot`` calls it replaced; and the layout keeps every tile to one
-expert.  Then one float case at each expert cell's decode and chunk
-shape, widths reduced."""
+``ragged_dot`` calls it replaced, with SwiGLU experts and with
+squared-ReLU ones; and the layout keeps every tile to one expert.
+Then one float case at each expert cell's decode and chunk shape,
+widths reduced."""
 
 import os
 import sys
@@ -33,10 +34,11 @@ from apex_tpu.serving.experts import held_experts  # noqa: E402
 D, F = 256, 128
 
 
-def loop_reference(u, experts, weights, p, held, valid=None):
+def loop_reference(u, experts, weights, p, held, valid=None, act="swiglu"):
     """Expert by expert over the rows whose pairs chose it (numpy picks
-    them): both products accumulated in float32, ``h`` and the output
-    rounded to the activations' type, the weighted sum in numpy."""
+    them): both products accumulated in float32, ``h`` (SwiGLU, or the
+    squared ReLU of ``act="relu2"``) and the output rounded to the
+    activations' type, the weighted sum in numpy."""
     lo, hi = held
     ex = np.asarray(experts)
     here = (ex >= lo) & (ex < hi)
@@ -49,9 +51,12 @@ def loop_reference(u, experts, weights, p, held, valid=None):
         if not len(t):
             continue
         x = u[t]
-        gate = jnp.dot(x, p["wg"][e], preferred_element_type=f32)
         up = jnp.dot(x, p["wu"][e], preferred_element_type=f32)
-        h = (jax.nn.silu(gate) * up).astype(u.dtype)
+        if act == "relu2":
+            h = jnp.square(jax.nn.relu(up)).astype(u.dtype)
+        else:
+            gate = jnp.dot(x, p["wg"][e], preferred_element_type=f32)
+            h = (jax.nn.silu(gate) * up).astype(u.dtype)
         y[t, j] = np.asarray(jnp.dot(h, p["wd"][e], preferred_element_type=f32)
                              .astype(u.dtype), np.float32)
     w = np.where(here, np.asarray(weights), 0.0).astype(np.float32)
@@ -95,8 +100,13 @@ LAYOUTS = {
 }
 
 
+@pytest.mark.parametrize("act", ["swiglu", "relu2"])
 @pytest.mark.parametrize("layout", sorted(LAYOUTS))
-def test_integer_valued_layer_is_bitwise_the_loop_and_ragged_dot(layout):
+def test_integer_valued_layer_is_bitwise_the_loop_and_ragged_dot(layout, act):
+    """SwiGLU experts (``grouped_swiglu_gate_up``) and squared-ReLU
+    experts (``grouped_relu2_up``: the up weights -2..2 on four ones
+    give ``relu(up)^2`` of at most 64, exact in bf16), then the same
+    down kernel."""
     experts, width, held, padding = LAYOUTS[layout]
     tokens, k = experts.shape
     u, _, weights, p, valid = chip_smoke.grouped_operands(
@@ -107,9 +117,10 @@ def test_integer_valued_layer_is_bitwise_the_loop_and_ragged_dot(layout):
     experts = jnp.asarray(experts, jnp.int32)
     tm = row_tile(tokens * k, width)
     assert tm == (64 if layout.startswith("rows_of") else TM)
-    y, load = jax.jit(lambda *a: held_experts(*a, held, valid, tm=tm))(
+    y, load = jax.jit(lambda *a: held_experts(*a, held, valid, tm=tm,
+                                              act=act))(
         u, experts, weights, p)
-    want = loop_reference(u, experts, weights, p, held, valid)
+    want = loop_reference(u, experts, weights, p, held, valid, act)
     np.testing.assert_array_equal(np.asarray(y, np.float32),
                                   want.astype(u.dtype).astype(np.float32))
     here = ((np.asarray(experts) >= held[0]) & (np.asarray(experts) < held[1])
@@ -121,7 +132,7 @@ def test_integer_valued_layer_is_bitwise_the_loop_and_ragged_dot(layout):
         assert not np.any(np.asarray(y))
         return
     y_r, load_r = jax.jit(lambda *a: chip_smoke.ragged_held_experts(
-        *a, held, valid))(u, experts, weights, p)
+        *a, held, valid, act))(u, experts, weights, p)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(y_r))
     np.testing.assert_array_equal(np.asarray(load), np.asarray(load_r))
 
@@ -171,20 +182,24 @@ def test_no_tile_holds_two_experts_and_each_pair_has_one_row(layout):
     (1024 * 4, 64, 64), (512 * 4, 64, 32),                    # LFM2 rows
     (2048 * 4, 256, 32), (1024 * 4, 256, 16),                 # Trinity
     (512 * 6, 160, 32),                                       # dsv2 chunk
+    (64 * 22, 512, 16), (1024 * 22, 512, 48),                 # Nemotron
     (100_000, 8, 256), (3, 8, 16)])
 def test_the_row_tile_is_the_mean_pairs_an_expert_takes(pairs, width, tm):
     assert row_tile(pairs, width) == tm
 
 
-# The three expert cells' decode and chunk shapes (tokens, top_k, router
-# width, experts held), d and f cut to 256 and 128.
+# The four expert cells' decode and chunk shapes (tokens, top_k, router
+# width, experts held, the experts' activation), d and f cut to 256 and
+# 128.
 CELL_SHAPES = {
-    "lfm2_decode": (64, 4, 64, (0, 64)),
-    "lfm2_row": (1024, 4, 64, (0, 64)),
-    "trinity_decode": (64, 4, 256, (0, 32)),
-    "trinity_chunk": (2048, 4, 256, (0, 32)),
-    "dsv2_decode": (64, 6, 160, (0, 20)),
-    "dsv2_chunk": (512, 6, 160, (0, 20)),
+    "lfm2_decode": (64, 4, 64, (0, 64), "swiglu"),
+    "lfm2_row": (1024, 4, 64, (0, 64), "swiglu"),
+    "trinity_decode": (64, 4, 256, (0, 32), "swiglu"),
+    "trinity_chunk": (2048, 4, 256, (0, 32), "swiglu"),
+    "dsv2_decode": (64, 6, 160, (0, 20), "swiglu"),
+    "dsv2_chunk": (512, 6, 160, (0, 20), "swiglu"),
+    "nemotron_decode": (64, 22, 512, (0, 128), "relu2"),
+    "nemotron_row": (1024, 22, 512, (0, 128), "relu2"),
 }
 # bf16, relative L2 of the output.  Against ragged_dot's three products:
 # ragged_dot rounds gate and up to bf16 before silu where the kernel
@@ -198,7 +213,7 @@ FLOAT_TOL = 1e-2
 
 @pytest.mark.parametrize("shape", sorted(CELL_SHAPES))
 def test_float_layer_at_each_cells_shapes(shape):
-    tokens, k, width, held = CELL_SHAPES[shape]
+    tokens, k, width, held, act = CELL_SHAPES[shape]
     n = held[1] - held[0]
     ks = jax.random.split(jax.random.PRNGKey(41), 5)
     bf = jnp.bfloat16
@@ -209,13 +224,14 @@ def test_float_layer_at_each_cells_shapes(shape):
     experts = jnp.asarray(_random(tokens, k, width, seed=4), jnp.int32)
     weights = jax.random.uniform(ks[4], (tokens, k))
     tm = row_tile(tokens * k, width)
-    y, _ = jax.jit(lambda *a: held_experts(*a, held, tm=tm))(
+    y, _ = jax.jit(lambda *a: held_experts(*a, held, tm=tm, act=act))(
         u, experts, weights, p)
-    y_r, _ = jax.jit(lambda *a: chip_smoke.ragged_held_experts(*a, held))(
-        u, experts, weights, p)
+    y_r, _ = jax.jit(lambda *a: chip_smoke.ragged_held_experts(
+        *a, held, act=act))(u, experts, weights, p)
     exact = loop_reference(u.astype(jnp.float32), experts, weights,
                            jax.tree_util.tree_map(
-                               lambda w: w.astype(jnp.float32), p), held)
+                               lambda w: w.astype(jnp.float32), p), held,
+                           act=act)
     assert chip_smoke.rel_l2(y, y_r) < FLOAT_TOL
     assert chip_smoke.rel_l2(y, exact) < FLOAT_TOL
     assert chip_smoke.rel_l2(y, exact) <= chip_smoke.rel_l2(y_r, exact)

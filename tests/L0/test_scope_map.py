@@ -19,7 +19,8 @@ import pytest
 from apex_tpu.analysis import hot_path_guard
 from apex_tpu.serving import (AfmoeConfig, DeepseekV2Config,
                               GraniteHybridConfig, Lfm2MoeConfig,
-                              ServingEngine, ServingModelConfig, SimClock)
+                              NemotronHConfig, ServingEngine,
+                              ServingModelConfig, SimClock)
 from apex_tpu.telemetry import scopes
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -357,6 +358,16 @@ def _lfm2_moe():
         experts_held=(0, 8), top_k=2)
 
 
+def _nemotron_h():
+    return NemotronHConfig(
+        vocab_size=96, hidden_size=64, num_heads=4, num_kv_heads=2,
+        head_dim=16, layer_types=("mamba", "moe", "attention", "moe"),
+        mamba_n_heads=4, mamba_d_head=16, mamba_n_groups=2, mamba_d_state=16,
+        moe_intermediate_size=32, moe_shared_expert_intermediate_size=48,
+        num_experts=16, experts_held=(0, 8), top_k=4, moe_latent_size=32,
+        route_scale=5.0, mamba_chunk_size=8)
+
+
 BLOCKS = {
     "gpt": (lambda: ServingModelConfig(
         vocab_size=64, hidden_size=32, num_heads=4, num_layers=2,
@@ -379,6 +390,19 @@ BLOCKS = {
                              "prefill_budget": 16},
                  {"conv_in_proj", "short_conv", "conv_out_proj", "attn_full",
                   "mlp", "moe_router", "moe_experts", "layer", "head"}),
+    "nemotron_h": (_nemotron_h, {"max_pages_per_request": 16,
+                                 "prefill_budget": 16},
+                   {"ssm_in_proj", "ssm_update", "ssm_gate_norm",
+                    "ssm_out_proj", "attn_nope", "moe_router",
+                    "moe_latent_down", "moe_experts", "moe_latent_up",
+                    "moe_shared", "layer", "head"}),
+}
+#: each expert block's two grouped kernels, by name
+GROUPED_KERNELS = {
+    "afmoe": ("grouped_swiglu_gate_up", "grouped_swiglu_down"),
+    "deepseek_v2": ("grouped_swiglu_gate_up", "grouped_swiglu_down"),
+    "lfm2_moe": ("grouped_swiglu_gate_up", "grouped_swiglu_down"),
+    "nemotron_h": ("grouped_relu2_up", "grouped_swiglu_down"),
 }
 #: of a tiny engine's decode instructions, at most this share is a fusion
 #: of parts from more than one scope (the CPU's fusions; measured 0.02-0.07)
@@ -440,7 +464,7 @@ def test_the_lfm2_blocks_three_executables_leave_nothing_under_no_scope():
         assert not bare, (name, bare[:10])
 
 
-@pytest.mark.parametrize("block", ["afmoe", "deepseek_v2", "lfm2_moe"])
+@pytest.mark.parametrize("block", sorted(GROUPED_KERNELS))
 def test_the_grouped_kernels_sit_under_moe_experts(block):
     """In each executable of every expert block the grouped
     product's two kernels are under ``moe_experts`` with their own names
@@ -459,9 +483,9 @@ def test_the_grouped_kernels_sit_under_moe_experts(block):
         (m,) = [m for m in maps[name]
                 if m.variant in (None, str(eng.prefill_widths[-1]))]
         kernels = {scope for scope, _ in m.instructions.values()
-                   if "grouped_swiglu" in scope}
-        assert kernels == {"layer/moe_experts/grouped_swiglu_gate_up",
-                           "layer/moe_experts/grouped_swiglu_down"}, name
+                   if "grouped_" in scope}
+        assert kernels == {"layer/moe_experts/" + k
+                           for k in GROUPED_KERNELS[block]}, name
         text = jax.jit(eng._exec_defs[key][0]).trace(
             *structs[key]).lower().compile().as_text()
         # what runs: the entry and the bodies of its loops; parameters,
