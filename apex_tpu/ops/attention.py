@@ -2317,21 +2317,60 @@ _DECODE_FEW_ROWS = 8
 _DECODE_VMEM_LIMIT = 64 * 2 ** 20
 
 
-def _decode_body(rows_n, h, quantized):
+def lane_dense_pages(num_heads: int, dtype) -> bool:
+    """Whether a page pool of ``num_heads`` K/V heads (the heads ONE
+    device holds) in ``dtype`` keeps a page lane-dense, ``[page_size,
+    num_heads * head_dim]``, rather than ``[page_size, num_heads,
+    head_dim]``.
+
+    Where the heads fill whole sublane tiles (8 or more, a multiple of
+    8) a head-split page lies in whole tiles and every body of the
+    decode kernel reads it as it lies.  Where they do not, XLA tiles the
+    minor ``(h, d)`` pair by ``h``: at 2 heads of 128 in bf16 a 64-token
+    page is 64 tiles of 512 bytes, fetched as 64 pieces, and a head is a
+    one-sublane-in-eight gather out of the slot.  Stored lane-dense the
+    same page is whole ``(8, 128)`` tiles, one contiguous copy, and head
+    ``hh`` is lanes ``[hh * d, (hh + 1) * d)``: a tile-aligned view.
+    The element order is the same.  A quantized pool (one-byte codes,
+    scaled per (slot, head)) keeps its heads split; so does a pool of
+    one head, whose lane-dense page would have the shape of one layer
+    of a head-split pool, which :func:`flash_decode` also takes."""
+    return (jnp.dtype(dtype).itemsize > 1 and num_heads > 1
+            and num_heads % 8 != 0)
+
+
+def _pool_layout(k_pages, d):
+    """``(lane_dense, page_size, h)`` of a pool :func:`flash_decode`
+    is handed: head-split ``[L, n_pages, page_size, h, d]`` (one layer:
+    ``[n_pages, page_size, h, d]``) or lane-dense ``[L, n_pages,
+    page_size, h * d]``.  A four-dimensional pool whose last axis is
+    ``d`` is one layer of a head-split pool; any other is lane-dense."""
+    shape = k_pages.shape
+    lane_dense = len(shape) == 4 and shape[-1] != d
+    if lane_dense:
+        return True, shape[-2], shape[-1] // d
+    return False, shape[-3], shape[-2]
+
+
+def _decode_body(rows_n, h, quantized, lane_dense=False):
     """How the kernel scores a block, from what it can see of the call.
 
     ``"all_heads"``: few query rows a K/V head (plain decode, a verify
     window): every head of the block in one contraction, the block
     read as ``[pages * page_size * h, d]`` rows, which is the block as
-    it lies only where ``h`` fills whole sublane tiles.
-    ``"per_head"``: many rows (a chunk's tile): head by head over the
-    whole block, ``[rows, d] x [d, pages * page_size]`` on the MXU.
+    it lies only where ``h`` fills whole sublane tiles of a head-split
+    page.
+    ``"per_head"``: many rows (a chunk's tile, or a decode row of a
+    large query group): head by head over the whole block, ``[rows, d]
+    x [d, pages * page_size]`` on the MXU.
     ``"per_page"``: the rest (a quantized pool, whose codes are scaled
-    per (slot, head) first; few rows over 4 heads, a tp shard): head by
-    head, a page at a time, as the kernel always did."""
+    per (slot, head) first; few rows over heads that fill no sublane
+    tile): head by head, a page at a time, as the kernel always did.
+    A lane-dense pool's head is a view of the slot either way."""
     if rows_n > _DECODE_FEW_ROWS:
         return "per_page" if quantized else "per_head"
-    return "all_heads" if h % 8 == 0 and not quantized else "per_page"
+    return ("all_heads" if h % 8 == 0 and not quantized and not lane_dense
+            else "per_page")
 
 
 def _decode_pages_per_step(page_size, h, d, itemsize, p_max, body):
@@ -2350,7 +2389,7 @@ def _decode_pages_per_step(page_size, h, d, itemsize, p_max, body):
 
 def _make_decode_kernel(*, scale, page_size, q_len, h, d, pages, p_max,
                         quantized=False, group=1, tq=None, window=None,
-                        has_start=False, body="per_page"):
+                        has_start=False, body="per_page", lane_dense=False):
     """Decode forward: grid (b, tiles); scalar-prefetch operands
     (page_table [b, p_max], kv_len [b], layer [1]).  Queries are the
     LAST ``q_len`` positions of the request's ``kv_len``-token cache
@@ -2361,7 +2400,8 @@ def _make_decode_kernel(*, scale, page_size, q_len, h, d, pages, p_max,
     from the first a query row of it can see to the last, ``pages`` of
     them (a block) a turn of a loop inside the kernel.  Each page of
     the block is one DMA, found through the page table, into one of two
-    VMEM slots ``[pages, page_size, h, d]``; the block after (or the
+    VMEM slots ``[pages, page_size, h, d]`` (``lane_dense``: ``[pages,
+    page_size, h * d]``, the pool's own page); the block after (or the
     first block of the next grid step, another row of the batch) is in
     flight while this one is scored.  A page past the row's last, or
     before its window, inside a live block is not fetched and its
@@ -2539,6 +2579,14 @@ def _make_decode_kernel(*, scale, page_size, q_len, h, d, pages, p_max,
             attend(0, q_ref[0, 0], k_buf[slot].reshape(n_cols, d),
                    v_buf[slot].reshape(n_cols, d), seen)
 
+        def head(buf, slot, pages, hh):
+            """Head ``hh`` of the slot's ``pages``: lanes ``[hh * d,
+            (hh + 1) * d)`` of a lane-dense page, a tile-aligned view;
+            an index of the head axis of a head-split one."""
+            if lane_dense:
+                return buf[slot, pages, :, pl.ds(hh * d, d)]
+            return buf[slot, pages, :, hh, :]
+
         def per_head(slot, j0, col_base):
             """Pages ``[j0, j0 + chunk_pages)`` of the slot, a head at
             a time: ``[rows_n, d] x [d, columns]`` is an MXU shape
@@ -2552,11 +2600,11 @@ def _make_decode_kernel(*, scale, page_size, q_len, h, d, pages, p_max,
             for hh in range(h):
                 q = q_ref[0, hh]          # [rows_n, d]
                 if body == "per_head":
-                    k = k_buf[slot, :, :, hh, :].reshape(n_cols, d)
-                    v = v_buf[slot, :, :, hh, :].reshape(n_cols, d)
+                    k = head(k_buf, slot, slice(None), hh).reshape(n_cols, d)
+                    v = head(v_buf, slot, slice(None), hh).reshape(n_cols, d)
                 else:
-                    k = k_buf[slot, j0, :, hh, :]
-                    v = v_buf[slot, j0, :, hh, :]
+                    k = head(k_buf, slot, j0, hh)
+                    v = head(v_buf, slot, j0, hh)
                 if quantized:   # always page by page (_decode_body)
                     q = q.astype(jnp.float32)
                     k = (k.astype(jnp.float32)
@@ -2634,20 +2682,20 @@ def _flash_decode_pallas(q, k_pages, v_pages, page_table, kv_len, scale,
                          layer, k_scale=None, v_scale=None, window=None,
                          kv_start=None):
     """q [b, h_q, q_len, d]; k_pages/v_pages [L, n_pages, page_size, h,
-    d], of which the static int ``layer`` is read; page_table [b, p_max]
-    int32 (rows padded with page 0); kv_len [b]; optional
-    k_scale/v_scale [L, n_pages, page_size, h] fp32 (quantized pool —
-    dequantized in-kernel); ``window``/``kv_start`` as in
-    :func:`flash_decode`.  Returns o [b, h_q, q_len, d]."""
+    d] or lane-dense [L, n_pages, page_size, h * d], of which the
+    static int ``layer`` is read; page_table [b, p_max] int32 (rows
+    padded with page 0); kv_len [b]; optional k_scale/v_scale [L,
+    n_pages, page_size, h] fp32 (quantized pool — dequantized
+    in-kernel); ``window``/``kv_start`` as in :func:`flash_decode`.
+    Returns o [b, h_q, q_len, d]."""
     b, hq, q_len, d = q.shape
-    h = k_pages.shape[3]
+    lane_dense, page_size, h = _pool_layout(k_pages, d)
     group = hq // h
-    page_size = k_pages.shape[2]
     p_max = page_table.shape[1]
     quantized = k_scale is not None
     has_start = kv_start is not None
     tq = _decode_q_tile(q_len, group)
-    body = _decode_body(group * q_len, h, quantized)
+    body = _decode_body(group * q_len, h, quantized, lane_dense)
     pages = _decode_pages_per_step(page_size, h, d, k_pages.dtype.itemsize,
                                    p_max, body)
     if group > 1:
@@ -2664,9 +2712,10 @@ def _flash_decode_pallas(q, k_pages, v_pages, page_table, kv_len, scale,
     pool_spec = pl.BlockSpec(memory_space=pltpu.HBM)
     in_specs = [q_spec, pool_spec, pool_spec]
     operands = [q, k_pages, v_pages]
-    slots = (2, pages, page_size, h)
-    scratch = [pltpu.VMEM(slots + (d,), k_pages.dtype),
-               pltpu.VMEM(slots + (d,), v_pages.dtype)]
+    slots = (2, pages, page_size)
+    page = (h * d,) if lane_dense else (h, d)
+    scratch = [pltpu.VMEM(slots + page, k_pages.dtype),
+               pltpu.VMEM(slots + page, v_pages.dtype)]
     if quantized:
         # The scale planes, unlike the pages, go in one layer at a time.
         # XLA keeps an fp32 [L, n_pages, page_size, h] plane in a tiled
@@ -2682,8 +2731,8 @@ def _flash_decode_pallas(q, k_pages, v_pages, page_table, kv_len, scale,
                                   ((0, 0), (0, 0), (0, lanes - h)))
         in_specs += [pool_spec, pool_spec]
         operands += [plane(k_scale), plane(v_scale)]
-        scratch += [pltpu.VMEM(slots[:3] + (lanes,), jnp.float32),
-                    pltpu.VMEM(slots[:3] + (lanes,), jnp.float32)]
+        scratch += [pltpu.VMEM(slots + (lanes,), jnp.float32),
+                    pltpu.VMEM(slots + (lanes,), jnp.float32)]
     prefetch = [page_table.astype(jnp.int32), kv_len.astype(jnp.int32),
                 jnp.full((1,), layer, jnp.int32)]
     if has_start:
@@ -2706,7 +2755,8 @@ def _flash_decode_pallas(q, k_pages, v_pages, page_table, kv_len, scale,
                             q_len=q_len, h=h, d=d, pages=pages,
                             p_max=p_max, quantized=quantized,
                             group=group, tq=tq, window=window,
-                            has_start=has_start, body=body),
+                            has_start=has_start, body=body,
+                            lane_dense=lane_dense),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         name="flash_decode_window" if window is not None
@@ -2733,12 +2783,12 @@ def _paged_attention_xla(q, k_pages, v_pages, page_table, kv_len, scale,
     quantized: the gathered bytes are dequantized (``value * scale``,
     fp32) before scoring — same contraction the Pallas kernel runs in
     VMEM.  Grouped-query heads repeat the gathered K/V heads;
-    ``window``/``kv_start`` as in :func:`flash_decode`."""
+    ``window``/``kv_start`` as in :func:`flash_decode`.  A lane-dense
+    pool's gathered pages are viewed ``[..., h, d]``: the same order."""
     b, hq, q_len, d = q.shape
-    h = k_pages.shape[3]
-    page_size = k_pages.shape[2]
+    _, page_size, h = _pool_layout(k_pages, d)
     p_max = page_table.shape[1]
-    kc = k_pages[layer][page_table]  # [b, p_max, page_size, h, d]
+    kc = k_pages[layer][page_table]  # [b, p_max, page_size, h(, d)]
     vc = v_pages[layer][page_table]
     if k_scale is not None:
         kc = kc.astype(jnp.float32) * k_scale[layer][page_table][..., None]
@@ -2771,13 +2821,14 @@ def _decode_shape_ok(q, k_pages):
     a whole number of native tiles for the POOL dtype (the same Mosaic
     grain rule ``_pallas_ok`` applies to block_q/block_k: 8 rows at
     fp32, 16 at bf16, 32 at one-byte dtypes), and pool/head dims must
-    agree.  ``k_pages`` is the whole pool or one layer of it: the page
-    shape is its last three dimensions either way."""
+    agree.  ``k_pages`` is the whole pool or one layer of it, head-split
+    or lane-dense (:func:`_pool_layout`)."""
     b, h, q_len, d = q.shape
-    page_size, hp, dp = k_pages.shape[-3:]
+    lane_dense, page_size, hp = _pool_layout(k_pages, d)
     grain = 32 // max(1, jnp.dtype(k_pages.dtype).itemsize)
-    return (h % hp == 0 and dp == d and page_size % grain == 0
-            and q_len >= 1)
+    return (hp >= 1 and h % hp == 0 and k_pages.shape[-1] == (
+        hp * d if lane_dense else d) and page_size % grain == 0
+        and q_len >= 1)
 
 
 def _decode_tpu_ok(q):
@@ -2840,7 +2891,10 @@ def flash_decode(
     lie, whereas a slice handed to a Mosaic call is first copied out,
     layer by layer (157 MB twice a layer at the 1.3B geometry).  A
     single layer's pool ``[n_pages, page_size, h, d]`` is the same
-    thing at ``L = 1``.  ``page_table`` [b, p_max] int32: each
+    thing at ``L = 1``.  A pool whose heads fill no sublane tile is
+    kept lane-dense (:func:`lane_dense_pages`): ``[L, n_pages,
+    page_size, h * d]``, the same numbers in the same order, ``h``
+    read as its last axis over ``d``.  ``page_table`` [b, p_max] int32: each
     request's page list in cache order, rows padded with page 0 (the
     pool's reserved scratch page — see ``apex_tpu.serving.kv_cache``).
     ``kv_len`` [b]: valid tokens per request, INCLUDING however many
@@ -2865,7 +2919,8 @@ def flash_decode(
     rule is dtype-aware: a one-byte pool needs ``page_size % 32 == 0``
     for the Pallas route; smaller pages fall back to the XLA route,
     which runs the identical dequant math.  Scales must come in pairs
-    (both or neither).
+    (both or neither).  A quantized pool is head-split, never
+    lane-dense.
 
     Grouped-query heads: the pool may hold fewer heads than ``q`` has
     (``h_q = g * h``); query head ``n`` reads pool head ``n // g``.
@@ -2887,7 +2942,11 @@ def flash_decode(
         raise ValueError(f"window {window} must be >= 1")
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    if k_pages.ndim == 4:  # one layer's pool: the whole-pool form at L = 1
+    lane_dense = _pool_layout(k_pages, q.shape[-1])[0]
+    if lane_dense and k_scale is not None:
+        raise ValueError("a quantized pool is [..., h, d], not lane-dense")
+    if k_pages.ndim == 4 and not lane_dense:
+        # one layer's pool: the whole-pool form at L = 1
         k_pages, v_pages = k_pages[None], v_pages[None]
         if k_scale is not None:
             k_scale, v_scale = k_scale[None], v_scale[None]

@@ -545,11 +545,12 @@ def corrupt_page(cache, page: int, *, which: str = "k") -> None:
     import jax.numpy as jnp
 
     arr = np.array(getattr(cache, which))   # host copy of the pool
-    l, r = 0, cache.page_size // 2
-    val = arr[l, page, r, 0, 0]
-    raw = bytearray(val.tobytes())
+    # layer 0, the middle row, its first number (a head-split, a
+    # lane-dense or a latent page alike)
+    at = (0, page, cache.page_size // 2) + (0,) * (arr.ndim - 3)
+    raw = bytearray(arr[at].tobytes())
     raw[0] ^= 0xFF
-    arr[l, page, r, 0, 0] = np.frombuffer(bytes(raw), dtype=arr.dtype)[0]
+    arr[at] = np.frombuffer(bytes(raw), dtype=arr.dtype)[0]
     setattr(cache, which, jnp.asarray(arr))
 
 

@@ -654,6 +654,15 @@ class ServingEngine:
             lambda s: NamedSharding(self._mesh, s), self._param_specs(),
             is_leaf=lambda x: isinstance(x, PartitionSpec))
 
+    def _pool_spec(self):
+        """The pools' ``PartitionSpec``: split on the head axis, or on a
+        lane-dense page's last axis, whose shards are whole heads."""
+        from jax.sharding import PartitionSpec as P
+        ax = self._tp_axis
+        if self.cache.page_layout == "lane_dense":
+            return P(None, None, None, ax)
+        return P(None, None, None, ax, None)
+
     def _shard_pools(self) -> None:
         """Place the pool (and scale) arrays on the mesh, sharded on
         their head axis — fresh pools (init / :meth:`recover`) must be
@@ -661,7 +670,7 @@ class ServingEngine:
         executable."""
         from jax.sharding import NamedSharding, PartitionSpec as P
         ax = self._tp_axis
-        pool = NamedSharding(self._mesh, P(None, None, None, ax, None))
+        pool = NamedSharding(self._mesh, self._pool_spec())
         self.cache.k = jax.device_put(self.cache.k, pool)
         self.cache.v = jax.device_put(self.cache.v, pool)
         if self.kv_quant is not None:
@@ -679,7 +688,7 @@ class ServingEngine:
         from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         ax = self._tp_axis
-        pool = P(None, None, None, ax, None)
+        pool = self._pool_spec()
         r = P()
         kv_row = P(None, None, ax, None)
         pspec = self._param_specs()
@@ -717,7 +726,8 @@ class ServingEngine:
                              "layer that keeps every token")
         geometry = dict(page_size=page_size, num_heads=cfg.kv_heads,
                         head_dim=dec.page_head_dim, dtype=cfg.dtype,
-                        crc_pages=crc_pages, latent_dim=cfg.latent_dim)
+                        crc_pages=crc_pages, latent_dim=cfg.latent_dim,
+                        head_shards=self.tp)
         cache = PagedKVCache(
             num_layers=dec.full_layers, num_pages=num_pages,
             max_pages_per_request=max_pages_per_request,

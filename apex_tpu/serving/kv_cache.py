@@ -12,7 +12,12 @@ of fixed-size pages:
   head_dim]`` device arrays, allocated ONCE at engine start.  A
   request's cache is a *page list* — pages need not be contiguous, so
   the pool never fragments and "grow by one token" is at most "append
-  one page id to a python list".
+  one page id to a python list".  Where a device's heads fill no
+  sublane tile (``ops.attention.lane_dense_pages``: 2 or 4 K/V heads,
+  not quantized) the pool is LANE-DENSE, ``[num_layers, num_pages,
+  page_size, num_heads * head_dim]``: the same numbers in the same
+  order, a page whole tiles that the decode kernel fetches in one copy
+  (:attr:`PagedKVCache.page_layout`).
 * Page 0 is the reserved **scratch page**: it is never allocated, page
   tables pad their rows with it, and packed-prefill scatter routes its
   padding positions there.  Readers never see its content (the decode
@@ -80,10 +85,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from apex_tpu.ops.attention import lane_dense_pages
+
+
+def as_stored(pool, x):
+    """Tokens' K or V ``[..., h, d]`` as ``pool`` keeps them: as they
+    are in a head-split pool, ``[..., h * d]`` in a lane-dense one."""
+    return x.reshape(x.shape[:-2] + (-1,)) if pool.ndim == 4 else x
+
 
 def _scatter_tokens(k_pool, v_pool, k_new, v_new, pages, offsets):
-    return (k_pool.at[:, pages, offsets].set(k_new),
-            v_pool.at[:, pages, offsets].set(v_new))
+    return (k_pool.at[:, pages, offsets].set(as_stored(k_pool, k_new)),
+            v_pool.at[:, pages, offsets].set(as_stored(v_pool, v_new)))
 
 
 def latent_width(latent_dim: int) -> int:
@@ -205,6 +218,10 @@ class PagedKVCache:
     ``max_pages_per_request`` fixes the page-table width ``p_max`` —
     every decode step sees a static ``[batch, p_max]`` table, so
     admitting or retiring requests never recompiles the step.
+
+    ``head_shards``: the devices the heads are split over (tensor
+    parallel); the page layout follows from the heads ONE device holds
+    (:attr:`page_layout`).
     """
 
     def __init__(self, *, num_layers: int, num_pages: int,
@@ -212,7 +229,8 @@ class PagedKVCache:
                  max_pages_per_request: int,
                  dtype=jnp.float32, crc_pages: bool = False,
                  quantize: Optional[str] = None,
-                 latent_dim: Optional[int] = None):
+                 latent_dim: Optional[int] = None,
+                 head_shards: int = 1):
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is the "
                              "reserved scratch page)")
@@ -236,9 +254,22 @@ class PagedKVCache:
         if latent_dim is not None and quantize:
             raise ValueError("a latent pool is not quantized")
         pool_dtype = quant_pool_dtype(quantize) if quantize else dtype
-        shape = (num_layers, num_pages, page_size) + (
-            (num_heads, head_dim) if latent_dim is None
-            else (latent_width(latent_dim),))
+        #: "latent" (one vector a token), "lane_dense" (a page
+        #: ``[page_size, num_heads * head_dim]``) or "heads" (``[page_size,
+        #: num_heads, head_dim]``): from the heads a device holds and the
+        #: pool's dtype alone
+        self.page_layout = (
+            "latent" if latent_dim is not None
+            else "lane_dense" if lane_dense_pages(num_heads // head_shards,
+                                                  pool_dtype)
+            else "heads")
+        if self.page_layout == "latent":
+            page = (latent_width(latent_dim),)
+        elif self.page_layout == "lane_dense":
+            page = (num_heads * head_dim,)
+        else:
+            page = (num_heads, head_dim)
+        shape = (num_layers, num_pages, page_size) + page
         self.k = jnp.zeros(shape, pool_dtype)
         self.v = (jnp.zeros(shape, pool_dtype) if latent_dim is None
                   else None)
@@ -521,8 +552,7 @@ class PagedKVCache:
         ``kv_import`` is on; part of the zero-compiles-after-warmup
         contract."""
         z = jnp.int32(0)
-        pshape = (self.num_layers, 1, self.page_size,
-                  self.num_heads, self.head_dim)
+        pshape = (self.num_layers, 1) + self.k.shape[2:]
         self.k = self._import(self.k, jnp.zeros(pshape, self.k.dtype), z)
         self.v = self._import(self.v, jnp.zeros(pshape, self.v.dtype), z)
         if self.quantize:
@@ -585,8 +615,9 @@ class PagedKVCache:
         first (:func:`verify_page_payload`); this method trusts it and
         records the shipped CRCs as the page's read-back digest."""
         self._no_latent_shipping()
-        pshape = (self.num_layers, 1, self.page_size,
-                  self.num_heads, self.head_dim)
+        # C order is the same in both page layouts: a payload lands in
+        # a head-split pool and a lane-dense one alike
+        pshape = (self.num_layers, 1) + self.k.shape[2:]
         dst = jnp.int32(page)
         k = np.frombuffer(base64.b64decode(data["k"]),
                           dtype=np.dtype(self.k.dtype)).reshape(pshape)

@@ -111,7 +111,7 @@ from apex_tpu.ops.grouped import rows_tiled
 from apex_tpu.ops.ssm import (causal_conv, short_conv_update, ssd_chunk_scan,
                               ssm_decode_update, tail_from_pool, tail_to_pool)
 from apex_tpu.serving.experts import expert_layer, expert_tile, swiglu
-from apex_tpu.serving.kv_cache import (latent_width, pad_latent,
+from apex_tpu.serving.kv_cache import (as_stored, latent_width, pad_latent,
                                        quantize_tokens)
 
 
@@ -1751,7 +1751,9 @@ class PagedDecoder:
         executable order, counters).
 
         Every layer hands ``flash_decode`` the WHOLE pool ``[L,
-        n_pages, ps, H, hd]`` and ``layer=``, never ``k_pool[li]``:
+        n_pages, ps, H, hd]`` (lane-dense: ``[L, n_pages, ps, H * hd]``,
+        and the tokens are appended in that form) and ``layer=``, never
+        ``k_pool[li]``:
         the kernel reads that layer's pages where they lie, and a
         slice would be copied out first (all of a layer's pages, twice,
         before each of the L calls).  The append before it stays in
@@ -1920,8 +1922,10 @@ class PagedDecoder:
                     v_new, v_s = quantize_tokens(v_new, pool[1].dtype, qmax)
                     pool[2] = pool[2].at[pi, pages, offsets].set(k_s)
                     pool[3] = pool[3].at[pi, pages, offsets].set(v_s)
-                pool[0] = pool[0].at[pi, pages, offsets].set(k_new)
-                pool[1] = pool[1].at[pi, pages, offsets].set(v_new)
+                pool[0] = pool[0].at[pi, pages, offsets].set(
+                    as_stored(pool[0], k_new))
+                pool[1] = pool[1].at[pi, pages, offsets].set(
+                    as_stored(pool[1], v_new))
                 q4 = q.reshape(b, -1, nh, hd).transpose(0, 2, 1, 3)
                 kw = dict(tables[w is not None])
                 if w is not None:

@@ -119,6 +119,10 @@ GROUPED_STATE_SHAPE = (64, 66, 128, 64, 8, 128, 4)
 # The kernel and the XLA route compute the same float32 arithmetic in
 # other fusions: relative L2 of y, the state and the tail.
 GROUPED_STATE_TOL = 1e-5
+# Paged decode over a pool of 2 K/V heads, kept lane-dense, at the
+# Nemotron-H cell's decode shape: rows, query heads, K/V heads, head dim,
+# pages, page size, p_max.
+LANE_DENSE_SHAPE = (64, 32, 2, 128, 1600, 64, 32)
 # Chips holding equal shards of one program's state.
 BALANCE_FACTOR = 1.5
 
@@ -929,6 +933,52 @@ def leg_grouped_state(shape, *, seed, reps=10) -> dict:
             "GBps": {k: moved / t / 1e6 for k, t in ms.items()}}
 
 
+def leg_lane_dense_decode(shape, *, seed, reps=10) -> dict:
+    """Paged decode over a pool whose heads fill no sublane tile, kept
+    lane-dense (``[1, pages, page_size, heads * head_dim]``,
+    ``ops.attention.lane_dense_pages``), at ``shape``
+    (``LANE_DENSE_SHAPE``' form; rows' lengths uniform up to the table):
+    the kernel within ``FWD_TOL`` of the XLA route on the same pool, and
+    equal bit for bit to the kernel on the same numbers stored ``[1,
+    pages, page_size, heads, head_dim]``.  Wall ms a call and K/V bytes
+    a second are information."""
+    rows, hq, heads, head_dim, pages, page_size, p_max = shape
+    rng = np.random.RandomState(seed)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    split = (1, pages, page_size, heads, head_dim)
+    k5, v5 = (jax.random.normal(key, split, jnp.bfloat16) for key in ks[:2])
+    pools = {"heads": (k5, v5),
+             "lane_dense": (k5.reshape(split[:3] + (-1,)),
+                            v5.reshape(split[:3] + (-1,)))}
+    kv_len = rng.randint(1, p_max * page_size + 1, (rows,))
+    table = np.zeros((rows, p_max), np.int32)
+    used = -(-kv_len // page_size)
+    ids = rng.permutation(np.arange(1, pages))[:int(used.sum())]
+    for i, (a, n) in enumerate(zip(np.cumsum(used) - used, used)):
+        table[i, :n] = ids[a:a + n]
+    q = jax.random.normal(ks[2], (rows, hq, 1, head_dim), jnp.bfloat16)
+    args = (q, jnp.asarray(table), jnp.asarray(kv_len, jnp.int32))
+    outs, ms = {}, {}
+    for name, route in (("lane_dense", None), ("heads", None),
+                        ("xla", "xla")):
+        k, v = pools["heads" if name == "heads" else "lane_dense"]
+        with routing_override(decode=route):
+            fn = jax.jit(lambda q, pt, kl, k, v: flash_decode(
+                q, k, v, pt, kl))
+            outs[name] = np.asarray(fn(*args, k, v), np.float32)
+            ms[name] = _call_ms(fn, args + (k, v), reps)
+    gap = rel_l2(outs["lane_dense"], outs["xla"])
+    require(gap <= FWD_TOL, f"lane-dense decode: kernel and XLA route "
+            f"differ by {gap:.3e}")
+    bad = int(np.sum(outs["lane_dense"] != outs["heads"]))
+    require(bad == 0, f"lane-dense decode: {bad} numbers differ from the "
+            "head-split pool's")
+    moved = int(used.sum()) * 2 * page_size * heads * head_dim * 2
+    return {"route": flash_decode_route(q, pools["lane_dense"][0]),
+            "rel_l2": gap, "live_pages": int(used.sum()), "ms": ms,
+            "GBps": {k: moved / t / 1e6 for k, t in ms.items()}}
+
+
 def _lfm2_config() -> Lfm2MoeConfig:
     """LFM2-24B-A2B's published widths: the two dense layers and one
     period (attention, conv, conv, conv), an eighth of the experts and
@@ -1058,6 +1108,10 @@ def main() -> int:
     _report("grouped_relu2", leg_grouped(GROUPED_RELU2_SHAPES, seed=6,
                                          act="relu2"))
     _report("grouped_state", leg_grouped_state(GROUPED_STATE_SHAPE, seed=7))
+    lane_dense = _report("lane_dense_decode", leg_lane_dense_decode(
+        LANE_DENSE_SHAPE, seed=8))
+    require(lane_dense["route"] == ROUTES_ON_TPU["decode"],
+            f"lane-dense decode: the paged route is {lane_dense['route']}")
 
     if len(devices) >= 4:
         _report("train_mesh", leg_train(

@@ -105,8 +105,10 @@ def test_flash_attention_varlen_gqa_and_window(window):
 PS = 8
 
 
-def paged_case(hq, h, q_len, window, kv_lens, compact, seed=0):
-    """Pools, tables and the plain answer for rows of ``kv_lens``."""
+def paged_case(hq, h, q_len, window, kv_lens, compact, seed=0,
+               lane_dense=False):
+    """Pools, tables and the plain answer for rows of ``kv_lens``;
+    ``lane_dense``: the pools handed over as ``[L, n, PS, h * D]``."""
     rng = np.random.RandomState(seed)
     b = len(kv_lens)
     n_pages = 1 + sum(-(-kv // PS) for kv in kv_lens)
@@ -139,6 +141,8 @@ def paged_case(hq, h, q_len, window, kv_lens, compact, seed=0):
     kw = dict(window=window)
     if compact:
         kw["kv_start"] = jnp.asarray(start)
+    if lane_dense:
+        kp, vp = (a.reshape(a.shape[:3] + (-1,)) for a in (kp, vp))
     got = flash_decode(jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
                        jnp.asarray(table), jnp.asarray(kv_lens, jnp.int32),
                        layer=1, **kw)
@@ -163,6 +167,60 @@ def test_flash_decode_gqa_and_window(route, hq, q_len, window, compact):
     with routing_override(decode=route):
         got, want = paged_case(hq, 2, q_len, window, KV_LENS, compact)
     np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+@pytest.mark.parametrize("h", [1, 2, 4])
+@pytest.mark.parametrize("q_len,window,compact", [
+    (1, None, False),             # decode, a group of 16 a K/V head
+    (1, 11, True),                # ... over a window pool's table
+    (32, None, False),            # a chunk's tile: 512 rows a K/V head
+    (32, 11, True),
+])
+def test_flash_decode_lane_dense_pool(h, q_len, window, compact):
+    """A pool whose heads fill no sublane tile is kept ``[L, n, PS, h *
+    D]`` (``lane_dense_pages``; one head stays head-split): the kernel
+    over it agrees with the plain answer, the XLA route over it too,
+    and it is the kernel over the same numbers stored head-split, bit
+    for bit: the same contractions in the same order."""
+    lane_dense = att.lane_dense_pages(h, jnp.float32)
+    assert lane_dense == (h > 1)
+    got = {}
+    for route, layout in (("decode", lane_dense), ("decode", False),
+                          ("xla", lane_dense)):
+        with routing_override(decode=route):
+            got[route, layout], want = paged_case(
+                16 * h, h, q_len, window, KV_LENS, compact,
+                lane_dense=layout)
+    np.testing.assert_allclose(got["decode", lane_dense], want, atol=2e-5)
+    np.testing.assert_allclose(got["xla", lane_dense], want, atol=2e-5)
+    np.testing.assert_array_equal(got["decode", lane_dense],
+                                  got["decode", False])
+
+
+def test_lane_dense_pages_follow_the_heads_and_the_dtype():
+    """Lane-dense where a device's heads fill no sublane tile and the
+    pool is not one-byte codes; never one head."""
+    for h, dtype, dense in ((2, jnp.bfloat16, True), (4, jnp.float32, True),
+                            (6, jnp.bfloat16, True), (1, jnp.bfloat16, False),
+                            (8, jnp.bfloat16, False),
+                            (16, jnp.float32, False), (2, jnp.int8, False),
+                            (4, jnp.float8_e4m3fn, False)):
+        assert att.lane_dense_pages(h, dtype) == dense, (h, dtype)
+    # a four-dimensional pool is lane-dense unless its last axis is the
+    # head's: then it is one layer of a head-split pool
+    assert att._pool_layout(jnp.zeros((1, 5, 64, 256)), 128) == (True, 64, 2)
+    assert att._pool_layout(jnp.zeros((5, 64, 2, 128)), 128) == (False, 64, 2)
+    assert att._pool_layout(jnp.zeros((1, 5, 64, 2, 128)), 128) == (
+        False, 64, 2)
+
+
+def test_a_quantized_pool_is_never_lane_dense():
+    q = jnp.zeros((1, 4, 1, D))
+    pool = jnp.zeros((1, 3, PS, 2 * D), jnp.int8)
+    scale = jnp.ones((1, 3, PS, 2))
+    with pytest.raises(ValueError, match="lane-dense"):
+        flash_decode(q, pool, pool, jnp.zeros((1, 1), jnp.int32),
+                     jnp.ones((1,), jnp.int32), k_scale=scale, v_scale=scale)
 
 
 @pytest.mark.parametrize("hq,window,compact", [(6, 11, True), (6, None, False),
@@ -196,6 +254,12 @@ def test_decode_body_and_pages_follow_the_shapes():
     assert att._decode_body(1, 16, True) == "per_page"      # int8 / fp8 codes
     assert att._decode_body(6 * 2048, 8, False) == "per_head"   # a chunk
     assert att._decode_body(128, 16, True) == "per_page"
+    # a lane-dense pool (Nemotron-H: 32 query heads on 2): its decode
+    # rows go head by head over blocks; a few rows page by page
+    assert att._decode_body(16, 2, False, True) == "per_head"
+    assert att._decode_body(16 * 1024, 2, False, True) == "per_head"
+    assert att._decode_body(1, 4, False, True) == "per_page"
+    assert att._decode_body(1, 8, False, True) == "per_page"
     pages = att._decode_pages_per_step
     assert pages(64, 16, 128, 2, 28, "all_heads") == 4      # GPT: 2 MB a turn
     assert pages(64, 8, 128, 2, 264, "all_heads") == 8      # Trinity, full
@@ -203,6 +267,7 @@ def test_decode_body_and_pages_follow_the_shapes():
     assert pages(64, 16, 128, 2, 28, "per_head") == 8       # VMEM bounds it
     assert pages(64, 16, 128, 2, 3, "all_heads") == 3       # a short table
     assert pages(64, 16, 128, 1, 28, "per_page") == 16
+    assert pages(64, 2, 128, 2, 32, "per_head") == 16       # Nemotron-H
 
 
 @pytest.fixture

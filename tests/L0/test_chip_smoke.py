@@ -200,6 +200,20 @@ def test_grouped_state_leg():
     json.dumps(out)
 
 
+def test_lane_dense_decode_leg():
+    """Paged decode over 2 K/V heads kept lane-dense: off the TPU both
+    routes of the smoke's comparison are XLA's, so this rehearses the
+    pools, tables and checks; the kernel's own parity is
+    ``test_attention_gqa_window.py``'s.  The chip's shape is the
+    Nemotron-H cell's decode."""
+    out = chip_smoke.leg_lane_dense_decode((4, 8, 2, 16, 40, 16, 4),
+                                           seed=8, reps=1)
+    assert out["route"] == "xla" and out["rel_l2"] == 0.0
+    assert set(out["ms"]) == {"lane_dense", "heads", "xla"}
+    assert chip_smoke.LANE_DENSE_SHAPE == (64, 32, 2, 128, 1600, 64, 32)
+    json.dumps(out)
+
+
 def test_grouped_leg_fails_on_one_misplaced_row(monkeypatch):
     def swapped(*args, **kw):
         y, load = held_experts(*args, **kw)

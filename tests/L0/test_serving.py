@@ -377,6 +377,65 @@ def _cache(num_pages=9, page_size=8, **kw):
 
 
 class TestPagedKVCache:
+    @pytest.mark.parametrize("heads,shards,quantize,layout", [
+        (2, 1, None, "lane_dense"),     # Nemotron-H's 2 K/V heads
+        (4, 1, None, "lane_dense"),
+        (8, 1, None, "heads"),          # Trinity, Granite, LFM2
+        (16, 1, None, "heads"),         # GPT
+        (2, 1, "int8", "heads"),        # codes scaled per (slot, head)
+        (16, 4, None, "lane_dense"),    # GPT's tp=4 shard: 4 heads
+        (16, 2, None, "heads"),
+        (4, 4, None, "heads"),          # one head a device
+    ])
+    def test_page_layout_follows_the_heads_a_device_holds(
+            self, heads, shards, quantize, layout):
+        c = _cache(num_heads=heads, head_dim=128, head_shards=shards,
+                   quantize=quantize, dtype=jnp.bfloat16)
+        assert c.page_layout == layout
+        page = (heads * 128,) if layout == "lane_dense" else (heads, 128)
+        assert c.k.shape == c.v.shape == (1, 9, 8) + page
+
+    def test_both_page_layouts_hold_the_same_kv(self, monkeypatch):
+        """The prefill scatter, a copy-on-write page copy and a page
+        shipped out of one pool into the other leave the same K/V in a
+        lane-dense pool as in a head-split one."""
+        from apex_tpu.serving import kv_cache
+
+        dense = _cache(num_heads=2, page_size=4)
+        monkeypatch.setattr(kv_cache, "lane_dense_pages", lambda *a: False)
+        split = _cache(num_heads=2, page_size=4)
+        assert (dense.page_layout, split.page_layout) == ("lane_dense",
+                                                          "heads")
+        rng = np.random.RandomState(0)
+        k_new, v_new = (rng.randn(1, 6, 2, 4).astype(np.float32)
+                        for _ in range(2))
+        idx = np.arange(6)
+        for c in (dense, split):
+            pages = c.allocate(2, owner=1)
+            c.write_tokens(jnp.asarray(k_new), jnp.asarray(v_new),
+                           np.asarray(pages)[idx // 4], idx % 4)
+            c.share(pages[:1])
+            assert c.cow(pages[0], owner=2) == 3
+        for src, dst in ((dense, split), (split, dense)):
+            [page] = dst.allocate(1, owner=3)
+            payload = src.export_page_bytes(2)
+            assert kv_cache.verify_page_payload(payload)
+            dst.import_page_bytes(page, payload)
+
+        def logical(c):
+            return [np.asarray(a).reshape(a.shape[:3] + (2, 4))
+                    for a in (c.k, c.v)]
+
+        for a, b in zip(logical(dense), logical(split)):
+            np.testing.assert_array_equal(a, b)
+        k, v = logical(dense)
+        for page, tokens in ((1, idx[:4]), (3, idx[:4]), (2, idx[4:]),
+                             (4, idx[4:])):
+            np.testing.assert_array_equal(k[0, page, :len(tokens)],
+                                          k_new[0, tokens])
+            np.testing.assert_array_equal(v[0, page, :len(tokens)],
+                                          v_new[0, tokens])
+
     def test_lowest_first_deterministic(self):
         c = _cache()
         assert c.allocate(3, owner=1) == [1, 2, 3]
@@ -422,7 +481,9 @@ class TestPagedKVCache:
         pg = np.asarray(pages, np.int32)[idx // 4]
         off = idx % 4
         c.write_tokens(jnp.asarray(k_new), jnp.asarray(k_new), pg, off)
-        got = np.asarray(c.k)[0, pg, off]
+        # two heads: a lane-dense page holds a token's heads side by side
+        assert c.page_layout == "lane_dense" and c.k.shape[-1] == 8
+        got = np.asarray(c.k)[0, pg, off].reshape(k_new[0].shape)
         np.testing.assert_array_equal(got, k_new[0])
 
     def test_defrag_compacts_and_rewrites_lists(self):
@@ -439,12 +500,12 @@ class TestPagedKVCache:
         c.free(b)
         lists = [a, cc]
         old_live = set(a) | set(cc)
-        before = [[int(np.asarray(c.k)[0, p, 0, 0, 0]) for p in lst]
+        before = [[int(np.asarray(c.k)[0, p].flat[0]) for p in lst]
                   for lst in lists]
         mapping = c.defrag(lists)
         # live pages now occupy the dense prefix 1..4, lists rewritten
         assert sorted(p for lst in lists for p in lst) == [1, 2, 3, 4]
-        after = [[int(np.asarray(c.k)[0, p, 0, 0, 0]) for p in lst]
+        after = [[int(np.asarray(c.k)[0, p].flat[0]) for p in lst]
                  for lst in lists]
         assert before == after  # content moved with the ids
         assert set(mapping) == old_live  # only live pages map

@@ -274,6 +274,28 @@ def test_the_decode_kernel_serves_what_the_xla_route_serves(params):
     assert out[0] == out[1]
 
 
+def test_the_lane_dense_pool_serves_what_a_head_split_pool_serves(
+        params, monkeypatch):
+    """Two K/V heads fill no sublane tile: the page pool keeps a page
+    ``[page_size, 2 * 128]`` and the kernel reads a head as a view.  The
+    same engine over the same numbers stored ``[page_size, 2, 128]``
+    serves the same tokens."""
+    from apex_tpu.serving import kv_cache
+
+    out = {}
+    for layout in ("lane_dense", "heads"):
+        if layout == "heads":
+            monkeypatch.setattr(kv_cache, "lane_dense_pages",
+                                lambda *a: False)
+        with routing_override(decode="decode"):
+            eng = engine(params)
+            assert eng.cache.page_layout == layout
+            reqs = [eng.submit(p, 7) for p in prompts(11, (8, 30))]
+            eng.run()
+        out[layout] = [r.generated for r in reqs]
+    assert out["lane_dense"] == out["heads"]
+
+
 # -- (b) the latent expert layer ----------------------------------------------
 
 def test_the_four_shares_add_up_to_the_uncut_layer():

@@ -124,6 +124,10 @@ CELL_GEOMETRIES = {
     # of 64, stored 128 wide
     "granite_decode": (64, 32, 1, (4, 1600, 64, 8, 128), 32, None),
     "granite_chunk": (1, 32, 1024, (4, 1600, 64, 8, 128), 32, None),
+    # nemotron-3-super-serve-ep4-l11.json: 32 query heads over 2 K/V
+    # heads of 128, a page kept lane-dense (2 x 128 lanes a token)
+    "nemotron_decode": (64, 32, 1, (1, 1600, 64, 256), 32, None),
+    "nemotron_chunk": (1, 32, 1024, (1, 1600, 64, 256), 32, None),
 }
 
 
